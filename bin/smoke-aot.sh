@@ -52,7 +52,7 @@ sys.stdout.write(urllib.request.urlopen(sys.argv[1], timeout=float(sys.argv[2]))
 echo "== serve-aot-build (populate the executable store) =="
 JAX_PLATFORMS=cpu PYTHONPATH="$ROOT" \
     KEYSTONE_AOT_CACHE="$AOT_DIR" \
-    KEYSTONE_COMPILE_CACHE="$TMPDIR/xc-build" \
+    JAX_COMPILATION_CACHE_DIR="$TMPDIR/xc-build" \
     python -m keystone_tpu serve-aot-build "${SHAPE_ARGS[@]}" \
     | tee "$TMPDIR/build.json"
 grep -q '"saved"' "$TMPDIR/build.json" || {
@@ -69,7 +69,7 @@ START_S=$(date +%s)
 # the AOT store, not to replayed XLA cache entries
 JAX_PLATFORMS=cpu PYTHONPATH="$ROOT" \
     KEYSTONE_AOT_CACHE="$AOT_DIR" \
-    KEYSTONE_COMPILE_CACHE="$TMPDIR/xc-fresh" \
+    JAX_COMPILATION_CACHE_DIR="$TMPDIR/xc-fresh" \
     python -m keystone_tpu serve-gateway --gateway-port 0 \
     "${SHAPE_ARGS[@]}" --lanes 2 >"$SERVER_LOG" 2>&1 &
 SERVER_PID=$!

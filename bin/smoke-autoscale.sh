@@ -59,7 +59,7 @@ echo "== serving_autoscale_ramp bench row =="
 # the row carries its own bounded retry; the compile/AOT caches keep
 # per-replica warmup (which the scale-up reaction time includes) short
 if ! JAX_PLATFORMS=cpu PYTHONPATH="$ROOT" \
-    KEYSTONE_COMPILE_CACHE="$TMPDIR/xc" KEYSTONE_AOT_CACHE="$AOT_CACHE" \
+    JAX_COMPILATION_CACHE_DIR="$TMPDIR/xc" KEYSTONE_AOT_CACHE="$AOT_CACHE" \
     python -m keystone_tpu serve-bench --autoscale-only \
     | tee "$BENCH_LOG" \
     || ! grep '"metric": "serving_autoscale_ramp"' "$BENCH_LOG" \
@@ -71,7 +71,7 @@ echo "PASS serving_autoscale_ramp (scale-out, green verdict, scale-down)"
 # ---- 2. the subprocess drill ----------------------------------------------
 echo "== serve-autoscale: router + subprocess replicas =="
 JAX_PLATFORMS=cpu PYTHONPATH="$ROOT" \
-    KEYSTONE_COMPILE_CACHE="$TMPDIR/xc" \
+    JAX_COMPILATION_CACHE_DIR="$TMPDIR/xc" \
     python -m keystone_tpu serve-autoscale \
     --min-replicas 1 --max-replicas 3 \
     --slo-latency-ms 200 --slo-fast-window 6 --slo-sample-interval 0.5 \

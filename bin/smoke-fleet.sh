@@ -149,7 +149,7 @@ start_replica() {  # start_replica <logfile> <extra args...>
         "${GW_ARGS[@]}" --register "$ROUTER" "$@" >"$log" 2>&1 &
 }
 
-KEYSTONE_COMPILE_CACHE="$TMPDIR/xc1" start_replica "$R1_LOG"
+JAX_COMPILATION_CACHE_DIR="$TMPDIR/xc1" start_replica "$R1_LOG"
 R1_PID=$!
 R1="$(wait_listen "$R1_LOG" "$R1_PID" replica1)"
 # replica 1 fully warm (and the shared AOT store populated) BEFORE
@@ -160,7 +160,7 @@ for _ in $(seq 1 240); do
 done
 echo "replica1 up on $R1 (cold start populated $AOT_CACHE)"
 
-KEYSTONE_COMPILE_CACHE="$TMPDIR/xc2" start_replica "$R2_LOG"
+JAX_COMPILATION_CACHE_DIR="$TMPDIR/xc2" start_replica "$R2_LOG"
 R2_PID=$!
 R2="$(wait_listen "$R2_LOG" "$R2_PID" replica2)"
 for _ in $(seq 1 240); do
@@ -228,7 +228,7 @@ echo "PASS /fleetz shows killed replica unhealthy"
 # ---- 4. restart at the SAME port; half-open recovery -----------------------
 echo "== restart replica1; half-open recovery =="
 R1_PORT="${R1##*:}"
-KEYSTONE_COMPILE_CACHE="$TMPDIR/xc1" start_replica "$R1_LOG.2" \
+JAX_COMPILATION_CACHE_DIR="$TMPDIR/xc1" start_replica "$R1_LOG.2" \
     --gateway-port "$R1_PORT"
 R1_PID=$!
 for _ in $(seq 1 240); do

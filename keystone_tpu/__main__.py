@@ -300,8 +300,9 @@ def main(argv=None) -> int:
         return 2
     import importlib
 
-    # join the multi-host runtime when launched as one process per pod
-    # host (no-op on a single host; see parallel/runtime.py)
+    # join the multi-host runtime when the environment says this is one
+    # process of several; a single host starts nothing and touches no
+    # network (see parallel/runtime.py)
     from keystone_tpu.parallel.runtime import initialize
 
     initialize()

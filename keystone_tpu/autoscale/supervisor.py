@@ -58,6 +58,20 @@ STARTUP_TIMEOUT_S = 180.0
 DRAIN_TIMEOUT_S = 30.0
 
 
+def _last_log_line(log_path: Optional[str]) -> Optional[str]:
+    """The last non-empty line a replica wrote (None without a log)."""
+    if not log_path:
+        return None
+    last = None
+    try:
+        with open(log_path, encoding="utf-8", errors="replace") as f:
+            for line in f:
+                last = line.strip() or last
+    except OSError:
+        return None
+    return last[-500:] if last else None
+
+
 def deregister_replica(
     router_url: str, replica_url: str, timeout_s: float = 5.0
 ) -> bool:
@@ -132,7 +146,9 @@ class SubprocessReplica:
             if self._url is not None:
                 return self._url
             if self.proc.poll() is not None:
-                return None  # died before binding
+                # died before binding; let its last words reach the log
+                self._reader.join(2.0)
+                return None
             self._url_event.clear()
         return self._url
 
@@ -387,9 +403,12 @@ class Supervisor:
         handle = self.launcher.launch(index)
         url = handle.wait_listening(self.startup_timeout_s)
         if url is None:
+            # the child's own last words (e.g. the chip on this host is
+            # already held by another replica) belong in the event
             self._event(
                 "replica_failed_to_start",
                 name=handle.name, pid=handle.pid,
+                reason=_last_log_line(handle.log_path),
             )
             handle.kill()
             return None

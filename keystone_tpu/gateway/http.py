@@ -894,7 +894,10 @@ def main(argv=None) -> int:
 
     import jax.numpy as jnp
 
-    from keystone_tpu.parallel.runtime import setup_compilation_cache
+    from keystone_tpu.parallel.runtime import (
+        claim_accelerator,
+        setup_compilation_cache,
+    )
     from keystone_tpu.serving.bench import build_pipeline
 
     ap = argparse.ArgumentParser(
@@ -1064,6 +1067,10 @@ def main(argv=None) -> int:
                     "serve-aot-build for a zero-compile cold start. "
                     "Ignored under --no-cache")
     args = ap.parse_args(argv)
+    # one process per chip: fail here, naming the holder, rather than
+    # late inside backend init (or hanging) when another replica on
+    # this host already serves from the TPU
+    claim_accelerator()
     if not args.no_cache:
         setup_compilation_cache()
         from keystone_tpu.parallel.runtime import setup_aot_cache

@@ -17,6 +17,7 @@ Images decode to (x=row, y=col, c) float arrays.
 from __future__ import annotations
 
 import dataclasses
+import logging
 
 import numpy as np
 
@@ -27,6 +28,8 @@ from keystone_tpu.loaders.streaming import (
     voc_label_fn,
 )
 from keystone_tpu.parallel.dataset import Dataset
+
+logger = logging.getLogger(__name__)
 
 NUM_IMAGENET_CLASSES = 1000
 
@@ -45,9 +48,14 @@ def ImageNetLoader(location: str, labels_path: str) -> Dataset:
     stream = StreamingImageLoader(
         tar_shard_paths(location, 0, 1), imagenet_label_fn(labels_path)
     )
-    return Dataset.from_items(
-        [LabeledImage(arr, label, name) for name, label, arr in stream.items()]
+    items = [
+        LabeledImage(arr, label, name) for name, label, arr in stream.items()
+    ]
+    logger.info(
+        "ImageNetLoader %s: %d images, decode path %s",
+        location, len(items), dict(stream.decode_counts),
     )
+    return Dataset.from_items(items)
 
 
 def VOCLoader(location: str, labels_path: str) -> Dataset:

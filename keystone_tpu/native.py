@@ -3,20 +3,25 @@
 The reference reaches native code via JNI (utils/external/VLFeat.scala,
 EncEval.scala); here the native layer serves the host input pipeline —
 multi-threaded CSV parsing and CIFAR record decoding — since the compute
-kernels are XLA programs. Falls back to numpy implementations when the
-shared library hasn't been built (``make -C native``); the first import
-attempts the build automatically.
+kernels are XLA programs. The shared libraries are not tracked: the
+first use builds them from ``native/*.cc`` (``make -C native``). When the
+build fails the numpy/PIL implementations take over, and the failure is
+logged as a WARNING with make's stderr and kept for ``status()`` — never
+swallowed.
 """
 
 from __future__ import annotations
 
 import ctypes
+import logging
 import os
 import subprocess
 import threading
-from typing import Optional, Tuple
+from typing import Dict, Optional, Tuple
 
 import numpy as np
+
+logger = logging.getLogger(__name__)
 
 _NATIVE_DIR = os.path.join(os.path.dirname(os.path.dirname(__file__)), "native")
 _LIB_PATH = os.path.join(_NATIVE_DIR, "libkeystone_io.so")
@@ -26,6 +31,7 @@ _lib: Optional[ctypes.CDLL] = None
 _tried = False
 _jpeg_lib: Optional[ctypes.CDLL] = None
 _jpeg_tried = False
+_build_error: Optional[str] = None  # the last failed make, for status()
 # first use commonly happens from inside the streaming loader's decode
 # THREAD pool — without the lock, threads arriving while another is
 # mid-load see tried=True/lib=None and silently take the slow fallback
@@ -81,13 +87,21 @@ def _build_once() -> None:
 
 
 def _load_locked() -> Optional[ctypes.CDLL]:
-    global _lib
+    global _lib, _build_error
     if (not os.path.exists(_LIB_PATH) or _is_stale()) and os.path.exists(
         os.path.join(_NATIVE_DIR, "Makefile")
     ):
         try:
             _build_once()
-        except Exception:
+        except (OSError, subprocess.SubprocessError) as e:
+            stderr = getattr(e, "stderr", None) or b""
+            if isinstance(stderr, bytes):
+                stderr = stderr.decode(errors="replace")
+            _build_error = f"{e}: {stderr.strip()[-800:]}"
+            logger.warning(
+                "native build failed; the numpy/PIL implementations "
+                "take over: %s", _build_error,
+            )
             if not os.path.exists(_LIB_PATH):
                 return None
             # rebuild failed but a previously built library exists: load
@@ -191,6 +205,17 @@ def _load_jpeg_locked() -> Optional[ctypes.CDLL]:
 
 def native_available() -> bool:
     return _load() is not None
+
+
+def status() -> Dict[str, Optional[str]]:
+    """Which implementation each native entry point resolved to (this
+    triggers the build on first use) and the build failure, if there
+    was one — for start-up logs; ``chip_smoke.py`` prints it."""
+    return {
+        "io": "native" if _load() is not None else "numpy",
+        "jpeg": "native" if _load_jpeg() is not None else "PIL",
+        "build_error": _build_error,
+    }
 
 
 def jpeg_native_available() -> bool:

@@ -84,9 +84,9 @@ def fisher_vector_stats_pallas(
     """x: (d, m) descriptors -> (s0 (k,), s1 (d, k), s2 (d, k)), each
     already divided by m (the FisherVector.scala:33-41 statistics, with
     the GMM's posterior thresholding applied). ``interpret=None``
-    auto-selects the backend: Mosaic-compiled on TPU, the Pallas
-    interpreter elsewhere (``pallas_kernels.auto_interpret``) — callers
-    no longer carry their own backend check."""
+    follows the backend: Mosaic-compiled on ``tpu``, the Pallas
+    interpreter on ``cpu``, an error anywhere else
+    (``pallas_kernels.auto_interpret``)."""
     interpret = auto_interpret(interpret)
     d, m = x.shape
     k = means.shape[1]

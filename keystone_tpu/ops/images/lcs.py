@@ -13,8 +13,8 @@ sample folds into one per-axis SAMPLING MATRIX applied as MXU GEMMs
 ``_sampling_matrix``), once on the image for means and once on its
 square for the variances. No convs, no gathers. The GEMM pair runs as
 the ``pallas_kernels.plane_sandwich`` kernel — each channel plane
-(image and image² stacked) stays VMEM-resident between its two dots,
-with interpret-mode fallback keeping CPU CI on the same dataflow.
+(image and image² stacked) stays VMEM-resident between its two dots
+(Mosaic-compiled on a TPU; the CPU backend interprets the same kernel).
 """
 
 from __future__ import annotations

@@ -17,8 +17,9 @@ same VMEM-residency move ``fv_pallas`` makes for the FV statistics:
 - ``plane_sandwich``: the plain sandwich for LCS box-mean/variance
   extraction (image and image² share the chain as stacked planes).
 
-Both run under ``interpret=True`` off-TPU (``auto_interpret``), so
-CPU tier-1/CI exercises the exact kernel dataflow; both batch cleanly
+Both run under ``interpret=True`` on the CPU backend
+(``auto_interpret``), so CPU tier-1 exercises the exact kernel
+dataflow, and Mosaic-compiled on TPU; both batch cleanly
 under ``vmap`` (pallas_call's batching rule folds the batch into the
 grid), which is how the bucket-vmapped extractors drive them. Dots
 pin f32 HIGHEST precision — the extractors' parity tolerances
@@ -41,13 +42,23 @@ _HP = jax.lax.Precision.HIGHEST
 
 def auto_interpret(interpret: Optional[bool] = None) -> bool:
     """Resolve an ``interpret`` flag: ``None`` selects the Mosaic
-    compile path on TPU and the Pallas interpreter everywhere else —
-    kernels stay drop-in on CPU/GPU CI without caller-side backend
-    checks. Resolved at trace time, so a jitted caller bakes the
-    choice into its program like any other static."""
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return bool(interpret)
+    compile path on ``tpu`` and the Pallas interpreter on ``cpu``; any
+    other backend raises — these are TPU kernels, and interpreting
+    them silently on an unknown accelerator would hide the device.
+    Resolved at trace time, so a jitted caller bakes the choice into
+    its program like any other static."""
+    if interpret is not None:
+        return bool(interpret)
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(
+        f"Pallas TPU kernels compile on 'tpu' and interpret on 'cpu'; "
+        f"the default backend is {backend!r} — pass interpret= "
+        "explicitly to run them there"
+    )
 
 
 def _sift_bin_sample_kernel(

@@ -29,16 +29,15 @@ bin-center sampling + Gaussian window factors) folds into two small
 per-scale SAMPLING MATRICES applied as MXU GEMMs. The stage is linear
 in the orientation planes and separable per axis, so
 ``A[y, f·4+j] = tri(y − (bound + f·step + j·bin)) · wf[j]`` expresses
-tri-conv→sample→window exactly; measured ~5× over the
-conv→strided-slice formulation on the v5e (SIFT device time ~110 →
-~22 ms per 128×256² batch; the C=1 depthwise convs ran on the VPU and
-the slicing materialized awkwardly-tiled intermediates), lifting the
-flagship featurize row from 889 to 1806 ex/s/chip (PERF_r05.md).
-The binning+GEMM hot loop itself runs as the ``pallas_kernels.
-sift_bin_sample`` kernel: the trilinear orientation scatter and both
-sampling-matrix contractions fuse in VMEM, so the (8, H, W) plane
-stack never hits HBM (interpret-mode fallback keeps CPU CI on the
-same dataflow). Static shapes per (W, H, scale).
+tri-conv→sample→window exactly (the C=1 depthwise convs it replaced
+ran on the VPU and the slicing materialized awkwardly-tiled
+intermediates). The binning+GEMM hot loop itself runs as the
+``pallas_kernels.sift_bin_sample`` kernel: the trilinear orientation
+scatter and both sampling-matrix contractions fuse in VMEM, so the
+(8, H, W) plane stack never hits HBM (Mosaic-compiled on a TPU; the
+CPU backend interprets the same kernel). Neither formulation has a
+number from the current machine (ROADMAP S1). Static shapes per
+(W, H, scale).
 """
 
 from __future__ import annotations

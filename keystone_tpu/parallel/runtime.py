@@ -24,16 +24,20 @@ tpu-vm ssh --worker=all``)::
     python -m keystone_tpu TimitPipeline --trainLocation gs://... \
         # jax.distributed auto-detects coordinator/process ids on TPU VMs
 
-On TPU VMs ``initialize()`` needs no arguments (cluster metadata supplies
-coordinator address / process count). On CPU/GPU clusters pass them
-explicitly or via env (COORDINATOR_ADDRESS, NUM_PROCESSES, PROCESS_ID).
+``initialize()`` decides from the environment alone (see its
+docstring): an explicit COORDINATOR_ADDRESS / NUM_PROCESSES /
+PROCESS_ID trio, a pod environment (``bin/run-pod`` exports
+``KEYSTONE_POD=1``; cluster metadata then supplies coordinator address
+and process count), or a single host that starts no distributed
+runtime and touches no network.
 """
 
 from __future__ import annotations
 
 import logging
 import os
-from typing import Optional, Sequence, Tuple
+import sys
+from typing import IO, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -45,61 +49,59 @@ logger = logging.getLogger(__name__)
 
 DCN_AXIS = "dcn"
 
-_initialized = False
+# the fixed in-checkout compile-cache path used when
+# JAX_COMPILATION_CACHE_DIR is unset (gitignored)
+DEFAULT_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    ),
+    ".jax_cache",
+)
+
+# what initialize() decided; None until it ran
+_decision: Optional[str] = None
+
+# host-wide advisory lock a device-serving process holds for its
+# lifetime (claim_accelerator); a fixed path, or the lock would not be
+# host-wide
+CHIP_LOCK_PATH = "/tmp/keystone_tpu_chip.lock"
+_chip_lock: Optional[IO[str]] = None
 _cache_dir: Optional[str] = None
 _aot_dir: Optional[str] = None
 
 
-def setup_compilation_cache(
-    cache_dir: Optional[str] = None,
-    min_compile_time_secs: float = 0.0,
-) -> Optional[str]:
+def setup_compilation_cache(min_compile_time_secs: float = 0.0) -> str:
     """Wire up JAX's persistent XLA compilation cache (idempotent).
 
     A restarted server pays ZERO cold compiles for shapes it has seen:
     ``CompiledPipeline.warmup`` replays each bucket's compile from this
-    on-disk cache instead of re-running XLA (seconds per program). The
-    dir resolves from the argument, ``$KEYSTONE_COMPILE_CACHE``, then
-    ``~/.cache/keystone_tpu/xla``. ``min_compile_time_secs=0`` caches
-    every program — serving wants even fast compiles persisted, unlike
-    one-shot training scripts where tiny entries are churn.
+    on-disk cache instead of re-running XLA (seconds per program).
 
-    Returns the cache dir, or None when this jax build lacks the
-    persistent-cache config knobs (the call is then a no-op — serving
-    still works, restarts just recompile)."""
+    The directory is placeable from outside and nowhere else: when
+    ``JAX_COMPILATION_CACHE_DIR`` is set, jax reads it itself and this
+    function sets no directory; when it is unset the cache lives at the
+    fixed in-checkout path ``DEFAULT_COMPILE_CACHE_DIR``
+    (``<repo>/.jax_cache``) — the path is part of the cache key, so a
+    directory that moved between runs would never hit.
+    ``min_compile_time_secs=0`` caches every program — serving wants
+    even fast compiles persisted, unlike one-shot training scripts
+    where tiny entries are churn.
+
+    Returns the cache dir in use."""
     global _cache_dir
     if _cache_dir is not None:
         return _cache_dir
-    cache_dir = (
-        cache_dir
-        or os.environ.get("KEYSTONE_COMPILE_CACHE")
-        or os.path.join(
-            os.path.expanduser("~"), ".cache", "keystone_tpu", "xla"
-        )
-    )
-    prev_dir = getattr(jax.config, "jax_compilation_cache_dir", None)
-    try:
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = DEFAULT_COMPILE_CACHE_DIR
         os.makedirs(cache_dir, exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update(
-            "jax_persistent_cache_min_compile_time_secs",
-            float(min_compile_time_secs),
-        )
-    except Exception as e:
-        # roll back to the PRE-CALL state so jax config never
-        # contradicts the None return (and a cache the user configured
-        # themselves isn't silently disabled by our failure)
-        try:
-            jax.config.update("jax_compilation_cache_dir", prev_dir)
-        except Exception:
-            pass
-        logger.info("persistent compilation cache unavailable: %s", e)
-        return None
-    try:
-        # cache regardless of entry size where the knob exists
-        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
-    except AttributeError:
-        pass
+    jax.config.update(
+        "jax_persistent_cache_min_compile_time_secs",
+        float(min_compile_time_secs),
+    )
+    # cache regardless of entry size
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
     _cache_dir = cache_dir
     logger.info("persistent compilation cache at %s", cache_dir)
     return cache_dir
@@ -148,10 +150,54 @@ def aot_cache_dir() -> Optional[str]:
     return _aot_dir
 
 
+def claim_accelerator(lock_path: str = CHIP_LOCK_PATH) -> bool:
+    """Take this host's TPU for this process, or fail saying who holds
+    it. A chip belongs to one process at a time: a second process that
+    initializes the backend fails late inside libtpu or hangs waiting.
+    Device-serving entry points (``serve-gateway``) call this BEFORE
+    touching the backend, so N replicas spawned on one host
+    (``autoscale/supervisor.py``) get an immediate error naming the
+    holder instead. Advisory (``flock``), so it only sees processes that
+    also claim; released when the process exits.
+
+    Returns False without locking when this process will not use a TPU
+    (``jax_platforms`` does not list it — CPU tests and smoke drills)."""
+    global _chip_lock
+    if "tpu" not in (jax.config.jax_platforms or "").split(","):
+        return False
+    if _chip_lock is not None:
+        return True
+    import fcntl
+
+    f = open(lock_path, "a+")
+    try:
+        fcntl.flock(f, fcntl.LOCK_EX | fcntl.LOCK_NB)
+    except BlockingIOError:
+        f.seek(0)
+        holder = f.read().strip() or "another process"
+        f.close()
+        raise RuntimeError(
+            f"the TPU on this host is already held by {holder}; a chip "
+            "belongs to one process at a time, so several replicas on "
+            "one host cannot share it — run one device process per "
+            "host (it can drive every chip of the host)"
+        ) from None
+    f.seek(0)
+    f.truncate()
+    f.write(f"pid {os.getpid()} ({' '.join(sys.argv[:3])})")
+    f.flush()
+    _chip_lock = f
+    return True
+
+
 def _looks_like_pod() -> bool:
-    """Whether this host appears to be one of several in a TPU pod /
-    multislice deployment — the situation where silently falling back to
-    single-host mode would make every host train its own model."""
+    """Whether the ENVIRONMENT says this host is one of several in a
+    TPU pod / multislice deployment — the situation where starting
+    single-host would make every host train its own model. Read from
+    variables only, never from the network: several worker hostnames
+    or process addresses, more than one slice, or ``KEYSTONE_POD=1``,
+    which ``bin/run-pod`` exports for Cloud TPU pods whose process grid
+    is known only to the instance metadata server."""
     hosts = os.environ.get("TPU_WORKER_HOSTNAMES", "")
     if "," in hosts:
         return True
@@ -163,7 +209,7 @@ def _looks_like_pod() -> bool:
             return True
     except ValueError:
         pass
-    return False
+    return os.environ.get("KEYSTONE_POD") == "1"
 
 
 def initialize(
@@ -171,26 +217,31 @@ def initialize(
     num_processes: Optional[int] = None,
     process_id: Optional[int] = None,
     local_device_ids: Optional[Sequence[int]] = None,
-) -> None:
-    """Join this process to the multi-host runtime (idempotent).
+) -> str:
+    """Join this process to the multi-host runtime (idempotent), or
+    decide it is a single host. Returns (and logs at INFO) what it
+    decided: ``"explicit"``, ``"pod"`` or ``"single-host"``.
 
-    Wraps ``jax.distributed.initialize``. On Cloud TPU the three
-    arguments are auto-detected from instance metadata; elsewhere they
-    come from the arguments or the COORDINATOR_ADDRESS / NUM_PROCESSES /
-    PROCESS_ID environment variables (the launch script sets these, the
-    way run-pipeline.sh exported SPARK_HOME/KEYSTONE_MEM).
+    The decision is read from arguments and environment alone, so a
+    single-host start never waits on a network:
 
-    Failure contract: a PARTIAL explicit config (some of the three set,
-    the rest missing) raises ``ValueError`` naming what's missing; a
-    complete explicit config that fails to connect raises; with no
-    explicit config, auto-detect failure degrades to single-host ONLY
-    when the host doesn't look like part of a pod — on a configured pod
-    (worker-hostnames/multislice env present) it raises instead of
-    letting every host silently train its own model.
+    - all three of COORDINATOR_ADDRESS / NUM_PROCESSES / PROCESS_ID
+      (arguments or env, set by the launch script the way
+      run-pipeline.sh exported SPARK_HOME/KEYSTONE_MEM): join that
+      rendezvous — ``"explicit"``. Some but not all raises
+      ``ValueError`` naming what's missing.
+    - a pod environment (``_looks_like_pod``): ``jax.distributed``
+      auto-detects the process grid (GKE worker lists, or the Cloud
+      TPU metadata server) — ``"pod"``. A failure there raises instead
+      of letting every host silently train its own model.
+    - neither: ``"single-host"``; ``jax.distributed`` is not called.
+      (Its argument-less auto-detect treats any TPU VM with
+      TPU_WORKER_HOSTNAMES set — even ``localhost`` — as a GKE cluster
+      and queries ``metadata.google.internal`` for the coordinator.)
     """
-    global _initialized
-    if _initialized:
-        return
+    global _decision
+    if _decision is not None:
+        return _decision
     coordinator_address = coordinator_address or os.environ.get(
         "COORDINATOR_ADDRESS"
     )
@@ -212,40 +263,49 @@ def initialize(
             f"{'/'.join(given)} set but {'/'.join(missing)} missing — "
             "set all three of COORDINATOR_ADDRESS / NUM_PROCESSES / "
             "PROCESS_ID (env or arguments), or none of them for "
-            "single-host / TPU-VM auto-detect"
+            "single-host / pod auto-detect"
         )
-    if not given:
-        # single-process (or TPU-VM auto-detect) path
-        try:
-            jax.distributed.initialize()
-        except Exception as e:
-            if _looks_like_pod():
-                raise RuntimeError(
-                    "this host looks like part of a multi-host pod "
-                    "(TPU_WORKER_HOSTNAMES / TPU_PROCESS_ADDRESSES / "
-                    "MEGASCALE_NUM_SLICES env) but "
-                    "jax.distributed.initialize() failed — refusing to "
-                    "fall back to single-host mode, which would train a "
-                    "separate model per host"
-                ) from e
-            logger.info("jax.distributed not initialized (%s); single host", e)
-            _initialized = True
-            return
-    else:
+    if given:
         jax.distributed.initialize(
             coordinator_address=coordinator_address,
             num_processes=num_processes,
             process_id=process_id,
             local_device_ids=local_device_ids,
         )
-    _initialized = True
-    logger.info(
-        "distributed runtime up: process %d/%d, %d local / %d global devices",
-        jax.process_index(),
-        jax.process_count(),
-        jax.local_device_count(),
-        jax.device_count(),
-    )
+        decision = "explicit"
+    elif _looks_like_pod():
+        try:
+            jax.distributed.initialize()
+        except Exception as e:
+            raise RuntimeError(
+                "this host looks like part of a multi-host pod "
+                "(TPU_WORKER_HOSTNAMES / TPU_PROCESS_ADDRESSES / "
+                "MEGASCALE_NUM_SLICES / KEYSTONE_POD env) but "
+                "jax.distributed.initialize() failed — refusing to "
+                "fall back to single-host mode, which would train a "
+                "separate model per host"
+            ) from e
+        decision = "pod"
+    else:
+        decision = "single-host"
+    _decision = decision
+    if decision == "single-host":
+        logger.info(
+            "single host: no COORDINATOR_ADDRESS/NUM_PROCESSES/"
+            "PROCESS_ID and no pod environment; jax.distributed not "
+            "started, no network touched"
+        )
+    else:
+        logger.info(
+            "distributed runtime up (%s): process %d/%d, %d local / "
+            "%d global devices",
+            decision,
+            jax.process_index(),
+            jax.process_count(),
+            jax.local_device_count(),
+            jax.device_count(),
+        )
+    return decision
 
 
 def multislice_shape(

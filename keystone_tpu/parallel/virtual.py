@@ -7,19 +7,14 @@ tests/conftest.py and the driver's ``dryrun_multichip`` entry point so the
 two can't drift.
 
 JAX constraint: ``jax_platforms`` / ``jax_num_cpu_devices`` must be set
-before the backend initializes, and initializing is the only in-process
-way to count real devices. So when the backend is uninitialized we probe
-the real device count in a THROWAWAY SUBPROCESS and only downgrade the
-parent to the virtual CPU platform when the real platform is short.
+before the backend initializes. Provisioning is always the virtual CPU
+platform and never probes for real chips: a chip belongs to one process
+at a time, so a throwaway child that initialized the backend to count
+devices would hold the chip the parent then needs. Real chips are driven
+by ``chip_smoke.py``, which takes whatever ``jax.devices()`` reports.
 """
 
 from __future__ import annotations
-
-import os
-import subprocess
-import sys
-
-_PROBE = "import jax; print(len(jax.devices()))"
 
 
 def backend_initialized() -> bool:
@@ -32,31 +27,13 @@ def backend_initialized() -> bool:
         return False
 
 
-def _probe_real_device_count(timeout: float = 120.0) -> int:
-    """Count devices the parent process would get, in a subprocess so the
-    parent's backend stays uninitialized (and configurable). The probe
-    inherits the environment unchanged — a user-forced JAX_PLATFORMS must
-    be counted the same way the parent will experience it."""
-    try:
-        out = subprocess.run(
-            [sys.executable, "-c", _PROBE],
-            capture_output=True,
-            text=True,
-            timeout=timeout,
-        )
-        return int(out.stdout.strip().splitlines()[-1])
-    except Exception:
-        return 0
-
-
-def provision_devices(n_devices: int, *, probe_real: bool = True) -> None:
-    """Ensure ``jax.devices()`` will return >= n_devices.
-
-    Real devices are preferred: if the default platform already has enough
-    (probed in a subprocess when the backend is uninitialized), it is left
-    untouched. Otherwise the process is switched to a virtual CPU platform
-    with exactly ``n_devices`` devices. Raises if the backend is already
-    initialized with too few devices (too late to reconfigure).
+def provision_devices(n_devices: int) -> None:
+    """Ensure ``jax.devices()`` will return >= n_devices by switching an
+    uninitialized process to a virtual CPU platform with exactly
+    ``n_devices`` devices. An already-initialized backend is kept when
+    it has enough devices (whatever its platform — callers that care
+    print ``jax.devices()[0].platform``); with too few it raises (too
+    late to reconfigure).
     """
     import jax
 
@@ -70,19 +47,8 @@ def provision_devices(n_devices: int, *, probe_real: bool = True) -> None:
             )
         return
 
-    if probe_real and _probe_real_device_count() >= n_devices:
-        return  # real platform suffices; leave config alone
-
     jax.config.update("jax_platforms", "cpu")
-    try:
-        jax.config.update("jax_num_cpu_devices", n_devices)
-    except AttributeError:
-        # older jax: the device count is an XLA flag, honored only if set
-        # before backend init (which provision_devices guarantees)
-        flag = f"--xla_force_host_platform_device_count={n_devices}"
-        flags = os.environ.get("XLA_FLAGS", "")
-        if flag not in flags:
-            os.environ["XLA_FLAGS"] = (flags + " " + flag).strip()
+    jax.config.update("jax_num_cpu_devices", n_devices)
     have = len(jax.devices())
     if have < n_devices:
         raise RuntimeError(

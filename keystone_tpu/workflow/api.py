@@ -49,6 +49,13 @@ from keystone_tpu.workflow.operators import (
 from keystone_tpu.workflow.rules import UnusedBranchRemovalRule
 
 
+# items per jit(vmap) dispatch of one shape group in
+# ``Transformer._bucketed_batch``. Dense SIFT at 256x256 keeps ~20 MB per
+# image in flight, so one dispatch over a whole training set does not fit
+# a 16 GB chip; 128 is the chunk bench.py's flagship rows settled on.
+BUCKET_CHUNK = 128
+
+
 def _array_digest(a: np.ndarray) -> Any:
     """Fixed-size fingerprint of an array's contents. CSE/prefix keys hold
     this digest, never the raw bytes, so key size (and key comparison cost)
@@ -314,10 +321,8 @@ class Transformer(Chainable, TransformerOperator):
 
     vmap_batch: bool = True
     # shape-bucketed vmap for ragged items-mode data: group items by
-    # shape, one jit(vmap) dispatch per group. Per-image host mapping of
-    # featurizers costs ~100 ms/image through a remote dispatch link;
-    # bucketing runs the same code ~35x faster (measured: dense SIFT at
-    # 256x256 — 9.3 imgs/s host-mapped vs 335 imgs/s bucketed).
+    # shape, one jit(vmap) dispatch per group instead of one host-mapped
+    # dispatch chain per image.
     bucket_vmap: bool = False
 
     def apply(self, x: Any) -> Any:  # single datum
@@ -350,9 +355,25 @@ class Transformer(Chainable, TransformerOperator):
         out: List[Any] = [None] * len(items)
         fn = self._jitted_vmap()
         for idxs in by_shape.values():
-            res = fn(jnp.stack([arrays[i] for i in idxs]))
-            for j, i in enumerate(idxs):
-                out[i] = jax.tree_util.tree_map(lambda a, j=j: a[j], res)
+            # a group larger than BUCKET_CHUNK goes through in chunks of
+            # that many items, the tail zero-padded to the same shape:
+            # the featurizer's in-flight intermediates are bounded by
+            # the chunk, not the dataset, and the group still compiles
+            # one program
+            chunk = min(len(idxs), BUCKET_CHUNK)
+            for s in range(0, len(idxs), chunk):
+                part = idxs[s : s + chunk]
+                batch = jnp.stack([arrays[i] for i in part])
+                if len(part) < chunk:
+                    pad = jnp.zeros(
+                        (chunk - len(part),) + batch.shape[1:], batch.dtype
+                    )
+                    batch = jnp.concatenate([batch, pad])
+                res = fn(batch)
+                for j, i in enumerate(part):
+                    out[i] = jax.tree_util.tree_map(
+                        lambda a, j=j: a[j], res
+                    )
         return Dataset.from_items(out)
 
     # TransformerOperator ABI

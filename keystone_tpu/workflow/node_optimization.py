@@ -115,6 +115,11 @@ class NodeOptimizationRule(Rule):
             n_total = collector.true_n(deps[0]) if deps else -1
             new_op = graph.operators[n].optimize(sample_values, n_total)
             if new_op is not None and new_op is not graph.operators[n]:
+                # the node keeps its prefix: the physical choice computes
+                # the same value at the same position, so its result is
+                # saved and reused like any other estimator fit. Dropping
+                # it made every later application of the pipeline refit
+                # its optimizable estimators while loading the others
+                # from the saved state — a model inconsistent with itself.
                 graph = graph.set_operator(n, new_op)
-                prefixes.pop(n, None)
         return graph, prefixes

@@ -15,6 +15,7 @@ class FakeHandle:
         self.index = index
         self.name = f"replica-{index}"
         self.pid = 1000 + index
+        self.log_path = None
         self.url = f"http://127.0.0.1:{9000 + index}"
         self._alive = True
         self.calls: List[str] = []
@@ -238,3 +239,39 @@ def test_concurrent_scale_and_reap_hold_the_target():
     live = [h for h in sup.replicas() if h.alive()]
     assert len(sup.replicas()) == 2, sup.status()
     assert len(live) == 2
+
+
+def test_failed_start_event_carries_the_replicas_last_words(tmp_path):
+    """A replica that dies before its handshake — e.g. serve-gateway
+    refusing to start because the chip on this host is already held —
+    must not fail silently: the supervisor's event quotes the child's
+    last log line."""
+    import subprocess
+    import sys
+
+    from keystone_tpu.autoscale.supervisor import SubprocessReplica
+
+    message = "RuntimeError: the TPU on this host is already held by pid 1"
+
+    class DyingLauncher:
+        self_registering = True
+
+        def launch(self, index):
+            proc = subprocess.Popen(
+                [sys.executable, "-c",
+                 f"print({message!r}); raise SystemExit(1)"],
+                stdout=subprocess.PIPE, stderr=subprocess.STDOUT,
+            )
+            return SubprocessReplica(
+                proc, f"replica-{index}", str(tmp_path / f"r{index}.log")
+            )
+
+    events = []
+    sup = Supervisor(
+        DyingLauncher(), None, startup_timeout_s=30.0,
+        on_event=events.append,
+    )
+    sup.scale_to(1)
+    failed = [e for e in events if e["event"] == "replica_failed_to_start"]
+    assert failed and failed[0]["reason"] == message
+    assert sup.replicas() == []

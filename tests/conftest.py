@@ -22,7 +22,7 @@ from keystone_tpu.parallel.virtual import provision_devices  # noqa: E402
 # TPU-only failures (e.g. DEFAULT-precision f32 matmuls) CPU runs hide.
 _REAL = os.environ.get("KEYSTONE_TPU_TEST_REAL") == "1"
 if not _REAL:
-    provision_devices(8, probe_real=False)
+    provision_devices(8)
 else:
     import jax
 

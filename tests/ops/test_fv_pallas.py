@@ -50,7 +50,7 @@ def test_auto_interpret_parity_vs_numpy_reference():
     """``fisher_vector_stats_pallas`` with NO interpret argument
     anywhere in the call chain: the backend auto-selection
     (``pallas_kernels.auto_interpret``) picks the Pallas interpreter
-    off-TPU, and the auto-selected path matches the INDEPENDENT numpy
+    on the CPU backend, and the auto-selected path matches the INDEPENDENT numpy
     FV reference (test_sift_fv._np_fisher_vector) — parity against the
     spec translation, not merely against the jax program it fuses."""
     import jax
@@ -58,7 +58,7 @@ def test_auto_interpret_parity_vs_numpy_reference():
     from keystone_tpu.ops.images.pallas_kernels import auto_interpret
     from test_sift_fv import _np_fisher_vector
 
-    assert auto_interpret(None) == (jax.default_backend() != "tpu")
+    assert auto_interpret(None) == (jax.default_backend() == "cpu")
 
     gmm = _random_model(d=8, k=32, seed=5)
     rng = np.random.default_rng(6)

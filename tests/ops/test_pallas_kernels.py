@@ -7,6 +7,7 @@ serving engine's fused bucket programs vmap over."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from keystone_tpu.ops.images.pallas_kernels import (
     NUM_ORIENTATIONS,
@@ -16,13 +17,26 @@ from keystone_tpu.ops.images.pallas_kernels import (
 )
 
 
-def test_auto_interpret_follows_backend():
-    """interpret=None resolves from the live backend (Mosaic on TPU,
-    the Pallas interpreter elsewhere); explicit values pass through."""
-    assert auto_interpret() == (jax.default_backend() != "tpu")
-    assert auto_interpret(None) == (jax.default_backend() != "tpu")
+@pytest.mark.parametrize(
+    "backend,want", [("cpu", True), ("tpu", False)]
+)
+def test_auto_interpret_follows_backend(monkeypatch, backend, want):
+    """interpret=None resolves from the live backend: Mosaic on tpu,
+    the Pallas interpreter on cpu; explicit values pass through."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    assert auto_interpret() is want
+    assert auto_interpret(None) is want
     assert auto_interpret(True) is True
     assert auto_interpret(False) is False
+
+
+def test_auto_interpret_rejects_other_backends(monkeypatch):
+    """A backend that is neither tpu nor cpu is an error, not a silent
+    interpret; an explicit flag still passes through."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        auto_interpret()
+    assert auto_interpret(True) is True
 
 
 def test_sift_bin_sample_matches_xla_reference():

@@ -3,6 +3,8 @@
 equivalent: the Spark cluster substrate, SURVEY.md §2.10 comm-backend row;
 multi-host orchestration via jax.distributed)."""
 
+import os
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -78,3 +80,35 @@ def test_initialize_single_host_is_noop():
 
     runtime.initialize()  # no cluster env -> logs and returns
     runtime.initialize()  # idempotent
+
+
+def test_claim_accelerator_one_process_per_chip(tmp_path, monkeypatch):
+    """A process that will use the TPU takes a host-wide lock; a second
+    claimant fails at once with a message naming the holder (N
+    serve-gateway replicas on one host cannot share one chip). A CPU
+    process claims nothing."""
+    import jax
+
+    from keystone_tpu.parallel import runtime
+
+    lock = str(tmp_path / "chip.lock")
+    monkeypatch.setattr(runtime, "_chip_lock", None)
+    assert runtime.claim_accelerator(lock) is False  # JAX_PLATFORMS=cpu
+    assert not os.path.exists(lock)
+
+    saved = jax.config.jax_platforms
+    try:
+        jax.config.update("jax_platforms", "tpu,cpu")
+        assert runtime.claim_accelerator(lock) is True
+        assert runtime.claim_accelerator(lock) is True  # idempotent
+        held = runtime._chip_lock
+        # a second claimant (another replica): a fresh open file
+        # description conflicts exactly like another process would
+        monkeypatch.setattr(runtime, "_chip_lock", None)
+        with pytest.raises(RuntimeError, match=f"pid {os.getpid()}"):
+            runtime.claim_accelerator(lock)
+        held.close()  # the holder exits: the chip is free again
+        assert runtime.claim_accelerator(lock) is True
+        runtime._chip_lock.close()
+    finally:
+        jax.config.update("jax_platforms", saved)

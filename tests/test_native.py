@@ -132,7 +132,8 @@ def test_jpeg_native_matches_pil_draft_path(tmp_path):
         data = _make_jpeg_bytes(w, h, seed)
         for target in (32, 96, 256):
             nat = jpeg_decode_f32(data, target)
-            pil = _decode_payload((data, target), use_native=False)
+            pil, how = _decode_payload((data, target), use_native=False)
+            assert how == "pil"
             assert nat is not None and pil is not None
             assert nat.shape == pil.shape == (target, target, 3)
             d = np.abs(nat - pil)
@@ -194,16 +195,49 @@ def test_streaming_native_decode_matches_pil_decode(tmp_path):
             p.write_bytes(_make_jpeg_bytes(90 + 7 * i, 70 + 5 * i, i))
             tf.add(str(p), arcname=f"m_{i}.JPEG")
 
-    def mk(native):
-        return list(
-            StreamingImageLoader(
-                [str(tar)], lambda name: 0, decode_size=64,
-                use_native_decode=native,
-            ).items()
+    def mk(native, how):
+        loader = StreamingImageLoader(
+            [str(tar)], lambda name: 0, decode_size=64,
+            use_native_decode=native,
         )
+        out = list(loader.items())
+        # the loader reports the decode path it actually took
+        assert dict(loader.decode_counts) == {how: 6}
+        return out
 
-    nat, pil = mk(True), mk(False)
+    nat, pil = mk(True, "native"), mk(False, "pil")
     assert len(nat) == len(pil) == 6
     for (n1, _, a1), (n2, _, a2) in zip(nat, pil):
         assert n1 == n2
         assert np.abs(a1 - a2).max() <= 2.0
+
+
+def test_failed_build_is_reported_not_hidden(monkeypatch, caplog, tmp_path):
+    """A failing make leaves the numpy fallback in charge, but the
+    failure is logged with make's stderr and kept for status()."""
+    import logging
+    import subprocess
+
+    from keystone_tpu import native
+
+    def failing_make():
+        raise subprocess.CalledProcessError(
+            2, ["make"], stderr=b"io.cc:1: error: no such compiler"
+        )
+
+    monkeypatch.setattr(native, "_build_once", failing_make)
+    monkeypatch.setattr(native, "_LIB_PATH", str(tmp_path / "absent.so"))
+    monkeypatch.setattr(
+        native, "_JPEG_LIB_PATH", str(tmp_path / "absent_jpeg.so")
+    )
+    for name in ("_lib", "_jpeg_lib", "_build_error"):
+        monkeypatch.setattr(native, name, None)
+    for name in ("_tried", "_jpeg_tried"):
+        monkeypatch.setattr(native, name, False)
+    with caplog.at_level(logging.WARNING, logger="keystone_tpu.native"):
+        st = native.status()
+    assert st["io"] == "numpy" and st["jpeg"] == "PIL"
+    assert "no such compiler" in st["build_error"]
+    assert any(
+        "native build failed" in r.getMessage() for r in caplog.records
+    )

@@ -216,3 +216,49 @@ def test_rule_swaps_operator_in_graph():
     assert not any(
         isinstance(op, OptimizableE) for op in g2.operators.values()
     )
+
+
+def test_optimized_estimator_fit_is_saved_and_reused():
+    """An Optimizable estimator's fit lands in the saved prefix state
+    like any other estimator's: applying the SAME pipeline to a second
+    dataset (or freezing it with fit()) must not refit it — a refit
+    beside estimators loaded from the saved state would give a model
+    inconsistent with itself (the flagship's PCA/GMM refit on fresh
+    samples while its solver stayed)."""
+    fits = []
+
+    class _Shift(Transformer):
+        def __init__(self, by):
+            self.by = by
+
+        def apply(self, x):
+            return x + self.by
+
+    class _CountingE(Estimator):
+        def fit(self, data):
+            fits.append("physical")
+            return _Shift(float(len(fits)))
+
+    class _OptE(Estimator, Optimizable):
+        def fit(self, data):
+            fits.append("default")
+            return _Shift(-1.0)
+
+        def optimize(self, samples, n_total):
+            return _CountingE()
+
+    train = Dataset.from_array(np.zeros((4, 2), np.float32))
+    pipe = _Shift(0.0).and_then(_OptE(), train)
+    first = np.asarray(
+        pipe(Dataset.from_array(np.ones((3, 2), np.float32))).get().array()
+    )
+    second = np.asarray(
+        pipe(Dataset.from_array(np.ones((5, 2), np.float32))).get().array()
+    )
+    frozen = np.asarray(
+        pipe.fit().apply(
+            Dataset.from_array(np.ones((2, 2), np.float32))
+        ).array()
+    )
+    assert fits == ["physical"]  # fit once, by the optimized operator
+    assert first[0, 0] == second[0, 0] == frozen[0, 0] == 2.0

@@ -247,3 +247,46 @@ def test_fitted_pipeline_jit_batch_matches_executor():
     ref = pipe.apply(Dataset.from_array(x)).get().padded()
     out = pipe.fit().jit_batch()(x)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), atol=1e-6)
+
+
+def test_bucketed_batch_chunks_large_shape_groups(monkeypatch):
+    """A shape group larger than BUCKET_CHUNK runs in chunks of that
+    many items (tail zero-padded to the same shape, so the group still
+    compiles once): results equal the per-item apply, in order, and no
+    dispatch ever sees more than a chunk."""
+    from keystone_tpu.workflow import api
+
+    monkeypatch.setattr(api, "BUCKET_CHUNK", 4)
+    seen = []
+
+    class RowSums(Transformer):
+        vmap_batch = False
+        bucket_vmap = True
+
+        def apply(self, x):
+            return jnp.sum(x, axis=0) + 1.0
+
+        def _jitted_vmap(self):
+            fn = super()._jitted_vmap()
+
+            def spy(batch):
+                seen.append(batch.shape)
+                return fn(batch)
+
+            return spy
+
+    rng = np.random.default_rng(0)
+    # 9 items of one shape (chunks 4+4+1, the tail padded to 4)
+    # interleaved with 4 of another (one dispatch of 4)
+    items = [
+        rng.standard_normal((3, 5) if i % 4 else (2, 7)).astype(np.float32)
+        for i in range(13)
+    ]
+    out = RowSums()._bucketed_batch(Dataset.from_items(items)).items()
+    assert len(out) == 13
+    for x, y in zip(items, out):
+        np.testing.assert_allclose(np.asarray(y), x.sum(0) + 1.0, rtol=1e-6)
+    big = [s for s in seen if s[1:] == (3, 5)]
+    small = [s for s in seen if s[1:] == (2, 7)]
+    assert [s[0] for s in big] == [4, 4, 4]
+    assert [s[0] for s in small] == [4]
