@@ -10,10 +10,12 @@ KeystoneML's operator decisions (auto-caching, solver selection) run on
   ``Counter``/``LatencyRecorder`` primitives in ``utils/profiling.py``.
   ``ServingMetrics`` registers itself here; the executor, auto-cache
   profiler, ``PhaseTimer``, and the request gateway publish here.
-- ``Tracer`` (tracing.py): Dapper-style spans with parent links and a
-  bounded ring of recent spans; Chrome trace-event JSON export for
-  chrome://tracing / Perfetto. Disabled by default (one attribute read
-  per call site when off).
+- ``span`` / ``Tracer`` (tracing.py): the one span call. Always a
+  ``ks:<name>`` TraceMe on the JAX profiler's clock (recorded while a
+  profiler session runs); with ``enable_tracing()`` also a ring of
+  recent spans with parent links, Chrome trace-event JSON export for
+  chrome://tracing / Perfetto. Off, a span costs its TraceMe and no
+  lock.
 - ``AdminServer`` (admin.py): stdlib-http background thread serving
   ``/metrics`` (Prometheus text exposition v0.0.4), ``/varz`` (JSON),
   ``/healthz``, and ``/tracez`` (recent spans). Off unless started —
@@ -77,6 +79,7 @@ from keystone_tpu.observability.tracing import (
     format_traceparent,
     get_tracer,
     parse_traceparent,
+    span,
 )
 
 __all__ = [
@@ -118,6 +121,7 @@ __all__ = [
     "parse_traceparent",
     "phase_decomposition",
     "reset_global_registry",
+    "span",
     "start_admin_server",
     "stop_admin_server",
 ]

@@ -1,26 +1,46 @@
-"""Span tracing: start/end spans with parent links, a bounded ring of
-recent spans, and Chrome trace-event JSON export.
+"""Span tracing: ONE span call that writes to the JAX profiler's trace
+and, when enabled, to a bounded ring of recent spans with parent links
+(``/tracez``, Chrome trace-event JSON export).
 
-Dapper-style application-level spans for the interpret layer — the JAX
-profiler (``utils.profiling.trace``) already covers the XLA/device
-substrate, but nothing records *why* the device was asked to do work:
-which executor node, which serving dispatch, which coalesced window,
-which lane-pipeline stage. Span names in the serving path:
-``gateway.admit`` → ``microbatch.coalesce`` → ``serving.dispatch``
-(serial lanes) or → ``pipeline.host_prep`` / ``pipeline.upload`` /
-``pipeline.compute`` / ``pipeline.deliver`` (staged lanes, one span
-per stage per window, each on its own stage thread).
-Spans nest via a thread-local stack, so a ``serving.dispatch`` span
-started inside a ``microbatch.dispatch`` span carries its parent's id —
-``/tracez`` (observability/admin.py) shows the tree, and
-``to_chrome_trace()`` exports the ring as Chrome trace-event JSON
-(the ``{"traceEvents": [...]}`` object format) loadable in
-chrome://tracing or Perfetto.
+``span(name, **attrs)`` (= ``get_tracer().span``; ``start_span`` /
+``end_span`` where a ``with`` block does not fit) is the one call every
+span of the package goes through. It always enters
+``jax.profiler.TraceAnnotation("ks:" + name)`` — a TraceMe, recorded
+only while a profiler session runs, so the program's spans share the
+clock of the trace's ``XLA Ops`` line and every idle gap of the device
+can be put down to the span open on the host meanwhile
+(``python3 -m benchmark.spans <trace dir>``; XProf shows them on the
+host thread's line). With ``enable_tracing()`` it also records a ring
+``Span`` (parent link from a thread-local stack, trace id, attrs,
+sinks), so ``/tracez`` (observability/admin.py) and
+``to_chrome_trace()`` show the same names without a profiler.
 
-Disabled is the default and costs one attribute read per ``span()``
-call (a shared no-op context manager is returned; nothing is recorded,
-no lock is taken). ``enable_tracing()`` flips the process-global
-tracer on.
+Span names, one per layer boundary and never one per item:
+
+- workflow: ``node:<label>`` around each graph node's own work (its
+  dependencies are forced before it opens), ``workflow.optimize``,
+  ``workflow.upload`` / ``.stack`` / ``.apply`` / ``.slice`` (the phases
+  of ``Transformer._bucketed_batch``, the last three per chunk),
+  ``workflow.map_items`` / ``.to_array`` / ``.to_items`` (``Dataset``);
+- solvers: ``solver.prep``, and per block step ``solver.block_stats``,
+  ``solver.readback``, ``solver.host_solve`` (attr ``fallback``),
+  ``solver.upload``, ``solver.residual_update`` (host solve) or
+  ``solver.block_step`` (device solve);
+- serving: ``gateway.admit`` → ``microbatch.coalesce`` →
+  ``serving.dispatch`` (serial lanes) or → ``pipeline.host_prep`` /
+  ``pipeline.upload`` / ``pipeline.compute`` / ``pipeline.deliver``
+  (staged lanes, one span per stage per window, each on its own stage
+  thread); ``router.forward``, ``lifecycle.*``, ``autoscale.*``.
+
+On the device side the same layers carry ``jax.named_scope`` names
+(``solver.gram``, ``sift.smooth``, ``fv.stats`` ...), which reach the
+trace as each operation's ``tf_op`` metadata.
+
+With the tracer disabled and no profiler session a ``span()`` allocates
+the TraceMe and nothing else: no ring entry, no lock (0.6–0.9 µs on a
+v5e host, PERF.md).
+jax is imported on first use, so this module imports without it (spans
+are then no-ops unless the tracer is enabled).
 """
 
 from __future__ import annotations
@@ -153,6 +173,7 @@ class _ActiveSpan:
 
     __slots__ = (
         "name", "span_id", "parent_id", "trace_id", "attrs", "_t0", "_wall",
+        "_annotation",
     )
 
     def __init__(
@@ -167,15 +188,21 @@ class _ActiveSpan:
         self.parent_id = parent_id
         self.trace_id = trace_id if trace_id is not None else new_trace_id()
         self.attrs = attrs
+        # the profiler's copy opens last and closes first, so the ring's
+        # bookkeeping is not charged to the span in a device trace
+        self._annotation = _profiler_span(
+            PROFILER_PREFIX + name, **attrs
+        ).__enter__()
         self._t0 = time.perf_counter()
         self._wall = time.time()
 
     def set_attr(self, key: str, value: Any) -> None:
         self.attrs[key] = value
+        self._annotation.set_attr(key, value)
 
 
 class _NullSpan:
-    """The shared disabled-path object: every method is a no-op."""
+    """The span where jax cannot be imported: every method is a no-op."""
 
     __slots__ = ()
     span_id = None
@@ -193,6 +220,33 @@ class _NullSpan:
 
 
 _NULL_SPAN = _NullSpan()
+
+PROFILER_PREFIX = "ks:"  # the program's spans in a profiler trace
+
+
+def _profiler_span(name: str, **attrs: Any):
+    """The profiler's copy of a span, not yet entered: a TraceMe (name
+    ``ks:<span name>``, the attrs as its metadata, encoded only while a
+    profiler session runs) that answers the span protocol (``span_id``
+    None, ``set_attr``), so the disabled path returns it as it is. The
+    first call imports jax and puts the class in this function's place."""
+    global _profiler_span
+    try:
+        from jax.profiler import TraceAnnotation
+    except ImportError:
+        _profiler_span = lambda name, **attrs: _NULL_SPAN  # noqa: E731
+    else:
+
+        class _ProfilerSpan(TraceAnnotation):
+            span_id = None
+            parent_id = None
+            trace_id = None
+
+            def set_attr(self, key: str, value: Any) -> None:
+                self.set_metadata(**{key: value})
+
+        _profiler_span = _ProfilerSpan
+    return _profiler_span(name, **attrs)
 
 
 class Tracer:
@@ -244,7 +298,9 @@ class Tracer:
         case; it wins over any inherited/mapped id so a forwarded
         request stays one trace fleet-wide."""
         if not self.enabled:
-            return _NULL_SPAN
+            return _profiler_span(
+                PROFILER_PREFIX + name, **attrs
+            ).__enter__()
         stack = self._stack()
         if parent_id is None:
             if stack:
@@ -266,8 +322,10 @@ class Tracer:
         return span
 
     def end_span(self, span: _ActiveSpan) -> Optional[Span]:
-        if span is _NULL_SPAN:
+        if span.span_id is None:  # tracer off: the profiler's copy alone
+            span.__exit__(None, None, None)
             return None
+        span._annotation.__exit__(None, None, None)
         done = Span(
             name=span.name,
             span_id=span.span_id,
@@ -329,12 +387,13 @@ class Tracer:
         trace_id: Optional[str] = None,
         **attrs: Any,
     ):
-        """``with tracer.span("serving.dispatch", bucket=8):`` — records
-        nothing when the tracer is disabled. ``parent_id`` pins the
-        parent explicitly (cross-thread chains); ``trace_id`` adopts a
-        remote trace identity (cross-process chains)."""
+        """``with tracer.span("serving.dispatch", bucket=8):`` — always a
+        ``ks:serving.dispatch`` TraceMe for a running profiler session;
+        a ring ``Span`` too when the tracer is enabled. ``parent_id``
+        pins the parent explicitly (cross-thread chains); ``trace_id``
+        adopts a remote trace identity (cross-process chains)."""
         if not self.enabled:
-            return _NULL_SPAN
+            return _profiler_span(PROFILER_PREFIX + name, **attrs)
         return self._span_cm(name, parent_id, trace_id, attrs)
 
     def current_span(self):
@@ -403,6 +462,11 @@ def get_tracer() -> Tracer:
     return _global_tracer
 
 
+# ``with span("solver.host_solve"):`` — the process-global tracer's span
+# call, the one every span of the package goes through (module docstring)
+span = _global_tracer.span
+
+
 def enable_tracing(capacity: Optional[int] = None) -> Tracer:
     if capacity is not None:
         # the ring replacement must be atomic with concurrent end_span
@@ -441,6 +505,7 @@ def tracez_document(
 
 __all__ = [
     "DEFAULT_CAPACITY",
+    "PROFILER_PREFIX",
     "Span",
     "TRACEPARENT_HEADER",
     "TRACE_RESPONSE_HEADER",
@@ -452,5 +517,6 @@ __all__ = [
     "get_tracer",
     "new_trace_id",
     "parse_traceparent",
+    "span",
     "tracez_document",
 ]

@@ -35,21 +35,13 @@ def _fisher_vector(fv_self, x):
     formulas (FisherVector.scala:33-52)."""
     gmm = fv_self.gmm
     m = x.shape[1]
-    q = gmm._posteriors(x.T)  # (m, k)
-    s0 = jnp.mean(q, axis=0)  # (k,)
-    s1 = mm(x, q) / m  # (d, k)
-    s2 = mm(x * x, q) / m  # (d, k)
-    means, variances = gmm.means, gmm.variances  # (d, k)
-    weights = gmm.weights  # (k,)
-    fv1 = (s1 - means * s0[None, :]) / (
-        jnp.sqrt(variances) * jnp.sqrt(weights)[None, :]
-    )
-    fv2 = (
-        s2
-        - 2.0 * means * s1
-        + (means * means - variances) * s0[None, :]
-    ) / (variances * jnp.sqrt(2.0 * weights)[None, :])
-    return jnp.concatenate([fv1, fv2], axis=1)  # (d, 2k)
+    with jax.named_scope("fv.posteriors"):
+        q = gmm._posteriors(x.T)  # (m, k)
+    with jax.named_scope("fv.stats"):
+        s0 = jnp.mean(q, axis=0)  # (k,)
+        s1 = mm(x, q) / m  # (d, k)
+        s2 = mm(x * x, q) / m  # (d, k)
+    return _fv_from_stats(gmm, s0, s1, s2)
 
 
 def _fv_from_stats(gmm, s0, s1, s2):
@@ -57,15 +49,16 @@ def _fv_from_stats(gmm, s0, s1, s2):
     (FisherVector.scala:42-52)."""
     means, variances = gmm.means, gmm.variances  # (d, k)
     weights = gmm.weights  # (k,)
-    fv1 = (s1 - means * s0[None, :]) / (
-        jnp.sqrt(variances) * jnp.sqrt(weights)[None, :]
-    )
-    fv2 = (
-        s2
-        - 2.0 * means * s1
-        + (means * means - variances) * s0[None, :]
-    ) / (variances * jnp.sqrt(2.0 * weights)[None, :])
-    return jnp.concatenate([fv1, fv2], axis=1)  # (d, 2k)
+    with jax.named_scope("fv.normalize"):
+        fv1 = (s1 - means * s0[None, :]) / (
+            jnp.sqrt(variances) * jnp.sqrt(weights)[None, :]
+        )
+        fv2 = (
+            s2
+            - 2.0 * means * s1
+            + (means * means - variances) * s0[None, :]
+        ) / (variances * jnp.sqrt(2.0 * weights)[None, :])
+        return jnp.concatenate([fv1, fv2], axis=1)  # (d, 2k)
 
 
 @dataclasses.dataclass(eq=False)
@@ -101,10 +94,11 @@ class FisherVectorFused(Transformer):
         )
 
         g = self.gmm
-        s0, s1, s2 = fisher_vector_stats_pallas(
-            jnp.asarray(x, jnp.float32), g.means, g.variances, g.weights,
-            g.weight_threshold,
-        )
+        with jax.named_scope("fv.stats"):
+            s0, s1, s2 = fisher_vector_stats_pallas(
+                jnp.asarray(x, jnp.float32), g.means, g.variances,
+                g.weights, g.weight_threshold,
+            )
         return _fv_from_stats(g, s0, s1, s2)
 
     def apply_batch(self, ds: Dataset) -> Dataset:

@@ -108,13 +108,15 @@ class LCSExtractor(Transformer):
         # planes through the Pallas sandwich kernel; HIGHEST-precision
         # dots in-kernel — validated at 1e-4 vs the naive translation,
         # TPU DEFAULT lands at ~1e-3)
-        z = jnp.concatenate([img, img * img], axis=-1)
-        out = plane_sandwich(
-            jnp.transpose(z, (2, 0, 1)), jnp.asarray(Ax.T.copy()), Ay
-        )
-        both = jnp.transpose(out, (1, 2, 0))  # (nxk·nb, nyk·nb, 2C)
-        m, sq = both[..., :C], both[..., C:]
-        sd = jnp.sqrt(jnp.maximum(sq - m * m, 0.0))
+        with jax.named_scope("lcs.box_sample"):
+            z = jnp.concatenate([img, img * img], axis=-1)
+            out = plane_sandwich(
+                jnp.transpose(z, (2, 0, 1)), jnp.asarray(Ax.T.copy()), Ay
+            )
+        with jax.named_scope("lcs.moments"):
+            both = jnp.transpose(out, (1, 2, 0))  # (nxk·nb, nyk·nb, 2C)
+            m, sq = both[..., :C], both[..., C:]
+            sd = jnp.sqrt(jnp.maximum(sq - m * m, 0.0))
 
         nxk, nyk, nb = len(xs), len(ys), len(offs)
 
@@ -124,5 +126,6 @@ class LCSExtractor(Transformer):
             z = z.reshape(nxk, nb, nyk, nb, C)
             return jnp.transpose(z, (4, 1, 3, 0, 2))  # (C, nbx, nby, xk, yk)
 
-        inter = jnp.stack([arrange(m), arrange(sd)], axis=3)
-        return inter.reshape(-1, nxk * nyk)
+        with jax.named_scope("lcs.arrange"):
+            inter = jnp.stack([arrange(m), arrange(sd)], axis=3)
+            return inter.reshape(-1, nxk * nyk)

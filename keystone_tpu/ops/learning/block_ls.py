@@ -21,6 +21,15 @@ so the reference's executor-GEMM â†’ treeReduce â†’ driver-solve â†’ broadcast â
 residual-update round trip collapses into two XLA programs around one small
 host solve; the O(nÂ·bÂ·(b+k)) work never leaves the device, and the residual
 buffer is donated to avoid an HBM copy per block.
+
+Observability: host spans ``solver.prep``, then per block step
+``solver.block_stats`` â†’ ``solver.readback`` â†’ ``solver.host_solve`` â†’
+``solver.upload`` â†’ ``solver.residual_update`` (``solve="host"``) or
+``solver.block_step`` (device solve); on the device ``jax.named_scope``
+names ``solver.residual_plus`` / ``solver.gram`` / ``solver.rhs`` /
+``solver.solve`` / ``solver.residual`` / ``solver.prep``; counters
+``keystone_solver_fits_total``, ``_block_steps_total``,
+``_gram_builds_total`` (hostsolve.py has the host solve's).
 """
 
 from __future__ import annotations
@@ -34,6 +43,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from keystone_tpu.observability.registry import get_global_registry
+from keystone_tpu.observability.tracing import span
 from keystone_tpu.parallel.dataset import Dataset
 from keystone_tpu.workflow.api import LabelEstimator, Transformer
 from keystone_tpu.ops.learning.hostsolve import psd_solve_host
@@ -141,15 +152,31 @@ def _block_step(X, R, Wb, mu, mask, start, lam, *, width: int, n: int,
     if first_pass:
         R_plus = R
     else:
-        contrib = _f32_mm(Xb, Wb) - mask[:, None] * _f32_mm(mu_b, Wb)
-        R_plus = R + contrib
-    gram = _f32_mm(Xb.T, Xb) - n * jnp.outer(mu_b, mu_b)
-    rhs = _f32_mm(Xb.T, R_plus) - jnp.outer(mu_b, jnp.sum(R_plus, axis=0))
-    Wb_new = _psd_solve_device(gram, rhs, lam)
+        with jax.named_scope("solver.residual_plus"):
+            contrib = _f32_mm(Xb, Wb) - mask[:, None] * _f32_mm(mu_b, Wb)
+            R_plus = R + contrib
+    gram, rhs = _gram_rhs(Xb, mu_b, R_plus, n)
+    with jax.named_scope("solver.solve"):
+        Wb_new = _psd_solve_device(gram, rhs, lam)
     if last_pass:
         return Wb_new, R_plus
-    contrib_new = _f32_mm(Xb, Wb_new) - mask[:, None] * _f32_mm(mu_b, Wb_new)
-    return Wb_new, R_plus - contrib_new
+    with jax.named_scope("solver.residual"):
+        contrib_new = (
+            _f32_mm(Xb, Wb_new) - mask[:, None] * _f32_mm(mu_b, Wb_new)
+        )
+        return Wb_new, R_plus - contrib_new
+
+
+def _gram_rhs(Xb, mu_b, R_plus, n):
+    """The block's centered Gram and right-hand side (traced inside the
+    block programs), under the names a device trace finds them by."""
+    with jax.named_scope("solver.gram"):
+        gram = _f32_mm(Xb.T, Xb) - n * jnp.outer(mu_b, mu_b)
+    with jax.named_scope("solver.rhs"):
+        rhs = _f32_mm(Xb.T, R_plus) - jnp.outer(
+            mu_b, jnp.sum(R_plus, axis=0)
+        )
+    return gram, rhs
 
 
 @partial(jax.jit, static_argnames=("width", "n"), donate_argnums=(1,))
@@ -166,10 +193,10 @@ def _block_stats(X, R, Wb, mu, mask, start, *, width: int, n: int):
     """
     Xb = jax.lax.dynamic_slice_in_dim(X, start, width, axis=1)
     mu_b = jax.lax.dynamic_slice_in_dim(mu, start, width)
-    contrib = _f32_mm(Xb, Wb) - mask[:, None] * _f32_mm(mu_b, Wb)
-    R_plus = R + contrib
-    gram = _f32_mm(Xb.T, Xb) - n * jnp.outer(mu_b, mu_b)
-    rhs = _f32_mm(Xb.T, R_plus) - jnp.outer(mu_b, jnp.sum(R_plus, axis=0))
+    with jax.named_scope("solver.residual_plus"):
+        contrib = _f32_mm(Xb, Wb) - mask[:, None] * _f32_mm(mu_b, Wb)
+        R_plus = R + contrib
+    gram, rhs = _gram_rhs(Xb, mu_b, R_plus, n)
     return gram, rhs, R_plus
 
 
@@ -177,8 +204,11 @@ def _block_stats(X, R, Wb, mu, mask, start, *, width: int, n: int):
 def _residual_update(X, R_plus, Wb_new, mu, mask, start, *, width: int):
     Xb = jax.lax.dynamic_slice_in_dim(X, start, width, axis=1)
     mu_b = jax.lax.dynamic_slice_in_dim(mu, start, width)
-    contrib = _f32_mm(Xb, Wb_new) - mask[:, None] * _f32_mm(mu_b, Wb_new)
-    return R_plus - contrib
+    with jax.named_scope("solver.residual"):
+        contrib = (
+            _f32_mm(Xb, Wb_new) - mask[:, None] * _f32_mm(mu_b, Wb_new)
+        )
+        return R_plus - contrib
 
 
 @jax.jit
@@ -201,8 +231,9 @@ def _centered_labels(Y, mu_y, mask):
 def _prep(X, Y, mask, n):
     """Means + centered residual in ONE dispatch (the Y pass for mu_y
     and the centering write share one program so XLA can fuse them)."""
-    mu, mu_y = _column_means.__wrapped__(X, Y, mask, n)
-    return mu, mu_y, _centered_labels.__wrapped__(Y, mu_y, mask)
+    with jax.named_scope("solver.prep"):
+        mu, mu_y = _column_means.__wrapped__(X, Y, mask, n)
+        return mu, mu_y, _centered_labels.__wrapped__(Y, mu_y, mask)
 
 
 @jax.jit
@@ -238,15 +269,19 @@ def _host_block_step(Xb, R, Wb, mu_b, mask, lam, *, n: int,
         )
         R_plus = R  # this block's model is exactly zero on sweep 0
     else:
-        contrib = _f32_mm(Xb, Wb) - mask[:, None] * _f32_mm(mu_b, Wb)
-        R_plus = R + contrib
-    gram = _f32_mm(Xb.T, Xb) - n * jnp.outer(mu_b, mu_b)
-    rhs = _f32_mm(Xb.T, R_plus) - jnp.outer(mu_b, jnp.sum(R_plus, axis=0))
-    Wb_new = _psd_solve_device(gram, rhs, lam)
+        with jax.named_scope("solver.residual_plus"):
+            contrib = _f32_mm(Xb, Wb) - mask[:, None] * _f32_mm(mu_b, Wb)
+            R_plus = R + contrib
+    gram, rhs = _gram_rhs(Xb, mu_b, R_plus, n)
+    with jax.named_scope("solver.solve"):
+        Wb_new = _psd_solve_device(gram, rhs, lam)
     if last_pass:
         return Wb_new, R_plus, mu_b
-    contrib_new = _f32_mm(Xb, Wb_new) - mask[:, None] * _f32_mm(mu_b, Wb_new)
-    return Wb_new, R_plus - contrib_new, mu_b
+    with jax.named_scope("solver.residual"):
+        contrib_new = (
+            _f32_mm(Xb, Wb_new) - mask[:, None] * _f32_mm(mu_b, Wb_new)
+        )
+        return Wb_new, R_plus - contrib_new, mu_b
 
 
 @partial(jax.jit, static_argnames=("n",), donate_argnums=(1,))
@@ -258,6 +293,29 @@ def _host_block_rebuild(Xb, R, Wb, mask, *, n: int):
     mu_b = jnp.sum(Xb.astype(jnp.float32) * mask[:, None], axis=0) / n
     contrib = _f32_mm(Xb, Wb) - mask[:, None] * _f32_mm(mu_b, Wb)
     return R - contrib, mu_b
+
+
+def _count_fit() -> Callable[[], None]:
+    """Count one fit started; the callable it returns counts one block
+    step of that fit, and the one Gram every block step builds."""
+    reg = get_global_registry()
+    reg.counter(
+        "keystone_solver_fits_total", "block least-squares fits started"
+    ).inc()
+    steps = reg.counter(
+        "keystone_solver_block_steps_total",
+        "block coordinate descent block updates",
+    )
+    grams = reg.counter(
+        "keystone_solver_gram_builds_total",
+        "block Gram matrices built on the device",
+    )
+
+    def count_step() -> None:
+        steps.inc()
+        grams.inc()
+
+    return count_step
 
 
 def _force_sync(x) -> None:
@@ -432,15 +490,17 @@ class BlockLeastSquaresEstimator(LabelEstimator):
         # mathematically identical) happens algebraically inside the Gram
         # math â€” X is never copied, so bf16 feature matrices of HBM scale
         # pass through untouched.
-        data = data.to_array_mode()
-        labels = labels.to_array_mode()
-        X = data.padded()
-        Y = labels.padded()
-        n = data.n
-        D = X.shape[1]
-        k = Y.shape[1]
-        mask = data.mask()
-        mu, mu_y, R = _prep(X, Y, mask, n)
+        count_step = _count_fit()
+        with span("solver.prep"):
+            data = data.to_array_mode()
+            labels = labels.to_array_mode()
+            X = data.padded()
+            Y = labels.padded()
+            n = data.n
+            D = X.shape[1]
+            k = Y.shape[1]
+            mask = data.mask()
+            mu, mu_y, R = _prep(X, Y, mask, n)
 
         blocks = [
             (s, min(s + self.block_size, D) - s)
@@ -491,23 +551,31 @@ class BlockLeastSquaresEstimator(LabelEstimator):
                 # zero in every path (including checkpoint resume: only
                 # never-completed blocks are revisited in sweep 0), so
                 # the old-contribution matmul is elided.
-                Wb[s], R = _block_step(
-                    X, R, Wb[s], mu, mask, s, self.lam,
-                    width=w, n=n, first_pass=(it == 0),
-                    last_pass=(
-                        it == self.num_iter - 1 and pos == len(blocks) - 1
-                    ),
-                )
+                with span("solver.block_step"):
+                    Wb[s], R = _block_step(
+                        X, R, Wb[s], mu, mask, s, self.lam,
+                        width=w, n=n, first_pass=(it == 0),
+                        last_pass=(
+                            it == self.num_iter - 1
+                            and pos == len(blocks) - 1
+                        ),
+                    )
             else:
-                gram, rhs, R_plus = _block_stats(
-                    X, R, Wb[s], mu, mask, s, width=w, n=n
-                )
+                with span("solver.block_stats"):
+                    gram, rhs, R_plus = _block_stats(
+                        X, R, Wb[s], mu, mask, s, width=w, n=n
+                    )
                 # (b,b) solve on host in f64 (reference: driver-side
-                # NormalEquations solve) â€” see hostsolve.py.
-                Wb[s] = jnp.asarray(psd_solve_host(gram, rhs, self.lam))
-                R = _residual_update(
-                    X, R_plus, Wb[s], mu, mask, s, width=w
-                )
+                # NormalEquations solve) â€” see hostsolve.py, which has
+                # the solver.readback and solver.host_solve spans.
+                W_host = psd_solve_host(gram, rhs, self.lam)
+                with span("solver.upload"):
+                    Wb[s] = jnp.asarray(W_host)
+                with span("solver.residual_update"):
+                    R = _residual_update(
+                        X, R_plus, Wb[s], mu, mask, s, width=w
+                    )
+            count_step()
             done += 1
             if ckpt is not None:
                 ckpt.tick(lambda: snapshot(*nxt))
@@ -538,6 +606,7 @@ class BlockLeastSquaresEstimator(LabelEstimator):
         The data-blocking ignores ``self.block_size``: the dataset's own
         block layout IS the coordinate-descent blocking (matching the
         reference, where the Seq of feature RDDs defines the blocks)."""
+        count_step = _count_fit()
         blocks = data.host_blocks
         widths = data.block_widths
         n = data.n
@@ -617,13 +686,15 @@ class BlockLeastSquaresEstimator(LabelEstimator):
                 if mu_bs[bi] is not None
                 else jnp.zeros((widths[bi],), jnp.float32)
             )
-            Wb[bi], R, mu_bs[bi] = _host_block_step(
-                Xb, R, Wb[bi], mu_arg, mask, self.lam, n=n,
-                first_pass=first,
-                last_pass=(
-                    it == self.num_iter - 1 and bi == nb - 1
-                ),
-            )
+            with span("solver.block_step"):
+                Wb[bi], R, mu_bs[bi] = _host_block_step(
+                    Xb, R, Wb[bi], mu_arg, mask, self.lam, n=n,
+                    first_pass=first,
+                    last_pass=(
+                        it == self.num_iter - 1 and bi == nb - 1
+                    ),
+                )
+            count_step()
             del Xb  # release this slab's HBM as soon as XLA is done
             limiter.add(Wb[bi])
             done += 1

@@ -61,9 +61,9 @@ class BatchPCATransformer(Transformer):
     def apply_batch(self, ds: Dataset) -> Dataset:
         if ds.is_array:
             x = ds.padded()  # (n, d, m)
-            return Dataset.from_array(
-                jnp.einsum("dk,ndm->nkm", self.pca_mat, x), n=ds.n
-            )
+            with jax.named_scope("pca.project"):
+                out = jnp.einsum("dk,ndm->nkm", self.pca_mat, x)
+            return Dataset.from_array(out, n=ds.n)
         return ds.map(self.apply)
 
 

@@ -280,9 +280,10 @@ class CosineRandomFeatures(Transformer):
 
     def apply_batch(self, ds: Dataset) -> Dataset:
         x = ds.padded()
-        out = jnp.cos(mm(x, self.W.T) + self.b)
-        # cos(0 + b) != 0: keep the pad-rows-are-zero invariant
-        out = out * ds.mask()[:, None]
+        with jax.named_scope("features.cosine"):
+            out = jnp.cos(mm(x, self.W.T) + self.b)
+            # cos(0 + b) != 0: keep the pad-rows-are-zero invariant
+            out = out * ds.mask()[:, None]
         return Dataset.from_array(out, n=ds.n)
 
 

@@ -37,6 +37,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from keystone_tpu.observability.registry import get_global_registry
+from keystone_tpu.observability.tracing import span
 from keystone_tpu.parallel import mesh as mesh_lib
 
 
@@ -49,6 +51,44 @@ def _leading_dim(tree: Any) -> int:
     if not leaves:
         raise ValueError("empty pytree")
     return leaves[0].shape[0]
+
+
+class HostPuts:
+    """The host-to-device puts one workflow call issues for its items."""
+
+    def __init__(self) -> None:
+        self.puts = 0
+        self.nbytes = 0
+
+    def asarray(self, x: Any) -> Any:
+        """``jnp.asarray`` of one leaf of an item; a leaf that arrives on
+        the host is one put."""
+        if isinstance(x, jax.Array):
+            return x
+        a = jnp.asarray(x)
+        self.puts += 1
+        self.nbytes += a.nbytes
+        return a
+
+    def count(self, host_items: int) -> None:
+        """Publish: ``host_items`` items arrived on the host. Items
+        already on the device count nowhere, so transfers over items is
+        the puts one host item costs."""
+        if not host_items:
+            return
+        reg = get_global_registry()
+        reg.counter(
+            "keystone_workflow_h2d_items_total",
+            "items that reached the workflow layer as host arrays",
+        ).inc(by=host_items)
+        reg.counter(
+            "keystone_workflow_h2d_transfers_total",
+            "host-to-device puts the workflow layer issued for host items",
+        ).inc(by=self.puts)
+        reg.counter(
+            "keystone_workflow_h2d_bytes_total",
+            "bytes of the workflow layer's host-to-device puts",
+        ).inc(by=self.nbytes)
 
 
 class Dataset:
@@ -240,12 +280,13 @@ class Dataset:
     def items(self) -> List[Any]:
         if self._items is not None:
             return self._items
-        arrs = self.array()
-        host = jax.tree_util.tree_map(np.asarray, arrs)
-        return [
-            jax.tree_util.tree_map(lambda a, i=i: a[i], host)
-            for i in range(self._n)
-        ]
+        with span("workflow.to_items", n=self._n):
+            arrs = self.array()
+            host = jax.tree_util.tree_map(np.asarray, arrs)
+            return [
+                jax.tree_util.tree_map(lambda a, i=i: a[i], host)
+                for i in range(self._n)
+            ]
 
     def __iter__(self):
         return iter(self.items())
@@ -271,16 +312,28 @@ class Dataset:
                 [jnp.asarray(b) for b in self._host_blocks], axis=1
             )
             return Dataset(arrays=full, n=self._n)
-        stacked = jax.tree_util.tree_map(
-            lambda *xs: jnp.stack([jnp.asarray(x) for x in xs]), *self._items
-        )
+        with span("workflow.to_array", n=self._n):
+            h2d = HostPuts()
+            host_items = sum(
+                any(
+                    not isinstance(leaf, jax.Array)
+                    for leaf in jax.tree_util.tree_leaves(x)
+                )
+                for x in self._items
+            )
+            stacked = jax.tree_util.tree_map(
+                lambda *xs: jnp.stack([h2d.asarray(x) for x in xs]),
+                *self._items,
+            )
+            h2d.count(host_items)
         return Dataset(arrays=stacked, n=self._n)
 
     # -- transforms (eager; graph-level laziness lives in Expressions) -----
 
     def map(self, fn: Callable[[Any], Any]) -> "Dataset":
         """Per-example host map (items mode result)."""
-        return Dataset(items=[fn(x) for x in self.items()])
+        with span("workflow.map_items", n=self._n):
+            return Dataset(items=[fn(x) for x in self.items()])
 
     def map_arrays(self, fn: Callable[[Any], Any]) -> "Dataset":
         """Whole-batch array transform; ``fn`` must preserve the leading axis

@@ -10,8 +10,9 @@ TPU equivalents here:
   XPlane traces viewable in TensorBoard/XProf (the substrate-level trace
   the reference lacked).
 - ``PhaseTimer``: the per-phase wall-clock logger.
-- ``instrument_executor``: hooks a GraphExecutor's per-node timing
-  callback to record execution wall time (the interpret-layer profile).
+- per-node and per-phase spans (the interpret-layer profile) are
+  ``observability.tracing.span``: on the profiler's clock inside
+  ``trace(dir)``, in ``/tracez`` with ``enable_tracing()``.
 - DOT export lives on the Graph itself (``Graph.to_dot``), same as the
   reference's toDOTString.
 """
@@ -188,17 +189,3 @@ class Counter:
     def snapshot(self) -> Dict:
         with self._lock:
             return dict(self._cells)
-
-
-def instrument_executor(executor) -> Dict:
-    """Record per-node wall time on a GraphExecutor via its ``node_hook``
-    (workflow/executor.py) — no monkey-patching; the hook also powers
-    ``/tracez`` node spans. Returns the (live) dict of node -> seconds,
-    accumulated as nodes execute."""
-    times: Dict = {}
-
-    def hook(graph_id, label, seconds):
-        times[graph_id] = times.get(graph_id, 0.0) + seconds
-
-    executor.node_hook = hook
-    return times

@@ -24,7 +24,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from keystone_tpu.parallel.dataset import Dataset
+from keystone_tpu.observability.registry import get_global_registry
+from keystone_tpu.observability.tracing import span
+from keystone_tpu.parallel.dataset import Dataset, HostPuts
 from keystone_tpu.workflow.executor import GraphExecutor, PipelineEnv
 from keystone_tpu.workflow.expressions import (
     DatasetExpression,
@@ -345,15 +347,22 @@ class Transformer(Chainable, TransformerOperator):
         return ds.map(self.apply)
 
     def _bucketed_batch(self, ds: Dataset) -> Dataset:
+        """Items grouped by shape, each group through ``jit(vmap(apply))``
+        in chunks. Spans: ``workflow.upload`` once, ``workflow.stack`` /
+        ``.apply`` / ``.slice`` per chunk — never one per item."""
         items = ds.items()
         by_shape: Dict[tuple, List[int]] = {}
         arrays = []
-        for i, x in enumerate(items):
-            a = jnp.asarray(x)
-            arrays.append(a)
-            by_shape.setdefault((a.shape, str(a.dtype)), []).append(i)
+        h2d = HostPuts()
+        with span("workflow.upload", n=len(items)):
+            for i, x in enumerate(items):
+                a = h2d.asarray(x)
+                arrays.append(a)
+                by_shape.setdefault((a.shape, str(a.dtype)), []).append(i)
+        h2d.count(h2d.puts)  # an item is one array here: a put each
         out: List[Any] = [None] * len(items)
         fn = self._jitted_vmap()
+        chunks = padded = 0
         for idxs in by_shape.values():
             # a group larger than BUCKET_CHUNK goes through in chunks of
             # that many items, the tail zero-padded to the same shape:
@@ -363,17 +372,40 @@ class Transformer(Chainable, TransformerOperator):
             chunk = min(len(idxs), BUCKET_CHUNK)
             for s in range(0, len(idxs), chunk):
                 part = idxs[s : s + chunk]
-                batch = jnp.stack([arrays[i] for i in part])
-                if len(part) < chunk:
-                    pad = jnp.zeros(
-                        (chunk - len(part),) + batch.shape[1:], batch.dtype
-                    )
-                    batch = jnp.concatenate([batch, pad])
-                res = fn(batch)
-                for j, i in enumerate(part):
-                    out[i] = jax.tree_util.tree_map(
-                        lambda a, j=j: a[j], res
-                    )
+                with span("workflow.stack", n=len(part)):
+                    batch = jnp.stack([arrays[i] for i in part])
+                    if len(part) < chunk:
+                        pad = jnp.zeros(
+                            (chunk - len(part),) + batch.shape[1:],
+                            batch.dtype,
+                        )
+                        batch = jnp.concatenate([batch, pad])
+                with span("workflow.apply", n=chunk):
+                    res = fn(batch)
+                with span("workflow.slice", n=len(part)):
+                    for j, i in enumerate(part):
+                        out[i] = jax.tree_util.tree_map(
+                            lambda a, j=j: a[j], res
+                        )
+                chunks += 1
+                padded += chunk - len(part)
+        reg = get_global_registry()
+        reg.counter(
+            "keystone_workflow_items_total",
+            "items through Transformer._bucketed_batch",
+        ).inc(by=len(items))
+        reg.counter(
+            "keystone_workflow_chunks_total",
+            "jit(vmap) chunk dispatches of Transformer._bucketed_batch",
+        ).inc(by=chunks)
+        reg.counter(
+            "keystone_workflow_padded_rows_total",
+            "zero rows that padded a short chunk to the chunk's shape",
+        ).inc(by=padded)
+        reg.counter(
+            "keystone_workflow_item_slices_total",
+            "per-item slices that cut chunk outputs back into items",
+        ).inc(by=len(items))
         return Dataset.from_items(out)
 
     # TransformerOperator ABI

@@ -8,10 +8,9 @@ save executed prefixes into the global state) and workflow/PipelineEnv.scala
 
 from __future__ import annotations
 
-import time
-from typing import Callable, Dict, Optional, Set, Tuple
+from typing import Dict, Optional, Set, Tuple
 
-from keystone_tpu.observability.tracing import get_tracer
+from keystone_tpu.observability.tracing import span
 from keystone_tpu.workflow.expressions import Expression
 from keystone_tpu.workflow.graph import (
     Graph,
@@ -21,6 +20,7 @@ from keystone_tpu.workflow.graph import (
     SourceId,
     get_ancestors,
 )
+from keystone_tpu.workflow.operators import ExpressionOperator
 from keystone_tpu.workflow.prefix import Prefix
 
 
@@ -211,30 +211,27 @@ class GraphExecutor:
     the first execution. Ids with a source ancestor cannot be executed (their
     value depends on unspliced runtime data).
 
-    Observability: ``node_hook`` is an optional
-    ``callable(node_id, label, seconds)`` invoked with each node's own
-    operator-execution wall time (excluding dependency time) the first
-    time the node runs — ``utils.profiling.instrument_executor`` sets it.
-    Independently, when the process-global tracer
-    (``observability.tracing``) is enabled, every first-time node
-    evaluation records a ``node:<label>`` span whose parent is the span
-    of the consumer that demanded it, so ``/tracez`` shows the executed
-    DAG as a span tree. Both are off by default and cost one attribute
-    check per node when off.
+    Observability: every node's own work runs inside a
+    ``node:<label>`` span (``observability.tracing.span``: ``ks:node:
+    <label>`` in a profiler trace, and in ``/tracez`` once
+    ``enable_tracing()`` is on). A node's value is a lazy expression, so
+    the span opens when the value is first asked for, after the node's
+    dependencies have been forced: node spans follow one another and do
+    not nest, a span's duration is the node's own time, and the phases
+    inside it (``workflow.*``, ``solver.*``) are its children.
+    ``workflow.optimize`` covers the optimizer's one run.
     """
 
     def __init__(
         self,
         graph: Graph,
         optimize: bool = True,
-        node_hook: Optional[Callable[[GraphId, str, float], None]] = None,
     ):
         self._raw_graph = graph
         self._optimize = optimize
         self._optimized: Optional[Tuple[Graph, Dict[NodeId, Prefix]]] = None
         self._execution_state: Dict[GraphId, Expression] = {}
         self._source_dependants: Optional[Set[GraphId]] = None
-        self.node_hook = node_hook
 
     @property
     def raw_graph(self) -> Graph:
@@ -252,7 +249,8 @@ class GraphExecutor:
         if self._optimized is None:
             if self._optimize:
                 env = PipelineEnv.get_or_create()
-                self._optimized = env.optimizer.execute(self._raw_graph)
+                with span("workflow.optimize"):
+                    self._optimized = env.optimizer.execute(self._raw_graph)
             else:
                 self._optimized = (self._raw_graph, {})
         return self._optimized
@@ -283,14 +281,12 @@ class GraphExecutor:
         if isinstance(graph_id, SinkId):
             expr = self.execute(g.sink_dependencies[graph_id])
         else:
-            tracer = get_tracer()
-            if tracer.enabled or self.node_hook is not None:
-                expr = self._execute_instrumented(graph_id, g, tracer)
-            else:
-                dep_exprs = [
-                    self.execute(d) for d in g.dependencies[graph_id]
-                ]
-                expr = g.operators[graph_id].execute(dep_exprs)
+            dep_exprs = [self.execute(d) for d in g.dependencies[graph_id]]
+            op = g.operators[graph_id]
+            expr = op.execute(dep_exprs)
+            if not isinstance(op, ExpressionOperator):
+                # a saved expression was made, and spanned, by another node
+                _span_node(expr, op, graph_id, dep_exprs)
             # Cross-pipeline prefix memoization (GraphExecutor.scala:68-70):
             # expose this node's expression under its structural prefix.
             prefix = prefixes.get(graph_id)
@@ -299,20 +295,17 @@ class GraphExecutor:
         self._execution_state[graph_id] = expr
         return expr
 
-    def _execute_instrumented(self, graph_id, g, tracer) -> Expression:
-        """First-time node evaluation with a ``node:<label>`` span around
-        the whole demand (so dependency spans nest under their consumer,
-        mirroring the executed DAG in ``/tracez``) and the node's OWN
-        operator wall time — dependencies excluded — reported to
-        ``node_hook`` and stamped on the span."""
-        op = g.operators[graph_id]
-        label = getattr(op, "label", type(op).__name__)
-        with tracer.span(f"node:{label}", node_id=str(graph_id)) as span:
-            dep_exprs = [self.execute(d) for d in g.dependencies[graph_id]]
-            t0 = time.perf_counter()
-            expr = op.execute(dep_exprs)
-            self_seconds = time.perf_counter() - t0
-            span.set_attr("self_ms", round(self_seconds * 1e3, 6))
-        if self.node_hook is not None:
-            self.node_hook(graph_id, label, self_seconds)
-        return expr
+
+def _span_node(expr: Expression, op, graph_id, dep_exprs) -> None:
+    """Put the node's own work, which runs when ``expr`` is first asked
+    for, inside its ``node:<label>`` span, its dependencies forced
+    before the span opens."""
+    label = getattr(op, "label", type(op).__name__)
+
+    def run(thunk):
+        for d in dep_exprs:
+            d.get()
+        with span(f"node:{label}", node_id=str(graph_id)):
+            return thunk()
+
+    expr.around(run)

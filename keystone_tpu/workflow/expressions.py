@@ -25,6 +25,13 @@ class Expression:
             self._thunk = None  # free captured state
         return self._value
 
+    def around(self, wrapper: Callable[[Callable[[], Any]], Any]) -> None:
+        """Have the pending computation run as ``wrapper(thunk)`` (the
+        executor's node span); nothing once the value is there."""
+        thunk = self._thunk
+        if not self.is_computed:
+            self._thunk = lambda: wrapper(thunk)
+
     @property
     def is_computed(self) -> bool:
         return self._value is not Expression._UNSET

@@ -131,12 +131,19 @@ def test_live_engine_scrape_end_to_end(traced):
 
 
 def test_executor_node_spans_in_tracez_with_parent_links(traced, mesh8):
-    """Acceptance: workflow executor node spans appear in /tracez with
-    parent links (the consumer that demanded a node is its parent)."""
-    from keystone_tpu.ops.stats import LinearRectifier, NormalizeRows
+    """Acceptance: workflow executor node spans appear in /tracez, one
+    per node around the node's own work, and the phases that ran inside
+    a node (here the estimator's solver phases) link to it as parent."""
+    from keystone_tpu.ops.learning import BlockLeastSquaresEstimator
+    from keystone_tpu.ops.stats import LinearRectifier
+    from keystone_tpu.parallel.dataset import Dataset
 
-    pipe = LinearRectifier(0.0).and_then(NormalizeRows())
-    pipe.apply(np.ones((4, 3), np.float32)).get()
+    rng = np.random.default_rng(0)
+    x = Dataset.from_array(rng.standard_normal((32, 8)).astype(np.float32))
+    y = Dataset.from_array(rng.standard_normal((32, 2)).astype(np.float32))
+    LinearRectifier(0.0).and_then(
+        BlockLeastSquaresEstimator(4, num_iter=1, solve="host"), x, y
+    ).fit()
 
     with AdminServer(registry=MetricsRegistry(), tracer=get_tracer()) as srv:
         _, _, body = _get(srv, "/tracez")
@@ -144,14 +151,19 @@ def test_executor_node_spans_in_tracez_with_parent_links(traced, mesh8):
     assert doc["enabled"] is True
     nodes = [s for s in doc["spans"] if s["name"].startswith("node:")]
     assert len(nodes) >= 2
+    # a node's dependencies are forced before its span opens: node spans
+    # follow one another, none is another's child
     by_id = {s["span_id"]: s for s in nodes}
-    linked = [
-        s for s in nodes
-        if s["parent_id"] is not None and s["parent_id"] in by_id
-    ]
-    assert linked, f"want node->node parent links, got {nodes}"
-    # every node span carries its own wall time
-    assert all("self_ms" in s["attrs"] for s in nodes)
+    assert not [s for s in nodes if s["parent_id"] in by_id]
+    assert all(s["attrs"]["node_id"] for s in nodes)
+    est = [s for s in nodes if "BlockLeastSquares" in s["name"]]
+    assert len(est) == 1
+    children = {
+        s["name"] for s in doc["spans"]
+        if s["parent_id"] == est[0]["span_id"]
+    }
+    assert {"solver.prep", "solver.block_stats", "solver.readback",
+            "solver.host_solve", "solver.residual_update"} <= children
 
 
 def test_chrome_trace_export_of_serving_run(traced, tmp_path):

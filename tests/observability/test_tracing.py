@@ -355,3 +355,280 @@ def test_disabled_tracer_span_accepts_trace_id():
     with tr.span("x", trace_id="cd" * 16) as s:
         assert s.trace_id is None
     assert tr.recent() == []
+
+
+# -- the one span call: the profiler's copy, the ring's copy, the counters --
+
+
+class _NoLock:
+    """Stands in for the tracer's lock: taking it fails the test."""
+
+    def __enter__(self):
+        raise AssertionError("span() took the tracer's lock")
+
+    def __exit__(self, *exc):
+        return False
+
+
+def _profiled(tmp_path, fn):
+    """Run ``fn`` under a profiler session; the ``ks:`` events of the
+    calling thread as (start ns, end ns, name), parents before children."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
+    try:
+        with jax.profiler.TraceAnnotation("test:calling-thread"):
+            fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(
+        str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb")
+    )
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            events = [
+                (int(e.start_ns), int(e.start_ns + e.duration_ns), e.name)
+                for e in line.events
+            ]
+            if any(n == "test:calling-thread" for _, _, n in events):
+                return sorted(
+                    (e for e in events if e[2].startswith("ks:")),
+                    key=lambda e: (e[0], -e[1]),
+                )
+    raise AssertionError("the calling thread's line is not in the trace")
+
+
+def _parent_names(events):
+    """name of each event's innermost enclosing event (None at the top),
+    in the events' order."""
+    out, stack = [], []
+    for s, e, name in events:
+        while stack and stack[-1][1] <= s:
+            stack.pop()
+        out.append(stack[-1][2] if stack else None)
+        stack.append((s, e, name))
+    return out
+
+
+def _count(events, name):
+    return sum(1 for _, _, n in events if n == name)
+
+
+def _counter(name):
+    from keystone_tpu.observability.registry import get_global_registry
+
+    return get_global_registry().counter(name).get()
+
+
+def test_span_off_records_nothing_and_takes_no_lock():
+    from keystone_tpu.observability import tracing
+
+    tr = Tracer(enabled=False)
+    tr._lock = _NoLock()
+    with tr.span("solver.host_solve", width=8) as sp:
+        sp.set_attr("fallback", "eigh")  # goes nowhere, no crash
+        assert sp.span_id is None
+    tr.end_span(tr.start_span("router.forward", attempt=1))
+    assert list(tr._ring) == []
+    # the module-level call is the global tracer's own method
+    assert tracing.span == get_tracer().span
+    get_tracer().clear()
+    disable_tracing()
+    with tracing.span("workflow.apply", n=2):
+        pass
+    assert get_tracer().recent() == []
+
+
+def test_ring_and_profiler_hold_the_same_names_and_parents(tmp_path):
+    from keystone_tpu.observability.tracing import span
+
+    tr = enable_tracing()
+    tr.clear()
+
+    def work():
+        with span("node:Outer", node_id="n1"):
+            with span("workflow.upload", n=3):
+                pass
+            with span("workflow.apply", n=3):
+                with span("solver.prep"):
+                    pass
+        done = tr.start_span("router.forward")
+        tr.end_span(done)
+
+    try:
+        events = _profiled(tmp_path, work)
+    finally:
+        disable_tracing()
+    ring = sorted(tr.recent(), key=lambda s: s.span_id)  # start order
+    tr.clear()
+    by_id = {s.span_id: s.name for s in ring}
+    assert [n for _, _, n in events] == ["ks:" + s.name for s in ring]
+    assert _parent_names(events) == [
+        None if s.parent_id is None else "ks:" + by_id[s.parent_id]
+        for s in ring
+    ]
+    assert _parent_names(events) == [
+        None, "ks:node:Outer", "ks:node:Outer", "ks:workflow.apply", None,
+    ]
+
+
+def test_block_ls_host_fit_spans_and_counters(tmp_path, mesh8):
+    import numpy as np
+
+    from keystone_tpu.observability.registry import reset_global_registry
+    from keystone_tpu.ops.learning import BlockLeastSquaresEstimator
+    from keystone_tpu.ops.stats import LinearRectifier
+    from keystone_tpu.parallel.dataset import Dataset
+
+    num_iter, blocks = 2, 3
+    rng = np.random.default_rng(0)
+    x = Dataset.from_array(rng.standard_normal((64, 12)).astype(np.float32))
+    y = Dataset.from_array(rng.standard_normal((64, 2)).astype(np.float32))
+    pipe = LinearRectifier(-9.0).and_then(
+        BlockLeastSquaresEstimator(4, num_iter=num_iter, solve="host"), x, y
+    )
+    reset_global_registry()
+    try:
+        events = _profiled(tmp_path, pipe.fit)
+        steps = num_iter * blocks
+        node = "ks:node:BlockLeastSquaresEstimator"
+        assert _count(events, node) == 1
+        assert _count(events, "ks:solver.prep") == 1
+        parents = dict(zip(events, _parent_names(events)))
+        for phase in ("block_stats", "readback", "host_solve", "upload",
+                      "residual_update"):
+            mine = [e for e in events if e[2] == "ks:solver." + phase]
+            assert len(mine) == steps, phase
+            assert {parents[e] for e in mine} == {node}, phase
+        assert _count(events, "ks:solver.block_step") == 0
+        # node spans follow one another: the features' node is no parent
+        assert parents[next(e for e in events if e[2] == node)] is None
+        assert _counter("keystone_solver_fits_total") == 1
+        assert _counter("keystone_solver_gram_builds_total") == steps
+        assert _counter("keystone_solver_block_steps_total") == steps
+        assert _counter("keystone_solver_host_solves_total") == steps
+        assert _counter("keystone_solver_host_solve_fallbacks_total") == 0
+        # a (4, 4) Gram and a (4, 2) right-hand side in f32, each step
+        assert _counter("keystone_solver_readback_bytes_total") == (
+            steps * (16 + 8) * 4
+        )
+    finally:
+        reset_global_registry()
+
+
+def test_block_ls_device_fit_opens_block_step_spans(mesh8):
+    import numpy as np
+
+    from keystone_tpu.observability.registry import reset_global_registry
+    from keystone_tpu.ops.learning import BlockLeastSquaresEstimator
+    from keystone_tpu.parallel.dataset import Dataset
+
+    rng = np.random.default_rng(1)
+    x = Dataset.from_array(rng.standard_normal((64, 8)).astype(np.float32))
+    y = Dataset.from_array(rng.standard_normal((64, 2)).astype(np.float32))
+    tr = enable_tracing()
+    tr.clear()
+    reset_global_registry()
+    try:
+        BlockLeastSquaresEstimator(4, num_iter=2, lam=0.1).fit(x, y)
+        names = [s.name for s in tr.recent()]
+        assert names.count("solver.block_step") == 4
+        assert names.count("solver.prep") == 1
+        assert "solver.host_solve" not in names
+        assert _counter("keystone_solver_gram_builds_total") == 4
+        assert _counter("keystone_solver_host_solves_total") == 0
+    finally:
+        disable_tracing()
+        tr.clear()
+        reset_global_registry()
+
+
+def _bucketed(monkeypatch, items):
+    from keystone_tpu.ops.images.core import PixelScaler
+    from keystone_tpu.parallel.dataset import Dataset
+    from keystone_tpu.workflow import api
+
+    monkeypatch.setattr(api, "BUCKET_CHUNK", 2)
+    return lambda: PixelScaler().apply_batch(Dataset.from_items(items))
+
+
+def test_bucketed_batch_spans_do_not_grow_with_items(tmp_path, monkeypatch):
+    import numpy as np
+
+    counts = {}
+    for n in (5, 6):
+        items = [np.full((4, 4, 3), i, np.uint8) for i in range(n)]
+        sub = tmp_path / str(n)
+        sub.mkdir()
+        events = _profiled(sub, _bucketed(monkeypatch, items))
+        counts[n] = {
+            name: _count(events, name) for name in {e[2] for e in events}
+        }
+    want = {"ks:workflow.upload": 1, "ks:workflow.stack": 3,
+            "ks:workflow.apply": 3, "ks:workflow.slice": 3}
+    # 5 items at chunk 2 are 3 chunks, and so are 6: one more item, not
+    # one more span of any name
+    assert counts[5] == want and counts[6] == want
+
+
+def test_bucketed_batch_counters(monkeypatch):
+    import jax.numpy as jnp
+    import numpy as np
+
+    from keystone_tpu.observability.registry import reset_global_registry
+
+    host = [np.full((4, 4, 3), i, np.uint8) for i in range(5)]
+    reset_global_registry()
+    try:
+        out = _bucketed(monkeypatch, host)()
+        assert len(out.items()) == 5
+        assert _counter("keystone_workflow_items_total") == 5
+        assert _counter("keystone_workflow_chunks_total") == 3
+        assert _counter("keystone_workflow_padded_rows_total") == 1
+        assert _counter("keystone_workflow_item_slices_total") == 5
+        assert _counter("keystone_workflow_h2d_items_total") == 5
+        assert _counter("keystone_workflow_h2d_transfers_total") == 5
+        assert _counter("keystone_workflow_h2d_bytes_total") == 5 * 48
+        reset_global_registry()
+        _bucketed(monkeypatch, [jnp.asarray(x) for x in host])()
+        assert _counter("keystone_workflow_items_total") == 5
+        assert _counter("keystone_workflow_h2d_items_total") == 0
+        assert _counter("keystone_workflow_h2d_transfers_total") == 0
+    finally:
+        reset_global_registry()
+
+
+def test_dataset_item_paths_carry_one_span_each():
+    import jax.numpy as jnp
+    import numpy as np
+
+    from keystone_tpu.observability.registry import reset_global_registry
+    from keystone_tpu.parallel.dataset import Dataset
+
+    tr = enable_tracing()
+    tr.clear()
+    reset_global_registry()
+    try:
+        items = [(np.ones(3, np.float32), jnp.ones(2)) for _ in range(4)]
+        arrays = Dataset.from_items(items).to_array_mode()
+        # each item is a host leaf and a device leaf: one put an item
+        assert _counter("keystone_workflow_h2d_items_total") == 4
+        assert _counter("keystone_workflow_h2d_transfers_total") == 4
+        assert _counter("keystone_workflow_h2d_bytes_total") == 4 * 12
+        arrays.items()
+        arrays.map(lambda x: x)
+        names = [s.name for s in tr.recent()]
+        assert names.count("workflow.to_array") == 1
+        assert names.count("workflow.map_items") == 1
+        # items() once for itself, once inside map
+        assert names.count("workflow.to_items") == 2
+        assert len(names) == 4
+    finally:
+        disable_tracing()
+        tr.clear()
+        reset_global_registry()

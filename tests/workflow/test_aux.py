@@ -8,7 +8,7 @@ import numpy as np
 import pytest
 
 from keystone_tpu.parallel.dataset import Dataset
-from keystone_tpu.utils.profiling import PhaseTimer, instrument_executor
+from keystone_tpu.utils.profiling import PhaseTimer
 from keystone_tpu.workflow.api import Pipeline, Transformer
 from keystone_tpu.workflow.executor import PipelineEnv
 
@@ -77,13 +77,26 @@ def test_phase_timer_and_instrumentation(mesh8):
         pass
     assert "work" in timer.times
 
+    from keystone_tpu.observability.tracing import (
+        disable_tracing,
+        enable_tracing,
+        get_tracer,
+    )
     from keystone_tpu.ops.stats import LinearRectifier
 
     pipe = LinearRectifier(0.0).to_pipeline()
     result = pipe.apply(np.ones((4, 3), np.float32))
-    times = instrument_executor(result._executor)
-    result.get()
-    assert len(times) >= 1
+    tracer = enable_tracing()
+    tracer.clear()
+    try:
+        result.get()
+    finally:
+        disable_tracing()
+    # per-node wall time is the node span's duration: one span per node
+    # that did work, opened around the node's own batch_transform
+    nodes = [s for s in get_tracer().recent() if s.name.startswith("node:")]
+    assert [s.name for s in nodes] == ["node:LinearRectifier"]
+    assert nodes[0].duration_s > 0 and nodes[0].attrs["node_id"]
 
 
 def test_dot_export(mesh8):
