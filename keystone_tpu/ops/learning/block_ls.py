@@ -22,14 +22,23 @@ residual-update round trip collapses into two XLA programs around one small
 host solve; the O(n·b·(b+k)) work never leaves the device, and the residual
 buffer is donated to avoid an HBM copy per block.
 
+G depends on X and μ only, so with ``solve="host"`` and more than one sweep
+a fit builds, reads back and factors each block's G once, on the block's
+first visit in that call of ``fit()``, and keeps the float64 factor on the
+host until the call returns (the reference's weighted solver caches its
+BlockStatistics across passes the same way). Later visits run
+``_block_stats_rhs`` (R⁺ and X_bᵀR⁺, no G), read back the (b, k)
+right-hand side alone and solve against the kept factor.
+
 Observability: host spans ``solver.prep``, then per block step
-``solver.block_stats`` → ``solver.readback`` → ``solver.host_solve`` →
-``solver.upload`` → ``solver.residual_update`` (``solve="host"``) or
-``solver.block_step`` (device solve); on the device ``jax.named_scope``
-names ``solver.residual_plus`` / ``solver.gram`` / ``solver.rhs`` /
-``solver.solve`` / ``solver.residual`` / ``solver.prep``; counters
-``keystone_solver_fits_total``, ``_block_steps_total``,
-``_gram_builds_total`` (hostsolve.py has the host solve's).
+``solver.block_stats`` (either stats program) → ``solver.readback`` →
+``solver.host_solve`` → ``solver.upload`` → ``solver.residual_update``
+(``solve="host"``) or ``solver.block_step`` (device solve); on the device
+``jax.named_scope`` names ``solver.residual_plus`` / ``solver.gram`` /
+``solver.rhs`` / ``solver.solve`` / ``solver.residual`` / ``solver.prep``;
+counters ``keystone_solver_fits_total``, ``_block_steps_total``,
+``_gram_builds_total`` (Grams really built), ``_factor_reuses_total``
+(hostsolve.py has the host solve's).
 """
 
 from __future__ import annotations
@@ -37,7 +46,7 @@ from __future__ import annotations
 import dataclasses
 from collections import deque
 from functools import partial
-from typing import Any, Callable, List, Optional, Sequence
+from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import jax
 import jax.numpy as jnp
@@ -47,7 +56,11 @@ from keystone_tpu.observability.registry import get_global_registry
 from keystone_tpu.observability.tracing import span
 from keystone_tpu.parallel.dataset import Dataset
 from keystone_tpu.workflow.api import LabelEstimator, Transformer
-from keystone_tpu.ops.learning.hostsolve import psd_solve_host
+from keystone_tpu.ops.learning.hostsolve import (
+    HostFactor,
+    psd_factor_solve_host,
+    psd_solve_factored_host,
+)
 from keystone_tpu.utils.checkpoint import (
     LoopCheckpointer,
     data_probe,
@@ -172,11 +185,24 @@ def _gram_rhs(Xb, mu_b, R_plus, n):
     block programs), under the names a device trace finds them by."""
     with jax.named_scope("solver.gram"):
         gram = _f32_mm(Xb.T, Xb) - n * jnp.outer(mu_b, mu_b)
+    return gram, _rhs(Xb, mu_b, R_plus)
+
+
+def _rhs(Xb, mu_b, R_plus):
     with jax.named_scope("solver.rhs"):
-        rhs = _f32_mm(Xb.T, R_plus) - jnp.outer(
+        return _f32_mm(Xb.T, R_plus) - jnp.outer(
             mu_b, jnp.sum(R_plus, axis=0)
         )
-    return gram, rhs
+
+
+def _block_residual_plus(X, R, Wb, mu, mask, start, width):
+    """The block's column slice, its means, and R⁺ = R + X_b W_b (centered):
+    the opening of both stats programs."""
+    Xb = jax.lax.dynamic_slice_in_dim(X, start, width, axis=1)
+    mu_b = jax.lax.dynamic_slice_in_dim(mu, start, width)
+    with jax.named_scope("solver.residual_plus"):
+        contrib = _f32_mm(Xb, Wb) - mask[:, None] * _f32_mm(mu_b, Wb)
+        return Xb, mu_b, R + contrib
 
 
 @partial(jax.jit, static_argnames=("width", "n"), donate_argnums=(1,))
@@ -191,13 +217,19 @@ def _block_stats(X, R, Wb, mu, mask, start, *, width: int, n: int):
     axis lower to per-shard MXU matmuls + a psum over the "data" axis.
     ``start`` is traced so every equal-width block shares this compilation.
     """
-    Xb = jax.lax.dynamic_slice_in_dim(X, start, width, axis=1)
-    mu_b = jax.lax.dynamic_slice_in_dim(mu, start, width)
-    with jax.named_scope("solver.residual_plus"):
-        contrib = _f32_mm(Xb, Wb) - mask[:, None] * _f32_mm(mu_b, Wb)
-        R_plus = R + contrib
+    Xb, mu_b, R_plus = _block_residual_plus(X, R, Wb, mu, mask, start, width)
     gram, rhs = _gram_rhs(Xb, mu_b, R_plus, n)
     return gram, rhs, R_plus
+
+
+@partial(jax.jit, static_argnames=("width",), donate_argnums=(1,))
+def _block_stats_rhs(X, R, Wb, mu, mask, start, *, width: int):
+    """``_block_stats`` without its Gram, for a block whose factor the
+    fit already holds: R⁺ and the centered right-hand side only. The name
+    keeps ``block_stats`` in it: device-time readers find the solver's
+    programs by name."""
+    Xb, mu_b, R_plus = _block_residual_plus(X, R, Wb, mu, mask, start, width)
+    return _rhs(Xb, mu_b, R_plus), R_plus
 
 
 @partial(jax.jit, static_argnames=("width",), donate_argnums=(1,))
@@ -295,9 +327,10 @@ def _host_block_rebuild(Xb, R, Wb, mask, *, n: int):
     return R - contrib, mu_b
 
 
-def _count_fit() -> Callable[[], None]:
+def _count_fit() -> Callable[..., None]:
     """Count one fit started; the callable it returns counts one block
-    step of that fit, and the one Gram every block step builds."""
+    step of that fit, and with it either the Gram the step built or,
+    with ``reused_factor``, the kept factor it solved against."""
     reg = get_global_registry()
     reg.counter(
         "keystone_solver_fits_total", "block least-squares fits started"
@@ -310,10 +343,14 @@ def _count_fit() -> Callable[[], None]:
         "keystone_solver_gram_builds_total",
         "block Gram matrices built on the device",
     )
+    reuses = reg.counter(
+        "keystone_solver_factor_reuses_total",
+        "block steps solved against a factor kept from an earlier sweep",
+    )
 
-    def count_step() -> None:
+    def count_step(reused_factor: bool = False) -> None:
         steps.inc()
-        grams.inc()
+        (reuses if reused_factor else grams).inc()
 
     return count_step
 
@@ -539,11 +576,17 @@ class BlockLeastSquaresEstimator(LabelEstimator):
                 st[f"Wb_{s}"] = np.asarray(Wb[s])
             return st
 
+        # solve="host": each block's float64 factor, by start column,
+        # from the block's first visit in THIS call (a resumed fit enters
+        # a later sweep with none) until the call returns. b²·8 bytes of
+        # host memory a block; the next fit builds its own.
+        factors: Dict[int, HostFactor] = {}
         done = 0
         for it, pos, nxt in two_level_schedule(
             self.num_iter, len(blocks), (start_it, start_pos)
         ):
             s, w = blocks[pos]
+            kept = factors.get(s)
             if self.solve == "device":
                 # whole block update in one dispatch; the entire fit
                 # stays in the async stream — no host sync until the
@@ -561,21 +604,33 @@ class BlockLeastSquaresEstimator(LabelEstimator):
                         ),
                     )
             else:
-                with span("solver.block_stats"):
-                    gram, rhs, R_plus = _block_stats(
-                        X, R, Wb[s], mu, mask, s, width=w, n=n
-                    )
                 # (b,b) solve on host in f64 (reference: driver-side
                 # NormalEquations solve) — see hostsolve.py, which has
                 # the solver.readback and solver.host_solve spans.
-                W_host = psd_solve_host(gram, rhs, self.lam)
+                if kept is None:
+                    with span("solver.block_stats"):
+                        gram, rhs, R_plus = _block_stats(
+                            X, R, Wb[s], mu, mask, s, width=w, n=n
+                        )
+                    W_host, factor = psd_factor_solve_host(
+                        gram, rhs, self.lam
+                    )
+                    del gram  # b² floats of HBM, not held into later steps
+                    if self.num_iter > 1:  # one sweep never comes back
+                        factors[s] = factor
+                else:
+                    with span("solver.block_stats"):
+                        rhs, R_plus = _block_stats_rhs(
+                            X, R, Wb[s], mu, mask, s, width=w
+                        )
+                    W_host = psd_solve_factored_host(kept, rhs)
                 with span("solver.upload"):
                     Wb[s] = jnp.asarray(W_host)
                 with span("solver.residual_update"):
                     R = _residual_update(
                         X, R_plus, Wb[s], mu, mask, s, width=w
                     )
-            count_step()
+            count_step(reused_factor=kept is not None)
             done += 1
             if ckpt is not None:
                 ckpt.tick(lambda: snapshot(*nxt))

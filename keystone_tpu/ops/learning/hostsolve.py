@@ -10,13 +10,25 @@ in f32; the O(b³) solve of a matrix that already fits on one host runs in
 numpy f64. Transfers are (b,b)+(b,k) — negligible next to the Gram pass.
 
 The two phases carry spans (``solver.readback``: the read-back waits for
-the device to finish the Gram; ``solver.host_solve``) and counters
+the device to finish the program that made the arrays; ``solver.host_solve``:
+the factorisation and the solve) and counters
 (``keystone_solver_readback_bytes_total``, ``_host_solves_total``,
 ``_host_solve_fallbacks_total``), so a profiler trace and a scrape say
 what the chip waited for.
+
+The solve has two halves: ``_factor`` (ridge + ``cho_factor``, ``eigh``
+where Cholesky breaks down) gives a ``HostFactor``, and
+``HostFactor.solve`` solves a right-hand side against it.
+``psd_solve_host`` is the two composed. A caller that meets the same
+matrix again (block coordinate descent: a block's Gram is the same in
+every sweep) keeps the factor of ``psd_factor_solve_host`` and later
+calls ``psd_solve_factored_host`` with the right-hand side alone: each
+opens one ``solver.readback`` and one ``solver.host_solve`` span.
 """
 
 from __future__ import annotations
+
+from typing import NamedTuple, Tuple
 
 import jax
 import numpy as np
@@ -26,35 +38,84 @@ from keystone_tpu.observability.registry import get_global_registry
 from keystone_tpu.observability.tracing import span
 
 
-def psd_solve_host(gram, rhs, lam: float = 0.0) -> np.ndarray:
-    """Solve (gram + lam·I) X = rhs in f64 on host; robust to indefiniteness
-    from f32 rounding (falls back to eigh with eigenvalue clamping)."""
-    reg = get_global_registry()
+class HostFactor(NamedTuple):
+    """The float64 factor of one ridged (b, b) system, in the form that
+    was taken when it was made, so every solve against it goes the same
+    way: ``(c, low)`` as ``cho_factor`` wrote it, or ``(w, V)`` of the
+    ``eigh`` fall-back with ``w`` already clamped."""
+
+    form: str  # "cholesky" | "eigh"
+    parts: tuple
+
+    def solve(self, R: np.ndarray) -> np.ndarray:
+        if self.form == "cholesky":
+            return scipy.linalg.cho_solve(self.parts, R, check_finite=False)
+        w, V = self.parts
+        return V @ ((V.T @ R) / w[:, None])
+
+
+def _read_back(*arrays) -> list:
+    """Float64 host copies of ``arrays``; counts the bytes that came
+    from the device."""
     with span("solver.readback"):
-        G = np.asarray(gram, dtype=np.float64)
-        R = np.asarray(rhs, dtype=np.float64)
-    reg.counter(
+        out = [np.asarray(a, dtype=np.float64) for a in arrays]
+    get_global_registry().counter(
         "keystone_solver_readback_bytes_total",
         "bytes of Gram and right-hand side read back for host solves",
-    ).inc(by=sum(
-        x.nbytes for x in (gram, rhs) if isinstance(x, jax.Array)
-    ))
-    reg.counter(
+    ).inc(by=sum(a.nbytes for a in arrays if isinstance(a, jax.Array)))
+    return out
+
+
+def _count_solve() -> None:
+    get_global_registry().counter(
         "keystone_solver_host_solves_total",
         "(b, b) systems solved on the host in float64",
     ).inc()
+
+
+def _factor(G: np.ndarray, lam: float, sp) -> HostFactor:
+    """Factor (G + lam·I); robust to indefiniteness from f32 rounding
+    (falls back to eigh with eigenvalue clamping, noted on ``sp``)."""
+    if lam:
+        G = G + lam * np.eye(G.shape[0])
+    try:
+        return HostFactor(
+            "cholesky", scipy.linalg.cho_factor(G, check_finite=False)
+        )
+    except np.linalg.LinAlgError:
+        sp.set_attr("fallback", "eigh")
+        get_global_registry().counter(
+            "keystone_solver_host_solve_fallbacks_total",
+            "host solves that fell back from Cholesky to eigh",
+        ).inc()
+        w, V = np.linalg.eigh(G)
+        w = np.maximum(w, 1e-12 * max(w.max(), 1.0))
+        return HostFactor("eigh", (w, V))
+
+
+def psd_factor_solve_host(
+    gram, rhs, lam: float = 0.0
+) -> Tuple[np.ndarray, HostFactor]:
+    """Solve (gram + lam·I) X = rhs in f64 on host, and hand back the
+    factor with the solution for later right-hand sides of the same
+    matrix (``psd_solve_factored_host``)."""
+    G, R = _read_back(gram, rhs)
+    _count_solve()
     with span("solver.host_solve", width=G.shape[0]) as sp:
-        if lam:
-            G = G + lam * np.eye(G.shape[0])
-        try:
-            c, low = scipy.linalg.cho_factor(G, check_finite=False)
-            return scipy.linalg.cho_solve((c, low), R, check_finite=False)
-        except np.linalg.LinAlgError:
-            sp.set_attr("fallback", "eigh")
-            reg.counter(
-                "keystone_solver_host_solve_fallbacks_total",
-                "host solves that fell back from Cholesky to eigh",
-            ).inc()
-            w, V = np.linalg.eigh(G)
-            w = np.maximum(w, 1e-12 * max(w.max(), 1.0))
-            return V @ ((V.T @ R) / w[:, None])
+        factor = _factor(G, lam, sp)
+        return factor.solve(R), factor
+
+
+def psd_solve_factored_host(factor: HostFactor, rhs) -> np.ndarray:
+    """Solve against a factor kept from ``psd_factor_solve_host``: only
+    the right-hand side is read back, nothing is factored."""
+    (R,) = _read_back(rhs)
+    _count_solve()
+    with span("solver.host_solve", width=R.shape[0], factor="kept"):
+        return factor.solve(R)
+
+
+def psd_solve_host(gram, rhs, lam: float = 0.0) -> np.ndarray:
+    """Solve (gram + lam·I) X = rhs in f64 on host; robust to indefiniteness
+    from f32 rounding (falls back to eigh with eigenvalue clamping)."""
+    return psd_factor_solve_host(gram, rhs, lam)[0]

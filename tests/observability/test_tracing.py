@@ -509,13 +509,18 @@ def test_block_ls_host_fit_spans_and_counters(tmp_path, mesh8):
         # node spans follow one another: the features' node is no parent
         assert parents[next(e for e in events if e[2] == node)] is None
         assert _counter("keystone_solver_fits_total") == 1
-        assert _counter("keystone_solver_gram_builds_total") == steps
+        # a Gram per block on its first visit, the kept factor after
+        assert _counter("keystone_solver_gram_builds_total") == blocks
+        assert _counter("keystone_solver_factor_reuses_total") == (
+            steps - blocks
+        )
         assert _counter("keystone_solver_block_steps_total") == steps
         assert _counter("keystone_solver_host_solves_total") == steps
         assert _counter("keystone_solver_host_solve_fallbacks_total") == 0
-        # a (4, 4) Gram and a (4, 2) right-hand side in f32, each step
+        # f32: a (4, 4) Gram and a (4, 2) right-hand side on a block's
+        # first visit, the right-hand side alone on every later one
         assert _counter("keystone_solver_readback_bytes_total") == (
-            steps * (16 + 8) * 4
+            blocks * (16 + 8) * 4 + (steps - blocks) * 8 * 4
         )
     finally:
         reset_global_registry()

@@ -120,3 +120,252 @@ def test_block_linear_mapper_apply_and_evaluate(mesh8):
 
 def test_block_ls_weight():
     assert BlockLeastSquaresEstimator(10, num_iter=3).weight == 10
+
+
+# -- solve="host": the per-fit factor bank --------------------------------
+#
+# With more than one sweep a fit builds, reads back and factors each
+# block's Gram once and solves later visits against the kept float64
+# factor. The references: a plain float64 numpy BCD, and the per-step path
+# composed from the same programs (Gram rebuilt and refactored each step).
+
+
+@pytest.fixture
+def solver_counters():
+    from keystone_tpu.observability.registry import (
+        get_global_registry,
+        reset_global_registry,
+    )
+
+    reset_global_registry()
+    yield lambda name: get_global_registry().counter(
+        "keystone_solver_" + name + "_total"
+    ).get()
+    reset_global_registry()
+
+
+def _bcd_f64(X, Y, bs, num_iter, lam):
+    """Centred Gauss-Seidel BCD in float64, every system solved anew."""
+    X = np.asarray(X, np.float64)
+    Y = np.asarray(Y, np.float64)
+    Xc = X - X.mean(0)
+    R = Y - Y.mean(0)
+    W = np.zeros((X.shape[1], Y.shape[1]))
+    for _ in range(num_iter):
+        for s in range(0, X.shape[1], bs):
+            Xb = Xc[:, s:s + bs]
+            R = R + Xb @ W[s:s + bs]
+            G = Xb.T @ Xb + lam * np.eye(Xb.shape[1])
+            W[s:s + bs] = np.linalg.solve(G, Xb.T @ R)
+            R = R - Xb @ W[s:s + bs]
+    return W
+
+
+def _per_step_host_fit(X, Y, bs, num_iter, lam):
+    """The host path as it was before the bank: ``_block_stats`` and a
+    whole ``psd_solve_host`` (read back, factor, solve) in every step."""
+    from keystone_tpu.ops.learning import block_ls
+    from keystone_tpu.ops.learning.hostsolve import psd_solve_host
+
+    data = Dataset.of(X).to_array_mode()
+    Xp, n, mask = data.padded(), data.n, data.mask()
+    mu, _, R = block_ls._prep(
+        Xp, Dataset.of(Y).to_array_mode().padded(), mask, n
+    )
+    starts = range(0, X.shape[1], bs)
+    Wb = {
+        s: jnp.zeros((min(bs, X.shape[1] - s), Y.shape[1]), jnp.float32)
+        for s in starts
+    }
+    for _ in range(num_iter):
+        for s in starts:
+            w = Wb[s].shape[0]
+            gram, rhs, R_plus = block_ls._block_stats(
+                Xp, R, Wb[s], mu, mask, s, width=w, n=n
+            )
+            Wb[s] = jnp.asarray(psd_solve_host(gram, rhs, lam))
+            R = block_ls._residual_update(
+                Xp, R_plus, Wb[s], mu, mask, s, width=w
+            )
+    return np.concatenate([np.asarray(Wb[s]) for s in starts])
+
+
+def _bank_problem(seed, n=96, d=12, k=3):
+    rng = np.random.default_rng(seed)
+    X = rng.standard_normal((n, d)).astype(np.float32) + 0.5
+    Y = (
+        X @ rng.standard_normal((d, k)) + 0.1 * rng.standard_normal((n, k))
+    ).astype(np.float32)
+    return X, Y
+
+
+@pytest.mark.parametrize("lam", [0.0, 0.3])
+def test_block_ls_host_bank_matches_f64_bcd_and_per_step_path(
+    lam, solver_counters
+):
+    X, Y = _bank_problem(10)
+    est = BlockLeastSquaresEstimator(4, num_iter=3, lam=lam, solve="host")
+    W = np.asarray(est.fit(Dataset.of(X), Dataset.of(Y)).W)
+    # 3 blocks x 3 sweeps: a Gram per block, then the kept factor
+    assert solver_counters("gram_builds") == 3
+    assert solver_counters("factor_reuses") == 6
+    assert solver_counters("host_solves") == 9
+    assert solver_counters("block_steps") == 9
+    np.testing.assert_allclose(
+        W, _bcd_f64(X, Y, 4, 3, lam), rtol=1e-4, atol=1e-5
+    )
+    np.testing.assert_allclose(
+        W, _per_step_host_fit(X, Y, 4, 3, lam), rtol=1e-5, atol=1e-6
+    )
+
+
+@pytest.mark.parametrize("bad_blocks", [(1,), (0, 2)])
+def test_block_ls_host_bank_keeps_the_eigh_form(bad_blocks, solver_counters):
+    """A zero column makes the block's Gram singular and ``cho_factor``
+    raise: the block takes the eigh form once and is solved against that
+    form in every later sweep, with the per-step path's result."""
+    X, Y = _bank_problem(11)
+    for b in bad_blocks:
+        X[:, 4 * b + 1] = 0.0
+    est = BlockLeastSquaresEstimator(4, num_iter=3, solve="host")
+    W = np.asarray(est.fit(Dataset.of(X), Dataset.of(Y)).W)
+    assert solver_counters("host_solve_fallbacks") == len(bad_blocks)
+    assert solver_counters("gram_builds") == 3
+    assert solver_counters("factor_reuses") == 6
+    assert np.all(np.isfinite(W))
+    np.testing.assert_allclose(
+        W, _per_step_host_fit(X, Y, 4, 3, 0.0), rtol=1e-5, atol=1e-6
+    )
+    # the per-step path fell back in every sweep
+    assert solver_counters("host_solve_fallbacks") == 4 * len(bad_blocks)
+
+
+class _Interrupt(Exception):
+    pass
+
+
+@pytest.mark.parametrize(
+    "die_after,grams,reuses", [(4, 3, 2), (5, 3, 1), (7, 2, 0)]
+)
+def test_block_ls_host_bank_resumed_fit_builds_each_gram_once(
+    die_after, grams, reuses, tmp_path, solver_counters
+):
+    """A fit resumed from a checkpoint enters a later sweep with an empty
+    bank: every block it still visits builds its Gram on the first of
+    those visits, whatever the sweep, and reuses the factor after."""
+    import dataclasses
+
+    X, Y = _bank_problem(12)
+    Xd, Yd = Dataset.of(X), Dataset.of(Y)
+    base = BlockLeastSquaresEstimator(4, num_iter=3, lam=0.1, solve="host")
+    W_whole = np.asarray(base.fit(Xd, Yd).W)
+
+    def die(done):
+        if done == die_after:
+            raise _Interrupt
+
+    path = str(tmp_path / "bls.npz")
+    with pytest.raises(_Interrupt):
+        dataclasses.replace(
+            base, checkpoint_path=path, checkpoint_every=1,
+            block_callback=die,
+        ).fit(Xd, Yd)
+    before = {
+        c: solver_counters(c)
+        for c in ("gram_builds", "factor_reuses", "block_steps")
+    }
+    resumed = dataclasses.replace(
+        base, checkpoint_path=path, checkpoint_every=1
+    )
+    W_resumed = np.asarray(resumed.fit(Xd, Yd).W)
+    assert solver_counters("block_steps") - before["block_steps"] == (
+        9 - die_after
+    )
+    assert solver_counters("gram_builds") - before["gram_builds"] == grams
+    assert solver_counters("factor_reuses") - before["factor_reuses"] == (
+        reuses
+    )
+    np.testing.assert_allclose(W_resumed, W_whole, rtol=2e-4, atol=2e-5)
+
+
+def test_block_ls_host_bank_does_not_outlive_a_fit(solver_counters):
+    """Two fits of one estimator instance on different data of the same
+    shape: the second builds its own Grams and gets its own model."""
+    est = BlockLeastSquaresEstimator(4, num_iter=3, lam=0.1, solve="host")
+    models = []
+    for seed in (13, 14):
+        X, Y = _bank_problem(seed)
+        models.append((X, Y, np.asarray(
+            est.fit(Dataset.of(X), Dataset.of(Y)).W
+        )))
+    assert solver_counters("gram_builds") == 6
+    assert solver_counters("factor_reuses") == 12
+    for X, Y, W in models:
+        np.testing.assert_allclose(
+            W, _bcd_f64(X, Y, 4, 3, 0.1), rtol=1e-4, atol=1e-5
+        )
+    assert np.abs(models[0][2] - models[1][2]).max() > 1e-2
+
+
+@pytest.mark.parametrize(
+    "solve,num_iter", [("host", 1), ("device", 1), ("device", 3)]
+)
+def test_block_ls_keeps_no_factor_without_a_second_host_sweep(
+    solve, num_iter, solver_counters, monkeypatch
+):
+    """One sweep never comes back to a block, and the device solve factors
+    on the chip: neither keeps a factor, both build a Gram every step."""
+    from keystone_tpu.ops.learning import block_ls
+
+    def no_rhs_program(*a, **k):
+        raise AssertionError("_block_stats_rhs ran with no factor kept")
+
+    monkeypatch.setattr(block_ls, "_block_stats_rhs", no_rhs_program)
+    X, Y = _bank_problem(15)
+    est = BlockLeastSquaresEstimator(
+        4, num_iter=num_iter, lam=0.1, solve=solve
+    )
+    W = np.asarray(est.fit(Dataset.of(X), Dataset.of(Y)).W)
+    assert solver_counters("factor_reuses") == 0
+    assert solver_counters("gram_builds") == 3 * num_iter
+    assert solver_counters("block_steps") == 3 * num_iter
+    np.testing.assert_allclose(
+        W, _bcd_f64(X, Y, 4, num_iter, 0.1), rtol=2e-3, atol=2e-4
+    )
+
+
+@pytest.mark.parametrize("form", ["cholesky", "eigh"])
+def test_host_factor_solves_later_rhs_as_a_whole_solve_would(
+    form, solver_counters
+):
+    """The two halves of ``psd_solve_host``: the factor handed back with
+    the first solution solves another right-hand side to the result of a
+    whole solve, in the form the first solve took."""
+    from keystone_tpu.ops.learning.hostsolve import (
+        psd_factor_solve_host,
+        psd_solve_factored_host,
+        psd_solve_host,
+    )
+
+    rng = np.random.default_rng(16)
+    A = rng.standard_normal((20, 6))
+    G = A.T @ A
+    if form == "eigh":
+        G[2, :] = G[:, 2] = 0.0  # a zero pivot: cho_factor raises
+    rhs1, rhs2 = rng.standard_normal((2, 6, 3))
+    if form == "eigh":
+        rhs1[2] = rhs2[2] = 0.0
+    lam = 0.0 if form == "eigh" else 0.05
+    W1, factor = psd_factor_solve_host(G, rhs1, lam)
+    assert factor.form == form
+    assert solver_counters("host_solve_fallbacks") == (form == "eigh")
+    np.testing.assert_array_equal(W1, psd_solve_host(G, rhs1, lam))
+    np.testing.assert_array_equal(
+        psd_solve_factored_host(factor, rhs2), psd_solve_host(G, rhs2, lam)
+    )
+    assert solver_counters("host_solves") == 4
+    ok = [i for i in range(6) if form != "eigh" or i != 2]
+    np.testing.assert_allclose(
+        (G + lam * np.eye(6))[np.ix_(ok, ok)] @ W1[ok], rhs1[ok],
+        rtol=1e-9, atol=1e-9,
+    )
