@@ -470,7 +470,14 @@ def bench_weighted_ls() -> None:
     block size 4096 (ImageNetSiftLcsFV.scala:139-142), 128 classes,
     262k examples (the reference published no time for this solver ->
     vs_baseline null; this row exists so the flagship's own solver has
-    a measured number, VERDICT r2 missing #3)."""
+    a measured number, VERDICT r2 missing #3).
+
+    Superseded, and not the record: the benchmark's cell
+    ``weighted-bcd-fit`` (BENCHMARK.json, PR 27) runs this solver through
+    the application's own ``fit_classifier`` at the application's
+    settings (4096 float32 features, 1,000 classes, lambda 6e-5, w 0.25),
+    which this row's 128 classes, 8,192 bf16 features and other lambda
+    and w are not."""
     from keystone_tpu.ops.learning import BlockWeightedLeastSquaresEstimator
     from keystone_tpu.ops.util.nodes import ClassLabelIndicators
     from keystone_tpu.parallel.dataset import Dataset

@@ -18,6 +18,17 @@ then per-class covariances are one batched einsum over class chunks and
 the per-class (b, b) solves are one batched Cholesky, all on device.
 Total flops match the reference (Σ_c n_c·b² = n·b²); no shuffle, no
 driver round trip, no distributed System.gc().
+
+Observability: host spans ``solver.wls.prep`` (array mode, padding, the
+labels' cast), ``solver.wls.dispatch`` (the fit's device programs
+enqueued; on the chol path its host loop too) and
+``solver.wls.converged`` (the read of the CG exit residual that
+``convergence_check`` waits on); on the device ``jax.named_scope`` names
+``wls.setup`` / ``wls.stats`` / ``wls.precond`` / ``wls.cg`` /
+``wls.update``; counters ``keystone_solver_wls_fits_total``,
+``keystone_solver_wls_path_total{solve,layout}`` (the path ``auto``
+took) and ``keystone_solver_wls_pcg_iterations_total`` (the iterations
+a fit reports, added where ``convergence_check`` reads them anyway).
 """
 
 from __future__ import annotations
@@ -30,6 +41,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from keystone_tpu.observability.registry import get_global_registry
+from keystone_tpu.observability.tracing import span
 from keystone_tpu.ops.learning.block_ls import BlockLinearMapper, _f32_mm
 from keystone_tpu.parallel.dataset import Dataset
 from keystone_tpu.workflow.api import LabelEstimator
@@ -339,48 +352,50 @@ def _pcg_block_core(X, R, P, Wb, inv_counts, valid, start, w, lam,
 
     # -- population stats + per-class moments (pad rows of X and R are
     # zero by the Dataset padding contract) -------------------------------
-    if bf16_data:
-        gram = _dot00(Xb, Xb)
-        # ONE X_b read for all three moment contractions: class sums
-        # (one-hot columns), XᵀR (3 limbs), and Xᵀ(P⊙r) (3 limbs)
-        r = jnp.einsum("nc,nc->n", R, Pf)  # own-class residual per row
-        cols = jnp.concatenate(
-            [P, _limb3(R, 1), onehot_scale_limbs(r)], axis=1
-        )  # (n, 7C) bf16
-        G = _dot00(Xb, cols)  # (b, 7C)
-        C_ = R.shape[1]
-        cmean = G[:, :C_].T * inv_counts[:, None]  # (C, b)
-        pop_xtr = _sum3(G[:, C_: 4 * C_], axis=1) / n  # (b, C)
-        cxtr = (
-            _sum3(G[:, 4 * C_:], axis=1).T * inv_counts[:, None]
-        )  # (C, b)
-    else:
-        gram = jax.lax.dot_general(
-            Xb, Xb, (((0,), (0,)), ((), ())),
-            preferred_element_type=f32, precision=hp,
-        )
-        pop_xtr = mm_bf16_f32_00(R) / n  # (b, C)
-        cmean = jax.lax.dot_general(
-            Pf, Xb, (((0,), (0,)), ((), ())),
-            preferred_element_type=f32, precision=hp,
-        ) * inv_counts[:, None]
-        r = jnp.einsum("nc,nc->n", R, Pf)
-        cxtr = mm_bf16_f32_00(Pf * r[:, None]).T * inv_counts[:, None]
-    # popMean = Σ_c n_c·classMean_c / n (P already excludes pad rows and
-    # empty classes contribute zero) — no extra X pass
-    counts = valid / inv_counts
-    pop_mean = jnp.einsum("c,cb->b", counts, cmean) / n
-    pop_cov = gram / n - jnp.outer(pop_mean, pop_mean)
-    residual_mean = jnp.einsum("nc->c", R) / n
-    rlm = jnp.einsum("nc,n->c", Pf, r) * inv_counts
+    with jax.named_scope("wls.stats"):
+        if bf16_data:
+            gram = _dot00(Xb, Xb)
+            # ONE X_b read for all three moment contractions: class sums
+            # (one-hot columns), XᵀR (3 limbs), and Xᵀ(P⊙r) (3 limbs)
+            # own-class residual per row
+            r = jnp.einsum("nc,nc->n", R, Pf)
+            cols = jnp.concatenate(
+                [P, _limb3(R, 1), onehot_scale_limbs(r)], axis=1
+            )  # (n, 7C) bf16
+            G = _dot00(Xb, cols)  # (b, 7C)
+            C_ = R.shape[1]
+            cmean = G[:, :C_].T * inv_counts[:, None]  # (C, b)
+            pop_xtr = _sum3(G[:, C_: 4 * C_], axis=1) / n  # (b, C)
+            cxtr = (
+                _sum3(G[:, 4 * C_:], axis=1).T * inv_counts[:, None]
+            )  # (C, b)
+        else:
+            gram = jax.lax.dot_general(
+                Xb, Xb, (((0,), (0,)), ((), ())),
+                preferred_element_type=f32, precision=hp,
+            )
+            pop_xtr = mm_bf16_f32_00(R) / n  # (b, C)
+            cmean = jax.lax.dot_general(
+                Pf, Xb, (((0,), (0,)), ((), ())),
+                preferred_element_type=f32, precision=hp,
+            ) * inv_counts[:, None]
+            r = jnp.einsum("nc,nc->n", R, Pf)
+            cxtr = mm_bf16_f32_00(Pf * r[:, None]).T * inv_counts[:, None]
+        # popMean = Σ_c n_c·classMean_c / n (P already excludes pad rows
+        # and empty classes contribute zero) — no extra X pass
+        counts = valid / inv_counts
+        pop_mean = jnp.einsum("c,cb->b", counts, cmean) / n
+        pop_cov = gram / n - jnp.outer(pop_mean, pop_mean)
+        residual_mean = jnp.einsum("nc->c", R) / n
+        rlm = jnp.einsum("nc,n->c", Pf, r) * inv_counts
+        mean_diff = cmean - pop_mean[None, :]
+        jm = cmean * w + pop_mean[None, :] * (1.0 - w)
+        mmw = residual_mean * (1.0 - w) + w * rlm
+        joint_xtr = pop_xtr.T * (1.0 - w) + cxtr * w - jm * mmw[:, None]
+        rhs = joint_xtr - Wb.T * lam  # (C, b)
 
-    Minv = _precond_inverse(pop_cov, w, lam)
-
-    mean_diff = cmean - pop_mean[None, :]
-    jm = cmean * w + pop_mean[None, :] * (1.0 - w)
-    mmw = residual_mean * (1.0 - w) + w * rlm
-    joint_xtr = pop_xtr.T * (1.0 - w) + cxtr * w - jm * mmw[:, None]
-    rhs = joint_xtr - Wb.T * lam  # (C, b)
+    with jax.named_scope("wls.precond"):
+        Minv = _precond_inverse(pop_cov, w, lam)
 
     def matvec(v):  # (C, b) -> (C, b)
         pv = (1.0 - w) * jnp.matmul(v, pop_cov, precision=hp)
@@ -438,16 +453,19 @@ def _pcg_block_core(X, R, P, Wb, inv_counts, valid, start, w, lam,
     # warm start + exact restart — was measured at parity: the cheaper
     # operator's error perturbs the CG directions enough that total
     # iterations grow ~20%, cancelling the per-iteration savings.)
-    x0 = jnp.zeros_like(rhs)
-    it, dW, r_fin, _, _, _ = cg_loop(
-        matvec, x0, rhs, jnp.asarray(0), max_iters, tol
-    )
+    with jax.named_scope("wls.cg"):
+        x0 = jnp.zeros_like(rhs)
+        it, dW, r_fin, _, _, _ = cg_loop(
+            matvec, x0, rhs, jnp.asarray(0), max_iters, tol
+        )
+        rel = rel_res(r_fin)
 
     # -- apply the update --------------------------------------------------
-    delta = (dW * valid[:, None]).T  # (b, C), empty classes masked
-    Wb_new = Wb + delta
-    R_new = R - mm_bf16_f32_10(delta)
-    return Wb_new, R_new, jm * valid[:, None], rel_res(r_fin), it
+    with jax.named_scope("wls.update"):
+        delta = (dW * valid[:, None]).T  # (b, C), empty classes masked
+        Wb_new = Wb + delta
+        R_new = R - mm_bf16_f32_10(delta)
+    return Wb_new, R_new, jm * valid[:, None], rel, it
 
 
 @partial(
@@ -476,15 +494,17 @@ def _pcg_setup_core(Y, mask, w, n):
     # labels whose positive entries are NOT all equal (arbitrary
     # real-valued Y) would need a true argmax — the estimator's
     # docstring pins indicator-style labels for this path.
-    pos = Y > 0
-    first_pos = pos & (jnp.cumsum(pos, axis=1) == 1)
-    P = first_pos.astype(jnp.bfloat16) * mask[:, None].astype(jnp.bfloat16)
-    counts = jnp.einsum("nc->c", P.astype(jnp.float32))
-    inv_counts = 1.0 / jnp.maximum(counts, 1.0)
-    valid = (counts > 0).astype(jnp.float32)
-    # jointLabelMean[c] = 2w + 2(1-w)·n_c/n − 1 (reference :148-155)
-    jlm = 2.0 * w + 2.0 * (1.0 - w) * counts / n - 1.0
-    R = (Y - jlm[None, :]) * mask[:, None]
+    with jax.named_scope("wls.setup"):
+        pos = Y > 0
+        first_pos = pos & (jnp.cumsum(pos, axis=1) == 1)
+        P = (first_pos.astype(jnp.bfloat16)
+             * mask[:, None].astype(jnp.bfloat16))
+        counts = jnp.einsum("nc->c", P.astype(jnp.float32))
+        inv_counts = 1.0 / jnp.maximum(counts, 1.0)
+        valid = (counts > 0).astype(jnp.float32)
+        # jointLabelMean[c] = 2w + 2(1-w)·n_c/n − 1 (reference :148-155)
+        jlm = 2.0 * w + 2.0 * (1.0 - w) * counts / n - 1.0
+        R = (Y - jlm[None, :]) * mask[:, None]
     return P, inv_counts, valid, jlm, R
 
 
@@ -565,6 +585,20 @@ def _class_chunk_stats_gathered(
     return class_cov, class_mean, class_xtr, res_local_mean
 
 
+def _count_fit(solve: str, layout: str) -> None:
+    """Count one weighted fit started and the path it takes."""
+    reg = get_global_registry()
+    reg.counter(
+        "keystone_solver_wls_fits_total",
+        "mixture-weighted block least-squares fits started",
+    ).inc()
+    reg.counter(
+        "keystone_solver_wls_path_total",
+        "weighted fits by the solver and row layout taken",
+        labelnames=("solve", "layout"),
+    ).inc((solve, layout))
+
+
 @dataclasses.dataclass(eq=False)
 class BlockWeightedLeastSquaresEstimator(LabelEstimator):
     """fit(features, ±1 indicator labels) -> BlockLinearMapper
@@ -637,11 +671,16 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
                     "(solve='auto' or 'pcg'); the chol path gathers "
                     "class-grouped layouts from a device-resident X"
                 )
-            return self._fit_pcg_host(data, labels)
-        data = data.to_array_mode()
-        labels = labels.to_array_mode()
-        X = data.padded()
-        Y = labels.padded().astype(jnp.float32)
+            _count_fit("pcg", "host_blocks")
+            with span("solver.wls.dispatch"):
+                model = self._fit_pcg_host(data, labels)
+            self._check_convergence(model.solver_info)
+            return model
+        with span("solver.wls.prep"):
+            data = data.to_array_mode()
+            labels = labels.to_array_mode()
+            X = data.padded()
+            Y = labels.padded().astype(jnp.float32)
         n = data.n
         D = X.shape[1]
         blocks = [
@@ -657,9 +696,13 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
             and blocks[0][1] >= 1024
             and self.mixture_weight <= 0.9
         )
-        if use_pcg:
-            return self._fit_pcg(data, X, Y, n, blocks)
-        return self._fit_chol(data, X, Y, n, blocks)
+        with span("solver.wls.dispatch"):
+            if use_pcg:
+                model = self._fit_pcg(data, X, Y, n, blocks)
+            else:
+                model = self._fit_chol(data, X, Y, n, blocks)
+        self._check_convergence(model.solver_info)
+        return model
 
     def _fit_pcg(self, data, X, Y, n, blocks):
         """Batched all-class PCG on the original row layout (see
@@ -667,6 +710,7 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
         w = self.mixture_weight
         mask = data.mask()
         C = Y.shape[1]
+        _count_fit("pcg", "original")
         if len({wd for _, wd in blocks}) == 1:
             # uniform widths (every real config: block_size divides D or
             # one block): the ENTIRE fit — setup, every epoch's scanned
@@ -678,7 +722,6 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
                 X, Y, mask, starts, w, self.lam, width=wd, n=n,
                 num_iter=self.num_iter, tol=self.pcg_tol,
             )
-            self._check_convergence(pcg_rel, pcg_iters)
             return BlockLinearMapper(
                 W, self.block_size, explicit_intercept=intercept,
                 solver_info={"pcg_max_rel_residual": pcg_rel,
@@ -706,7 +749,6 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
                     jnp.maximum(pcg_iters, its)
                 )
 
-        self._check_convergence(pcg_rel, pcg_iters)
         return self._finish(blocks, Wb, joint_means, jlm, {
             "pcg_max_rel_residual": pcg_rel,
             "pcg_iterations": pcg_iters,
@@ -766,22 +808,28 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
             del Xb
             limiter.add(Wb[s])
 
-        self._check_convergence(pcg_rel, pcg_iters)
         return self._finish(blocks, Wb, joint_means, jlm, {
             "pcg_max_rel_residual": pcg_rel,
             "pcg_iterations": pcg_iters,
         })
 
-    def _check_convergence(self, pcg_rel, pcg_iters) -> None:
-        if self.convergence_check == "off":
+    def _check_convergence(self, solver_info: Optional[dict]) -> None:
+        if self.convergence_check == "off" or solver_info is None:
             return
-        # reading the device scalar syncs the dispatch stream; the CG
+        # reading the device scalars syncs the dispatch stream; the CG
         # loop exits with rel <= tol unless the iteration cap hit
-        rel_val = float(pcg_rel)
+        with span("solver.wls.converged"):
+            rel_val = float(solver_info["pcg_max_rel_residual"])
+            iters = int(solver_info["pcg_iterations"])
+        get_global_registry().counter(
+            "keystone_solver_wls_pcg_iterations_total",
+            "CG iterations of weighted fits (the most of any block "
+            "step), counted where convergence_check reads them",
+        ).inc(by=iters)
         if rel_val > self.pcg_tol:
             msg = (
                 f"weighted PCG hit its iteration cap "
-                f"(max {int(pcg_iters)} iters) with max relative "
+                f"(max {iters} iters) with max relative "
                 f"residual {rel_val:.2e} > tol {self.pcg_tol:.0e}; "
                 "the fit may be under-converged — try solve='chol', "
                 "a smaller mixture_weight, or a larger lam"
@@ -823,6 +871,7 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
             )
         else:
             use_grouped = self.layout == "grouped"
+        _count_fit("chol", "grouped" if use_grouped else "gathered")
         # clamp to 1 so empty-class divisions stay finite; their zero wt
         # rows already zero the numerators, and their delta is masked out
         counts_j = jnp.asarray(np.maximum(counts, 1), jnp.float32)

@@ -112,11 +112,36 @@ def compute_pca_and_fisher_branch(
     )
 
 
+def fit_classifier(
+    featurizer, train_data, train_labels, conf: ImageNetSiftLcsFVConfig
+) -> Pipeline:
+    """The fit from Fisher vectors to model (reference:
+    ImageNetSiftLcsFV.scala:136-143): ``featurizer`` → Cacher → the
+    mixture-weighted block solver at the reference's settings (block
+    4096, one pass, ``conf.lam``, ``conf.mixture_weight``), fitted on
+    ``featurizer(train_data)`` and the ±1 indicators of the integer
+    ``train_labels`` → TopKClassifier(5). ``build_pipeline`` ends in it;
+    a caller that already holds the combined Fisher vectors passes them
+    as ``train_data`` with ``Identity()`` as the featurizer."""
+    indicator_labels = ClassLabelIndicators(conf.num_classes)(train_labels)
+    num_features = 2 * 2 * conf.desc_dim * conf.vocab_size
+    return (
+        featurizer.and_then(Cacher())
+        .and_then(
+            BlockWeightedLeastSquaresEstimator(
+                4096, 1, conf.lam, conf.mixture_weight,
+                num_features=num_features,
+            ),
+            train_data,
+            indicator_labels,
+        )
+        .and_then(TopKClassifier(5))
+    )
+
+
 def build_pipeline(
     train_images: Dataset, train_labels, conf: ImageNetSiftLcsFVConfig
 ) -> Pipeline:
-    indicator_labels = ClassLabelIndicators(conf.num_classes)(train_labels)
-
     sift_prefix = (
         PixelScaler()
         .and_then(GrayScaler())
@@ -136,21 +161,10 @@ def build_pipeline(
         conf.lcs_gmm_files,
     )
 
-    num_features = 2 * 2 * conf.desc_dim * conf.vocab_size
-    return (
-        Pipeline.gather([sift_branch, lcs_branch])
-        .and_then(VectorCombiner())
-        .and_then(Cacher())
-        .and_then(
-            BlockWeightedLeastSquaresEstimator(
-                4096, 1, conf.lam, conf.mixture_weight,
-                num_features=num_features,
-            ),
-            train_images,
-            indicator_labels,
-        )
-        .and_then(TopKClassifier(5))
+    featurizer = Pipeline.gather([sift_branch, lcs_branch]).and_then(
+        VectorCombiner()
     )
+    return fit_classifier(featurizer, train_images, train_labels, conf)
 
 
 def run(train_data: Dataset, test_data: Dataset, conf: ImageNetSiftLcsFVConfig):
