@@ -20,7 +20,12 @@ Span names, one per layer boundary and never one per item:
 - workflow: ``node:<label>`` around each graph node's own work (its
   dependencies are forced before it opens), ``workflow.optimize``,
   ``workflow.upload`` / ``.stack`` / ``.apply`` / ``.slice`` (the phases
-  of ``Transformer._bucketed_batch``, the last three per chunk),
+  of ``Transformer._bucketed_batch`` / ``_chunked_batch``: the upload
+  once a call, the others once a chunk — ``.stack`` takes a chunk's rows
+  and pads the tail, ``.slice`` drops the pad rows and joins the chunks'
+  outputs, or cuts a ragged group's back into items; counter
+  ``keystone_workflow_array_items_total`` over ``_items_total`` is the
+  share of items that stayed an array),
   ``workflow.map_items`` / ``.to_array`` / ``.to_items`` (``Dataset``);
 - solvers: ``solver.prep``, and per block step ``solver.block_stats``,
   ``solver.readback``, ``solver.host_solve`` (attr ``fallback``),

@@ -10,7 +10,10 @@ Three physical modes:
 - **items mode**: a host-side list of per-example Python objects (ragged
   arrays, images of varying size, token lists). This replaces RDDs of
   non-uniform records; operators map over it on host and convert to array
-  mode as soon as shapes become uniform.
+  mode as soon as shapes become uniform. The data decide, not the caller:
+  ``uniform_array`` hands a node the items as one array when they all
+  share one shape and dtype, and ``Transformer._bucketed_batch`` then
+  returns array mode; only items of two or more shapes stay items.
 - **host-blocks mode**: a feature matrix column-blocked into HOST-RAM
   numpy arrays (each (padded_n, w_i), C-contiguous). This is the
   out-of-aggregate-HBM training substrate: the reference caches features
@@ -122,6 +125,7 @@ class Dataset:
         else:
             self._n = len(items)
         self._cached = False
+        self._uploaded: Any = None
 
     # -- constructors ------------------------------------------------------
 
@@ -287,6 +291,34 @@ class Dataset:
                 jax.tree_util.tree_map(lambda a, i=i: a[i], host)
                 for i in range(self._n)
             ]
+
+    def uniform_array(self) -> Optional[Any]:
+        """The items as one array with a leading item axis when every item
+        is an array of one shape and dtype, else None (ragged items, or
+        items that are not single arrays). Host items cost one
+        ``np.stack`` and one put, and that array is kept: a second node
+        fed by this same ``Dataset`` finds it and uploads nothing. Device
+        items cost one ``jnp.stack``, not kept (it would hold a second
+        copy of data the device already has)."""
+        if self._uploaded is not None:
+            return self._uploaded
+        items = self.items()
+        keys = {
+            (x.shape, str(x.dtype))
+            if hasattr(x, "shape") and hasattr(x, "dtype")
+            else None
+            for x in items
+        }
+        if len(keys) != 1 or None in keys:
+            return None
+        h2d = HostPuts()
+        if any(isinstance(x, jax.Array) for x in items):
+            stacked = jnp.stack([h2d.asarray(x) for x in items])
+            h2d.count(h2d.puts)  # a put for each item still on the host
+            return stacked
+        self._uploaded = h2d.asarray(np.stack(items))
+        h2d.count(len(items))
+        return self._uploaded
 
     def __iter__(self):
         return iter(self.items())

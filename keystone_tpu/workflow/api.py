@@ -51,11 +51,65 @@ from keystone_tpu.workflow.operators import (
 from keystone_tpu.workflow.rules import UnusedBranchRemovalRule
 
 
-# items per jit(vmap) dispatch of one shape group in
-# ``Transformer._bucketed_batch``. Dense SIFT at 256x256 keeps ~20 MB per
-# image in flight, so one dispatch over a whole training set does not fit
-# a 16 GB chip; 128 is the chunk bench.py's flagship rows settled on.
+# rows per jit(vmap) dispatch of a ``bucket_vmap`` node outside ``jit``.
+# Dense SIFT at 256x256 keeps ~20 MB per image in flight, so one dispatch
+# over a whole training set does not fit a 16 GB chip; 128 is the chunk
+# bench.py's flagship rows settled on. What the node is handed decides the
+# rest: items of one shape and dtype, or an array, go through as slices of
+# one array and come back in array mode (``_chunked_batch``); items of two
+# or more shapes are grouped by shape and come back as items
+# (``_bucketed_batch``); a tracer, or an array of at most this many rows,
+# is one ``vmap`` call.
 BUCKET_CHUNK = 128
+
+
+def _more_than_a_chunk(x: Any) -> bool:
+    """A concrete array of more than BUCKET_CHUNK rows. A tracer is not
+    one: inside ``jit`` the compiler schedules the memory, and a staged
+    program keeps its single ``vmap``."""
+    return (
+        isinstance(x, (jax.Array, np.ndarray))
+        and not isinstance(x, jax.core.Tracer)
+        and x.shape[0] > BUCKET_CHUNK
+    )
+
+
+def _zero_padded(batch: Any, rows: int) -> Any:
+    """``batch`` with zero rows appended up to ``rows``."""
+    short = rows - batch.shape[0]
+    if not short:
+        return batch
+    pad = jnp.zeros((short,) + batch.shape[1:], batch.dtype)
+    return jnp.concatenate([batch, pad])
+
+
+def _count_chunked(
+    items: int, chunks: int, padded: int, array_items: int
+) -> None:
+    """Publish one call of ``Transformer._bucketed_batch`` or
+    ``_chunked_batch``: ``array_items`` of its ``items`` left in array
+    mode, the others were cut back into items, a slice each."""
+    reg = get_global_registry()
+    reg.counter(
+        "keystone_workflow_items_total",
+        "items through Transformer._bucketed_batch / _chunked_batch",
+    ).inc(by=items)
+    reg.counter(
+        "keystone_workflow_array_items_total",
+        "items of those that left in array mode, never cut into items",
+    ).inc(by=array_items)
+    reg.counter(
+        "keystone_workflow_chunks_total",
+        "jit(vmap) chunk dispatches of Transformer._bucketed_batch",
+    ).inc(by=chunks)
+    reg.counter(
+        "keystone_workflow_padded_rows_total",
+        "zero rows that padded a short chunk to the chunk's shape",
+    ).inc(by=padded)
+    reg.counter(
+        "keystone_workflow_item_slices_total",
+        "per-item slices that cut chunk outputs back into items",
+    ).inc(by=items - array_items)
 
 
 def _array_digest(a: np.ndarray) -> Any:
@@ -339,27 +393,34 @@ class Transformer(Chainable, TransformerOperator):
 
     def apply_batch(self, ds: Dataset) -> Dataset:
         if ds.is_array and (self.vmap_batch or self.bucket_vmap):
-            return Dataset.from_array(
-                self._jitted_vmap()(ds.padded()), n=ds.n
-            )
+            x = ds.padded()
+            if self.bucket_vmap and _more_than_a_chunk(x):
+                return self._chunked_batch(x, ds.n)
+            return Dataset.from_array(self._jitted_vmap()(x), n=ds.n)
         if self.bucket_vmap:
             return self._bucketed_batch(ds)
         return ds.map(self.apply)
 
     def _bucketed_batch(self, ds: Dataset) -> Dataset:
-        """Items grouped by shape, each group through ``jit(vmap(apply))``
-        in chunks. Spans: ``workflow.upload`` once, ``workflow.stack`` /
-        ``.apply`` / ``.slice`` per chunk — never one per item."""
+        """Items through ``jit(vmap(apply))`` in chunks. Items of one shape
+        and dtype become one array, go through ``_chunked_batch`` and come
+        back in array mode; ragged items are grouped by shape, each group
+        in chunks, and come back as items. Spans: ``workflow.upload`` once,
+        ``workflow.stack`` / ``.apply`` / ``.slice`` per chunk — never one
+        per item."""
         items = ds.items()
-        by_shape: Dict[tuple, List[int]] = {}
         arrays = []
-        h2d = HostPuts()
         with span("workflow.upload", n=len(items)):
-            for i, x in enumerate(items):
-                a = h2d.asarray(x)
-                arrays.append(a)
-                by_shape.setdefault((a.shape, str(a.dtype)), []).append(i)
-        h2d.count(h2d.puts)  # an item is one array here: a put each
+            batch = ds.uniform_array()
+            if batch is None:
+                h2d = HostPuts()
+                arrays = [h2d.asarray(x) for x in items]
+                h2d.count(h2d.puts)  # an item is one array here: a put each
+        if batch is not None:
+            return self._chunked_batch(batch, len(items))
+        by_shape: Dict[tuple, List[int]] = {}
+        for i, a in enumerate(arrays):
+            by_shape.setdefault((a.shape, str(a.dtype)), []).append(i)
         out: List[Any] = [None] * len(items)
         fn = self._jitted_vmap()
         chunks = padded = 0
@@ -373,13 +434,9 @@ class Transformer(Chainable, TransformerOperator):
             for s in range(0, len(idxs), chunk):
                 part = idxs[s : s + chunk]
                 with span("workflow.stack", n=len(part)):
-                    batch = jnp.stack([arrays[i] for i in part])
-                    if len(part) < chunk:
-                        pad = jnp.zeros(
-                            (chunk - len(part),) + batch.shape[1:],
-                            batch.dtype,
-                        )
-                        batch = jnp.concatenate([batch, pad])
+                    batch = _zero_padded(
+                        jnp.stack([arrays[i] for i in part]), chunk
+                    )
                 with span("workflow.apply", n=chunk):
                     res = fn(batch)
                 with span("workflow.slice", n=len(part)):
@@ -389,24 +446,39 @@ class Transformer(Chainable, TransformerOperator):
                         )
                 chunks += 1
                 padded += chunk - len(part)
-        reg = get_global_registry()
-        reg.counter(
-            "keystone_workflow_items_total",
-            "items through Transformer._bucketed_batch",
-        ).inc(by=len(items))
-        reg.counter(
-            "keystone_workflow_chunks_total",
-            "jit(vmap) chunk dispatches of Transformer._bucketed_batch",
-        ).inc(by=chunks)
-        reg.counter(
-            "keystone_workflow_padded_rows_total",
-            "zero rows that padded a short chunk to the chunk's shape",
-        ).inc(by=padded)
-        reg.counter(
-            "keystone_workflow_item_slices_total",
-            "per-item slices that cut chunk outputs back into items",
-        ).inc(by=len(items))
+        _count_chunked(len(items), chunks, padded, array_items=0)
         return Dataset.from_items(out)
+
+    def _chunked_batch(self, x: Any, n: int) -> Dataset:
+        """The rows of one array through ``jit(vmap(apply))``, a chunk of
+        at most BUCKET_CHUNK rows a dispatch (the tail zero-padded to the
+        chunk's shape, so all chunks share one program), the outputs
+        joined into an array-mode result of the same rows, ``n`` of them
+        valid. Spans per chunk: ``workflow.stack`` takes the chunk and pads
+        it, ``.apply`` dispatches it, ``.slice`` drops the pad rows a
+        featurizer made of the zeros (it does not map them to zeros) and,
+        in the last chunk, joins the outputs."""
+        rows = x.shape[0]
+        chunk = min(rows, BUCKET_CHUNK)
+        fn = self._jitted_vmap()
+        outs: List[Any] = []
+        for s in range(0, rows, chunk):
+            valid = min(chunk, rows - s)
+            with span("workflow.stack", n=valid):
+                part = x if valid == rows else x[s : s + valid]
+                part = _zero_padded(part, chunk)
+            with span("workflow.apply", n=chunk):
+                res = fn(part)
+            with span("workflow.slice", n=valid):
+                if valid < chunk:
+                    res = jax.tree_util.tree_map(lambda a: a[:valid], res)
+                outs.append(res)
+                if s + chunk >= rows:
+                    joined = res if len(outs) == 1 else jax.tree_util.tree_map(
+                        lambda *parts: jnp.concatenate(parts), *outs
+                    )
+        _count_chunked(n, len(outs), len(outs) * chunk - rows, array_items=n)
+        return Dataset.from_array(joined, n=n)
 
     # TransformerOperator ABI
     def single_transform(self, inputs: Sequence[Any]) -> Any:

@@ -590,20 +590,37 @@ def test_bucketed_batch_counters(monkeypatch):
     host = [np.full((4, 4, 3), i, np.uint8) for i in range(5)]
     reset_global_registry()
     try:
+        # host items of one shape: one array, one put, no item ever cut
         out = _bucketed(monkeypatch, host)()
-        assert len(out.items()) == 5
+        assert out.is_array and len(out.items()) == 5
         assert _counter("keystone_workflow_items_total") == 5
+        assert _counter("keystone_workflow_array_items_total") == 5
+        assert _counter("keystone_workflow_chunks_total") == 3
+        assert _counter("keystone_workflow_padded_rows_total") == 1
+        assert _counter("keystone_workflow_item_slices_total") == 0
+        assert _counter("keystone_workflow_h2d_items_total") == 5
+        assert _counter("keystone_workflow_h2d_transfers_total") == 1
+        assert _counter("keystone_workflow_h2d_bytes_total") == 5 * 48
+        reset_global_registry()
+        _bucketed(monkeypatch, [jnp.asarray(x) for x in host])()
+        assert _counter("keystone_workflow_items_total") == 5
+        assert _counter("keystone_workflow_array_items_total") == 5
+        assert _counter("keystone_workflow_item_slices_total") == 0
+        assert _counter("keystone_workflow_h2d_items_total") == 0
+        assert _counter("keystone_workflow_h2d_transfers_total") == 0
+        reset_global_registry()
+        # ragged: a put and a slice an item, as before; chunks 2 + 1 and 2
+        ragged = host[:3] + [np.zeros((2, 6, 3), np.uint8)] * 2
+        out = _bucketed(monkeypatch, ragged)()
+        assert not out.is_array and len(out.items()) == 5
+        assert _counter("keystone_workflow_items_total") == 5
+        assert _counter("keystone_workflow_array_items_total") == 0
         assert _counter("keystone_workflow_chunks_total") == 3
         assert _counter("keystone_workflow_padded_rows_total") == 1
         assert _counter("keystone_workflow_item_slices_total") == 5
         assert _counter("keystone_workflow_h2d_items_total") == 5
         assert _counter("keystone_workflow_h2d_transfers_total") == 5
-        assert _counter("keystone_workflow_h2d_bytes_total") == 5 * 48
-        reset_global_registry()
-        _bucketed(monkeypatch, [jnp.asarray(x) for x in host])()
-        assert _counter("keystone_workflow_items_total") == 5
-        assert _counter("keystone_workflow_h2d_items_total") == 0
-        assert _counter("keystone_workflow_h2d_transfers_total") == 0
+        assert _counter("keystone_workflow_h2d_bytes_total") == 3 * 48 + 2 * 36
     finally:
         reset_global_registry()
 

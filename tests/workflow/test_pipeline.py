@@ -258,13 +258,31 @@ def test_bucketed_batch_chunks_large_shape_groups(monkeypatch):
 
     monkeypatch.setattr(api, "BUCKET_CHUNK", 4)
     seen = []
+    rng = np.random.default_rng(0)
+    # 9 items of one shape (chunks 4+4+1, the tail padded to 4)
+    # interleaved with 4 of another (one dispatch of 4)
+    items = [
+        rng.standard_normal((3, 5) if i % 4 else (2, 7)).astype(np.float32)
+        for i in range(13)
+    ]
+    out = _row_sums(seen)._bucketed_batch(Dataset.from_items(items)).items()
+    assert len(out) == 13
+    for x, y in zip(items, out):
+        np.testing.assert_allclose(np.asarray(y), x.sum(0) + 1.0, rtol=1e-6)
+    big = [s for s in seen if s[1:] == (3, 5)]
+    small = [s for s in seen if s[1:] == (2, 7)]
+    assert [s[0] for s in big] == [4, 4, 4]
+    assert [s[0] for s in small] == [4]
 
+
+def _row_sums(seen, pytree=False):
     class RowSums(Transformer):
         vmap_batch = False
         bucket_vmap = True
 
         def apply(self, x):
-            return jnp.sum(x, axis=0) + 1.0
+            y = jnp.sum(x, axis=0) + 1.0
+            return (y, {"twice": 2.0 * x}) if pytree else y
 
         def _jitted_vmap(self):
             fn = super()._jitted_vmap()
@@ -275,18 +293,105 @@ def test_bucketed_batch_chunks_large_shape_groups(monkeypatch):
 
             return spy
 
+    return RowSums()
+
+
+@pytest.mark.parametrize(
+    "case",
+    [
+        "host_items", "device_items", "array_over_chunk",
+        "array_with_pad_rows", "array_under_chunk", "ragged_items",
+        "pytree_apply", "traced_array",
+    ],
+)
+def test_bucket_vmap_keeps_one_shape_an_array(monkeypatch, case):
+    """A bucket_vmap node handed items of one shape, or an array, works
+    on slices of one array, a chunk a dispatch, and returns array mode
+    with no pad rows of its own; ragged items come back as items; inside
+    jit the whole input is one vmap call. Results equal the per-item
+    apply, in order."""
+    import jax
+
+    from keystone_tpu.workflow import api
+
+    monkeypatch.setattr(api, "BUCKET_CHUNK", 4)
+    seen = []
+    node = _row_sums(seen, pytree=case == "pytree_apply")
     rng = np.random.default_rng(0)
-    # 9 items of one shape (chunks 4+4+1, the tail padded to 4)
-    # interleaved with 4 of another (one dispatch of 4)
+    n = 3 if case == "array_under_chunk" else 9
     items = [
-        rng.standard_normal((3, 5) if i % 4 else (2, 7)).astype(np.float32)
-        for i in range(13)
+        rng.standard_normal(
+            (2, 7) if case == "ragged_items" and i % 4 == 0 else (3, 5)
+        ).astype(np.float32)
+        for i in range(n)
     ]
-    out = RowSums()._bucketed_batch(Dataset.from_items(items)).items()
-    assert len(out) == 13
-    for x, y in zip(items, out):
+    rows = n
+    if case in ("array_over_chunk", "array_under_chunk"):
+        out = node.apply_batch(Dataset.from_array(jnp.asarray(items)))
+    elif case == "array_with_pad_rows":
+        rows = n + 1  # a zero row past n, as Dataset.shard leaves them
+        x = jnp.concatenate([jnp.asarray(items), jnp.zeros((1, 3, 5))])
+        out = node.apply_batch(Dataset.from_array(x, n=n))
+    elif case == "traced_array":
+        out = Dataset.from_array(jax.jit(
+            lambda x: node.apply_batch(Dataset.from_array(x)).padded()
+        )(jnp.asarray(items)))
+    elif case == "device_items":
+        out = node.apply_batch(
+            Dataset.from_items([jnp.asarray(x) for x in items])
+        )
+    else:
+        out = node.apply_batch(Dataset.from_items(items))
+    assert out.n == n
+    if case == "ragged_items":
+        assert not out.is_array
+        assert sorted(seen) == [(3, 2, 7)] + [(4, 3, 5)] * 2
+    else:
+        assert out.is_array and out.padded_n == rows
+        assert [s[0] for s in seen] == {
+            "array_under_chunk": [3], "traced_array": [9],
+        }.get(case, [4, 4, 4])
+    got = out.items()
+    assert len(got) == n
+    for x, y in zip(items, got):
+        if case == "pytree_apply":
+            y, extra = y
+            np.testing.assert_allclose(extra["twice"], 2.0 * x, rtol=1e-6)
         np.testing.assert_allclose(np.asarray(y), x.sum(0) + 1.0, rtol=1e-6)
-    big = [s for s in seen if s[1:] == (3, 5)]
-    small = [s for s in seen if s[1:] == (2, 7)]
-    assert [s[0] for s in big] == [4, 4, 4]
-    assert [s[0] for s in small] == [4]
+
+
+def test_flagship_featurizer_never_leaves_array_mode():
+    """The flagship featurizer on host images of one shape: every node
+    takes its array branch (no per-item map, no restacking of items) and
+    the features equal the per-image apply."""
+    from keystone_tpu.observability.tracing import (
+        disable_tracing, enable_tracing,
+    )
+    from keystone_tpu.serving.featurize import flagship_pipeline
+
+    pipe = flagship_pipeline(
+        np.random.default_rng(3), 8, 4, sift_step=4, sift_scales=2
+    )
+    images = [
+        np.random.default_rng(i).integers(0, 256, (64, 64, 3), np.uint8)
+        for i in range(3)
+    ]
+    want = np.stack(
+        [np.asarray(pipe.apply_datum(img).get()) for img in images]
+    )
+    tr = enable_tracing()
+    tr.clear()
+    try:
+        out = pipe(Dataset.from_items(images)).get()
+        names = {s.name for s in tr.recent()}
+    finally:
+        disable_tracing()
+        tr.clear()
+    assert out.is_array and out.padded_n == 3
+    # float32 rounding moves a few SIFT bins across the quantiser's
+    # floor(512 d): 1e-5 of the largest feature, not more
+    np.testing.assert_allclose(
+        np.asarray(out.array()), want, rtol=1e-4, atol=1e-5
+    )
+    assert "workflow.apply" in names
+    assert not names & {"workflow.map_items", "workflow.to_array"}
