@@ -28,7 +28,7 @@ JAX_PLATFORMS=cpu KEYSTONE_PEAK_FLOPS=1e12 KEYSTONE_PEAK_MEMBW_GBPS=100 \
 import sys, time
 import numpy as np
 from keystone_tpu.observability import enable_tracing, start_admin_server
-from keystone_tpu.serving.bench import build_pipeline
+from keystone_tpu.serving.demo_model import build_pipeline
 
 enable_tracing()
 server = start_admin_server(port=0)

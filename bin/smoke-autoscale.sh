@@ -1,30 +1,27 @@
 #!/usr/bin/env bash
 # Smoke-test autonomous fleet elasticity end to end:
+# the real SUBPROCESS drill — `serve-autoscale` stands up a
+# router + supervisor + SLO-driven policy loop and spawns
+# serve-gateway replicas as child processes (port-0
+# {"listening": ...} handshake, --register self-registration, a
+# shared AOT store so scale-out starts warm). Then:
+#   a. a `serve-loadgen --ramp` staircase drives the fleet past
+#      one replica's capacity — the supervisor must GROW the
+#      fleet (scale_up decision events + /fleetz shows >= 2
+#      replicas + keystone_autoscale_* series on /metrics);
+#   b. MID-SURGE — while the fleet is hot, so no scale-down can
+#      race the victim — one replica process is kill -9'd: the
+#      supervisor must REPLACE it (replica_died /
+#      replicas_replaced events) and the loadgen verdict must
+#      stay green through the death;
+#   c. the load stops — the control loop must DRAIN-RETIRE back
+#      to the 1-replica baseline (scale_down events, /fleetz
+#      back to 1, retired replicas deregistered not just dead);
+# and the loadgen invariant verdict for the ramp must be green
+# (nothing lost, typed sheds only).
 #
-#   1. the elasticity bench row (serving_autoscale_ramp) — a step-load
-#      ramp through an in-process router + autoscale control loop,
-#      router.replica.partition fired mid-scale-up, with scale-out,
-#      the loadgen invariant verdict, and drain-based scale-down all
-#      ASSERTED inside the row;
-#   2. the real SUBPROCESS drill — `serve-autoscale` stands up a
-#      router + supervisor + SLO-driven policy loop and spawns
-#      serve-gateway replicas as child processes (port-0
-#      {"listening": ...} handshake, --register self-registration, a
-#      shared AOT store so scale-out starts warm). Then:
-#        a. a `serve-loadgen --ramp` staircase drives the fleet past
-#           one replica's capacity — the supervisor must GROW the
-#           fleet (scale_up decision events + /fleetz shows >= 2
-#           replicas + keystone_autoscale_* series on /metrics);
-#        b. MID-SURGE — while the fleet is hot, so no scale-down can
-#           race the victim — one replica process is kill -9'd: the
-#           supervisor must REPLACE it (replica_died /
-#           replicas_replaced events) and the loadgen verdict must
-#           stay green through the death;
-#        c. the load stops — the control loop must DRAIN-RETIRE back
-#           to the 1-replica baseline (scale_down events, /fleetz
-#           back to 1, retired replicas deregistered not just dead);
-#      and the loadgen invariant verdict for the ramp must be green
-#      (nothing lost, typed sheds only).
+# The policy, the supervisor and the control loop in one process are
+# tests/autoscale/'s; the load ramp is tests/loadgen/test_ramp.py's.
 #
 # CI-friendly: CPU backend, localhost only, small pipeline, short
 # windows/cooldowns (the policy ARITHMETIC is under test, not
@@ -36,7 +33,6 @@ set -euo pipefail
 ROOT="$(cd "$(dirname "$0")/.." && pwd)"
 TMPDIR="$(mktemp -d)"
 AS_LOG="$TMPDIR/autoscale.log"
-BENCH_LOG="$TMPDIR/bench.log"
 VERDICT="$TMPDIR/verdict.json"
 AOT_CACHE="$TMPDIR/aot"
 REPLICA_LOGS="$TMPDIR/replicas"
@@ -54,21 +50,7 @@ trap cleanup EXIT
 
 D=48
 
-# ---- 1. the elasticity bench row (everything asserted in-row) -------------
-echo "== serving_autoscale_ramp bench row =="
-# the row carries its own bounded retry; the compile/AOT caches keep
-# per-replica warmup (which the scale-up reaction time includes) short
-if ! JAX_PLATFORMS=cpu PYTHONPATH="$ROOT" \
-    JAX_COMPILATION_CACHE_DIR="$TMPDIR/xc" KEYSTONE_AOT_CACHE="$AOT_CACHE" \
-    python -m keystone_tpu serve-bench --autoscale-only \
-    | tee "$BENCH_LOG" \
-    || ! grep '"metric": "serving_autoscale_ramp"' "$BENCH_LOG" \
-        | grep -q '"verdict": "green"'; then
-    echo "FAIL: serving_autoscale_ramp not green"; exit 1
-fi
-echo "PASS serving_autoscale_ramp (scale-out, green verdict, scale-down)"
-
-# ---- 2. the subprocess drill ----------------------------------------------
+# ---- the subprocess drill - ----------------------------------------------
 echo "== serve-autoscale: router + subprocess replicas =="
 JAX_PLATFORMS=cpu PYTHONPATH="$ROOT" \
     JAX_COMPILATION_CACHE_DIR="$TMPDIR/xc" \
@@ -134,7 +116,7 @@ done
     echo "FAIL: first replica never became ready"; tail -40 "$AS_LOG"; exit 1; }
 echo "PASS baseline (1 subprocess replica registered + ready)"
 
-# ---- 2a+2b. ramp load -> scale-out; kill -9 MID-SURGE -> replacement -----
+# ---- a+b. ramp load -> scale-out; kill -9 MID-SURGE -> replacement -----
 echo "== ramp: scale-out under SLO pressure + kill -9 mid-surge =="
 # calibrate the surge to this host: time one sequential request and
 # offer ~4x that rate (a fixed rate would be a no-op on a fast box)
@@ -220,7 +202,7 @@ fetch "$ROUTER/metrics" \
     echo "FAIL: replacement not counted on keystone_autoscale_replicas_replaced_total"; exit 1; }
 echo "PASS keystone_autoscale_* exported"
 
-# ---- 2c. load gone -> drain-based scale-down to baseline ------------------
+# ---- c. load gone -> drain-based scale-down to baseline ------------------
 echo "== idle: drain-based scale-down to the 1-replica baseline =="
 BASELINE=""
 for _ in $(seq 1 120); do

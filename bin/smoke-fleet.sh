@@ -1,35 +1,33 @@
 #!/usr/bin/env bash
 # Smoke-test the fleet tier end to end:
 #
-#   1. the fleet bench row (serving_router_failover) — open-loop load
-#      through an in-process router + two HTTP replicas, one replica's
-#      responses black-holed mid-run, the invariant verdict ASSERTED
-#      inside the row and the fleet p99 read from the router's own
-#      federated /metrics;
-#   2. a real THREE-process drill — serve-router + two serve-gateway
+#   1. a real THREE-process drill — serve-router + two serve-gateway
 #      replicas that self-register (--register) after binding
 #      ephemeral ports (--gateway-port 0 prints the bound address as
 #      a parseable JSON line — no port races), both pointed at ONE
 #      shared KEYSTONE_AOT_CACHE so replica #2 must start warm
 #      (keystone_aot_cache_hits_total > 0 on its own /metrics);
-#   3. chaos across hosts — serve-loadgen replays a synthetic trace
+#   2. chaos across hosts — serve-loadgen replays a synthetic trace
 #      through the ROUTER while replica #1's process is kill -9'd
 #      mid-load; the invariant checker must report green (zero lost
 #      futures, typed sheds only) and /fleetz must show the replica
 #      leave the healthy set;
-#   4. half-open recovery — replica #1 restarts AT THE SAME PORT;
+#   3. half-open recovery — replica #1 restarts AT THE SAME PORT;
 #      /fleetz must show it healthy again once router traffic
 #      half-opens and restores it;
-#   5. SLO federation — histogram_quantile over the router's
+#   4. SLO federation — histogram_quantile over the router's
 #      federated /metrics must agree with the per-replica quantiles
 #      to within one bucket boundary;
-#   6. distributed tracing — one /predict through the three-process
+#   5. distributed tracing — one /predict through the three-process
 #      drill must come back with an X-Keystone-Trace id that appears
 #      in BOTH processes' /tracez and stitches at the router's
 #      /debugz?trace_id= into one tree with spans from both processes
 #      and a phase decomposition summing to within 10% of the
-#      measured total (the serving_router_trace_overhead bench row in
-#      step 1 bounds the cost of all this at <= 1.05x p99).
+#      measured total.
+#
+# The in-process form (a router over two HTTP replicas, one black-holed
+# mid-run, verdict green, both replicas in the federated histogram) is
+# tests/fleet/test_router_http.py's.
 #
 # CI-friendly: CPU backend, localhost only, ~3 min.
 #
@@ -41,7 +39,6 @@ TMPDIR="$(mktemp -d)"
 ROUTER_LOG="$TMPDIR/router.log"
 R1_LOG="$TMPDIR/replica1.log"
 R2_LOG="$TMPDIR/replica2.log"
-BENCH_LOG="$TMPDIR/bench.log"
 VERDICT="$TMPDIR/verdict.json"
 AOT_CACHE="$TMPDIR/aot"
 cleanup() {
@@ -53,7 +50,7 @@ cleanup() {
 trap cleanup EXIT
 
 D=64
-# --trace: replicas adopt the router's W3C traceparent so step 6's
+# --trace: replicas adopt the router's W3C traceparent so step 5's
 # stitched-trace assertion has both halves to join
 GW_ARGS=(--d "$D" --hidden "$D" --depth 2 --buckets 4,16 --lanes 2 --trace)
 
@@ -96,43 +93,7 @@ sys.stdout.write(urllib.request.urlopen(sys.argv[1], timeout=float(sys.argv[2]))
     fi
 }
 
-# ---- 1. the fleet bench row (verdict + federation asserted in-row) -------
-# One bounded retry, same as smoke-chaos's drill: the row's
-# p99-recovery clock races the host scheduler (router + 2 replicas +
-# client threads share this box), so a single red attempt on a loaded
-# host gets one fresh chance (the row is idempotent — the fired-count
-# audit is delta-based) before the smoke fails for real.
-echo "== fleet bench rows (in-process router + HTTP replicas) =="
-# each row runs in its OWN process with its OWN bounded retry: a
-# p99-recovery (or p99-ratio) clock on a loaded 2-core host gets one
-# fresh chance per row, and the tracing A/B measures a quiet process
-# instead of the failover row's thread aftermath
-bench_row() {  # bench_row <rows> <metric>
-    local rows="$1" metric="$2" attempt
-    for attempt in 1 2; do
-        if JAX_PLATFORMS=cpu PYTHONPATH="$ROOT" \
-            python -m keystone_tpu serve-bench --fleet-only \
-            --fleet-rows "$rows" \
-            --d "$D" --hidden "$D" --depth 2 --buckets 4,16 --no-cache \
-            | tee "$BENCH_LOG" \
-            && grep "\"metric\": \"$metric\"" "$BENCH_LOG" \
-                | grep -q '"verdict": "green"'; then
-            return 0
-        fi
-        echo "$metric attempt $attempt not green; $([ "$attempt" -lt 2 ] \
-            && echo 'retrying once (host-load flake guard)' \
-            || echo 'out of retries')"
-    done
-    return 1
-}
-bench_row failover serving_router_failover || {
-    echo "FAIL: serving_router_failover red on both attempts"; exit 1; }
-echo "PASS serving_router_failover (verdict green, fleet p99 federated)"
-bench_row trace serving_router_trace_overhead || {
-    echo "FAIL: serving_router_trace_overhead red on both attempts"; exit 1; }
-echo "PASS serving_router_trace_overhead (tracing-on p99 <= 1.05x off)"
-
-# ---- 2. three-process fleet: router + 2 self-registering replicas --------
+# ---- 1. three-process fleet: router + 2 self-registering replicas --------
 echo "== three-process drill: router + 2 replicas =="
 JAX_PLATFORMS=cpu PYTHONPATH="$ROOT" \
     python -m keystone_tpu serve-router --router-port 0 \
@@ -193,7 +154,7 @@ done
     echo "FAIL: /fleetz never showed 2 ready replicas"; fetch "$ROUTER/fleetz"; exit 1; }
 echo "PASS self-registration (/fleetz: 2 replicas ready)"
 
-# ---- 3. kill a replica PROCESS mid-load; verdict must stay green ---------
+# ---- 2. kill a replica PROCESS mid-load; verdict must stay green ---------
 echo "== chaos across hosts: kill -9 replica1 mid-load =="
 ( sleep 2; kill -9 "$R1_PID" 2>/dev/null || true ) &
 KILLER_PID=$!
@@ -225,7 +186,7 @@ done
     fetch "$ROUTER/fleetz"; exit 1; }
 echo "PASS /fleetz shows killed replica unhealthy"
 
-# ---- 4. restart at the SAME port; half-open recovery -----------------------
+# ---- 3. restart at the SAME port; half-open recovery -----------------------
 echo "== restart replica1; half-open recovery =="
 R1_PORT="${R1##*:}"
 JAX_COMPILATION_CACHE_DIR="$TMPDIR/xc1" start_replica "$R1_LOG.2" \
@@ -264,7 +225,7 @@ done
     fetch "$ROUTER/fleetz"; exit 1; }
 echo "PASS half-open recovery (/fleetz: replica1 healthy after restart)"
 
-# ---- 5. federated quantile agrees with the per-replica quantiles ---------
+# ---- 4. federated quantile agrees with the per-replica quantiles ---------
 echo "== SLO federation: fleet quantile vs per-replica quantiles =="
 PYTHONPATH="$ROOT" python -c '
 import sys, urllib.request
@@ -305,7 +266,7 @@ print("fleet p99 %.1fms agrees with per-replica %sms "
     echo "FAIL: federated quantile disagreed with per-replica quantiles"; exit 1; }
 echo "PASS SLO federation"
 
-# ---- 6. distributed tracing: one id, two processes, one stitched tree ----
+# ---- 5. distributed tracing: one id, two processes, one stitched tree ----
 echo "== distributed tracing: cross-process stitch through the router =="
 PYTHONPATH="$ROOT" python -c '
 import json, sys, time, urllib.request

@@ -33,7 +33,7 @@ import sys, time
 import jax.numpy as jnp
 from keystone_tpu.gateway import Gateway, GatewayServer
 from keystone_tpu.observability import enable_tracing
-from keystone_tpu.serving.bench import build_pipeline
+from keystone_tpu.serving.demo_model import build_pipeline
 
 enable_tracing()
 fitted = build_pipeline(d=8, hidden=8, depth=2)

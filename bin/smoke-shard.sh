@@ -1,22 +1,20 @@
 #!/usr/bin/env bash
 # Smoke-test mesh-sharded serving end to end:
 #
-#  1. the `serving_sharded_vs_replicated` bench row on an 8-device
-#     host-platform mesh — the same model served mesh-sharded vs N
-#     replicated lanes, with the row's own asserts (output parity at
-#     every size both paths serve, the over-one-device-budget model
-#     serving SHARDED while the replicated path is refused, the
-#     crossover curve emitted) re-checked here off the emitted JSON;
-#  2. a real `serve-gateway --shard-model` subprocess next to an
+#  1. a real `serve-gateway --shard-model` subprocess next to an
 #     unsharded one over the SAME model: /predict answers match, the
 #     sharded gateway's AOT store holds entries whose fingerprint meta
 #     carries the `sharding_token` (a mesh-sharded program can never
 #     collide with a replicated one), and the AOT counters are on
 #     /metrics;
-#  3. keystone-lint self-clean stays at 0 findings (the new
+#  2. keystone-lint self-clean stays at 0 findings (the new
 #     serving/sharding.py module included).
 #
-# CI-friendly: CPU backend with 8 virtual devices, ~3 min, no network
+# Output parity of a sharded engine with a replicated one, and the
+# over-one-device-budget model served sharded inside the budget, are
+# held by tests/serving/test_sharding.py.
+#
+# CI-friendly: CPU backend with 8 virtual devices, ~1 min, no network
 # beyond localhost.
 #
 #   bin/smoke-shard.sh
@@ -24,7 +22,6 @@ set -euo pipefail
 
 ROOT="$(cd "$(dirname "$0")/.." && pwd)"
 TMPDIR="$(mktemp -d)"
-BENCH_OUT="$TMPDIR/bench.jsonl"
 AOT_DIR="$TMPDIR/aot"
 SHARD_LOG="$TMPDIR/shard.log"
 PLAIN_LOG="$TMPDIR/plain.log"
@@ -35,39 +32,6 @@ cleanup() {
     rm -rf "$TMPDIR"
 }
 trap cleanup EXIT
-
-echo "== serving_sharded_vs_replicated bench row =="
-XLA_FLAGS="$DEV8" JAX_PLATFORMS=cpu PYTHONPATH="$ROOT" \
-    python -m keystone_tpu serve-bench --shard-only --no-cache \
-    | tee "$BENCH_OUT"
-
-python - "$BENCH_OUT" <<'PY'
-import json, sys
-rows = [json.loads(l) for l in open(sys.argv[1]) if l.strip()]
-row = next(
-    r for r in rows if r.get("metric") == "serving_sharded_vs_replicated"
-)
-curve = row["crossover_curve"]
-assert len(curve) >= 2, row
-fitting = [e for e in curve if e["fits_one_device"]]
-assert fitting and all(e["outputs_allclose"] for e in fitting), row
-assert all(
-    "replicated_examples_per_sec" in e and "sharded_examples_per_sec" in e
-    for e in fitting
-), row
-big = curve[-1]
-assert not big["fits_one_device"] and big["replicated"] == "over_budget", row
-assert big["sharded_examples_per_sec"] > 0, row
-assert big["max_device_params_mb"] <= row["device_budget_mb"] \
-    < big["params_mb"], row
-print(
-    f"row OK: over-budget model ({big['params_mb']} MB params, "
-    f"{big['max_device_params_mb']} MB/device sharded) served at "
-    f"{big['sharded_examples_per_sec']} ex/s; "
-    f"{len(fitting)} crossover points with output parity"
-)
-PY
-echo "PASS bench row"
 
 echo "== serve-gateway --shard-model vs unsharded parity drill =="
 GWARGS=(--gateway-port 0 --buckets 4,8 --lanes 1 --d 64 --hidden 64 --depth 2)

@@ -358,8 +358,7 @@ def phase_kernels(state: dict) -> str:
 
 def _texture(c: int, rng) -> "np.ndarray":
     """One 256x256x3 uint8 image of class ``c``: a class-dependent
-    oriented texture and tint plus noise (bench.py's synthetic images,
-    spread over 1000 classes)."""
+    oriented texture and tint plus noise, spread over 1000 classes."""
     import numpy as np
 
     x, y = np.meshgrid(np.arange(IMG), np.arange(IMG))

@@ -27,7 +27,7 @@ def main(argv=None) -> int:
         # observability plane: /metrics (Prometheus), /varz, /healthz,
         # /tracez on a background thread, span tracing enabled so
         # executor/serving spans land in /tracez. Peeled before app
-        # dispatch so EVERY app (and serve-bench) is scrapeable.
+        # dispatch so EVERY app is scrapeable.
         i = argv.index("--admin-port")
         try:
             port = int(argv[i + 1])
@@ -114,7 +114,7 @@ def main(argv=None) -> int:
         # engine swap in front of a compiled pipeline, HTTP /predict
         # frontend (keystone_tpu/gateway/). Peeled here so
         # `python -m keystone_tpu --gateway-port N` alone stands up the
-        # serve-gateway demo (bench pipeline); with an explicit
+        # serve-gateway demo (serving/demo_model.py); with an explicit
         # serve-gateway app the port just rides along.
         i = argv.index("--gateway-port")
         try:
@@ -150,10 +150,8 @@ def main(argv=None) -> int:
         print("apps:")
         for name in sorted(APPS):
             print(f"  {name}")
-        print("  serve-bench  (serving engine benchmarks; see "
-              "keystone_tpu/serving/bench.py)")
-        print("  serve-gateway  (HTTP request plane over the bench "
-              "pipeline; keystone_tpu/gateway/. --shard-model serves "
+        print("  serve-gateway  (HTTP request plane over the demo "
+              "model; keystone_tpu/gateway/. --shard-model serves "
               "the model mesh-sharded over the local devices — "
               "keystone_tpu/serving/sharding.py)")
         print("  serve-router  (fleet tier: cross-host router over N "
@@ -195,10 +193,6 @@ def main(argv=None) -> int:
               "serialize the executables so a brand-new host's "
               "serve-gateway goes from exec() to serving with zero "
               "XLA compiles; keystone_tpu/serving/aot.py)")
-        print("  bench-diff  (compare two bench-round JSONs and exit "
-              "nonzero on headline-metric regressions beyond per-row "
-              "tolerance — bin/bench-diff last-green.json "
-              "this-round.json; keystone_tpu/bench_diff.py)")
         print("  keystone-lint  (AST contract analyzer over this "
               "repo's own source: lock discipline, blocking-under-"
               "lock, strippable asserts, absent-not-zero metrics, "
@@ -247,10 +241,6 @@ def main(argv=None) -> int:
               " stitched topology.")
         return 0 if argv else 2
     app = argv[0]
-    if app == "serve-bench":
-        from keystone_tpu.serving.bench import main as serve_bench_main
-
-        return serve_bench_main(argv[1:])
     if app == "serve-gateway":
         from keystone_tpu.gateway.http import main as serve_gateway_main
 
@@ -283,12 +273,6 @@ def main(argv=None) -> int:
         from keystone_tpu.serving.aot import build_main
 
         return build_main(argv[1:])
-    if app == "bench-diff":
-        # stdlib-only like the linter: regression gating runs in CI
-        # hooks without paying the jax import
-        from keystone_tpu.bench_diff import main as bench_diff_main
-
-        return bench_diff_main(argv[1:])
     if app == "keystone-lint":
         # stdlib-only path by design: the linter must run in hooks and
         # CI without paying the jax import (analysis/ never imports it)

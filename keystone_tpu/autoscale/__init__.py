@@ -10,7 +10,7 @@ controller over all of it:
 
 - ``supervisor.py`` — replica processes as a managed set: spawn
   ``serve-gateway`` subprocesses (or in-process replicas for the
-  bench/tests), retire through the graceful
+  capacity planner), retire through the graceful
   deregister → drain → exit protocol, replace the dead.
 - ``policy.py`` — the pure decision engine: SLO burn + fleet p99 +
   per-replica load + phase attribution (scale out only when
@@ -28,8 +28,7 @@ controller over all of it:
   one command.
 
 CLI: ``python -m keystone_tpu serve-autoscale --slo-latency-ms 250``;
-drill: ``bin/smoke-autoscale.sh``; regression row:
-``serving_autoscale_ramp`` (``serve-bench --autoscale-only``).
+drill: ``bin/smoke-autoscale.sh``; tests: ``tests/autoscale/``.
 """
 
 from keystone_tpu.autoscale.policy import (

@@ -126,9 +126,6 @@ class AutoscaleMetrics:
     def record_scrape_error(self) -> None:
         self._scrape_errors.inc((self.autoscaler,))
 
-    def decision_count(self, action: str) -> float:
-        return self._decisions.get((self.autoscaler, action))
-
 
 def _scrape_stats(
     metrics_text: str,
@@ -388,7 +385,7 @@ class RouterScraper:
 class Autoscaler:
     """The loop: reap -> observe -> decide -> act, every
     ``interval_s`` on a daemon thread. ``tick()`` is also directly
-    callable (tests and the bench drive it synchronously)."""
+    callable (the tests drive it synchronously)."""
 
     def __init__(
         self,
@@ -417,7 +414,6 @@ class Autoscaler:
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
         self.decisions: List[Decision] = []  # newest last, bounded
-        self.max_replicas_seen = 0
 
     def _emit(self, event: str, **fields: Any) -> None:
         doc = {"event": event, "autoscaler": self.name, **fields}
@@ -446,7 +442,6 @@ class Autoscaler:
         running = sum(
             1 for h in self.supervisor.replicas() if h.alive()
         )
-        self.max_replicas_seen = max(self.max_replicas_seen, running)
         self.metrics.set_replicas(target, running)
         if obs is None:
             self.metrics.record_scrape_error()

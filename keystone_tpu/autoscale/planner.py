@@ -24,7 +24,7 @@ with ``serve-autoscale --plan plan.json``.
 Replicas come from the same ``Supervisor`` the autoscaler uses:
 ``--mode subprocess`` spawns real ``serve-gateway`` processes (share
 an AOT store to keep the K legs warm); the default ``--mode inproc``
-builds them as in-process threads over the bench pipeline — what CI
+builds them as in-process threads over the demo model — what CI
 and the tests run, same measurement harness, no per-replica JAX
 import.
 """
@@ -232,7 +232,7 @@ def main(argv: Optional[List[str]] = None) -> int:
     fleet.add_argument("--mode", choices=("inproc", "subprocess"),
                        default="inproc",
                        help="inproc: replicas as in-process threads "
-                       "over the bench pipeline (CI-friendly); "
+                       "over the demo model (CI-friendly); "
                        "subprocess: real serve-gateway processes "
                        "(share --aot-cache for warm legs)")
     fleet.add_argument("--d", type=int, default=64)
@@ -339,12 +339,12 @@ def _build_supervisor(args, router_url: str) -> Supervisor:
             startup_timeout_s=args.startup_timeout,
         )
 
-    # inproc: replicas over the bench pipeline, private registries
+    # inproc: replicas over the demo model, private registries
     import jax.numpy as jnp
 
     from keystone_tpu.gateway import Gateway, GatewayServer
     from keystone_tpu.observability.registry import MetricsRegistry
-    from keystone_tpu.serving.bench import build_pipeline
+    from keystone_tpu.serving.demo_model import build_pipeline
 
     fitted = build_pipeline(d=args.d, hidden=args.hidden, depth=args.depth)
     buckets = tuple(int(b) for b in args.buckets.split(","))

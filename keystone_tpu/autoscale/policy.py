@@ -137,7 +137,7 @@ class Decision:
 @dataclasses.dataclass
 class PolicyConfig:
     """The policy's knobs. Defaults are production-flavored (tens of
-    seconds); the bench/smoke paths shrink them to single seconds —
+    seconds); the test and smoke paths shrink them to single seconds —
     the ARITHMETIC is what's under test, not the wall clock."""
 
     min_replicas: int = 1

@@ -13,7 +13,7 @@ protocol is the fleet tier's:
   drills use — port 0 means no port races, and the replica
   self-registers with the router on its own). ``InprocLauncher``
   runs the same topology as in-process threads over a caller-supplied
-  factory — what the bench row and the unit tests use, so the
+  factory — what ``serve-capacity-plan --mode inproc`` uses, so the
   supervisor's logic is exercised without paying a JAX import per
   replica.
 - **retire** (graceful drain) — scale-down is the three-step
@@ -309,7 +309,7 @@ class InprocReplica:
 class InprocLauncher:
     """Build replicas in-process via a caller-supplied
     ``factory(index) -> (gateway, server)`` (server already started).
-    The bench row's path: the supervisor/policy/controller machinery
+    The capacity planner's in-process path: the supervisor machinery
     runs for real while replicas cost threads, not JAX imports. The
     factory owns registration semantics; by default the supervisor
     POSTs ``/registerz`` for these replicas."""

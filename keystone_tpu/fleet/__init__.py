@@ -20,8 +20,7 @@ HTTP distance, where a replica is a whole ``serve-gateway`` process:
   the ``router.replica.blackhole`` chaos point on the forward path.
 
 CLI: ``python -m keystone_tpu serve-router --replica URL ...``;
-drill: ``bin/smoke-fleet.sh``; regression row:
-``serving_router_failover`` (``serve-bench --fleet-only``).
+drill: ``bin/smoke-fleet.sh``; tests: ``tests/fleet/``.
 """
 
 from keystone_tpu.fleet.registry import Replica, ReplicaRegistry

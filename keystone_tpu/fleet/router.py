@@ -97,9 +97,8 @@ replica's whole admit → coalesce → dispatch chain shares the trace
 id, and echoes ``X-Keystone-Trace`` on every /predict response —
 success AND typed shed. ``--request-log`` writes the gateway's
 replayable JSONL schema plus ``replica``/``attempts`` per routed
-POST. Tracing is ON by default (``--no-trace`` opts out); the
-``serving_router_trace_overhead`` bench row bounds its cost at
-<= 1.05x p99.
+POST. Tracing is ON by default (``--no-trace`` opts out); its cost on
+the chip is unmeasured (ROADMAP R2).
 """
 
 from __future__ import annotations
@@ -1103,9 +1102,7 @@ def main(argv=None) -> int:
                     help="disable distributed tracing: no "
                     "router.forward spans, no W3C traceparent "
                     "propagation to replicas, no X-Keystone-Trace "
-                    "echo, no /debugz stitching (default ON — the "
-                    "serving_router_trace_overhead bench row bounds "
-                    "the cost at <= 1.05x p99)")
+                    "echo, no /debugz stitching (default ON)")
     ap.add_argument("--request-log", nargs="?", const=True,
                     default=False, metavar="FILE",
                     help="one structured JSON line per routed "

@@ -886,8 +886,8 @@ def deregister_from_router(router_url: str, own_url: str) -> bool:
 
 def main(argv=None) -> int:
     """``python -m keystone_tpu serve-gateway [--gateway-port N] ...`` —
-    stand up the full request plane over the serve-bench pipeline (the
-    demo/smoke entry; real deployments construct ``Gateway`` over their
+    stand up the full request plane over the demo model
+    (``serving/demo_model.py``: the demo/smoke entry; real deployments construct ``Gateway`` over their
     own fitted pipeline)."""
     import argparse
     import time
@@ -898,7 +898,7 @@ def main(argv=None) -> int:
         claim_accelerator,
         setup_compilation_cache,
     )
-    from keystone_tpu.serving.bench import build_pipeline
+    from keystone_tpu.serving.demo_model import build_pipeline
 
     ap = argparse.ArgumentParser(
         prog="keystone_tpu serve-gateway", description=__doc__
@@ -1166,7 +1166,7 @@ def main(argv=None) -> int:
             # draws → bitwise-equal outputs), split at the last layer
             # so the lifecycle can refit the head in closed form and
             # rebuild candidates as base.and_then(affine_head(W, b))
-            from keystone_tpu.serving.bench import (
+            from keystone_tpu.serving.demo_model import (
                 affine_head,
                 build_split_pipeline,
             )

@@ -8,8 +8,7 @@ The controller owns the SIDE EFFECTS around the pure policy
 per-version AOT namespace), arms/clears the pool's shadow mirror and
 canary router, and drives ``Gateway.swap_model`` on promotion and
 rollback. One ``tick()`` = one policy decision plus its effects;
-ticks run manually (``POST /lifecyclez {"tick": true}``, tests,
-benches) or on the background interval thread (``interval_s``).
+ticks run manually (``POST /lifecyclez {"tick": true}``, tests) or on the background interval thread (``interval_s``).
 
 Versioned snapshots: candidate v's engines build against
 ``namespaced_store("<namespace>/v<version>")`` when the process has

@@ -2,7 +2,7 @@
 a re-solved head.
 
 The served demo model ends in a ``tanh(x @ W + b)`` head over a frozen
-feature base (``serving/bench.build_split_pipeline``). Because the
+feature base (``serving/demo_model.build_split_pipeline``). Because the
 normal-equations state is ADDITIVE — the same property that makes the
 ELL one-pass accumulator in ``ops/learning/sparse_ell.py``
 chunk-size-independent — "refit" is never a full refit: each labeled

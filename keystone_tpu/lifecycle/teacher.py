@@ -1,7 +1,7 @@
 """Synthetic ground truth for labeled-load drills — numpy only.
 
 ``teacher_labels`` reproduces the demo pipeline's forward math
-(``serving/bench.build_pipeline``: ``tanh(x @ W + b)`` per layer, the
+(``serving/demo_model.build_pipeline``: ``tanh(x @ W + b)`` per layer, the
 identical ``default_rng`` draw order) without importing jax or the
 serving stack, so ``serve-loadgen`` can synthesize labeled feedback
 traffic against a live gateway from nothing but the model's shape

@@ -24,8 +24,8 @@ stack's invariants held:
   fault clears, shed rate stays in bounds.
 
 ``python -m keystone_tpu serve-loadgen`` is the CLI
-(``loadgen/cli.py``); ``serving/bench.py``'s ``serving_chaos_*`` rows
-and ``bin/smoke-chaos.sh`` drive the same APIs in CI.
+(``loadgen/cli.py``); ``tests/loadgen/`` and ``bin/smoke-chaos.sh``
+drive the same APIs.
 
 Import weight: the serving hot paths (``gateway/pool.py``,
 ``serving/engine.py``, ``serving/pipeline.py``,

@@ -2,7 +2,7 @@
 
 Replays a workload (recorded ``--trace`` JSONL or ``--synthetic``)
 open-loop against a gateway (``--target URL``, or ``--self-gateway``
-to stand one up in-process over the bench pipeline), optionally arms
+to stand one up in-process over the demo model), optionally arms
 a chaos timeline mid-run (``--fault``, armed over ``POST /chaosz``
 for HTTP targets so the fault fires in the SERVER process), runs the
 invariant checker over the result, prints the structured verdict, and
@@ -88,7 +88,7 @@ def build_parser() -> argparse.ArgumentParser:
                      help="base URL of a running gateway frontend")
     tgt.add_argument("--self-gateway", action="store_true",
                      help="stand up an in-process gateway over the "
-                     "bench pipeline instead of --target")
+                     "demo model instead of --target")
     tgt.add_argument("--d", type=int, default=64,
                      help="feature dim of the --self-gateway pipeline "
                      "(and the default replay example shape)")
@@ -259,7 +259,7 @@ def main(argv: Optional[List[str]] = None) -> int:
         import jax.numpy as jnp
 
         from keystone_tpu.gateway import Gateway
-        from keystone_tpu.serving.bench import build_pipeline
+        from keystone_tpu.serving.demo_model import build_pipeline
 
         fitted = build_pipeline(d=args.d, hidden=args.d, depth=2)
         gateway = Gateway(

@@ -15,9 +15,7 @@ traffic exercises — no parallel "test mode" dispatch.
 Cost contract: an UNARMED injector is a no-op on the hot path — one
 attribute read and one falsy check (``fire`` returns before touching
 any spec state, allocating nothing); the tier-1 suite asserts this
-with a counting stub, and the bench family asserts the
-``serving_gateway_p99`` / ``serving_pipeline_overlap`` numbers are
-unchanged with the points compiled in.
+with a counting stub.
 
 Arming, three ways (all land in the same process-global registry):
 

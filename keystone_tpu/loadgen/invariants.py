@@ -74,7 +74,7 @@ class Verdict:
 class InvariantChecker:
     """Declared bounds for one experiment; ``check`` renders the
     verdict. Bounds are per-experiment state (not per-call args) so a
-    bench row / CLI invocation states its contract once, up front."""
+    test / CLI invocation states its contract once, up front."""
 
     def __init__(
         self,

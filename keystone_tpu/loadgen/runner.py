@@ -18,8 +18,8 @@ Two targets behind one interface:
   sin), and a transport timeout is a LOST request (an admitted future
   that never resolved — the other cardinal sin).
 - ``InprocTarget`` — drives a ``Gateway`` object directly
-  (``predict().result()``), same classification; this is what the
-  bench rows use so ``serving_chaos_*`` needs no socket.
+  (``predict().result()``), same classification; this is what
+  ``--self-gateway`` and the tests use, so a chaos run needs no socket.
 
 The ``LoadReport`` collects one ``RequestRecord`` per issued request
 plus the chaos timeline (``FaultWindow``s the driver armed) and the
@@ -395,7 +395,7 @@ class HttpTarget:
 
 
 class InprocTarget:
-    """Drive a ``Gateway`` object directly (the bench rows' path)."""
+    """Drive a ``Gateway`` object directly (no socket)."""
 
     def __init__(self, gateway, default_shape: Sequence[int] = (8,)):
         self.gateway = gateway

@@ -16,8 +16,8 @@ prefix's modeled cost (its own XLA cost model, vs the heads') is
 apportioned across the window's models **by row share**, and each
 head's cost goes to its own model. The per-window weights are
 normalized against the ENGINE's dispatch totals, so per-model charges
-sum exactly to the engine totals — the invariant the tests and the
-``serving_attribution_drift`` bench row pin at 1e-6 relative. Engines
+sum exactly to the engine totals — the invariant the tests pin at
+1e-6 relative (``tests/zoo/test_host.py``). Engines
 whose prefix/head cost models are absent (CPU CI) degrade to pure
 row-share splitting — still exactly summing, just less informed.
 

@@ -28,8 +28,8 @@ Rules implemented:
 The reverse direction lives here too: ``parse_samples`` reads an
 exposition body back into (name, labels, value) rows and
 ``quantile_from_buckets`` reproduces PromQL's ``histogram_quantile``
-interpolation — so the regression bench reads its p99 from the SAME
-``/metrics`` surface operators scrape, not from bench-local counters.
+interpolation — so the autoscaler and the tests read a p99 from the
+SAME ``/metrics`` surface operators scrape, not from local counters.
 
 The FLEET direction stacks on those: ``merge_histograms`` sums
 per-replica cumulative ``le`` buckets into one fleet-wide histogram
@@ -205,7 +205,7 @@ def parse_samples(
     """An exposition body -> ``(name, labels, value)`` rows. Comments
     (including exemplar tails — the regex stops at ``#``) are skipped;
     this is the scrape-side half of the format the renderer above
-    emits, used by the regression bench to read ``/metrics``."""
+    emits, used by the router's federation and the autoscaler's scraper."""
     out: List[Tuple[str, Dict[str, str], float]] = []
     for line in text.splitlines():
         line = line.strip()

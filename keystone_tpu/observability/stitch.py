@@ -405,8 +405,7 @@ class TraceStitcher:
         # the ROUTER-origin spans of this trace: router spans stamp a
         # ``router=<name>`` attr at creation. In a real router process
         # this filter is a no-op (its ring holds nothing else for the
-        # trace); with a SHARED tracer (in-process tests, the bench
-        # A/B rig) it is what keeps the replica's admit/coalesce chain
+        # trace); with a SHARED tracer (in-process tests) it is what keeps the replica's admit/coalesce chain
         # from double-counting as router-side spans.
         own = [
             s.to_dict()

@@ -71,6 +71,38 @@ class ImageNetSiftLcsFVConfig:
     lcs_gmm_files: Optional[tuple] = None
 
 
+def sift_prefix(**sift) -> Pipeline:
+    """Pixels to Hellinger-mapped dense SIFT descriptors; ``sift`` goes
+    to ``SIFTExtractor`` (reference: ImageNetSiftLcsFV.scala:106-110)."""
+    return (
+        PixelScaler()
+        .and_then(GrayScaler())
+        .and_then(SIFTExtractor(**sift))
+        .and_then(SignedHellingerMapper())
+    )
+
+
+def lcs_prefix(stride: int, border: int, patch: int) -> Pipeline:
+    """Pixels to LCS descriptors (reference: ImageNetSiftLcsFV.scala:120)."""
+    return LCSExtractor(stride, border, patch).to_pipeline()
+
+
+def fisher_branch(prefix: Pipeline, pca_pipeline, fv_pipeline) -> Pipeline:
+    """One branch of the flagship's featurizer: descriptors → PCA →
+    Fisher vector → one L2-normalised, Hellinger-mapped row an image.
+    The application hands it fitted estimators, the seeded
+    ``serving/featurize.py: flagship_pipeline`` transformers."""
+    return (
+        prefix.and_then(pca_pipeline)
+        .and_then(fv_pipeline)
+        .and_then(FloatToDouble())
+        .and_then(MatrixVectorizer())
+        .and_then(NormalizeRows())
+        .and_then(SignedHellingerMapper())
+        .and_then(NormalizeRows())
+    )
+
+
 def compute_pca_and_fisher_branch(
     prefix: Pipeline,
     training_data,
@@ -101,15 +133,7 @@ def compute_pca_and_fisher_branch(
             conf.vocab_size, seed=conf.seed
         ).with_data(pca_pipeline.apply(sampled))
 
-    return (
-        prefix.and_then(pca_pipeline)
-        .and_then(fv_pipeline)
-        .and_then(FloatToDouble())
-        .and_then(MatrixVectorizer())
-        .and_then(NormalizeRows())
-        .and_then(SignedHellingerMapper())
-        .and_then(NormalizeRows())
-    )
+    return fisher_branch(prefix, pca_pipeline, fv_pipeline)
 
 
 def fit_classifier(
@@ -142,23 +166,13 @@ def fit_classifier(
 def build_pipeline(
     train_images: Dataset, train_labels, conf: ImageNetSiftLcsFVConfig
 ) -> Pipeline:
-    sift_prefix = (
-        PixelScaler()
-        .and_then(GrayScaler())
-        .and_then(SIFTExtractor(scale_step=conf.sift_scale_step))
-        .and_then(SignedHellingerMapper())
-    )
     sift_branch = compute_pca_and_fisher_branch(
-        sift_prefix, train_images, conf, conf.sift_pca_file,
-        conf.sift_gmm_files,
+        sift_prefix(scale_step=conf.sift_scale_step), train_images, conf,
+        conf.sift_pca_file, conf.sift_gmm_files,
     )
-
-    lcs_prefix = LCSExtractor(
-        conf.lcs_stride, conf.lcs_border, conf.lcs_patch
-    ).to_pipeline()
     lcs_branch = compute_pca_and_fisher_branch(
-        lcs_prefix, train_images, conf, conf.lcs_pca_file,
-        conf.lcs_gmm_files,
+        lcs_prefix(conf.lcs_stride, conf.lcs_border, conf.lcs_patch),
+        train_images, conf, conf.lcs_pca_file, conf.lcs_gmm_files,
     )
 
     featurizer = Pipeline.gather([sift_branch, lcs_branch]).and_then(

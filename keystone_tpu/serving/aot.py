@@ -49,9 +49,8 @@ The store directory is configured beside the persistent compile cache
 (``parallel.runtime.setup_aot_cache``: argument, then
 ``$KEYSTONE_AOT_CACHE``, then ``~/.cache/keystone_tpu/aot``); the
 ``serve-aot-build`` CLI app pre-populates it at build/deploy time so a
-brand-new host starts hot (``bin/smoke-aot.sh`` drills exactly that,
-and the ``serving_cold_start_aot`` bench row measures it
-cross-process).
+brand-new host starts hot (``bin/smoke-aot.sh`` drills exactly that;
+what it buys on the chip is unmeasured: ROADMAP D3).
 """
 
 from __future__ import annotations
@@ -746,8 +745,7 @@ def status() -> Dict[str, Any]:
 
 def build_main(argv=None) -> int:
     """``python -m keystone_tpu serve-aot-build [--buckets 8,32,128]``
-    — compile every bucket of the (serve-bench/serve-gateway demo)
-    pipeline once and serialize the executables into the AOT store, so
+    — compile every bucket of the (serve-gateway demo) pipeline once and serialize the executables into the AOT store, so
     a brand-new host's ``serve-gateway`` goes from exec() to serving
     without a single XLA compile. Real deployments call
     ``CompiledPipeline.warmup`` over their own fitted pipeline with
@@ -760,7 +758,7 @@ def build_main(argv=None) -> int:
         setup_aot_cache,
         setup_compilation_cache,
     )
-    from keystone_tpu.serving.bench import build_pipeline
+    from keystone_tpu.serving.demo_model import build_pipeline
 
     ap = argparse.ArgumentParser(
         prog="keystone_tpu serve-aot-build",

@@ -26,7 +26,7 @@ per-bucket goodput accounting every dispatch records
 (``keystone_serving_goodput_rows_total`` / ``padded_rows_total`` and
 the ``padding_efficiency`` gauge, serving/metrics.py).
 ``predicted_efficiency`` bridges the two so the gateway can log
-model-vs-observed at each re-bucket and the bench can assert they
+model-vs-observed at each re-bucket and the tests can assert they
 agree.
 """
 

@@ -2,9 +2,9 @@
 
 ``CompiledPipeline(featurize=...)`` fuses any fitted pure-JAX pipeline
 in front of the model; this module provides the canonical image chains
-the ``--device-featurize`` gateway modes, the featurize bench rows, and
-the smoke/tests all share — kept OUT of the benchmark module so the
-production CLI path doesn't depend on bench code.
+the ``--device-featurize`` gateway modes, ``benchmark/programs/flagship.py``
+and the smoke scripts and tests all share. Nothing here imports a
+benchmark program (``tests/test_import_direction.py``).
 
 Two chains:
 
@@ -19,7 +19,7 @@ Two chains:
   Fittable-then-frozen: pass ``fit_images`` to fit real PCA/GMM
   parameters through the reference estimator path, or let the seeded
   warm-start stand in where a deterministic chain is what matters
-  (gateway startup, benches, tests). Either way the result is a frozen
+  (gateway startup, the benchmark, tests). Either way the result is a frozen
   pure-JAX ``FittedPipeline`` that ``CompiledPipeline(featurize=)``
   fuses — branches and all — into each per-bucket XLA program.
 """
@@ -46,7 +46,7 @@ def build_featurize_pipeline(
     sum-Pooler → channel-major ImageVectorizer, the
     RandomPatchCifar-style dense-conv stack from ``ops/images``.
     Returns ``(fitted_featurize, feature_dim)``. The default geometry
-    is the device-featurize demo/bench shape: 16·16·3 = 768 raw uint8
+    is the device-featurize demo shape: 16·16·3 = 768 raw uint8
     bytes per example featurize to 768 f32 features = 3072 bytes, so
     shipping raw instead of featurized is a 4× H2D reduction."""
     import jax.numpy as jnp
@@ -107,23 +107,17 @@ def flagship_pipeline(
     kernel for large vocabularies, the plain XLA program below it."""
     import jax.numpy as jnp
 
-    from keystone_tpu.ops.images.core import GrayScaler, PixelScaler
     from keystone_tpu.ops.images.fisher_vector import (
         FisherVector,
         FisherVectorFused,
     )
-    from keystone_tpu.ops.images.lcs import LCSExtractor
-    from keystone_tpu.ops.images.sift import SIFTExtractor
     from keystone_tpu.ops.learning import BatchPCATransformer
     from keystone_tpu.ops.learning.gmm import GaussianMixtureModel
-    from keystone_tpu.ops.stats import (
-        NormalizeRows,
-        SignedHellingerMapper,
-    )
-    from keystone_tpu.ops.util.nodes import (
-        FloatToDouble,
-        MatrixVectorizer,
-        VectorCombiner,
+    from keystone_tpu.ops.util.nodes import VectorCombiner
+    from keystone_tpu.pipelines.images.imagenet_sift_lcs_fv import (
+        fisher_branch,
+        lcs_prefix,
+        sift_prefix,
     )
     from keystone_tpu.workflow.api import Pipeline
 
@@ -142,30 +136,16 @@ def flagship_pipeline(
         fv = (
             FisherVectorFused(gmm) if vocab >= 32 else FisherVector(gmm)
         )
-        return (
-            prefix
-            .and_then(BatchPCATransformer(pca.T))
-            .and_then(fv)
-            .and_then(FloatToDouble())
-            .and_then(MatrixVectorizer())
-            .and_then(NormalizeRows())
-            .and_then(SignedHellingerMapper())
-            .and_then(NormalizeRows())
-        )
+        return fisher_branch(prefix, BatchPCATransformer(pca.T), fv)
 
     sift = branch(
-        PixelScaler().and_then(GrayScaler())
-        .and_then(SIFTExtractor(
+        sift_prefix(
             step=sift_step, bin=sift_bin, num_scales=sift_scales,
             scale_step=sift_scale_step,
-        ))
-        .and_then(SignedHellingerMapper()),
+        ),
         128,
     )
-    lcs = branch(
-        LCSExtractor(lcs_stride, lcs_border, lcs_patch).to_pipeline(),
-        96,
-    )
+    lcs = branch(lcs_prefix(lcs_stride, lcs_border, lcs_patch), 96)
     return Pipeline.gather([sift, lcs]).and_then(VectorCombiner())
 
 
@@ -194,8 +174,8 @@ def build_flagship_featurize_pipeline(
     (``compute_pca_and_fisher_branch``: ColumnSampler → ColumnPCA,
     sampled+projected descriptors → GMM); without it, a seeded
     warm-start stands in (``flagship_pipeline``) — deterministic
-    parameters, identical graph, which is what gateway startup, the
-    bench A/B, and the AOT fingerprint tests need. Both paths freeze to
+    parameters, identical graph, which is what gateway startup and the
+    AOT fingerprint tests need. Both paths freeze to
     the same pure-JAX branched DAG; ``feature_dim`` is probed off a
     zero image through ``_batch_run`` — the exact staging surface the
     serving engine fuses.
@@ -220,15 +200,13 @@ def build_flagship_featurize_pipeline(
             lcs_patch=lcs_patch,
         )
     else:
-        from keystone_tpu.ops.images.core import GrayScaler, PixelScaler
-        from keystone_tpu.ops.images.lcs import LCSExtractor
-        from keystone_tpu.ops.images.sift import SIFTExtractor
-        from keystone_tpu.ops.stats import SignedHellingerMapper
         from keystone_tpu.ops.util.nodes import VectorCombiner
         from keystone_tpu.parallel.dataset import Dataset
         from keystone_tpu.pipelines.images.imagenet_sift_lcs_fv import (
             ImageNetSiftLcsFVConfig,
             compute_pca_and_fisher_branch,
+            lcs_prefix,
+            sift_prefix,
         )
         from keystone_tpu.workflow.api import Pipeline
 
@@ -241,23 +219,17 @@ def build_flagship_featurize_pipeline(
             sift_scale_step=sift_scale_step, lcs_stride=lcs_stride,
             lcs_border=lcs_border, lcs_patch=lcs_patch,
         )
-        sift_prefix = (
-            PixelScaler().and_then(GrayScaler())
-            .and_then(SIFTExtractor(
-                step=sift_step, bin=sift_bin, num_scales=sift_scales,
-                scale_step=sift_scale_step,
-            ))
-            .and_then(SignedHellingerMapper())
-        )
-        lcs_prefix = LCSExtractor(
-            lcs_stride, lcs_border, lcs_patch
-        ).to_pipeline()
         pipe = Pipeline.gather([
             compute_pca_and_fisher_branch(
-                sift_prefix, fit_images, conf, None, None
+                sift_prefix(
+                    step=sift_step, bin=sift_bin, num_scales=sift_scales,
+                    scale_step=sift_scale_step,
+                ),
+                fit_images, conf, None, None,
             ),
             compute_pca_and_fisher_branch(
-                lcs_prefix, fit_images, conf, None, None
+                lcs_prefix(lcs_stride, lcs_border, lcs_patch),
+                fit_images, conf, None, None,
             ),
         ]).and_then(VectorCombiner())
     fitted = pipe.fit()

@@ -453,13 +453,13 @@ class ServingMetrics:
         """LIFETIME average (examples since construction / wall time
         since construction) — it decays over idle periods and includes
         warmup, so it's a capacity sanity number, not an instantaneous
-        throughput gauge. Benches that need a true rate time their own
-        window (serving/bench.py does)."""
+        throughput gauge. A benchmark that needs a true rate times its own
+        window."""
         dt = self._clock() - self._t0
         return self.examples.total / dt if dt > 0 else 0.0
 
     def summary(self) -> Dict:
-        """Flat dict suitable for a bench row's ``extra`` or a log line."""
+        """Flat dict suitable for a log line or a test's assertions."""
 
         def ms(v: Optional[float]) -> Optional[float]:
             return round(v * 1e3, 3) if v is not None else None

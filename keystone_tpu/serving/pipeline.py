@@ -1,9 +1,9 @@
 """Staged lane pipeline: overlap host prep, H2D upload, and device
 compute behind one ``MicroBatcher``.
 
-The serving-side analogue of the streaming featurize bench's
-decode/upload/compute overlap (bench.py's ``imagenet_stream_featurize``
-row): a serial batcher lane runs coalesce → stack → pad → device_put →
+The serving-side analogue of the streaming loader's
+decode/upload/compute overlap
+(``loaders/streaming.py: featurized_batches``): a serial batcher lane runs coalesce → stack → pad → device_put →
 compute → deliver one window at a time, so while the device runs window
 k, window k+1's host work and H2D transfer sit idle in the queue. Here
 the dispatch is split into explicit stages connected by BOUNDED handoff

@@ -73,7 +73,7 @@ PartitionRules = Sequence[Tuple[str, PartitionSpec]]
 
 # The repo's solver outputs: every fitted linear map stores its weights
 # as one (d_in, d_out) / (D, k) matrix named W (BlockLinearMapper,
-# LinearMapper, SparseLinearMapper, the bench _Affine chain), so the
+# LinearMapper, SparseLinearMapper, the demo model's _Affine chain), so the
 # output/feature-block axis is the LAST one — split it over MODEL_AXIS;
 # biases, intercepts, means, scaler state stay replicated (they are
 # k- or D-vectors, noise next to the matrices). The trailing catch-all

@@ -54,7 +54,8 @@ from keystone_tpu.workflow.rules import UnusedBranchRemovalRule
 # rows per jit(vmap) dispatch of a ``bucket_vmap`` node outside ``jit``.
 # Dense SIFT at 256x256 keeps ~20 MB per image in flight, so one dispatch
 # over a whole training set does not fit a 16 GB chip; 128 is the chunk
-# bench.py's flagship rows settled on. What the node is handed decides the
+# the flagship settled on (two to a 256-image step of `flagship-score`,
+# PERF.md). What the node is handed decides the
 # rest: items of one shape and dtype, or an array, go through as slices of
 # one array and come back in array mode (``_chunked_batch``); items of two
 # or more shapes are grouped by shape and come back as items

@@ -20,7 +20,7 @@ Dict outputs ride the existing window plumbing untouched: the
 ``MicroBatcher`` tree-slices each row out of the batched output, so
 every request's future resolves to a per-model dict and the zoo picks
 (or fans out) from it. The engine's own compile/dispatch counters are
-the measurement seam the ``serving_zoo`` bench row gates on: one
+the measurement seam ``tests/zoo/test_cse.py`` gates on: one
 trace per bucket and one dispatch per window for the whole group,
 where solo hosting pays one of each PER MODEL.
 
@@ -186,7 +186,7 @@ class SharedPrefixEngine(CompiledPipeline):
 
         def staged(arr):
             # one trace-count per XLA compile of the whole group's
-            # program — the bench's compile-counter gate reads this
+            # program — the tests' compile-counter gate reads this
             metrics.record_trace(bucket)
             feat = feat_run(arr)
             # the shared prefix is computed ONCE; every head consumes

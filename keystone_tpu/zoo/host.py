@@ -638,7 +638,7 @@ class ModelZoo:
     ) -> None:
         """Feed one live request's row count into the drift detector
         (the HTTP frontend calls this per /predict request with its
-        instance count; benches call it directly)."""
+        instance count; tests call it directly)."""
         try:
             mid, _spec = self.resolve(model_id)
         except UnknownModel:

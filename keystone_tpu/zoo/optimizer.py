@@ -12,8 +12,7 @@ cost models; this is the serving-plane analogue. Inputs per model
   the demand weight that decides who gets spare lanes;
 - ``params_nbytes`` — what one REPLICATED engine must hold per chip
   (``serving/sharding.params_nbytes``), checked against the per-chip
-  HBM budget for the replicated-vs-mesh-sharded decision (the same
-  check the PR 15 bench row hand-flagged).
+  HBM budget for the replicated-vs-mesh-sharded decision.
 
 Everything here is PURE and deterministic: same profiles + same budget
 -> byte-identical plan, no jax, no device, no clock. The live side

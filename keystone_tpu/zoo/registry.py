@@ -21,8 +21,8 @@ to a zoo without breaking its clients).
          "img": 34, "hidden": 64, "depth": 2, "buckets": [4, 8]}
     ]}
 
-Each entry builds the same demo pipelines the bench/CLI stack already
-serves (``serving/bench.build_pipeline``, ``serving/featurize``);
+Each entry builds the same demo pipelines the CLI stack already
+serves (``serving/demo_model.build_pipeline``, ``serving/featurize``);
 real deployments register their own fitted pipelines through the
 Python API instead of the JSON shorthand.
 """
@@ -189,7 +189,7 @@ def _entry_to_spec(entry: Dict[str, Any]) -> ModelSpec:
     def build() -> BuiltModel:
         # deferred imports: assembling a registry must not initialize
         # jax; params materialize at page-in
-        from keystone_tpu.serving.bench import build_pipeline
+        from keystone_tpu.serving.demo_model import build_pipeline
         from keystone_tpu.serving.featurize import (
             build_featurize_pipeline,
             build_flagship_featurize_pipeline,

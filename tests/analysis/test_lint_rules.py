@@ -192,7 +192,7 @@ def test_strippable_assert_fires_outside_tests():
     fs = findings_for(
         StrippableAssertRule(),
         "def gate(ok):\n    assert ok, 'enforced'\n",
-        rel="keystone_tpu/serving/bench.py",
+        rel="keystone_tpu/serving/demo_model.py",
     )
     assert len(fs) == 1
     assert fs[0].rule == "strippable-assert"
@@ -211,7 +211,7 @@ def test_strippable_assert_quiet_in_tests_and_on_raise():
             "    if not ok:\n"
             "        raise AssertionError('enforced')\n"
         ),
-        rel="keystone_tpu/serving/bench.py",
+        rel="keystone_tpu/serving/demo_model.py",
     ) == []
 
 

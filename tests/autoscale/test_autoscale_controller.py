@@ -129,3 +129,70 @@ def test_phase_samples_land_in_observation():
     )
     assert obs.dominant_phase == "queue_wait"
     assert obs.phase_shares["queue_wait"] == pytest.approx(0.75)
+
+
+# -- the loop itself: reap -> observe -> decide -> act ----------------------
+
+
+class ScriptedScraper:
+    """``observe()`` from a script of (fleet p99 or None for a blind
+    scrape); the clock advances 10 s a tick, past every cooldown."""
+
+    def __init__(self, script):
+        self.script = list(script)
+        self.t = 0.0
+
+    def observe(self):
+        from keystone_tpu.autoscale.policy import FleetObservation
+
+        self.t += 10.0
+        p99 = self.script.pop(0)
+        if p99 is None:
+            return None
+        return FleetObservation(t=self.t, fleet_p99_s=p99, metrics_ok=True)
+
+
+def test_control_loop_scales_out_replaces_the_dead_and_retires_to_baseline():
+    """The elasticity contract on scripted evidence, no clock and no
+    sockets: ticks over the latency objective grow the fleet, a replica
+    that dies is replaced outside the policy, a blind scrape decides
+    nothing, and ticks far inside the objective drain-retire the fleet
+    to its one-replica baseline — each decision counted and emitted."""
+    from keystone_tpu.autoscale.controller import Autoscaler
+    from keystone_tpu.autoscale.policy import PolicyConfig, PolicyEngine
+    from keystone_tpu.observability.registry import MetricsRegistry
+
+    from test_autoscale_supervisor import make
+
+    launcher, sup = make()
+    hot, cold = 0.5, 0.001
+    scraper = ScriptedScraper([hot] * 4 + [None] + [cold] * 8)
+    events = []
+    loop = Autoscaler(
+        sup, scraper,
+        PolicyEngine(PolicyConfig(
+            min_replicas=1, max_replicas=3, slo_latency_s=0.1,
+            up_consecutive=2, down_consecutive=2,
+            up_cooldown_s=5.0, down_cooldown_s=5.0,
+        )),
+        interval_s=1.0, registry=MetricsRegistry(), name="loop",
+        on_event=events.append,
+    )
+    sup.scale_to(1)
+    actions = [loop.tick().action for _ in range(4)]
+    assert actions == ["hold", "scale_up", "hold", "scale_up"]
+    assert sup.target == 3 and len(sup.replicas()) == 3
+    # kill -9 one replica: the next tick repairs before it observes,
+    # and that tick's scrape is blind, so nothing else is decided
+    launcher.launched[0]._alive = False
+    assert loop.tick() is None
+    assert sup.replaced_total == 1 and len(sup.replicas()) == 3
+    assert [e["event"] for e in events].count("replicas_replaced") == 1
+    while scraper.script:
+        loop.tick()
+    assert sup.target == 1 and len(sup.replicas()) == 1
+    decided = [e for e in events if e["event"] == "autoscale_decision"]
+    assert len(decided) == 12  # every tick but the blind one
+    assert max(e["running"] for e in decided) == 3
+    assert [e["action"] for e in decided].count("scale_up") == 2
+    assert [e["action"] for e in decided].count("scale_down") == 2

@@ -18,7 +18,7 @@ from keystone_tpu.fleet.client import post_roster
 from keystone_tpu.fleet.registry import ReplicaRegistry
 from keystone_tpu.gateway import Gateway, GatewayServer
 from keystone_tpu.observability.registry import MetricsRegistry
-from keystone_tpu.serving.bench import build_pipeline
+from keystone_tpu.serving.demo_model import build_pipeline
 from keystone_tpu.zoo import (
     BuiltModel,
     ModelRegistry,

@@ -14,7 +14,7 @@ from keystone_tpu.lifecycle.controller import LifecycleController
 from keystone_tpu.lifecycle.policy import PromotionConfig
 from keystone_tpu.lifecycle.teacher import teacher_labels
 from keystone_tpu.loadgen import faults
-from keystone_tpu.serving.bench import affine_head, build_split_pipeline
+from keystone_tpu.serving.demo_model import affine_head, build_split_pipeline
 
 D, HIDDEN, DEPTH = 6, 8, 2
 HEAD_SEED = 55

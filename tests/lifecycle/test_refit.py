@@ -8,7 +8,7 @@ import pytest
 from keystone_tpu.lifecycle.refit import RefitAccumulator
 from keystone_tpu.lifecycle.teacher import teacher_labels
 from keystone_tpu.loadgen import faults
-from keystone_tpu.serving.bench import affine_head, build_split_pipeline
+from keystone_tpu.serving.demo_model import affine_head, build_split_pipeline
 
 D, HIDDEN, DEPTH = 6, 8, 2
 HEAD_SEED = 99

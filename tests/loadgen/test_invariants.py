@@ -1,7 +1,7 @@
 """The invariant checker must be able to FAIL: a stub gateway that
 loses a future, returns an untyped 500, or never recovers readiness
 must each produce a red verdict — otherwise the green verdicts the
-bench rows assert are worthless."""
+tests and smoke scripts assert are worthless."""
 
 import pytest
 

@@ -90,7 +90,7 @@ def test_live_engine_scrape_end_to_end(traced):
     """Acceptance: GET /metrics on a live engine returns Prometheus text
     with per-bucket compile/dispatch counters and latency quantiles;
     /tracez shows the dispatch spans."""
-    from keystone_tpu.serving.bench import build_pipeline
+    from keystone_tpu.serving.demo_model import build_pipeline
 
     reg = MetricsRegistry()
     fitted = build_pipeline(d=8, hidden=8, depth=2)
@@ -171,7 +171,7 @@ def test_chrome_trace_export_of_serving_run(traced, tmp_path):
     that is structurally loadable (traceEvents of complete "X" events
     with numeric ts/dur) — the chrome://tracing / Perfetto format."""
     from keystone_tpu.serving import MicroBatcher
-    from keystone_tpu.serving.bench import build_pipeline
+    from keystone_tpu.serving.demo_model import build_pipeline
 
     fitted = build_pipeline(d=8, hidden=8, depth=2)
     engine = fitted.compiled(buckets=(4,))

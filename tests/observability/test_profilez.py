@@ -122,7 +122,7 @@ def test_profilez_e2e_on_admin_and_gateway_ports(tmp_path):
 
 def test_profilez_route_on_gateway_port():
     from keystone_tpu.gateway import Gateway, GatewayServer
-    from keystone_tpu.serving.bench import build_pipeline
+    from keystone_tpu.serving.demo_model import build_pipeline
 
     import numpy as np
 

@@ -4,7 +4,10 @@ The reference validates SIFT against a MATLAB vl_phow export
 (feats128.csv) and FV against a fixture-sum constant (EncEvalSuite) — the
 CSV fixtures are absent from the reference repo, so these tests validate
 against independent numpy translations of the same math plus structural
-invariants, and FV against the actual voc_codebook GMM fixtures.
+invariants, and FV on a seeded GMM written and read back in the
+reference's ``voc_codebook`` layout (``means.csv`` and ``variances.csv``,
+dim x centers, and ``priors``) — the reference's own files are compared
+in ``test_reference_fixtures.py``, where they are mounted.
 """
 
 import numpy as np
@@ -20,7 +23,23 @@ from keystone_tpu.ops.images.sift import SIFTExtractor
 from keystone_tpu.ops.learning.gmm import GaussianMixtureModel
 from keystone_tpu.parallel.dataset import Dataset
 
-VOC_CODEBOOK = "/root/reference/src/test/resources/images/voc_codebook"
+
+@pytest.fixture
+def voc_codebook(tmp_path):
+    """A GMM at the reference codebook's size (80-dim PCA-SIFT, 256
+    centers) and descriptor scale, in its three-file layout."""
+    rng = np.random.default_rng(7)
+    d, k = 80, 256
+    np.savetxt(tmp_path / "means.csv",
+               rng.normal(0.0, 50.0, (d, k)), delimiter=",")
+    np.savetxt(tmp_path / "variances.csv",
+               rng.uniform(400.0, 2500.0, (d, k)), delimiter=",")
+    np.savetxt(tmp_path / "priors", rng.dirichlet(np.full(k, 5.0)))
+    return GaussianMixtureModel.load(
+        str(tmp_path / "means.csv"),
+        str(tmp_path / "variances.csv"),
+        str(tmp_path / "priors"),
+    )
 
 
 def _test_image(h=64, w=64, seed=0):
@@ -165,12 +184,8 @@ def _np_fisher_vector(gmm_means, gmm_vars, gmm_weights, x, thresh=1e-4):
     return np.concatenate([fv1, fv2], axis=1)
 
 
-def test_fisher_vector_matches_numpy_on_voc_codebook():
-    gmm = GaussianMixtureModel.load(
-        f"{VOC_CODEBOOK}/means.csv",
-        f"{VOC_CODEBOOK}/variances.csv",
-        f"{VOC_CODEBOOK}/priors",
-    )
+def test_fisher_vector_matches_numpy_on_voc_codebook(voc_codebook):
+    gmm = voc_codebook
     rng = np.random.default_rng(0)
     d = gmm.dim
     x = rng.standard_normal((d, 50)).astype(np.float32) * 100
@@ -197,16 +212,12 @@ def test_fisher_vector_estimator_end_to_end():
     assert np.asarray(out).shape == (8, 4)
 
 
-def test_fused_fisher_vector_matches_numpy_on_voc_codebook():
-    """Same reference-codebook check for the fused Pallas path
+def test_fused_fisher_vector_matches_numpy_on_voc_codebook(voc_codebook):
+    """Same codebook check for the fused Pallas path
     (the enceval-native parallel, external/FisherVector.scala:17)."""
     from keystone_tpu.ops.images.fisher_vector import FisherVectorFused
 
-    gmm = GaussianMixtureModel.load(
-        f"{VOC_CODEBOOK}/means.csv",
-        f"{VOC_CODEBOOK}/variances.csv",
-        f"{VOC_CODEBOOK}/priors",
-    )
+    gmm = voc_codebook
     rng = np.random.default_rng(0)
     d = gmm.dim
     x = rng.standard_normal((d, 50)).astype(np.float32) * 100

@@ -13,7 +13,7 @@ import pytest
 from keystone_tpu.observability.registry import MetricsRegistry
 from keystone_tpu.serving import aot
 from keystone_tpu.serving.aot import AotStore
-from keystone_tpu.serving.bench import build_pipeline
+from keystone_tpu.serving.demo_model import build_pipeline
 
 D = 16
 EXAMPLE = jnp.zeros((D,), jnp.float32)
@@ -318,7 +318,7 @@ def test_bucket_key_featurize_token_isolates():
 def _fused_pair():
     """Two featurize chains differing only in filter weights, plus a
     model sized to their shared output dim."""
-    from keystone_tpu.serving.bench import build_pipeline
+    from keystone_tpu.serving.demo_model import build_pipeline
     from keystone_tpu.serving.featurize import build_featurize_pipeline
 
     feat1, feat_d = build_featurize_pipeline(

@@ -10,7 +10,7 @@ import pytest
 
 from keystone_tpu.observability.registry import MetricsRegistry
 from keystone_tpu.serving.aot import AotStore, bucket_key
-from keystone_tpu.serving.bench import build_pipeline
+from keystone_tpu.serving.demo_model import build_pipeline
 
 D = 16
 EXAMPLE = jnp.zeros((D,), jnp.float32)

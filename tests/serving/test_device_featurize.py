@@ -12,7 +12,7 @@ import pytest
 
 from keystone_tpu.observability.registry import MetricsRegistry
 from keystone_tpu.serving.batching import MicroBatcher
-from keystone_tpu.serving.bench import build_pipeline
+from keystone_tpu.serving.demo_model import build_pipeline
 from keystone_tpu.serving.featurize import build_featurize_pipeline
 
 IMG, C = 8, 3
@@ -176,6 +176,49 @@ def test_flagship_branched_dag_fuses_and_matches_two_stage(flagship):
     # no retrace on dispatch, and the wire carried raw pixels
     assert eng.metrics.compile_count == len(eng.buckets)
     assert eng.metrics.h2d_bytes.snapshot() == {4: 4 * FIMG * FIMG * C}
+
+
+@pytest.fixture
+def pinned_peaks(monkeypatch):
+    """Known peaks for a device absent from the table (the CPU here)."""
+    from keystone_tpu.observability import device as device_obs
+
+    monkeypatch.setenv("KEYSTONE_PEAK_FLOPS", "1e12")
+    monkeypatch.setenv("KEYSTONE_PEAK_MEMBW_GBPS", "100")
+    device_obs.reset_device_table()
+    yield
+    device_obs.reset_device_table()
+
+
+def test_fused_flagship_program_publishes_its_device_series(
+    flagship, pinned_peaks
+):
+    """The fused featurize-and-predict program has a cost model for every
+    warmed bucket, and where the chip's peaks are known the series
+    derived from it are present: a roofline class for each bucket and,
+    after traffic, a utilization. Presence only — the values are a
+    chip's to give."""
+    feat, feat_d = flagship
+    eng = build_pipeline(d=feat_d, hidden=8, depth=2).compiled(
+        buckets=(2, 4), featurize=feat, aot_store=False,
+        name="dfz-fl-series",
+    )
+    eng.warmup(example=jnp.zeros((FIMG, FIMG, C), jnp.uint8))
+    m = eng.metrics
+    if not m.cost_models:
+        pytest.skip("backend reports no XLA cost analysis")
+    assert sorted(m.cost_models) == [2, 4]
+    assert all(m.cost_models[b]["flops"] > 0 for b in (2, 4))
+    assert {m.roofline_bound(b) for b in (2, 4)} <= {
+        "compute", "bandwidth"
+    }
+    rng = np.random.default_rng(5)
+    eng.apply(
+        rng.integers(0, 256, (3, FIMG, FIMG, C), dtype=np.uint8),
+        sync=True,
+    )
+    assert m.device_flops.total == m.cost_models[4]["flops"]
+    assert m.mfu() is not None
 
 
 def test_gateway_device_featurize_swap_keeps_fused_stage(model, featurize):

@@ -517,7 +517,7 @@ def test_uint8_staging_pool_reuse_and_byte_accounting():
     both the pool's byte ledger and the staging-bytes gauge account
     the one-byte-per-element footprint exactly (the f32 ledger would
     be 4x this for the same element count)."""
-    from keystone_tpu.serving.bench import build_pipeline
+    from keystone_tpu.serving.demo_model import build_pipeline
     from keystone_tpu.serving.featurize import build_featurize_pipeline
 
     img, ch = 8, 3

@@ -303,7 +303,7 @@ def test_staging_bytes_gauge_exports_when_set():
 
 def test_engine_autoregisters_into_global_registry():
     from keystone_tpu.observability.registry import get_global_registry
-    from keystone_tpu.serving.bench import build_pipeline
+    from keystone_tpu.serving.demo_model import build_pipeline
 
     fitted = build_pipeline(d=4, hidden=4, depth=1)
     engine = fitted.compiled(buckets=(2,), name="autoreg-test")

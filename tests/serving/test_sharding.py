@@ -14,7 +14,7 @@ from jax.sharding import PartitionSpec as PS
 
 from keystone_tpu.parallel import mesh as mesh_lib
 from keystone_tpu.serving import sharding
-from keystone_tpu.serving.bench import build_pipeline
+from keystone_tpu.serving.demo_model import build_pipeline
 from keystone_tpu.serving.engine import CompiledPipeline
 from keystone_tpu.workflow.api import Transformer
 

@@ -6,7 +6,7 @@ group, outputs bit-matching each member's solo engine."""
 import numpy as np
 import pytest
 
-from keystone_tpu.serving.bench import build_pipeline
+from keystone_tpu.serving.demo_model import build_pipeline
 from keystone_tpu.serving.engine import CompiledPipeline
 from keystone_tpu.serving.featurize import build_featurize_pipeline
 from keystone_tpu.zoo import SharedPrefixEngine, featurize_groups
@@ -86,8 +86,8 @@ def test_shared_prefix_traces_once_per_bucket(featurize):
     shared.apply(_raws(3), sync=True)   # bucket 4: first trace
     shared.apply(_raws(4), sync=True)   # bucket 4 again: cached
     shared.apply(_raws(2), sync=True)   # bucket 2: second trace
-    # ONE program per bucket serves the whole group — this is the
-    # counter seam the serving_zoo bench row gates on
+    # ONE program per bucket serves the whole group, where solo
+    # hosting traces one per model and bucket
     assert shared.metrics.compiles.total == 2
     assert shared.metrics.dispatches.total == 3
 

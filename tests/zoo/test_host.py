@@ -9,7 +9,7 @@ import numpy as np
 import pytest
 
 from keystone_tpu.observability.registry import MetricsRegistry
-from keystone_tpu.serving.bench import build_pipeline
+from keystone_tpu.serving.demo_model import build_pipeline
 from keystone_tpu.serving.engine import CompiledPipeline
 from keystone_tpu.serving.featurize import build_featurize_pipeline
 from keystone_tpu.zoo import (
@@ -352,3 +352,115 @@ def test_predict_many_solo_units_account_each_model_once():
             assert _goodput(zoo)[mid] - good0.get(mid, 0) == (
                 pytest.approx(1.0)
             )
+
+
+# -- attribution and drift through a hosted, planned zoo --------------------
+
+
+def _planned_cse_zoo(base_mix):
+    """Two heads on one featurize prefix (so the ledger's fair split is
+    in play), hosted, and a plan applied whose profiles pin each model's
+    expected request sizes as its drift baseline."""
+    from keystone_tpu.zoo.optimizer import ChipBudget, plan_placement
+
+    feat, feat_d = build_featurize_pipeline(img=IMG)
+    heads = {
+        "alpha": build_pipeline(d=feat_d, hidden=8, depth=2, seed=1),
+        "beta": build_pipeline(d=feat_d, hidden=8, depth=2, seed=2),
+    }
+    specs = [
+        ModelSpec(
+            model_id=mid,
+            build=lambda h=head: BuiltModel(fitted=h, featurize=feat),
+            buckets=(2, 8, 32),
+            lanes=1,
+            max_delay_ms=1.0,
+            input_dtype=np.uint8,
+            warmup_example=np.zeros((IMG, IMG, 3), np.uint8),
+            expected_sizes=dict(base_mix),
+            default=(mid == "alpha"),
+        )
+        for mid, head in heads.items()
+    ]
+    zoo = _zoo(specs, cse=True)
+    zoo.host()
+    profiles = zoo.profiles(build=True)
+    budget = ChipBudget(lane_budget=2)
+    zoo.apply_plan(
+        plan_placement(profiles, budget), budget=budget, profiles=profiles
+    )
+    return zoo
+
+
+def _drive(zoo, schedule):
+    rng = np.random.default_rng(23)
+    x = rng.integers(0, 256, (IMG, IMG, 3), dtype=np.uint8)
+    for mid, size in schedule:
+        zoo.observe_request(mid, size)
+        for f in [zoo.predict(x, mid) for _ in range(size)]:
+            f.result(timeout=60)
+
+
+def test_ledger_totals_equal_the_engines_in_every_field():
+    """Per-model charges sum to what the engines counted — rows, padded
+    rows, dispatches, modelled FLOPs, H2D bytes, device seconds — with
+    both models' windows split on one shared engine."""
+    with _planned_cse_zoo({1: 80, 2: 20}) as zoo:
+        _drive(zoo, [("alpha", 1), ("beta", 2), ("alpha", 5), ("beta", 1)])
+        engines = {
+            "goodput_rows": 0.0, "padded_rows": 0.0, "dispatches": 0.0,
+            "device_flops": 0.0, "h2d_bytes": 0.0, "device_seconds": 0.0,
+        }
+        gw = zoo.gateway_for("alpha")
+        assert gw is zoo.gateway_for("beta")  # the CSE group's engines
+        for lane in gw.pool.lanes:
+            m = lane.engine.metrics
+            engines["goodput_rows"] += m.examples.total
+            engines["padded_rows"] += m.padded_rows.total
+            engines["dispatches"] += m.dispatches.total
+            engines["device_flops"] += m.device_flops.total
+            engines["h2d_bytes"] += m.h2d_bytes.total
+            engines["device_seconds"] += (
+                m.dispatch_latency.snapshot()["total"]
+            )
+        ledger = zoo.attribution.totals()
+        assert engines["goodput_rows"] == 9
+        for field, total in engines.items():
+            assert ledger[field] == pytest.approx(total, rel=1e-6), field
+        doc = zoo.attributionz()
+        assert set(doc["models"]) == {"alpha", "beta"}
+
+
+def test_drift_flags_the_shifted_model_only_and_the_replan_follows_it():
+    """Traffic that matches the plan's mixture flags nobody; when one
+    model's requests move from singles to 24 rows, its PSI crosses the
+    threshold and the other's does not, and ``driftz`` carries a re-plan
+    (recommended, never applied) that changes the shifted model and
+    covers its new size with a tighter bucket than the applied plan."""
+    base = [1] * 16 + [2] * 4
+    with _planned_cse_zoo({1: 80, 2: 20}) as zoo:
+        before = {
+            m: zoo.plan.placement_for(m).buckets for m in ("alpha", "beta")
+        }
+        _drive(zoo, [(m, s) for s in base for m in ("alpha", "beta")])
+        assert zoo.driftz()["drifted"] == []
+        assert zoo.driftz()["recommendation"] is None
+        _drive(zoo, [("alpha", 24)] * 20 + [("beta", s) for s in base])
+        doc = zoo.driftz()
+        assert doc["drifted"] == ["alpha"]
+        assert doc["scores"]["alpha"] > doc["threshold"]
+        assert doc["scores"]["beta"] <= doc["threshold"]
+        rec = doc["recommendation"]
+        assert "alpha" in rec["changes"]
+        proposed = {
+            p["model"]: tuple(p["buckets"])
+            for p in rec["proposed_plan"]["placements"]
+        }
+
+        def covering(buckets):
+            fits = [b for b in buckets if b >= 24]
+            return min(fits) if fits else max(buckets)
+
+        assert covering(proposed["alpha"]) < covering(before["alpha"])
+        # recommended only: the applied plan is what it was
+        assert zoo.plan.placement_for("alpha").buckets == before["alpha"]

@@ -14,7 +14,7 @@ import pytest
 
 from keystone_tpu.gateway import Gateway, GatewayServer
 from keystone_tpu.observability.registry import MetricsRegistry
-from keystone_tpu.serving.bench import build_pipeline
+from keystone_tpu.serving.demo_model import build_pipeline
 from keystone_tpu.zoo import (
     BuiltModel,
     ModelRegistry,
