@@ -17,18 +17,34 @@ size with zero-weight rows) — the EP-style grouping of SURVEY §2.10 —
 then per-class covariances are one batched einsum over class chunks and
 the per-class (b, b) solves are one batched Cholesky, all on device.
 Total flops match the reference (Σ_c n_c·b² = n·b²); no shuffle, no
-driver round trip, no distributed System.gc().
+driver round trip, no distributed System.gc(). That is ``solve="chol"``.
+
+``solve="pcg"`` (what ``auto`` takes at wide blocks) never forms a class
+covariance: all C systems share one matrix-free preconditioned CG
+(``_pcg_block_core``). Its statistics read the original rows through
+one-hot GEMMs; its matvec needs each row with its own class's vector
+only, and reads one of two row layouts, chosen a fit from what the
+estimator can see (``_sorted_layout``): ``sorted`` — the block's rows
+gathered once a block step into class order, tiles of consecutive rows
+against a window of 128 class vectors — where the copy fits the device
+beside X and the class counts keep every tile inside one window, and
+``original`` — one-hot GEMMs over all C classes, no copy, C/128 times
+the operations — elsewhere (sharded rows, host-RAM slabs, tight memory,
+classes so small that 16,384 sorted rows hold more than 128 of them).
 
 Observability: host spans ``solver.wls.prep`` (array mode, padding, the
-labels' cast), ``solver.wls.dispatch`` (the fit's device programs
-enqueued; on the chol path its host loop too) and
-``solver.wls.converged`` (the read of the CG exit residual that
-``convergence_check`` waits on); on the device ``jax.named_scope`` names
-``wls.setup`` / ``wls.stats`` / ``wls.precond`` / ``wls.cg`` /
-``wls.update``; counters ``keystone_solver_wls_fits_total``,
+labels' cast), ``solver.wls.layout`` (pcg: the choice of the matvec's
+row layout, with its read of the class counts), ``solver.wls.dispatch``
+(the fit's device programs enqueued; on the chol path its host loop
+too) and ``solver.wls.converged`` (the read of the CG exit residual
+that ``convergence_check`` waits on); on the device ``jax.named_scope``
+names ``wls.setup`` / ``wls.stats`` / ``wls.precond`` / ``wls.sort`` /
+``wls.cg`` / ``wls.update``; counters ``keystone_solver_wls_fits_total``,
 ``keystone_solver_wls_path_total{solve,layout}`` (the path ``auto``
-took) and ``keystone_solver_wls_pcg_iterations_total`` (the iterations
-a fit reports, added where ``convergence_check`` reads them anyway).
+took), ``keystone_solver_wls_sorted_fits_total`` (fits whose matvec ran
+on sorted rows) and ``keystone_solver_wls_pcg_iterations_total`` (the
+iterations a fit reports, added where ``convergence_check`` reads them
+anyway).
 """
 
 from __future__ import annotations
@@ -269,26 +285,113 @@ def _dot10(a, b):
     )
 
 
+def _class_sorted_rows(P, tile):
+    """The rows' order by class for the sorted-rows matvec, from the
+    one-hot membership P (n, C): ``order`` (a stable argsort of each
+    row's class; rows of no class — pad rows, rows without a positive
+    label — last) and ``kcls``, the class of each sorted row (C for a
+    row of no class), both (tiles, tile): padded to a whole number of
+    tiles with rows of no class. The order follows from the labels
+    alone: one sort a fit serves every block step."""
+    n_rows, C = P.shape
+    col = jax.lax.broadcasted_iota(jnp.int32, P.shape, 1)
+    cls = jnp.min(jnp.where(P > 0, col, C), axis=1)  # P is one-hot
+    order = jnp.argsort(cls, stable=True).astype(jnp.int32)
+    pad = -n_rows % tile
+    return (
+        jnp.concatenate([order, jnp.zeros((pad,), jnp.int32)])
+        .reshape(-1, tile),
+        jnp.concatenate([cls[order], jnp.full((pad,), C, jnp.int32)])
+        .reshape(-1, tile),
+    )
+
+
+def _sorted_class_products(Xs, kcls, C, window):
+    """The CG matvec's two data-sized products on class-sorted rows.
+    ``Xs`` (tiles, tile, b) holds the block's rows in class order and
+    ``kcls`` (tiles, tile) each row's class (C: none). Returns v (C, b)
+    -> Σ_{i in c} x_i (x_i·v_c), (C, b).
+
+    Tile i's rows lie in classes [lo_i, lo_i + window) (``_sorted_layout``
+    holds the caller to that), so a row meets ``window`` vectors, not C:
+    T_i = X_i·V_iᵀ against the window's vectors (tile, window), all but
+    each row's own-class entry (z_i) zeroed by a one-hot local to the
+    window, then X_iᵀ(onehot_i ⊙ z_i) (window, b), added into rows
+    [lo_i, lo_i + window) of the result. Precision as on the original
+    rows: f32 data at HIGHEST, bf16 data against the three limbs of the
+    f32 side."""
+    f32 = jnp.float32
+    hp = jax.lax.Precision.HIGHEST
+    b = Xs.shape[2]
+    bf16_data = Xs.dtype == jnp.bfloat16
+    lo = kcls[:, 0]  # sorted: a tile's first row has its lowest class
+    # a row of no class matches no column of the window
+    kloc = jnp.where(kcls < C, kcls - lo[:, None], -1)
+    # the iterate is padded with ``window`` zero rows, so a window that
+    # starts at the last classes (or at C: a tile of pad rows) stays
+    # inside it
+    wrows = lo[:, None] + jnp.arange(window, dtype=jnp.int32)
+
+    def bdot(spec, a, c):
+        return jnp.einsum(spec, a, c, preferred_element_type=f32,
+                          precision=None if bf16_data else hp)
+
+    def products(v):
+        vp = jnp.concatenate([v, jnp.zeros((window, b), f32)])
+        Vw = vp.at[wrows].get(mode="promise_in_bounds")  # (tiles, K, b)
+        own = kloc[:, :, None] == jnp.arange(window, dtype=jnp.int32)
+        if bf16_data:
+            T = _sum3(bdot("itb,ikb->itk", Xs, _limb3(Vw, 1)), axis=2)
+        else:
+            T = bdot("itb,ikb->itk", Xs, Vw)
+        # onehot ⊙ z without z: a row's one own-class entry of T is z_i
+        oz = jnp.where(own, T, 0.0)  # (tiles, tile, K)
+        if bf16_data:
+            S = _sum3(bdot("itb,itk->ikb", Xs, _limb3(oz, 2)), axis=1)
+        else:
+            S = bdot("itb,itk->ikb", Xs, oz)
+        out = jnp.zeros((C + window, b), f32).at[wrows].add(
+            S, mode="promise_in_bounds"
+        )
+        return out[:C]
+
+    return products
+
+
 def _pcg_block_core(X, R, P, Wb, inv_counts, valid, start, w, lam,
-                    *, width, n, max_iters=96, tol=1e-6):
-    """One whole weighted-BCD block update for ALL classes at once, on
-    the ORIGINAL (ungrouped) row layout, as a single device program:
-    population stats, shared-preconditioner inverse, batched matrix-free
-    PCG over the C per-class systems, and the residual update.
+                    sort=None, *, width, n, max_iters=96, tol=1e-6,
+                    sort_window=0):
+    """One whole weighted-BCD block update for ALL classes at once, as a
+    single device program: population stats, shared-preconditioner
+    inverse, batched matrix-free PCG over the C per-class systems, and
+    the residual update. The statistics and the update read the
+    ORIGINAL (ungrouped) rows; the CG matvec reads them too, or a
+    class-sorted copy of the block (``sort``, below).
 
     This replaced a design of class-grouped gathers and 8 class-chunks,
     each its own CG with triangular-solve preconditioning, whose
     chunked TRSMs were the largest single cost of the flagship fit.
     Here:
 
-    - per-class contractions ride ONE-HOT GEMMs: with P (n, C) the 0/1
-      class-membership matrix, classMean = PᵀX_b, resLocal = (R ⊙ P)·1,
-      and the CG matvec's class-restricted products
-      X_bᵀ(diag(z) X_b v_c-per-row) become two (n,b)x(b,3C)-shaped MXU
-      GEMMs via ``_limb3`` — no grouping gather (the r3 grouped copy
-      doubled HBM and cost ~160 ms), no host-side index building, no
-      per-chunk padding pathology for skewed classes (ADVICE r3), and
-      every CG iteration reads X_b exactly twice at stream bandwidth;
+    - the statistics' per-class contractions ride ONE-HOT GEMMs: with
+      P (n, C) the 0/1 class-membership matrix, classMean = PᵀX_b and
+      resLocal = (R ⊙ P)·1 — no host-side index building, no per-chunk
+      padding pathology for skewed classes (ADVICE r3);
+    - the CG matvec's class-restricted products, z_i = x_i·v_{y_i} and
+      Σ_{i in c} x_i z_i, need each row with its OWN class's vector
+      only. With ``sort`` = (order, kcls) from ``_class_sorted_rows``
+      the block's rows are gathered ONCE, after the statistics, into
+      class order as (tiles, tile, b); a tile of consecutive sorted
+      rows meets a contiguous window of at most ``sort_window`` classes
+      (the caller checked the class counts: ``_sorted_layout``), so both
+      products are GEMMs batched over tiles against the window's
+      vectors: 2·n·b·sort_window operations each where the one-hot form
+      spends 2·n·b·C, and two reads of the copy an iteration. The copy
+      costs the block's bytes again, so the caller takes it only where
+      it fits (``_sorted_layout``). Without ``sort`` the same products
+      ride one-hot GEMMs over all C classes on the original rows,
+      (n,b)x(b,C)-shaped (3C via ``_limb3`` for bf16 rows): no copy, C
+      times the operations;
     - all C systems share one CG loop (the per-class solves are batched
       rows of the iterate), preconditioned by the explicit inverse of
       M = (1−w)·popCov + (λ+ε)I (see ``_precond_inverse``) applied as
@@ -397,14 +500,32 @@ def _pcg_block_core(X, R, P, Wb, inv_counts, valid, start, w, lam,
     with jax.named_scope("wls.precond"):
         Minv = _precond_inverse(pop_cov, w, lam)
 
-    def matvec(v):  # (C, b) -> (C, b)
-        pv = (1.0 - w) * jnp.matmul(v, pop_cov, precision=hp)
+    def onehot_xxv(v):  # (C, b) -> Σ_{i in c} x_i (x_i·v_c), (C, b)
         T = mm_bf16_f32_11(v)  # (n, C) rows X_b·v_c for every class c
         z = jnp.einsum("nc,nc->n", T, Pf)  # pick own-class entry
         if bf16_data:
-            xxv = _sum3(_dot00(Xb, onehot_scale_limbs(z)), axis=1).T
-        else:
-            xxv = mm_bf16_f32_00(Pf * z[:, None]).T  # (C, b)
+            return _sum3(_dot00(Xb, onehot_scale_limbs(z)), axis=1).T
+        return mm_bf16_f32_00(Pf * z[:, None]).T
+
+    if sort is None:
+        class_xxv = onehot_xxv
+    else:
+        with jax.named_scope("wls.sort"):
+            # the copy is the block's bytes again: the barrier keeps its
+            # gather behind the statistics, whose (n, C) temporaries are
+            # dead by then (the gather depends on nothing else, and XLA
+            # is free to schedule it first)
+            order, rhs, pop_cov = jax.lax.optimization_barrier(
+                (sort[0], rhs, pop_cov)
+            )
+            class_xxv = _sorted_class_products(
+                Xb.at[order].get(mode="promise_in_bounds"), sort[1], C,
+                sort_window,
+            )
+
+    def matvec(v):  # (C, b) -> (C, b)
+        pv = (1.0 - w) * jnp.matmul(v, pop_cov, precision=hp)
+        xxv = class_xxv(v)
         cm_dot = jnp.einsum("gb,gb->g", cmean, v, precision=hp)
         ccov_v = xxv * inv_counts[:, None] - cmean * cm_dot[:, None]
         dd = (
@@ -470,18 +591,23 @@ def _pcg_block_core(X, R, P, Wb, inv_counts, valid, start, w, lam,
 
 @partial(
     jax.jit,
-    static_argnames=("width", "n", "max_iters", "tol"),
+    static_argnames=("width", "n", "max_iters", "tol", "sort_window"),
     donate_argnums=(1,),
 )
 def _pcg_block_step(X, R, P, Wb, inv_counts, valid, start, w, lam,
-                    *, width, n, max_iters=96, tol=1e-6):
+                    sort=None, *, width, n, max_iters=96, tol=1e-6,
+                    sort_window=0):
     """Single-block dispatch of ``_pcg_block_core`` (used for non-uniform
-    tail blocks; uniform-width fits go through ``_pcg_fit_full``)."""
+    tail blocks and host-RAM slabs; uniform-width fits go through
+    ``_pcg_fit_full``)."""
     return _pcg_block_core(X, R, P, Wb, inv_counts, valid, start, w, lam,
-                           width=width, n=n, max_iters=max_iters, tol=tol)
+                           sort, width=width, n=n, max_iters=max_iters,
+                           tol=tol, sort_window=sort_window)
 
 
-def _pcg_setup_core(Y, mask, w, n):
+def _membership(Y, mask):
+    """One-hot class membership P (n, C) (bf16, exact 0/1; pad rows
+    zero) and the rows of each class (C,)."""
     # Class membership must match the chol path / the reference
     # (indexOf(label.max), i.e. argmax with first-index tie-breaking,
     # BlockWeightedLeastSquares.scala) — an explicit argmax + one_hot
@@ -494,43 +620,61 @@ def _pcg_setup_core(Y, mask, w, n):
     # labels whose positive entries are NOT all equal (arbitrary
     # real-valued Y) would need a true argmax — the estimator's
     # docstring pins indicator-style labels for this path.
+    pos = Y > 0
+    first_pos = pos & (jnp.cumsum(pos, axis=1) == 1)
+    P = first_pos.astype(jnp.bfloat16) * mask[:, None].astype(jnp.bfloat16)
+    return P, jnp.einsum("nc->c", P.astype(jnp.float32))
+
+
+@jax.jit
+def _class_counts(Y, mask):
+    """Rows of each class (C,), for the host's choice of the matvec's
+    row layout (``_sorted_layout``): the one read-back a sorted fit
+    makes before its program is enqueued."""
+    return _membership(Y, mask)[1]
+
+
+def _pcg_setup_core(Y, mask, w, n, sort_tile=0):
     with jax.named_scope("wls.setup"):
-        pos = Y > 0
-        first_pos = pos & (jnp.cumsum(pos, axis=1) == 1)
-        P = (first_pos.astype(jnp.bfloat16)
-             * mask[:, None].astype(jnp.bfloat16))
-        counts = jnp.einsum("nc->c", P.astype(jnp.float32))
+        P, counts = _membership(Y, mask)
         inv_counts = 1.0 / jnp.maximum(counts, 1.0)
         valid = (counts > 0).astype(jnp.float32)
         # jointLabelMean[c] = 2w + 2(1-w)·n_c/n − 1 (reference :148-155)
         jlm = 2.0 * w + 2.0 * (1.0 - w) * counts / n - 1.0
         R = (Y - jlm[None, :]) * mask[:, None]
-    return P, inv_counts, valid, jlm, R
+        sort = _class_sorted_rows(P, sort_tile) if sort_tile else None
+    return P, inv_counts, valid, jlm, R, sort
 
 
-@partial(jax.jit, static_argnames=("n",))
-def _pcg_setup(Y, mask, w, *, n):
+@partial(jax.jit, static_argnames=("n", "sort_tile"))
+def _pcg_setup(Y, mask, w, *, n, sort_tile=0):
     """One-hot class membership P (bf16, exact 0/1), per-class counts,
-    joint label mean, and the initial residual — all on device (the r3
-    implementation synced class ids to host and built gather indices in
-    a Python loop over classes, ~250 ms of the flagship fit). Dispatch
-    wrapper for the ragged-block path; uniform fits use the fully fused
+    joint label mean, the initial residual and, with ``sort_tile``, the
+    rows' order by class — all on device (the r3 implementation synced
+    class ids to host and built gather indices in a Python loop over
+    classes, ~250 ms of the flagship fit). Dispatch wrapper for the
+    ragged-block path; uniform fits use the fully fused
     ``_pcg_fit_full``."""
-    return _pcg_setup_core(Y, mask, w, n)
+    return _pcg_setup_core(Y, mask, w, n, sort_tile)
 
 
 @partial(
     jax.jit,
-    static_argnames=("width", "n", "num_iter", "max_iters", "tol"),
+    static_argnames=("width", "n", "num_iter", "max_iters", "tol",
+                     "sort_tile", "sort_window"),
 )
 def _pcg_fit_full(X, Y, mask, starts, w, lam,
-                  *, width, n, num_iter, max_iters=96, tol=1e-5):
-    """The ENTIRE weighted-BCD fit — label setup, every epoch's scanned
-    block updates, model concatenation, and the intercept — as ONE
-    jitted program: a single dispatch and zero host work per fit.
+                  *, width, n, num_iter, max_iters=96, tol=1e-5,
+                  sort_tile=0, sort_window=0):
+    """The ENTIRE weighted-BCD fit — label setup (with ``sort_tile``,
+    the rows' order by class too), every epoch's scanned block updates,
+    model concatenation, and the intercept — as ONE jitted program: a
+    single dispatch and zero host work per fit.
     Returns (W (D, C), intercept (C,), max rel residual, max CG iters).
     """
-    P, inv_counts, valid, jlm, R = _pcg_setup_core(Y, mask, w, n)
+    P, inv_counts, valid, jlm, R, sort = _pcg_setup_core(
+        Y, mask, w, n, sort_tile
+    )
     C = Y.shape[1]
     nb = starts.shape[0]
     W0 = jnp.zeros((nb, width, C), jnp.float32)
@@ -540,7 +684,8 @@ def _pcg_fit_full(X, Y, mask, starts, w, lam,
         i, start = xs
         Wb_new, R_new, jm, rel, its = _pcg_block_core(
             X, R_c, P, Wstack[i], inv_counts, valid, start, w, lam,
-            width=width, n=n, max_iters=max_iters, tol=tol,
+            sort, width=width, n=n, max_iters=max_iters, tol=tol,
+            sort_window=sort_window,
         )
         Wstack = jax.lax.dynamic_update_index_in_dim(
             Wstack, Wb_new, i, axis=0
@@ -597,6 +742,75 @@ def _count_fit(solve: str, layout: str) -> None:
         "weighted fits by the solver and row layout taken",
         labelnames=("solve", "layout"),
     ).inc((solve, layout))
+    reg.counter(
+        "keystone_solver_wls_sorted_fits_total",
+        "weighted fits whose CG matvec ran on class-sorted rows",
+    ).inc(by=int(layout == "sorted"))
+
+
+# The sorted-rows matvec's shapes. A tile of _SORT_TILE class-sorted rows
+# (a fit of fewer rows is one tile) is multiplied by a window of
+# _SORT_WINDOW class vectors: one MXU width — a narrower window would
+# leave the array's columns idle, a wider one spends operations on
+# classes the tile does not hold. ImageNet's, TIMIT's and VOC's classes
+# all keep a tile inside a window; smaller tiles (4,096, 1,024 rows)
+# measured slower on the flagship's rows and serve no workload yet.
+_SORT_WINDOW = 128
+_SORT_TILE = 16384
+
+
+def _tiles_fit_window(counts, tile: int) -> bool:
+    """Whether every tile of ``tile`` consecutive class-sorted rows
+    spans at most _SORT_WINDOW classes, empty ones between them
+    included (False too when no row has a class)."""
+    ends = np.cumsum(np.asarray(counts, np.int64))
+    labelled = int(ends[-1])
+    if labelled == 0:
+        return False
+    first = np.arange(0, labelled, tile)
+    last = np.minimum(first + tile, labelled) - 1
+    # sorted row j lies in the first class whose rows end beyond j
+    span = (np.searchsorted(ends, last, "right")
+            - np.searchsorted(ends, first, "right") + 1)
+    return bool(span.max() <= _SORT_WINDOW)
+
+
+def _sorted_layout(X, Y, mask, width: int, block_steps: int) -> int:
+    """Whether this fit's CG matvec reads class-sorted rows, as the
+    tile to sort into (0: the one-hot matvec on the original rows).
+    Decided from what can be seen before the program is enqueued:
+
+    - the rows live on one device (a global sort of sharded rows would
+      move every row between devices each block step);
+    - the copy fits: what the program holds at its peak stays under
+      nine tenths of ``_device_memory_limit()`` (the tenth is for the
+      (b, b) and (C, b) arrays and whatever else the process holds).
+      That is X, the labels, the block's rows again and two
+      (tiles, tile, window) products; in a fit of several block steps
+      also the residual (carried, and written anew by the update), the
+      membership and, where the block is narrower than X, its columns
+      cut out of X before they are sorted. A fit of one block step
+      holds none of these: XLA frees the statistics' arrays before the
+      copy is made. Checked against the TPU compiler's own count at
+      the flagship's shape (n 327,680, D 4,096, C 1,000; compiler |
+      here): one step 12.51 | 12.38 GB, two blocks of 2,048 15.66 |
+      15.66, one block twice 16.13 | 15.66;
+    - the class counts keep every tile inside one window — the one
+      read-back, made last.
+    """
+    sharding = getattr(X, "sharding", None)  # a host array has none
+    if sharding is not None and len(sharding.device_set) > 1:
+        return 0
+    tile = min(_SORT_TILE, -(-X.shape[0] // 8) * 8)
+    rows = -(-X.shape[0] // tile) * tile
+    block = rows * width * X.dtype.itemsize
+    need = X.nbytes + Y.nbytes + block + 2 * rows * _SORT_WINDOW * 4
+    if block_steps > 1:
+        need += 2.5 * Y.nbytes + (block if width < X.shape[1] else 0)
+    if need > 0.9 * _device_memory_limit():
+        return 0
+    counts = np.asarray(_class_counts(Y, mask))
+    return tile if _tiles_fit_window(counts, tile) else 0
 
 
 @dataclasses.dataclass(eq=False)
@@ -620,16 +834,21 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
     class_chunk: int = 16  # classes per batched device step (chol path)
     solve: str = "auto"  # "chol": exact batched per-class Cholesky over
     # the class-grouped layout | "pcg": batched matrix-free
-    # preconditioned CG over the original layout (never materializes
-    # class covariances, the grouped copy, or the C per-class b³/3
-    # factorizations — each class has a single rhs) | "auto": pcg when
-    # the first block is wide (≥1024, where factorizations dominate)
-    # and w ≤ 0.9 (as w→1 the shared popCov preconditioner drains and
-    # CG may hit its iteration cap), chol otherwise
+    # preconditioned CG (never materializes class covariances, the
+    # padded grouped copy, or the C per-class b³/3 factorizations —
+    # each class has a single rhs); its matvec reads class-sorted rows
+    # where one more copy of a block fits the device and the class
+    # counts allow, the original rows otherwise (``_sorted_layout``
+    # decides a fit; ``keystone_solver_wls_path_total`` says which) |
+    # "auto": pcg when the first block is wide (≥1024, where
+    # factorizations dominate) and w ≤ 0.9 (as w→1 the shared popCov
+    # preconditioner drains and CG may hit its iteration cap), chol
+    # otherwise
     layout: str = "auto"  # chol-path row layout: "grouped" (one padded
     # (C, m, ·) gather), "gathered" (per-chunk gathers, for skewed
     # classes / tight HBM), "auto" (grouped iff padding ≤ ~1.5n AND the
-    # copy fits a third of device memory — ADVICE r3)
+    # copy fits a third of device memory — ADVICE r3). The pcg path
+    # takes no layout from here: it chooses its own (see ``solve``)
     convergence_check: str = "warn"  # after a pcg/auto fit, read the
     # max CG exit residual and "warn" / "raise" when it exceeds
     # ``pcg_tol`` (a capped CG exit would otherwise pass silently —
@@ -705,12 +924,17 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
         return model
 
     def _fit_pcg(self, data, X, Y, n, blocks):
-        """Batched all-class PCG on the original row layout (see
-        ``_pcg_block_step``); zero host work, one dispatch per block."""
+        """Batched all-class PCG (see ``_pcg_block_core``): one
+        dispatch per fit or per block, and no host work but the choice
+        of the matvec's row layout (``_sorted_layout``)."""
         w = self.mixture_weight
         mask = data.mask()
         C = Y.shape[1]
-        _count_fit("pcg", "original")
+        with span("solver.wls.layout"):
+            tile = _sorted_layout(X, Y, mask, max(wd for _, wd in blocks),
+                                  len(blocks) * self.num_iter)
+        _count_fit("pcg", "sorted" if tile else "original")
+        window = _SORT_WINDOW if tile else 0
         if len({wd for _, wd in blocks}) == 1:
             # uniform widths (every real config: block_size divides D or
             # one block): the ENTIRE fit — setup, every epoch's scanned
@@ -720,7 +944,8 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
             starts = jnp.asarray([s for s, _ in blocks], jnp.int32)
             W, intercept, pcg_rel, pcg_iters = _pcg_fit_full(
                 X, Y, mask, starts, w, self.lam, width=wd, n=n,
-                num_iter=self.num_iter, tol=self.pcg_tol,
+                num_iter=self.num_iter, tol=self.pcg_tol, sort_tile=tile,
+                sort_window=window,
             )
             return BlockLinearMapper(
                 W, self.block_size, explicit_intercept=intercept,
@@ -728,7 +953,9 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
                              "pcg_iterations": pcg_iters},
             )
         # ragged tail block: one dispatch per block
-        P, inv_counts, valid, jlm, R = _pcg_setup(Y, mask, w, n=n)
+        P, inv_counts, valid, jlm, R, sort = _pcg_setup(
+            Y, mask, w, n=n, sort_tile=tile
+        )
         Wb = {s: jnp.zeros((wd, C), jnp.float32) for s, wd in blocks}
         joint_means = {}
         pcg_rel = None  # max CG exit residual across block solves
@@ -739,7 +966,8 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
             for s, wd in blocks:
                 Wb[s], R, jm, rel, its = _pcg_block_step(
                     X, R, P, Wb[s], inv_counts, valid, s,
-                    w, self.lam, width=wd, n=n, tol=self.pcg_tol,
+                    w, self.lam, sort, width=wd, n=n, tol=self.pcg_tol,
+                    sort_window=window,
                 )
                 joint_means[s] = jm
                 pcg_rel = rel if pcg_rel is None else (
@@ -778,7 +1006,7 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
         blocks = list(zip(starts, widths))
         C = Y.shape[1]
 
-        P, inv_counts, valid, jlm, R = _pcg_setup(Y, mask, w, n=n)
+        P, inv_counts, valid, jlm, R, _ = _pcg_setup(Y, mask, w, n=n)
         Wb = {s: jnp.zeros((wd, C), jnp.float32) for s, wd in blocks}
         joint_means = {}
         pcg_rel = None

@@ -12,8 +12,9 @@ from keystone_tpu.ops.learning.weighted_ls import (
 from keystone_tpu.parallel.dataset import Dataset
 
 
-def ref_block_weighted_bcd(X, Y, block_size, num_iter, lam, w):
-    """numpy f64 translation of BlockWeightedLeastSquares.scala:139-314."""
+def ref_block_weighted_bcd(X, Y, block_size, num_iter, lam, w, conds=None):
+    """numpy f64 translation of BlockWeightedLeastSquares.scala:139-314.
+    ``conds``: a list that takes each solved system's condition number."""
     X = X.astype(np.float64)
     Y = Y.astype(np.float64)
     n, D = X.shape
@@ -51,9 +52,10 @@ def ref_block_weighted_bcd(X, Y, block_size, num_iter, lam, w):
                 mmw = res_mean[c] * (1 - w) + w * rl.mean()
                 jm = cmean * w + pop_mean * (1 - w)
                 jxtr = pop_xtr[:, c] * (1 - w) + cxtr * w - jm * mmw
-                delta[:, c] = np.linalg.solve(
-                    jxtx + lam * np.eye(e - s), jxtr - W[s:e, c] * lam
-                )
+                A = jxtx + lam * np.eye(e - s)
+                delta[:, c] = np.linalg.solve(A, jxtr - W[s:e, c] * lam)
+                if conds is not None:
+                    conds.append(np.linalg.cond(A))
                 jm_full[c, s:e] = jm
             W[s:e] += delta
             R = R - Xb @ delta
@@ -307,3 +309,328 @@ def test_block_weighted_multi_hot_rows_agree_across_solvers():
     np.testing.assert_allclose(
         np.asarray(pcg.W), np.asarray(chol.W), atol=5e-4
     )
+
+
+# -- the CG matvec on class-sorted rows (PR 30) ---------------------------
+
+
+def _sorted_fits_total():
+    from keystone_tpu.observability.registry import get_global_registry
+
+    return get_global_registry().counter(
+        "keystone_solver_wls_sorted_fits_total"
+    ).get()
+
+
+def _path_total(layout):
+    from keystone_tpu.observability.registry import get_global_registry
+
+    return get_global_registry().counter(
+        "keystone_solver_wls_path_total", labelnames=("solve", "layout")
+    ).get(("pcg", layout))
+
+
+@pytest.fixture
+def small_tiles(monkeypatch):
+    """Tiles of 16 rows and windows of 4 classes, so that fits of a
+    hundred rows have several tiles, pad rows and several windows."""
+    from keystone_tpu.ops.learning import weighted_ls as wls
+
+    monkeypatch.setattr(wls, "_SORT_TILE", 16)
+    monkeypatch.setattr(wls, "_SORT_WINDOW", 4)
+    return wls
+
+
+def _matvec_case(case):
+    """(X (n, b), class of each row or -1 for none, C, tile, window)."""
+    rng = np.random.default_rng(11)
+    n, b, C, tile, window = 96, 12, 6, 16, 4
+    dtype = np.float32
+    if case == "many_windows":
+        n, C = 192, 12
+    elif case == "pad_rows":
+        n = 90  # 5 tiles of 16 and 10 rows of a sixth
+    y = rng.integers(0, C, n)
+    if case == "sorted_already":
+        y = np.sort(y)
+    elif case == "empty_class":
+        y[y == 2] = 3
+    elif case == "no_class_rows":
+        y[rng.choice(n, 20, replace=False)] = -1
+    elif case == "one_tile":
+        tile = 96
+        y = rng.integers(0, 3, n)  # 96 rows in 3 classes: one window
+    X = rng.standard_normal((n, b)).astype(dtype)
+    return X, y, C, tile, window
+
+
+@pytest.mark.parametrize("case", [
+    "shuffled", "sorted_already", "empty_class", "pad_rows",
+    "no_class_rows", "many_windows", "one_tile", "bf16",
+])
+def test_sorted_rows_products_match_the_one_hot_products(case):
+    """z_i = x_i·v_{y_i} and Σ_{i in c} x_i z_i on class-sorted tiles
+    against the same two products over all classes in float64."""
+    import jax.numpy as jnp
+
+    from keystone_tpu.ops.learning import weighted_ls as wls
+
+    X, y, C, tile, window = _matvec_case(case)
+    if case == "bf16":
+        Xd = jnp.asarray(X, jnp.bfloat16)
+        X = np.asarray(Xd.astype(jnp.float32))
+    else:
+        Xd = jnp.asarray(X)
+    P = np.zeros((len(y), C), np.float32)
+    P[np.flatnonzero(y >= 0), y[y >= 0]] = 1.0
+    order, kcls = wls._class_sorted_rows(jnp.asarray(P, jnp.bfloat16), tile)
+    assert order.shape[1] == tile and order.shape == kcls.shape
+    # sorted by class, rows of no class and pad rows last
+    k = np.asarray(kcls).ravel()
+    assert np.all(np.diff(k) >= 0)
+    labelled = int((y >= 0).sum())
+    np.testing.assert_array_equal(
+        k[:labelled], np.sort(y[y >= 0]))
+    assert np.all(k[labelled:] == C)
+    products = wls._sorted_class_products(Xd[order], kcls, C, window)
+    v = np.random.default_rng(1).standard_normal((C, X.shape[1])).astype(
+        np.float32)
+    got = np.asarray(products(jnp.asarray(v)))
+    X64, v64 = X.astype(np.float64), v.astype(np.float64)
+    z = np.einsum("nb,ncb->nc", X64, v64[None].repeat(len(y), 0))
+    z = (z * P).sum(1)
+    want = (P * z[:, None]).T @ X64
+    np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("counts,tile,window,want", [
+    # 1,000 classes of 235 to 420 rows: a 16,384-row tile meets 72
+    ([235 + (i * 37) % 186 for i in range(1000)], 16384, 128, True),
+    # classes of ~20 rows: 16,384 rows would meet ~800
+    ([20] * 4000, 16384, 128, False),
+    # classes of 128 rows fill a window exactly; of 127, one more slips in
+    ([128] * 1000, 16384, 128, True),
+    ([127] * 1000, 16384, 128, False),
+    # the empty classes between two classes count: the window is
+    # contiguous in the class index
+    ([8, 0, 0, 0, 0, 8], 16, 4, False),
+    ([8, 0, 0, 8, 0, 0], 16, 4, True),
+    # tiles start at multiples of the tile: 4 classes of 4 fill one, a
+    # first class of 2 shifts a fifth class into it
+    ([4, 4, 4, 4, 4], 16, 4, True),
+    ([2, 4, 4, 4, 4], 16, 4, False),
+    ([2, 4, 4, 4, 4], 8, 4, True),
+    # no row has a class
+    ([0, 0, 0], 16, 4, False),
+])
+def test_tiles_fit_window_follows_the_class_counts(
+        monkeypatch, counts, tile, window, want):
+    from keystone_tpu.ops.learning import weighted_ls as wls
+
+    monkeypatch.setattr(wls, "_SORT_WINDOW", window)
+    assert wls._tiles_fit_window(np.asarray(counts), tile) is want
+
+
+@pytest.mark.parametrize("n,width,steps,limit,want", [
+    # a fit smaller than the tile is one tile, rounded up to 8 rows
+    (90, 64, 1, 1 << 30, 96),
+    # 2,048 rows of 64 float32 features, 8 classes: X, Y, the copy and
+    # two (2048, 128) products are 3,211,264 bytes; across block steps
+    # the residual twice and the membership too (2.5 Y): 3,375,104;
+    # a block of 32 columns is half the copy, and as much again for
+    # its cut out of X: 3,375,104 too (3,112,960 without the cut)
+    (2048, 64, 1, 3_600_000, 2048),
+    (2048, 64, 2, 3_600_000, 0),
+    (2048, 64, 2, 3_800_000, 2048),
+    (2048, 64, 1, 3_500_000, 0),
+    (2048, 32, 4, 3_800_000, 2048),
+    (2048, 32, 4, 3_600_000, 0),
+    # the flagship's fit on a v5e (15.75 GiB): 12.38 GB of 15.22; a
+    # fifth more rows still fit, a quarter more do not; nor does a
+    # second pass over the block (15.66 GB), while two blocks of 2,048
+    # columns do on a seventh fewer rows
+    (327680, 4096, 1, 15.75 * 2**30, 16384),
+    (393216, 4096, 1, 15.75 * 2**30, 16384),
+    (425984, 4096, 1, 15.75 * 2**30, 0),
+    (327680, 4096, 2, 15.75 * 2**30, 0),
+    (278528, 2048, 2, 15.75 * 2**30, 16384),
+])
+def test_sorted_layout_follows_the_bytes_the_program_holds(
+        monkeypatch, n, width, steps, limit, want):
+    """The budget is X + Y + the block's copy + two tile products (and,
+    across block steps, 2.5 Y and a narrower block's cut) against nine
+    tenths of the device's memory."""
+    import types
+
+    from keystone_tpu.ops.learning import weighted_ls as wls
+
+    flagship = n > 4096
+    b, C = (4096, 1000) if flagship else (64, 8)
+
+    def shaped(rows, cols):  # what the budget reads of an array
+        return types.SimpleNamespace(
+            shape=(rows, cols), nbytes=rows * cols * 4,
+            dtype=np.dtype(np.float32))
+
+    monkeypatch.setattr(wls, "_device_memory_limit", lambda: limit)
+    monkeypatch.setattr(
+        wls, "_class_counts", lambda Y, mask: np.full(C, n // C))
+    assert wls._sorted_layout(
+        shaped(n, b), shaped(n, C), None, width, steps) == want
+
+
+def _sorted_fit_case(case):
+    """(X, Y, estimator keywords, whether the loop translation applies)."""
+    kw = dict(block_size=48, num_iter=1, lam=0.05, mixture_weight=0.5)
+    X, Y, y = _weighted_problem(n=200, D=48, C=6, seed=13)
+    translates = True
+    if case == "sorted_labels":
+        o = np.argsort(y, kind="stable")
+        X, Y = X[o], Y[o]
+    elif case == "empty_class":
+        Y = Y.copy()
+        rows = np.flatnonzero(y == 2)
+        Y[rows, 2], Y[rows, 3] = -1.0, 1.0
+        translates = False  # the translation divides by a class's count
+    elif case == "pad_rows":
+        X, Y = X[:187], Y[:187]
+    elif case == "multi_hot":
+        Y = Y.copy()
+        for i in np.random.default_rng(0).choice(200, 66, replace=False):
+            c = int(np.argmax(Y[i]))
+            if c < 5:
+                Y[i, c + 1] = 1.0  # a later +1: the first positive wins
+    elif case == "ragged_tail":
+        kw.update(block_size=20, num_iter=2)  # widths 20, 20, 8
+    elif case == "two_blocks":
+        kw.update(block_size=24, num_iter=2)  # the fused scan, 4 steps
+    else:
+        assert case == "shuffled", case
+    return X, Y, kw, translates
+
+
+@pytest.mark.parametrize("case", [
+    "shuffled", "sorted_labels", "empty_class", "pad_rows", "multi_hot",
+    "ragged_tail", "two_blocks",
+])
+def test_pcg_fit_on_sorted_rows_matches_chol_and_the_translation(
+        small_tiles, case):
+    X, Y, kw, translates = _sorted_fit_case(case)
+    before = _sorted_fits_total()
+    pcg = BlockWeightedLeastSquaresEstimator(solve="pcg", **kw).fit(
+        Dataset.of(X), Dataset.of(Y))
+    assert _sorted_fits_total() == before + 1, "the sorted rows did not run"
+    chol = BlockWeightedLeastSquaresEstimator(solve="chol", **kw).fit(
+        Dataset.of(X), Dataset.of(Y))
+    np.testing.assert_allclose(
+        np.asarray(pcg.W), np.asarray(chol.W), atol=5e-4)
+    np.testing.assert_allclose(
+        np.asarray(pcg.intercept), np.asarray(chol.intercept), atol=5e-4)
+    assert float(pcg.solver_info["pcg_max_rel_residual"]) <= 1e-5
+    if translates:
+        W_ref, b_ref = ref_block_weighted_bcd(
+            X, Y, kw["block_size"], kw["num_iter"], kw["lam"],
+            kw["mixture_weight"])
+        np.testing.assert_allclose(np.asarray(pcg.W), W_ref, atol=2e-2)
+        np.testing.assert_allclose(
+            np.asarray(pcg.intercept), b_ref, atol=2e-2)
+
+
+def test_pcg_fit_on_sorted_bf16_rows_matches_the_f32_fit_of_the_same_rows(
+        small_tiles):
+    """bf16 features take the limb products on sorted rows too: the
+    model is the float32 fit's of the same (bf16-exact) features."""
+    import jax.numpy as jnp
+
+    X, Y, _ = _weighted_problem(n=200, D=48, C=6, seed=13)
+    X16 = jnp.asarray(X, jnp.bfloat16)
+    kw = dict(block_size=48, num_iter=1, lam=0.05, mixture_weight=0.5,
+              solve="pcg")
+    before = _sorted_fits_total()
+    m16 = BlockWeightedLeastSquaresEstimator(**kw).fit(
+        Dataset.from_array(X16), Dataset.of(Y))
+    m32 = BlockWeightedLeastSquaresEstimator(**kw).fit(
+        Dataset.of(np.asarray(X16.astype(jnp.float32))), Dataset.of(Y))
+    assert _sorted_fits_total() == before + 2
+    np.testing.assert_allclose(
+        np.asarray(m16.W), np.asarray(m32.W), atol=5e-5)
+
+
+def _no_room(monkeypatch, wls):
+    monkeypatch.setattr(wls, "_device_memory_limit", lambda: 1)
+
+
+def _window_too_narrow(monkeypatch, wls):
+    # 6 classes of ~33 rows, and a tile of 64 rows meets three of them
+    monkeypatch.setattr(wls, "_SORT_TILE", 64)
+    monkeypatch.setattr(wls, "_SORT_WINDOW", 2)
+
+
+@pytest.mark.parametrize("why", ["no_room", "window_too_narrow", "sharded"])
+def test_pcg_falls_back_to_the_original_rows_and_fits_the_same_model(
+        monkeypatch, small_tiles, mesh8, why):
+    """Where the copy does not fit, the class counts break the window
+    bound or the rows are spread over devices, the one-hot matvec runs
+    as before, the counters say so, and the model is the same."""
+    from keystone_tpu.parallel import mesh as mesh_lib
+
+    wls = small_tiles
+    X, Y, _ = _weighted_problem(n=200, D=48, C=6, seed=13)
+    kw = dict(block_size=48, num_iter=1, lam=0.05, mixture_weight=0.5)
+    est = BlockWeightedLeastSquaresEstimator(**kw, solve="pcg")
+    one = mesh_lib.make_mesh(n_data=1, devices=mesh8.devices.ravel()[:1])
+    with mesh_lib.use_mesh(one):
+        s0, p0 = _sorted_fits_total(), _path_total("sorted")
+        W_sorted = np.asarray(est.fit(Dataset.of(X), Dataset.of(Y)).W)
+        assert (_sorted_fits_total(), _path_total("sorted")) == (
+            s0 + 1, p0 + 1)
+    s0, o0 = _sorted_fits_total(), _path_total("original")
+    if why == "sharded":
+        model = est.fit(Dataset.of(X).shard(), Dataset.of(Y).shard())
+    else:
+        {"no_room": _no_room, "window_too_narrow": _window_too_narrow}[why](
+            monkeypatch, wls)
+        with mesh_lib.use_mesh(one):
+            model = est.fit(Dataset.of(X), Dataset.of(Y))
+    assert (_sorted_fits_total(), _path_total("original")) == (s0, o0 + 1)
+    np.testing.assert_allclose(np.asarray(model.W), W_sorted, atol=1e-4)
+
+
+def test_sorted_fits_share_metric_reads_the_program_s_counters(
+        monkeypatch, small_tiles):
+    """The benchmark's ``wls_sorted_fits_share.wfit`` is one data file:
+    both families it names exist after one small pcg fit, and its
+    reader gives 1.0 after sorted fits and less after a fall-back."""
+    import json
+    import os
+
+    from benchmark.readers import counter_ratio
+    from keystone_tpu.observability import registry
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "metrics",
+                           "wls_sorted_fits_share.wfit.json")) as f:
+        metric = json.load(f)
+    assert metric["reader"] == "counter_ratio"
+    entry = [m for m in json.load(open(os.path.join(root, "BENCHMARK.json")))
+             ["per_layer"] if m["name"] == "wls_sorted_fits_share.wfit"]
+    assert entry == [{
+        "name": "wls_sorted_fits_share.wfit", "unit": "share",
+        "better": "higher", "source": "program_counter",
+        "layer": "Solvers", "moves": "fit_rows_per_s",
+        "workloads": ["weighted-bcd-fit"]}]
+    # a registry of this test's own: the ratio counts since it began
+    monkeypatch.setattr(registry, "_global_registry",
+                        registry.MetricsRegistry())
+    assert counter_ratio.read(None, **metric["args"]) is None  # no fit yet
+    X, Y, _ = _weighted_problem(n=120, D=16, C=3, seed=2)
+    est = BlockWeightedLeastSquaresEstimator(16, 1, 0.05, 0.5, solve="pcg")
+    est.fit(Dataset.of(X), Dataset.of(Y))
+    names = {f.name for f in registry.get_global_registry().collect()}
+    assert {metric["args"]["numerator"],
+            metric["args"]["denominator"]} <= names
+    assert counter_ratio.read(None, **metric["args"]) == 1.0
+    _no_room(monkeypatch, small_tiles)
+    est.fit(Dataset.of(X), Dataset.of(Y))
+    assert counter_ratio.read(None, **metric["args"]) == 0.5

@@ -183,9 +183,14 @@ def counters():
 
 
 @pytest.mark.parametrize("features,solve,layout", [
-    (1024, "pcg", "original"), (64, "chol", "grouped")])
+    (1024, "pcg", "sorted"), (1024, "pcg", "original"),
+    (64, "chol", "grouped")])
 def test_spans_and_counters_appear_once_a_fit_and_name_the_path(
-        features, solve, layout):
+        monkeypatch, features, solve, layout):
+    if layout == "original":
+        # no room for the class-sorted copy: the one-hot matvec runs
+        from keystone_tpu.ops.learning import weighted_ls
+        monkeypatch.setattr(weighted_ls, "_device_memory_limit", lambda: 1)
     rng = np.random.default_rng(3)
     n, c = 240, 5
     x = rng.standard_normal((n, features)).astype(np.float32)
@@ -208,9 +213,13 @@ def test_spans_and_counters_appear_once_a_fit_and_name_the_path(
             (("layout", layout), ("solve", solve)))
     assert delta.pop(("keystone_solver_wls_fits_total", ())) == 1
     assert delta.pop(path) == 1
+    if layout == "sorted":
+        assert delta.pop(
+            ("keystone_solver_wls_sorted_fits_total", ())) == 1
     if solve == "pcg":
         assert sorted(names) == ["solver.wls.converged",
-                                 "solver.wls.dispatch", "solver.wls.prep"]
+                                 "solver.wls.dispatch", "solver.wls.layout",
+                                 "solver.wls.prep"]
         iterations = int(model.solver_info["pcg_iterations"])
         assert 0 < iterations < 96
         assert delta.pop(
