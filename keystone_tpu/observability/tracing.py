@@ -26,6 +26,8 @@ Span names, one per layer boundary and never one per item:
   outputs, or cuts a ragged group's back into items; counter
   ``keystone_workflow_array_items_total`` over ``_items_total`` is the
   share of items that stayed an array),
+  ``workflow.run`` once a call and ``workflow.run.chunk`` once a chunk
+  of a ``RowwiseRun`` that goes through in chunks of rows,
   ``workflow.map_items`` / ``.to_array`` / ``.to_items`` (``Dataset``);
 - solvers: ``solver.prep``, and per block step ``solver.block_stats``,
   ``solver.readback``, ``solver.host_solve`` (attr ``fallback``),

@@ -24,7 +24,6 @@ GEMM (Convolver.scala:128-205) without materializing a patch matrix.
 from __future__ import annotations
 
 import dataclasses
-from functools import partial
 from typing import Any, Callable, List, Optional, Sequence
 
 import jax
@@ -33,7 +32,7 @@ import numpy as np
 
 from keystone_tpu.parallel.dataset import Dataset
 from keystone_tpu.utils.precision import mm
-from keystone_tpu.workflow.api import FunctionNode, Transformer
+from keystone_tpu.workflow.api import FunctionNode, Transformer, run_rowwise
 
 # MATLAB rgb2gray weights (reference: utils/images/ImageUtils.scala:73-76)
 GRAYSCALE_WEIGHTS = (0.2989, 0.5870, 0.1140)
@@ -107,42 +106,70 @@ class Convolver(Transformer):
             return Dataset.from_array(self._convolve(ds.padded()), n=ds.n)
         return ds.map(self.apply)
 
-    @partial(jax.jit, static_argnums=(0,))
+    def rowwise(self):
+        return (
+            _Convolve(
+                self.conv_size, self.img_channels, self.normalize_patches,
+                float(self.var_constant), self.fast,
+            ),
+            (self._W, self._filter_sums, self._whitener_dot),
+        )
+
     def _convolve(self, imgs):
         """imgs: (n, X, Y, C) -> (n, resX, resY, F)."""
-        k = self.conv_size
-        C = self.img_channels
+        fn, arrays = self.rowwise()
+        return run_rowwise((fn,), (arrays,), imgs)
+
+
+@dataclasses.dataclass(frozen=True)
+class _Convolve:
+    """The Convolver's rows-in, rows-out function, with its arrays as
+    arguments: two Convolvers of equal settings share one compiled
+    program whatever their filters (a fit builds its filters anew)."""
+
+    conv_size: int
+    channels: int
+    normalize_patches: bool
+    var_constant: float
+    fast: bool
+
+    def __call__(self, arrays, imgs):
+        W, filter_sums, whitener_dot = arrays
+        k, C = self.conv_size, self.channels
         x = imgs.astype(jnp.float32)
         hp = None if self.fast else jax.lax.Precision.HIGHEST
         # XLA correlation: out[n,x,y,f] = Σ A[n,x+dx,y+dy,c]·W[f,dx,dy,c]
         dn = jax.lax.conv_dimension_numbers(
-            x.shape, self._W.shape, ("NHWC", "OHWI", "NHWC")
+            x.shape, W.shape, ("NHWC", "OHWI", "NHWC")
         )
-        raw = jax.lax.conv_general_dilated(
-            x, self._W, (1, 1), "VALID", dimension_numbers=dn,
-            preferred_element_type=jnp.float32, precision=hp,
-        )
-        if not self.normalize_patches and self._whitener_dot is None:
-            return raw
-        P = k * k * C
-        ones = jnp.ones((1, k, k, C), jnp.float32)
-        s1 = jax.lax.conv_general_dilated(
-            x, ones, (1, 1), "VALID", dimension_numbers=dn, precision=hp
-        )
-        out = raw
-        if self.normalize_patches:
-            s2 = jax.lax.conv_general_dilated(
-                x * x, ones, (1, 1), "VALID", dimension_numbers=dn,
-                precision=hp,
+        with jax.named_scope("conv.correlate"):
+            raw = jax.lax.conv_general_dilated(
+                x, W, (1, 1), "VALID", dimension_numbers=dn,
+                preferred_element_type=jnp.float32, precision=hp,
             )
-            m = s1 / P
-            # Stats.normalizeRows: var over patch entries, /(P-1), +alpha
-            var = (s2 - P * m * m) / (P - 1)
-            sd = jnp.sqrt(var + self.var_constant)
-            out = (raw - m * self._filter_sums[None, None, None, :]) / sd
-        if self._whitener_dot is not None:
-            out = out - self._whitener_dot[None, None, None, :]
-        return out
+        if not self.normalize_patches and whitener_dot is None:
+            return raw
+        with jax.named_scope("conv.normalize"):
+            P = k * k * C
+            ones = jnp.ones((1, k, k, C), jnp.float32)
+            s1 = jax.lax.conv_general_dilated(
+                x, ones, (1, 1), "VALID", dimension_numbers=dn, precision=hp
+            )
+            out = raw
+            if self.normalize_patches:
+                s2 = jax.lax.conv_general_dilated(
+                    x * x, ones, (1, 1), "VALID", dimension_numbers=dn,
+                    precision=hp,
+                )
+                m = s1 / P
+                # Stats.normalizeRows: var over patch entries, /(P-1),
+                # +alpha
+                var = (s2 - P * m * m) / (P - 1)
+                sd = jnp.sqrt(var + self.var_constant)
+                out = (raw - m * filter_sums[None, None, None, :]) / sd
+            if whitener_dot is not None:
+                out = out - whitener_dot[None, None, None, :]
+            return out
 
 
 @dataclasses.dataclass(eq=False)
@@ -164,28 +191,49 @@ class Pooler(Transformer):
             return Dataset.from_array(self._pool(ds.padded()), n=ds.n)
         return ds.map(self.apply)
 
-    @partial(jax.jit, static_argnums=(0,))
+    def rowwise(self):
+        return (
+            _Pool(self.stride, self.pool_size, self.pixel_fn, self.pool_fn),
+            (),
+        )
+
     def _pool(self, imgs):
+        fn, arrays = self.rowwise()
+        return run_rowwise((fn,), (arrays,), imgs)
+
+
+@dataclasses.dataclass(frozen=True)
+class _Pool:
+    """The Pooler's rows-in, rows-out function (see ``_Convolve``)."""
+
+    stride: int
+    pool_size: int
+    pixel_fn: Optional[Callable]
+    pool_fn: Optional[Callable]
+
+    def __call__(self, arrays, imgs):
+        del arrays
         x_dim, y_dim = imgs.shape[1], imgs.shape[2]
         half = self.pool_size // 2
         start = half
         xs = list(range(start, x_dim, self.stride))
         ys = list(range(start, y_dim, self.stride))
-        vals = imgs.astype(jnp.float32)
-        if self.pixel_fn is not None:
-            vals = self.pixel_fn(vals)
-        pool_fn = self.pool_fn or (lambda w: jnp.sum(w, axis=(1, 2)))
-        rows = []
-        for px in xs:
-            cols = []
-            for py in ys:
-                window = vals[
-                    :, px - half : min(px + half, x_dim),
-                    py - half : min(py + half, y_dim), :,
-                ]
-                cols.append(pool_fn(window))
-            rows.append(jnp.stack(cols, axis=1))  # (n, ny, C)
-        return jnp.stack(rows, axis=1)  # (n, nx, ny, C)
+        with jax.named_scope("conv.pool"):
+            vals = imgs.astype(jnp.float32)
+            if self.pixel_fn is not None:
+                vals = self.pixel_fn(vals)
+            pool_fn = self.pool_fn or (lambda w: jnp.sum(w, axis=(1, 2)))
+            rows = []
+            for px in xs:
+                cols = []
+                for py in ys:
+                    window = vals[
+                        :, px - half : min(px + half, x_dim),
+                        py - half : min(py + half, y_dim), :,
+                    ]
+                    cols.append(pool_fn(window))
+                rows.append(jnp.stack(cols, axis=1))  # (n, ny, C)
+            return jnp.stack(rows, axis=1)  # (n, nx, ny, C)
 
 
 @dataclasses.dataclass(eq=False)
@@ -198,22 +246,35 @@ class SymmetricRectifier(Transformer):
     alpha: float = 0.0
 
     def apply(self, img):
-        pos = jnp.maximum(self.max_val, img - self.alpha)
-        neg = jnp.maximum(self.max_val, -img - self.alpha)
-        return jnp.concatenate([pos, neg], axis=-1)
+        return self.rowwise()[0]((), img)
+
+    def rowwise(self):
+        return _Rectify(float(self.max_val), float(self.alpha)), ()
 
     def apply_batch(self, ds: Dataset) -> Dataset:
         if ds.is_array:
-            x = ds.padded()
-            pos = jnp.maximum(self.max_val, x - self.alpha)
-            neg = jnp.maximum(self.max_val, -x - self.alpha)
-            out = jnp.concatenate([pos, neg], axis=-1)
+            out = self.rowwise()[0]((), ds.padded())
             if self.max_val > 0 or self.alpha < 0:
                 out = out * ds.mask().reshape(
                     (-1,) + (1,) * (out.ndim - 1)
                 )
             return Dataset.from_array(out, n=ds.n)
         return ds.map(self.apply)
+
+
+@dataclasses.dataclass(frozen=True)
+class _Rectify:
+    """The SymmetricRectifier's rows-in, rows-out function."""
+
+    max_val: float
+    alpha: float
+
+    def __call__(self, arrays, x):
+        del arrays
+        with jax.named_scope("conv.rectify"):
+            pos = jnp.maximum(self.max_val, x - self.alpha)
+            neg = jnp.maximum(self.max_val, -x - self.alpha)
+            return jnp.concatenate([pos, neg], axis=-1)
 
 
 class ImageVectorizer(Transformer):
@@ -225,13 +286,20 @@ class ImageVectorizer(Transformer):
 
     def apply_batch(self, ds: Dataset) -> Dataset:
         if ds.is_array:
-            x = ds.padded()
-            out = jnp.transpose(x, (0, 2, 1, 3)).reshape(x.shape[0], -1)
-            return Dataset.from_array(out, n=ds.n)
+            return Dataset.from_array(_vectorize((), ds.padded()), n=ds.n)
         return ds.map(self.apply)
+
+    def rowwise(self):
+        return _vectorize, ()
 
     def eq_key(self):
         return ("image_vectorizer",)
+
+
+def _vectorize(arrays, x):
+    """The ImageVectorizer's rows-in, rows-out function."""
+    del arrays
+    return jnp.transpose(x, (0, 2, 1, 3)).reshape(x.shape[0], -1)
 
 
 class PixelScaler(Transformer):

@@ -85,6 +85,11 @@ def _f32_mm(a, b):
     )
 
 
+# widest system that still carries the eigh fall-back (RandomPatchCifar's
+# last block is 2,176 wide, the applications' blocks 4,096)
+_EIGH_FALLBACK_MAX_WIDTH = 2048
+
+
 def _psd_solve_with_factor(A, L, rhs, refine=2):
     """A X = rhs given A's (already-ridged) Cholesky factor ``L``, f32
     + ``refine`` iterative-refinement steps. Refinement recovers most
@@ -109,13 +114,15 @@ def _psd_solve_with_factor(A, L, rhs, refine=2):
             W = W + solve(rhs - jnp.matmul(A, W, precision=hp))
         return W
 
-    if A.shape[0] > 8192:
-        # No eigh fallback at large d: lax.cond compiles BOTH branches,
-        # and eigh's QR workspace at (16384,16384) is several extra
-        # ~1 GB f32 buffers — it OOMed the 16 GiB chip alongside the
-        # Gram/data the Amazon-16384 solve holds. Cholesky breakdown
-        # (f32-rounding indefiniteness at lam≈0) then surfaces as
-        # non-finite W, which every large-d caller already asserts on;
+    if A.shape[0] > _EIGH_FALLBACK_MAX_WIDTH:
+        # No eigh fallback from a block of a few thousand columns up:
+        # lax.cond compiles BOTH branches, and eigh at (4096, 4096) took
+        # the TPU compiler ~8 min, a 309 MB executable and 40 GiB of
+        # host memory (PERF.md, PR 24), so the default solve could not
+        # be built at the block width the applications use; at
+        # (16384, 16384) its QR workspace OOMed the chip. Cholesky
+        # breakdown (f32-rounding indefiniteness at lam≈0) then surfaces
+        # as non-finite W, which large-width callers assert on;
         # regularized fits at this scale are well inside chol's range.
         return chol_path(L)
 

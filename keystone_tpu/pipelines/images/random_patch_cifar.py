@@ -13,25 +13,27 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import time
+from functools import partial
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
 from keystone_tpu.evaluation import MulticlassClassifierEvaluator
 from keystone_tpu.loaders.cifar import CifarLoader, LabeledImages
+from keystone_tpu.observability.tracing import span
 from keystone_tpu.ops.images import (
     Convolver,
     ImageVectorizer,
     Pooler,
     SymmetricRectifier,
-    Windower,
 )
 from keystone_tpu.ops.learning import (
     BlockLeastSquaresEstimator,
     ZCAWhitenerEstimator,
 )
-from keystone_tpu.ops.stats import Sampler, StandardScaler
+from keystone_tpu.ops.stats import StandardScaler
 from keystone_tpu.ops.util.nodes import ClassLabelIndicators, MaxClassifier
 from keystone_tpu.parallel.dataset import Dataset
 from keystone_tpu.workflow.api import Pipeline
@@ -54,25 +56,70 @@ class RandomCifarConfig:
     pool_stride: int = 13
     alpha: float = 0.25
     lam: float = 0.0
+    block_size: int = 4096  # the solver's; RandomPatchCifar.scala:21 fixes it
     seed: int = 0
 
 
 def _normalize_rows(mat: np.ndarray, alpha: float) -> np.ndarray:
-    """Stats.normalizeRows (reference: utils/Stats.scala:112-123)."""
+    """Stats.normalizeRows (reference: utils/Stats.scala:112-123). The
+    centred matrix is made once and scaled in place: on 100,000 patches
+    this is host time every fit, with the chip idle under it."""
     means = np.nan_to_num(mat.mean(axis=1))
-    var = ((mat - means[:, None]) ** 2).sum(axis=1) / (mat.shape[1] - 1)
+    centred = mat - means[:, None]
+    var = np.einsum("ij,ij->i", centred, centred) / (mat.shape[1] - 1)
     sds = np.sqrt(var + alpha)
     sds = np.where(np.isnan(sds), np.sqrt(alpha), sds)
-    return (mat - means[:, None]) / sds[:, None]
+    centred /= sds[:, None]
+    return centred
+
+
+@partial(jax.jit, static_argnames=("size",))
+def _gather_patches(imgs, img, x0, y0, *, size: int):
+    """Patch j = imgs[img_j, x0_j : x0_j + size, y0_j : y0_j + size, :],
+    vectorised channel-major. One gather of whole windows: indexed value
+    by value (36 index pairs a patch) it took the TPU compiler six
+    minutes (PERF.md, PR 31)."""
+    n, X, Y, C = imgs.shape
+    rows = imgs.reshape(n, X, Y * C)  # a window's y and c are contiguous
+    patches = jax.vmap(
+        lambda i, x, y: jax.lax.dynamic_slice(
+            rows, (i, x, y * C), (1, size, size * C)
+        )[0]
+    )(img, x0, y0).reshape(-1, size, size, C)
+    return jnp.transpose(patches, (0, 2, 1, 3)).reshape(img.shape[0], -1)
+
+
+def sample_patches(train_images: Dataset, conf: RandomCifarConfig):
+    """The rows that ``Sampler(WHITENER_SAMPLE, seed)`` draws of
+    ``ImageVectorizer`` over ``Windower(patch_steps, patch_size)``, made
+    without the others: the same draw over the same order (image, then
+    x, then y), gathered from the images where they are. All 9.1 M
+    patches of one chip's 12,544 images, 729 slices stacked, do not fit
+    the chip (PERF.md, PR 31); the sample is 43 MB."""
+    ds = Dataset.of(train_images).to_array_mode()
+    imgs = ds.padded()
+    k = conf.patch_size
+    xs = np.arange(0, imgs.shape[1] - k + 1, conf.patch_steps)
+    ys = np.arange(0, imgs.shape[2] - k + 1, conf.patch_steps)
+    per_image = len(xs) * len(ys)
+    total = ds.n * per_image
+    rng = np.random.default_rng(conf.seed)
+    idx = np.sort(
+        rng.choice(total, size=min(WHITENER_SAMPLE, total), replace=False)
+    )
+    img, pos = idx // per_image, idx % per_image
+    return _gather_patches(
+        imgs, jnp.asarray(img, jnp.int32),
+        jnp.asarray(xs[pos // len(ys)], jnp.int32),
+        jnp.asarray(ys[pos % len(ys)], jnp.int32), size=k,
+    )
 
 
 def build_filters(train_images: Dataset, conf: RandomCifarConfig):
     """Sample patches, normalize, fit ZCA, emit whitened filter bank
     (reference: RandomPatchCifar.scala:45-57)."""
-    patches = Windower(conf.patch_steps, conf.patch_size).apply(train_images)
-    vecs = ImageVectorizer().apply_batch(patches)
-    sample = Sampler(WHITENER_SAMPLE, seed=conf.seed).apply(vecs)
-    base = _normalize_rows(np.asarray(sample.array(), np.float64), 10.0)
+    sample = sample_patches(train_images, conf)
+    base = _normalize_rows(np.asarray(sample, np.float64), 10.0)
     whitener = ZCAWhitenerEstimator(eps=conf.whitening_epsilon).fit_single(
         jnp.asarray(base, jnp.float32)
     )
@@ -92,7 +139,8 @@ def build_filters(train_images: Dataset, conf: RandomCifarConfig):
 def build_pipeline(
     train: LabeledImages, conf: RandomCifarConfig
 ) -> Pipeline:
-    filters, whitener = build_filters(train.images, conf)
+    with span("cifar.filters", filters=conf.num_filters):
+        filters, whitener = build_filters(train.images, conf)
     labels = ClassLabelIndicators(NUM_CLASSES)(train.labels)
     featurizer = (
         Convolver(
@@ -106,7 +154,9 @@ def build_pipeline(
     return (
         featurizer.and_then(StandardScaler(), train.images)
         .and_then(
-            BlockLeastSquaresEstimator(4096, num_iter=1, lam=conf.lam),
+            BlockLeastSquaresEstimator(
+                conf.block_size, num_iter=1, lam=conf.lam
+            ),
             train.images,
             labels,
         )

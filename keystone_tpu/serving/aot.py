@@ -55,6 +55,7 @@ what it buys on the chip is unmeasured: ROADMAP D3).
 
 from __future__ import annotations
 
+import dataclasses
 import hashlib
 import json
 import logging
@@ -153,6 +154,15 @@ def _hash_update(h, value: Any) -> None:
         for v in value:
             _hash_update(h, v)
         h.update(b">")
+    elif dataclasses.is_dataclass(value) and not isinstance(value, type):
+        # a node held by another (RowwiseRun's nodes, a Convolver's
+        # whitener): its fields are parameters too
+        h.update(b"o<" + type(value).__qualname__.encode() + b"|")
+        for f in dataclasses.fields(value):
+            if not f.name.startswith("_"):
+                h.update(b"f<" + f.name.encode() + b">")
+                _hash_update(h, getattr(value, f.name, None))
+        h.update(b">")
     else:
         h.update(b"t<" + type(value).__qualname__.encode() + b">")
 
@@ -172,8 +182,6 @@ def pipeline_token(fitted) -> str:
     exactly the path this module optimizes. A ``FittedPipeline`` is
     immutable once fit (refits build new objects), so the cache can't
     go stale."""
-    import dataclasses
-
     cached = getattr(fitted, "_aot_pipeline_token", None)
     if cached is not None:
         return cached

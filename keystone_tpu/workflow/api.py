@@ -18,6 +18,7 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import pickle
+from functools import partial
 from typing import Any, Callable, Dict, List, Optional, Sequence, Union
 
 import jax
@@ -26,7 +27,7 @@ import numpy as np
 
 from keystone_tpu.observability.registry import get_global_registry
 from keystone_tpu.observability.tracing import span
-from keystone_tpu.parallel.dataset import Dataset, HostPuts
+from keystone_tpu.parallel.dataset import Dataset, HostPuts, _leading_dim
 from keystone_tpu.workflow.executor import GraphExecutor, PipelineEnv
 from keystone_tpu.workflow.expressions import (
     DatasetExpression,
@@ -385,6 +386,16 @@ class Transformer(Chainable, TransformerOperator):
     def apply(self, x: Any) -> Any:  # single datum
         raise NotImplementedError
 
+    def rowwise(self) -> Optional[tuple]:
+        """``(fn, arrays)`` where ``fn(arrays, batch)`` is this node's
+        array-mode ``apply_batch`` as a traceable function that maps row
+        i of ``batch`` to row i of its result and looks at no other row;
+        None (the default) for a node that says no such thing. ``fn``
+        hashes and compares by its settings, the node's arrays go in
+        ``arrays``: nodes of equal settings then share compiled programs
+        (``RowwiseRun``)."""
+        return None
+
     def _jitted_vmap(self):
         fn = self.__dict__.get("_vmapped_apply")
         if fn is None:
@@ -503,6 +514,198 @@ class Transformer(Chainable, TransformerOperator):
     @property
     def label(self) -> str:  # type: ignore[override]
         return type(self).__name__
+
+
+def _device_free_bytes(batch: Any) -> Optional[int]:
+    """What the allocator of the device that holds ``batch`` could still
+    hand out: its limit less what is live. None where the backend keeps
+    no such account (the CPU)."""
+    device = next(iter(jax.tree_util.tree_leaves(batch)[0].devices()))
+    stats = device.memory_stats() or {}
+    if "bytes_limit" not in stats or "bytes_in_use" not in stats:
+        return None
+    return int(stats["bytes_limit"]) - int(stats["bytes_in_use"])
+
+
+def _tree_bytes(tree: Any) -> int:
+    return sum(
+        int(np.prod(a.shape)) * a.dtype.itemsize
+        for a in jax.tree_util.tree_leaves(tree)
+    )
+
+
+@dataclasses.dataclass(frozen=True)
+class RunPlan:
+    """How ``RowwiseRun`` takes one batch through: ``chunk_rows`` rows a
+    program (all of ``rows`` where the batch goes through whole),
+    ``item_bytes`` what one row holds across the run (every node's
+    output), ``out_item`` the shapes of one row of the run's result."""
+
+    rows: int
+    chunk_rows: int
+    item_bytes: int
+    out_item: Any
+
+    @property
+    def chunked(self) -> bool:
+        return self.chunk_rows < self.rows
+
+    @property
+    def chunk_bytes(self) -> int:
+        return self.chunk_rows * self.item_bytes
+
+    @property
+    def out_bytes(self) -> int:
+        return self.rows * _tree_bytes(self.out_item)
+
+
+def plan_rowwise_run(
+    fns: Sequence[Callable], arrays: Sequence[Any], batch: Any,
+    free_bytes: Optional[int],
+) -> RunPlan:
+    """Rows a chunk from bytes, by ``jax.eval_shape`` alone: one row's
+    outputs of every node of the run against half of what the device
+    has free once the joined result is taken out (the other half is
+    the compiler's: a program's temporaries are not in the shapes). The
+    rows are cut to a power of two, so that a little more or less free
+    memory plans the same program. The batch goes through whole where
+    it fits, or where the backend gives no account of its memory."""
+    rows = _leading_dim(batch)
+    one = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct((1,) + a.shape[1:], a.dtype), batch
+    )
+    item_bytes = 0
+    for fn, arr in zip(fns, arrays):
+        one = jax.eval_shape(fn, arr, one)
+        item_bytes += _tree_bytes(one)
+    whole = RunPlan(rows, rows, item_bytes, one)
+    if free_bytes is None:
+        return whole
+    budget = (free_bytes - whole.out_bytes) // 2
+    if rows * item_bytes <= budget:
+        return whole
+    fit = max(budget // max(item_bytes, 1), 1)
+    chunk = 1 << (int(fit).bit_length() - 1)
+    return dataclasses.replace(whole, chunk_rows=min(chunk, rows))
+
+
+@partial(jax.jit, static_argnums=(0,))
+def run_rowwise(fns, arrays, batch):
+    """``rowwise()`` functions, one after another, on one whole batch:
+    one program per tuple of functions (not per node) and batch shape."""
+    for fn, arr in zip(fns, arrays):
+        batch = fn(arr, batch)
+    return batch
+
+
+@partial(jax.jit, static_argnums=(0, 1), donate_argnums=(3,))
+def _run_chunk(fns, chunk_rows, arrays, out, batch, start, n):
+    """One chunk of a ``RowwiseRun``: rows [start, start + chunk_rows)
+    of ``batch`` through every function of the run, rows past ``n``
+    zeroed (the Dataset's padding rule), written into ``out`` in place."""
+    part = jax.tree_util.tree_map(
+        lambda a: jax.lax.dynamic_slice_in_dim(a, start, chunk_rows), batch
+    )
+    for fn, arr in zip(fns, arrays):
+        part = fn(arr, part)
+    valid = start + jnp.arange(chunk_rows) < n
+
+    def write(o, r):
+        r = jnp.where(valid.reshape((-1,) + (1,) * (r.ndim - 1)), r, 0)
+        return jax.lax.dynamic_update_slice_in_dim(
+            o, r.astype(o.dtype), start, 0
+        )
+
+    return jax.tree_util.tree_map(write, out, part)
+
+
+class RowwiseRun(Transformer):
+    """Consecutive row-wise nodes as one node (``RowwiseRunRule`` makes
+    it of nodes whose ``rowwise()`` says they map row to row). An
+    array-mode batch whose intermediates fit the device goes from node to
+    node as before. One that does not goes through the whole run a chunk
+    of rows at a time, one program a chunk, and only the run's last
+    output is ever whole: a Convolver's maps, megabytes an image, live
+    for a chunk's rows and no longer.
+
+    The chunk's rows follow from bytes (``plan_rowwise_run``). All chunks
+    share one program: the last one starts ``chunk_rows`` before the end
+    and computes a few rows twice rather than pad. Spans
+    ``workflow.run`` once a call and ``workflow.run.chunk`` once a
+    chunk; counters ``keystone_workflow_run_items_total``,
+    ``_run_chunks_total`` and ``_run_chunk_bytes_total`` (the planned
+    bytes of the chunks dispatched)."""
+
+    def __init__(self, nodes: Sequence[Transformer]):
+        self.nodes = tuple(nodes)
+
+    @property
+    def label(self) -> str:  # type: ignore[override]
+        return "+".join(n.label for n in self.nodes)
+
+    def eq_key(self) -> Any:
+        return ("rowwise_run", tuple(n.eq_key() for n in self.nodes))
+
+    def apply(self, x: Any) -> Any:
+        for node in self.nodes:
+            x = node.apply(x)
+        return x
+
+    def _node_by_node(self, ds: Dataset) -> Dataset:
+        for node in self.nodes:
+            ds = node.apply_batch(ds)
+        return ds
+
+    def _parts(self) -> tuple:
+        """(the nodes' functions, the nodes' arrays)."""
+        return tuple(zip(*(node.rowwise() for node in self.nodes)))
+
+    def plan(self, batch: Any, free_bytes: Optional[int]) -> RunPlan:
+        return plan_rowwise_run(*self._parts(), batch, free_bytes)
+
+    def apply_batch(self, ds: Dataset) -> Dataset:
+        if not ds.is_array:
+            return self._node_by_node(ds)
+        batch = ds.padded()
+        if any(
+            isinstance(a, jax.core.Tracer)
+            for a in jax.tree_util.tree_leaves(batch)
+        ):  # inside jit the compiler schedules the memory
+            return self._node_by_node(ds)
+        plan = self.plan(batch, _device_free_bytes(batch))
+        if not plan.chunked:
+            return self._node_by_node(ds)
+        return self._chunked(ds, batch, plan)
+
+    def _chunked(self, ds: Dataset, batch: Any, plan: RunPlan) -> Dataset:
+        fns, arrays = self._parts()
+        rows, chunk = plan.rows, plan.chunk_rows
+        starts = list(range(0, rows - chunk, chunk)) + [rows - chunk]
+        with span("workflow.run", n=ds.n, chunks=len(starts),
+                  chunk_rows=chunk, chunk_bytes=plan.chunk_bytes):
+            out = jax.tree_util.tree_map(
+                lambda a: jnp.zeros((rows,) + a.shape[1:], a.dtype),
+                plan.out_item,
+            )
+            for start in starts:
+                with span("workflow.run.chunk", n=chunk):
+                    out = _run_chunk(
+                        fns, chunk, arrays, out, batch, start, ds.n
+                    )
+        reg = get_global_registry()
+        reg.counter(
+            "keystone_workflow_run_items_total",
+            "items that went through a RowwiseRun in chunks",
+        ).inc(by=ds.n)
+        reg.counter(
+            "keystone_workflow_run_chunks_total",
+            "chunk programs RowwiseRun dispatched",
+        ).inc(by=len(starts))
+        reg.counter(
+            "keystone_workflow_run_chunk_bytes_total",
+            "bytes RowwiseRun planned for the chunks it dispatched",
+        ).inc(by=len(starts) * plan.chunk_bytes)
+        return Dataset.from_array(out, n=ds.n)
 
 
 def transformer(fn: Callable[[Any], Any], name: str = None) -> Transformer:

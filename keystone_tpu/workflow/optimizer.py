@@ -4,7 +4,8 @@ Reference semantics: workflow/DefaultOptimizer.scala — batches:
 (1) load saved state (extract saveable prefixes, substitute saved results,
     prune the now-dead branches), once;
 (2) common-subexpression elimination, fixed point;
-(3) cost-based physical node optimization, once.
+(3) cost-based physical node optimization, once;
+(4) runs of row-wise nodes merged, so that they can run in chunks, once.
 ``AutoCachingOptimizer`` appends profile-driven cache insertion.
 """
 
@@ -18,6 +19,7 @@ from keystone_tpu.workflow.rules import (
     ExtractSaveablePrefixes,
     FixedPoint,
     Once,
+    RowwiseRunRule,
     RuleExecutor,
     SavedStateLoadRule,
     UnusedBranchRemovalRule,
@@ -44,6 +46,7 @@ class DefaultOptimizer(RuleExecutor):
                 [EquivalentNodeMergeRule()],
             ),
             Batch("Node Level Optimization", Once(), [NodeOptimizationRule()]),
+            Batch("Row-wise Runs", Once(), [RowwiseRunRule()]),
         ]
 
 

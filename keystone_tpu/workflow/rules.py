@@ -166,6 +166,60 @@ class UnusedBranchRemovalRule(Rule):
         return graph, prefixes
 
 
+class RowwiseRunRule(Rule):
+    """Merge each maximal run of row-wise nodes into one ``RowwiseRun``
+    node: consecutive transformers whose ``rowwise()`` says they map row
+    to row, each fed by the one before and feeding nothing else. A batch
+    too large to hold a node's output whole then goes through the run in
+    chunks of rows, and only the run's last output is joined."""
+
+    def apply(self, graph: Graph, prefixes: PrefixMap) -> Tuple[Graph, PrefixMap]:
+        from keystone_tpu.workflow.api import RowwiseRun, Transformer
+
+        def rowwise(n) -> bool:
+            op = graph.operators.get(n)
+            return (
+                isinstance(op, Transformer)
+                and len(graph.dependencies[n]) == 1
+                and op.rowwise() is not None
+            )
+
+        users: Dict[object, List[object]] = {}
+        for n, deps in graph.dependencies.items():
+            for d in deps:
+                users.setdefault(d, []).append(n)
+        for k, d in graph.sink_dependencies.items():
+            users.setdefault(d, []).append(k)
+
+        def next_in_run(n):
+            """The row-wise node that alone reads ``n``, or None."""
+            (user,) = users[n] if len(users.get(n, ())) == 1 else (None,)
+            return user if user in graph.operators and rowwise(user) else None
+
+        heads = [
+            n for n in sorted(graph.operators)
+            if rowwise(n) and not (
+                rowwise(graph.dependencies[n][0])
+                and next_in_run(graph.dependencies[n][0]) == n
+            )
+        ]
+        for head in heads:
+            run = [head]
+            while next_in_run(run[-1]) is not None:
+                run.append(next_in_run(run[-1]))
+            if len(run) < 2:
+                continue
+            merged = RowwiseRun([graph.operators[n] for n in run])
+            graph = graph.set_operator(run[-1], merged)
+            graph = graph.set_dependencies(
+                run[-1], graph.dependencies[head]
+            )
+            for n in reversed(run[:-1]):
+                graph = graph.remove_node(n)
+                prefixes.pop(n, None)
+        return graph, prefixes
+
+
 def _is_saveable_op(op: Operator) -> bool:
     from keystone_tpu.ops.util.cacher import Cacher
 

@@ -2,6 +2,8 @@
 pipelines/images/imagenet/ImageNetSiftLcsFV.scala), plus a loader test on
 a tar in the layout of the reference's test fixture."""
 
+import dataclasses
+
 import numpy as np
 import pytest
 
@@ -169,8 +171,14 @@ FLAGSHIP_GRAPH_AT_7243142 = [
     ("gather", _STR, ["node10", "node18"]),
     ("VectorCombiner", _STR, ["node19"]),
 ]
+# The same seeded pipeline's token. Until PR 31 it read 8c0df507…: a node
+# held by another node (FisherVector's GaussianMixtureModel) gave the token
+# its type name and nothing of its arrays, so two flagships that differed
+# in their GMMs alone shared a token; since PR 31 serving/aot.py folds a
+# held node's fields in (a merged RowwiseRun's nodes need it), which moved
+# this value once and the graph above not at all.
 FLAGSHIP_TOKEN_AT_7243142 = (
-    "8c0df507b9dfecbaa9381c6f33d519322711802ac5a41f16db546a4735ce1f7a"
+    "d8344da50ba0b95752766b95380831d904994d40ee69f731ff22c1a14ae850cd"
 )
 
 
@@ -206,6 +214,12 @@ def test_flagship_pipeline_graph_is_what_it_was():
             ))
     assert got == FLAGSHIP_GRAPH_AT_7243142
     assert pipeline_token(pipe.fit()) == FLAGSHIP_TOKEN_AT_7243142
+    # and a held node's arrays count: another GMM, another token
+    other = pipe.fit()
+    fv = next(op for op in other.graph.operators.values()
+              if type(op).__name__ == "FisherVector")
+    fv.gmm = dataclasses.replace(fv.gmm, means=fv.gmm.means + 1.0)
+    assert pipeline_token(other) != FLAGSHIP_TOKEN_AT_7243142
 
 
 def test_flagship_features_separate_textures_and_keep_their_rank():
