@@ -10,15 +10,26 @@ Conventions: an image is a jnp array ``A[x, y, c]`` (the reference's
 ``vec[c + x·C + y·C·X]`` (ChannelMajorArrayVectorizedImage), i.e.
 ``A.transpose(1, 0, 2).ravel()``.
 
-TPU-first: the Convolver is NOT an im2col + GEMM translation. Patch
-normalization and whitening are folded into closed-form corrections around
-one XLA convolution (which the compiler maps onto the MXU):
+TPU-first: the Convolver is NOT an im2col + GEMM translation. Alone,
+patch normalization and whitening are folded into closed-form
+corrections around one XLA convolution (which the compiler maps onto
+the MXU):
 
     out = (conv(A, W) − m·S_f) / sd − ⟨μ_zca, W_f⟩
 
 where m/sd are per-patch mean/std obtained from two box-filter convs.
 This reproduces makePatches(normalizePatches)+whitener-mean-subtraction+
 GEMM (Convolver.scala:128-205) without materializing a patch matrix.
+
+Where a Convolver feeds a SymmetricRectifier that feeds a sum Pooler
+and nothing else reads between them (one ``RowwiseRun``), the three run
+as one function, ``_ConvolveRectifyPool``: the maps, 27·27·F floats an
+image where 2·2·2F leave the Pooler, are made, rectified and summed
+into their windows a tile at a time in VMEM
+(``pallas_kernels.conv_rectify_pool``) and never reach HBM. That
+function does hold the patch matrix — (positions, k·k·C) an image, a
+hundredth of the maps — so it normalizes the patches themselves,
+``(p − m)/sd``, which is the same ``(conv(A, W) − m·S_f)/sd``.
 """
 
 from __future__ import annotations
@@ -30,6 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from keystone_tpu.ops.images.pallas_kernels import conv_rectify_pool
 from keystone_tpu.parallel.dataset import Dataset
 from keystone_tpu.utils.precision import mm
 from keystone_tpu.workflow.api import FunctionNode, Transformer, run_rowwise
@@ -133,6 +145,24 @@ class _Convolve:
     var_constant: float
     fast: bool
 
+    def absorb(self, rest):
+        """A rectifier and then a sum pooler behind this function make
+        one function with it (``workflow.api.fold_rowwise``): no map
+        leaves the chip. Any other successor, a pooler with a function
+        of its own, or windows that overlap more than two deep along an
+        axis (the kernel unrolls over the pieces the windows cut the map
+        into) leave the three as they are."""
+        if (
+            len(rest) >= 2
+            and isinstance(rest[0], _Rectify)
+            and isinstance(rest[1], _Pool)
+            and rest[1].pixel_fn is None
+            and rest[1].pool_fn is None
+            and rest[1].pool_size // 2 <= rest[1].stride
+        ):
+            return _ConvolveRectifyPool(self, rest[0], rest[1]), 2
+        return None
+
     def __call__(self, arrays, imgs):
         W, filter_sums, whitener_dot = arrays
         k, C = self.conv_size, self.channels
@@ -211,27 +241,28 @@ class _Pool:
     pixel_fn: Optional[Callable]
     pool_fn: Optional[Callable]
 
+    def windows(self, dim: int) -> List[tuple]:
+        """The [start, stop) ranges the windows take along an axis of
+        ``dim`` positions."""
+        half = self.pool_size // 2
+        return [
+            (p - half, min(p + half, dim))
+            for p in range(half, dim, self.stride)
+        ]
+
     def __call__(self, arrays, imgs):
         del arrays
-        x_dim, y_dim = imgs.shape[1], imgs.shape[2]
-        half = self.pool_size // 2
-        start = half
-        xs = list(range(start, x_dim, self.stride))
-        ys = list(range(start, y_dim, self.stride))
         with jax.named_scope("conv.pool"):
             vals = imgs.astype(jnp.float32)
             if self.pixel_fn is not None:
                 vals = self.pixel_fn(vals)
             pool_fn = self.pool_fn or (lambda w: jnp.sum(w, axis=(1, 2)))
             rows = []
-            for px in xs:
-                cols = []
-                for py in ys:
-                    window = vals[
-                        :, px - half : min(px + half, x_dim),
-                        py - half : min(py + half, y_dim), :,
-                    ]
-                    cols.append(pool_fn(window))
+            for x0, x1 in self.windows(imgs.shape[1]):
+                cols = [
+                    pool_fn(vals[:, x0:x1, y0:y1, :])
+                    for y0, y1 in self.windows(imgs.shape[2])
+                ]
                 rows.append(jnp.stack(cols, axis=1))  # (n, ny, C)
             return jnp.stack(rows, axis=1)  # (n, nx, ny, C)
 
@@ -275,6 +306,167 @@ class _Rectify:
             pos = jnp.maximum(self.max_val, x - self.alpha)
             neg = jnp.maximum(self.max_val, -x - self.alpha)
             return jnp.concatenate([pos, neg], axis=-1)
+
+
+# images whose patches _ConvolveRectifyPool makes together: few enough
+# for XLA to keep them in VMEM between their fusions and the kernel
+PATCH_GROUP = 64
+
+
+def _axis_cells(windows: Sequence[tuple]) -> List[tuple]:
+    """An axis cut at every window's edge: ``(start, stop, windows that
+    hold the piece)`` for each piece that some window holds."""
+    edges = sorted({e for w in windows for e in w})
+    cells = []
+    for a, b in zip(edges, edges[1:]):
+        inside = tuple(
+            i for i, (lo, hi) in enumerate(windows) if lo <= a and b <= hi
+        )
+        if inside:
+            cells.append((a, b, inside))
+    return cells
+
+
+@dataclasses.dataclass(frozen=True)
+class _ConvolveRectifyPool:
+    """``_Convolve`` → ``_Rectify`` → a sum ``_Pool`` as one rows-in,
+    rows-out function (``_Convolve.absorb``), the same numbers with
+    another order of summation. The pooling windows cut the map into
+    rectangles, each inside the same windows throughout. An image's
+    patches are laid out rectangle after rectangle, normalized as the
+    Convolver's closed form does — ``(p − m)/sd`` is
+    ``(conv − m·S_f)/sd`` — and ``conv_rectify_pool`` makes, rectifies
+    and sums the responses a (positions, filter tile) slab at a time in
+    VMEM; a window is the sum of its rectangles. Its arrays are the
+    three functions' arrays, one after another."""
+
+    conv: _Convolve
+    rect: _Rectify
+    pool: _Pool
+
+    def _layout(self, imgs):
+        """For the map's two axes: how many windows, and the pieces
+        their edges cut the axis into (``_axis_cells``)."""
+        k = self.conv.conv_size
+        windows = [self.pool.windows(dim - k + 1) for dim in imgs.shape[1:3]]
+        return [len(w) for w in windows], [_axis_cells(w) for w in windows]
+
+    def _patches(self, imgs):
+        """(n, R, K): the patches of every rectangle, rows in the order
+        of the rectangles, columns ``(dx, dy, c)`` as ``W[f]`` flattens;
+        R is padded to the sublane tile and K to the lane tile with
+        zeros."""
+        k = self.conv.conv_size
+        x = imgs.astype(jnp.float32)
+        n, x_dim, y_dim, channels = x.shape
+        rx, ry = x_dim - k + 1, y_dim - k + 1
+        every = jnp.stack(
+            [x[:, dx:dx + rx, dy:dy + ry, :]
+             for dx in range(k) for dy in range(k)],
+            axis=3,
+        ).reshape(n, rx, ry, k * k * channels)
+        _, (x_cells, y_cells) = self._layout(imgs)
+        p = jnp.concatenate(
+            [
+                every[:, x0:x1, y0:y1, :].reshape(
+                    n, (x1 - x0) * (y1 - y0), every.shape[3]
+                )
+                for x0, x1, _ in x_cells for y0, y1, _ in y_cells
+            ],
+            axis=1,
+        )
+        if self.conv.normalize_patches:
+            # Stats.normalizeRows: var over patch entries, /(P-1), +alpha
+            centred = p - jnp.mean(p, axis=2, keepdims=True)
+            var = jnp.sum(centred * centred, axis=2, keepdims=True) / (
+                p.shape[2] - 1
+            )
+            p = centred / jnp.sqrt(var + self.conv.var_constant)
+        return jnp.pad(
+            p, ((0, 0), (0, -p.shape[1] % 8), (0, -p.shape[2] % 128))
+        )
+
+    def _sums(self, arrays, imgs):
+        """(patches, the kernel's sums (n, 2·windows, F)) of a group of
+        images."""
+        (W, _, whitener_dot), _, _ = arrays
+        with jax.named_scope("conv.patches"):
+            patches = self._patches(imgs)
+        (nx, ny), (x_cells, y_cells) = self._layout(imgs)
+        # the rectangles' rows in ``patches``, and each window's rectangles
+        sizes = [
+            (x1 - x0) * (y1 - y0)
+            for x0, x1, _ in x_cells for y0, y1, _ in y_cells
+        ]
+        stops = np.cumsum(sizes)
+        windows = [
+            [
+                i * len(y_cells) + j
+                for i, (_, _, in_x) in enumerate(x_cells) if wx in in_x
+                for j, (_, _, in_y) in enumerate(y_cells) if wy in in_y
+            ]
+            for wx in range(nx) for wy in range(ny)
+        ]
+        num_filters = W.shape[0]
+        w = jnp.pad(
+            W.reshape(num_filters, -1).T,
+            ((0, patches.shape[2] - W[0].size), (0, 0)),
+        )
+        bias = (
+            jnp.zeros((num_filters,), jnp.float32)
+            if whitener_dot is None else whitener_dot
+        )
+        with jax.named_scope("conv.rectify_pool"):
+            sums = conv_rectify_pool(
+                patches, w, bias[None, :],
+                segments=[
+                    (int(stop - size), int(stop))
+                    for size, stop in zip(sizes, stops)
+                ],
+                windows=windows,
+                max_val=self.rect.max_val, alpha=self.rect.alpha,
+                precision=(
+                    None if self.conv.fast else jax.lax.Precision.HIGHEST
+                ),
+            )
+        return patches, sums
+
+    @staticmethod
+    def _groups(n: int) -> tuple:
+        """(groups, images a group): ``n`` images divided evenly over
+        the groups that ``PATCH_GROUP`` images a group ask for."""
+        groups = -(-n // PATCH_GROUP)
+        return groups, -(-n // groups)
+
+    def held(self, arrays, imgs):
+        """What the rows keep on the device beside their result: the
+        patches of one group of images, and the kernel's sums (n,
+        2·windows, F) before they are put in the Pooler's order
+        (``workflow.api.plan_rowwise_run`` counts both)."""
+        groups, size = self._groups(imgs.shape[0])
+        if groups == 1:
+            return self._sums(arrays, imgs)
+        grouped = jnp.pad(
+            imgs, ((0, groups * size - imgs.shape[0]),) + ((0, 0),) * 3
+        ).reshape((groups, size) + imgs.shape[1:])
+        # a group's sums leave the loop as (size, 2·windows · F)
+        sums = jax.lax.map(
+            lambda g: self._sums(arrays, g)[1].reshape(size, -1), grouped
+        )
+        (nx, ny), _ = self._layout(imgs)
+        return (
+            jax.eval_shape(self._patches, grouped[0]),
+            sums.reshape(groups * size, 2 * nx * ny, -1)[:imgs.shape[0]],
+        )
+
+    def __call__(self, arrays, imgs):
+        _, sums = self.held(arrays, imgs)
+        n, _, num_filters = sums.shape
+        (nx, ny), _ = self._layout(imgs)
+        # (n, [pos | neg], nx·ny, F) -> (n, nx, ny, [pos F | neg F])
+        return jnp.transpose(
+            sums.reshape(n, 2, nx, ny, num_filters), (0, 2, 3, 1, 4)
+        ).reshape(n, nx, ny, 2 * num_filters)
 
 
 class ImageVectorizer(Transformer):

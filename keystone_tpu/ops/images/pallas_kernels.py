@@ -16,10 +16,16 @@ same VMEM-residency move ``fv_pallas`` makes for the FV statistics:
   plane stack never exists in HBM.
 - ``plane_sandwich``: the plain sandwich for LCS box-mean/variance
   extraction (image and image² share the chain as stacked planes).
+- ``conv_rectify_pool``: a Convolver's product fused with the
+  SymmetricRectifier and a sum Pooler. The grid walks (image tile ×
+  filter tile); each step makes one (positions, filter tile) slab of
+  maps in VMEM on the MXU, rectifies it both ways on the VPU and sums
+  it into its pooling windows — the (rows, X, Y, F) maps never exist
+  in HBM, only the pooled sums do.
 
-Both run under ``interpret=True`` on the CPU backend
+All run under ``interpret=True`` on the CPU backend
 (``auto_interpret``), so CPU tier-1 exercises the exact kernel
-dataflow, and Mosaic-compiled on TPU; both batch cleanly
+dataflow, and Mosaic-compiled on TPU; the first two batch cleanly
 under ``vmap`` (pallas_call's batching rule folds the batch into the
 grid), which is how the bucket-vmapped extractors drive them. Dots
 pin f32 HIGHEST precision — the extractors' parity tolerances
@@ -28,7 +34,8 @@ pin f32 HIGHEST precision — the extractors' parity tolerances
 
 from __future__ import annotations
 
-from typing import Optional
+from functools import partial
+from typing import Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -173,4 +180,119 @@ def plane_sandwich(
     )
 
 
-__all__ = ["auto_interpret", "sift_bin_sample", "plane_sandwich"]
+# conv_rectify_pool's tiles: images a grid step (their patches stay in
+# VMEM while the filter tiles pass) and filters a grid step
+CONV_IMAGE_TILE = 4
+CONV_FILTER_TILE = 512
+
+
+def _rows_sum(val, start: int, stop: int):
+    """Rows [start, stop) of ``val`` (R, L) summed to (1, L): whole
+    groups of 8 rows add vreg to vreg, a group the range cuts is masked."""
+    width = val.shape[1]
+    full0, full1 = -(-start // 8), stop // 8
+    acc = None
+    if full1 > full0:
+        acc = val[8 * full0:8 * full1].reshape(
+            full1 - full0, 8, width
+        ).sum(axis=0)
+    for g in range(start // 8, -(-stop // 8)):
+        if full0 <= g < full1:
+            continue
+        rows = 8 * g + jax.lax.broadcasted_iota(jnp.int32, (8, width), 0)
+        cut = jnp.where(
+            (rows >= start) & (rows < stop), val[8 * g:8 * g + 8], 0.0
+        )
+        acc = cut if acc is None else acc + cut
+    return jnp.sum(acc, axis=0, keepdims=True)
+
+
+def _conv_rectify_pool_kernel(
+    segments, windows, max_val, alpha, precision,
+    p_ref, w_ref, b_ref, out_ref,
+):
+    w = w_ref[:]
+    bias = b_ref[:]
+    for t in range(p_ref.shape[0]):
+        x = jnp.dot(p_ref[t], w, preferred_element_type=jnp.float32,
+                    precision=precision) - bias
+        row = 0
+        for part in (jnp.maximum(max_val, x - alpha),
+                     jnp.maximum(max_val, -x - alpha)):
+            sums = [_rows_sum(part, a, b) for a, b in segments]
+            for window in windows:
+                total = sums[window[0]]
+                for s in window[1:]:
+                    total = total + sums[s]
+                out_ref[t, row:row + 1, :] = total
+                row += 1
+
+
+def conv_rectify_pool(
+    patches: jnp.ndarray,
+    w: jnp.ndarray,
+    bias: jnp.ndarray,
+    *,
+    segments: Sequence[Tuple[int, int]],
+    windows: Sequence[Sequence[int]],
+    max_val: float,
+    alpha: float,
+    precision=_HP,
+    interpret: Optional[bool] = None,
+) -> jnp.ndarray:
+    """Filter responses, symmetric rectifier and sum pooling in one pass.
+
+    ``patches``: (n, R, K) — an image's patches as rows, in an order
+    the caller chooses; ``w``: (K, F) filters as columns; ``bias``:
+    (1, F), subtracted from every response. ``segments``: row ranges
+    [start, stop) of ``patches`` (rows in no segment are padding and
+    count nowhere); ``windows``: for each pooling window the segments
+    whose sum it is. Returns (n, 2·len(windows), F): row i is window i
+    of ``max(max_val, x − alpha)``, row len(windows) + i window i of
+    ``max(max_val, −x − alpha)``, x = patches @ w − bias.
+
+    R is a multiple of 8 and K of 128 (zero columns against zero rows
+    of ``w`` count nothing). n and F are arbitrary: the last image
+    tile and the last filter tile are partial blocks, whose rows and
+    columns beyond the arrays are computed and dropped."""
+    n, rows, k = patches.shape
+    num_filters = w.shape[1]
+    image_tile = min(CONV_IMAGE_TILE, n)
+    filter_tile = min(CONV_FILTER_TILE, num_filters)
+    out_rows = 2 * len(windows)
+    kernel = partial(
+        _conv_rectify_pool_kernel,
+        tuple(tuple(s) for s in segments),
+        tuple(tuple(win) for win in windows),
+        float(max_val), float(alpha), precision,
+    )
+    return pl.pallas_call(
+        kernel,
+        grid=(pl.cdiv(n, image_tile), pl.cdiv(num_filters, filter_tile)),
+        in_specs=[
+            pl.BlockSpec((image_tile, rows, k), lambda i, j: (i, 0, 0),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((k, filter_tile), lambda i, j: (0, j),
+                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((1, filter_tile), lambda i, j: (0, j),
+                         memory_space=pltpu.VMEM),
+        ],
+        out_specs=pl.BlockSpec(
+            (image_tile, out_rows, filter_tile), lambda i, j: (i, 0, j),
+            memory_space=pltpu.VMEM,
+        ),
+        out_shape=jax.ShapeDtypeStruct(
+            (n, out_rows, num_filters), jnp.float32
+        ),
+        interpret=auto_interpret(interpret),
+    )(
+        patches.astype(jnp.float32),
+        w.astype(jnp.float32),
+        bias.astype(jnp.float32),
+    )
+
+
+__all__ = [
+    "auto_interpret", "sift_bin_sample", "plane_sandwich",
+    "conv_rectify_pool",
+]

@@ -18,7 +18,7 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import pickle
-from functools import partial
+from functools import lru_cache, partial
 from typing import Any, Callable, Dict, List, Optional, Sequence, Union
 
 import jax
@@ -393,7 +393,12 @@ class Transformer(Chainable, TransformerOperator):
         None (the default) for a node that says no such thing. ``fn``
         hashes and compares by its settings, the node's arrays go in
         ``arrays``: nodes of equal settings then share compiled programs
-        (``RowwiseRun``)."""
+        (``RowwiseRun``). Two things ``fn`` may say besides
+        (``fold_rowwise``, ``plan_rowwise_run``): ``fn.absorb(rest)``,
+        given the functions that follow it in a run, returns ``(folded,
+        k)`` where one function does its work and that of the next
+        ``k``, or None; ``fn.held(arrays, batch)`` returns the arrays a
+        row keeps on the device beside its result."""
         return None
 
     def _jitted_vmap(self):
@@ -538,8 +543,9 @@ def _tree_bytes(tree: Any) -> int:
 class RunPlan:
     """How ``RowwiseRun`` takes one batch through: ``chunk_rows`` rows a
     program (all of ``rows`` where the batch goes through whole),
-    ``item_bytes`` what one row holds across the run (every node's
-    output), ``out_item`` the shapes of one row of the run's result."""
+    ``item_bytes`` what one row holds across the run (every function's
+    output, and what a function says it holds beside it), ``out_item``
+    the shapes of one row of the run's result."""
 
     rows: int
     chunk_rows: int
@@ -559,25 +565,53 @@ class RunPlan:
         return self.rows * _tree_bytes(self.out_item)
 
 
+def _shape_key(tree: Any, shape: Callable) -> tuple:
+    """A pytree of arrays as a hashable (structure, shapes and dtypes)."""
+    leaves, treedef = jax.tree_util.tree_flatten(tree)
+    return treedef, tuple(
+        jax.ShapeDtypeStruct(shape(a), a.dtype) for a in leaves
+    )
+
+
+@lru_cache(maxsize=64)
+def _row_account(fns: tuple, arrays_key: tuple, one_key: tuple) -> tuple:
+    """(the bytes one row holds across the functions, the shapes of one
+    row of the last one's result), by ``jax.eval_shape``. Kept by the
+    functions and the shapes: a fit plans the same run again, and
+    tracing a folded function takes the host tens of milliseconds in
+    which the chip has nothing to do."""
+    arrays = jax.tree_util.tree_unflatten(*arrays_key)
+    one = jax.tree_util.tree_unflatten(*one_key)
+    item_bytes = 0
+    for fn, arr in zip(fns, arrays):
+        if hasattr(fn, "held"):
+            item_bytes += _tree_bytes(jax.eval_shape(fn.held, arr, one))
+        one = jax.eval_shape(fn, arr, one)
+        item_bytes += _tree_bytes(one)
+    return item_bytes, one
+
+
 def plan_rowwise_run(
     fns: Sequence[Callable], arrays: Sequence[Any], batch: Any,
     free_bytes: Optional[int],
 ) -> RunPlan:
     """Rows a chunk from bytes, by ``jax.eval_shape`` alone: one row's
-    outputs of every node of the run against half of what the device
-    has free once the joined result is taken out (the other half is
-    the compiler's: a program's temporaries are not in the shapes). The
-    rows are cut to a power of two, so that a little more or less free
-    memory plans the same program. The batch goes through whole where
-    it fits, or where the backend gives no account of its memory."""
+    outputs of every function of the run, and what a function says it
+    holds beside its output (``fn.held``), against half of what the
+    device has free once the joined result is taken out (the other half
+    is the compiler's: a program's temporaries are not in the shapes).
+    The rows that fit are cut to a power of two, so that a little more
+    or less free memory plans the same program, and the batch is
+    divided evenly over the chunks that many rows ask for: the last
+    chunk starts ``chunk_rows`` before the end, and the rows it computes
+    twice are fewer than there are chunks. The batch goes through whole
+    where it fits, or where the backend gives no account of its memory."""
     rows = _leading_dim(batch)
-    one = jax.tree_util.tree_map(
-        lambda a: jax.ShapeDtypeStruct((1,) + a.shape[1:], a.dtype), batch
+    item_bytes, one = _row_account(
+        tuple(fns),
+        _shape_key(arrays, lambda a: a.shape),
+        _shape_key(batch, lambda a: (1,) + a.shape[1:]),
     )
-    item_bytes = 0
-    for fn, arr in zip(fns, arrays):
-        one = jax.eval_shape(fn, arr, one)
-        item_bytes += _tree_bytes(one)
     whole = RunPlan(rows, rows, item_bytes, one)
     if free_bytes is None:
         return whole
@@ -585,8 +619,23 @@ def plan_rowwise_run(
     if rows * item_bytes <= budget:
         return whole
     fit = max(budget // max(item_bytes, 1), 1)
-    chunk = 1 << (int(fit).bit_length() - 1)
-    return dataclasses.replace(whole, chunk_rows=min(chunk, rows))
+    chunks = -(-rows // (1 << (int(fit).bit_length() - 1)))
+    return dataclasses.replace(whole, chunk_rows=-(-rows // chunks))
+
+
+def fold_rowwise(fns: Sequence[Callable], arrays: Sequence[Any]) -> tuple:
+    """The functions of a run with every function that absorbs its
+    successors (``fn.absorb``, see ``Transformer.rowwise``) in their
+    place; the folded function's arrays are the tuple of the arrays of
+    the functions it stands for."""
+    out_fns, out_arrays, i = [], [], 0
+    while i < len(fns):
+        absorb = getattr(fns[i], "absorb", None)
+        fn, k = (absorb and absorb(fns[i + 1:])) or (fns[i], 0)
+        out_fns.append(fn)
+        out_arrays.append(tuple(arrays[i:i + 1 + k]) if k else arrays[i])
+        i += 1 + k
+    return tuple(out_fns), tuple(out_arrays)
 
 
 @partial(jax.jit, static_argnums=(0,))
@@ -598,25 +647,34 @@ def run_rowwise(fns, arrays, batch):
     return batch
 
 
+def _valid_rows(part, start, n):
+    """``part`` with the rows at ``start`` + i >= ``n`` zeroed (the
+    Dataset's padding rule)."""
+    valid = start + jnp.arange(_leading_dim(part)) < n
+    return jax.tree_util.tree_map(
+        lambda r: jnp.where(
+            valid.reshape((-1,) + (1,) * (r.ndim - 1)), r, 0
+        ),
+        part,
+    )
+
+
 @partial(jax.jit, static_argnums=(0, 1), donate_argnums=(3,))
 def _run_chunk(fns, chunk_rows, arrays, out, batch, start, n):
     """One chunk of a ``RowwiseRun``: rows [start, start + chunk_rows)
     of ``batch`` through every function of the run, rows past ``n``
-    zeroed (the Dataset's padding rule), written into ``out`` in place."""
+    zeroed, written into ``out`` in place."""
     part = jax.tree_util.tree_map(
         lambda a: jax.lax.dynamic_slice_in_dim(a, start, chunk_rows), batch
     )
     for fn, arr in zip(fns, arrays):
         part = fn(arr, part)
-    valid = start + jnp.arange(chunk_rows) < n
-
-    def write(o, r):
-        r = jnp.where(valid.reshape((-1,) + (1,) * (r.ndim - 1)), r, 0)
-        return jax.lax.dynamic_update_slice_in_dim(
+    return jax.tree_util.tree_map(
+        lambda o, r: jax.lax.dynamic_update_slice_in_dim(
             o, r.astype(o.dtype), start, 0
-        )
-
-    return jax.tree_util.tree_map(write, out, part)
+        ),
+        out, _valid_rows(part, start, n),
+    )
 
 
 class RowwiseRun(Transformer):
@@ -628,11 +686,18 @@ class RowwiseRun(Transformer):
     output is ever whole: a Convolver's maps, megabytes an image, live
     for a chunk's rows and no longer.
 
+    The run's functions are folded once (``fold_rowwise``), for the plan
+    and for the programs alike. A run in which a function absorbed its
+    successors (``folded``) holds less a row than its nodes would one
+    by one, so it never goes node by node: a batch the plan calls whole
+    is one chunk of the same program.
+
     The chunk's rows follow from bytes (``plan_rowwise_run``). All chunks
     share one program: the last one starts ``chunk_rows`` before the end
     and computes a few rows twice rather than pad. Spans
     ``workflow.run`` once a call and ``workflow.run.chunk`` once a
     chunk; counters ``keystone_workflow_run_items_total``,
+    ``_run_folded_items_total`` (those of them in a folded run),
     ``_run_chunks_total`` and ``_run_chunk_bytes_total`` (the planned
     bytes of the chunks dispatched)."""
 
@@ -657,8 +722,13 @@ class RowwiseRun(Transformer):
         return ds
 
     def _parts(self) -> tuple:
-        """(the nodes' functions, the nodes' arrays)."""
-        return tuple(zip(*(node.rowwise() for node in self.nodes)))
+        """(the run's functions, their arrays), folded."""
+        return fold_rowwise(*zip(*(node.rowwise() for node in self.nodes)))
+
+    @property
+    def folded(self) -> bool:
+        """Some function of the run stands for several nodes."""
+        return len(self._parts()[0]) < len(self.nodes)
 
     def plan(self, batch: Any, free_bytes: Optional[int]) -> RunPlan:
         return plan_rowwise_run(*self._parts(), batch, free_bytes)
@@ -667,18 +737,28 @@ class RowwiseRun(Transformer):
         if not ds.is_array:
             return self._node_by_node(ds)
         batch = ds.padded()
+        fns, arrays = self._parts()
+        folded = len(fns) < len(self.nodes)
         if any(
             isinstance(a, jax.core.Tracer)
             for a in jax.tree_util.tree_leaves(batch)
         ):  # inside jit the compiler schedules the memory
+            if not folded:
+                return self._node_by_node(ds)
+            for fn, arr in zip(fns, arrays):
+                batch = fn(arr, batch)
+            return Dataset.from_array(_valid_rows(batch, 0, ds.n), n=ds.n)
+        plan = plan_rowwise_run(
+            fns, arrays, batch, _device_free_bytes(batch)
+        )
+        if not plan.chunked and not folded:
             return self._node_by_node(ds)
-        plan = self.plan(batch, _device_free_bytes(batch))
-        if not plan.chunked:
-            return self._node_by_node(ds)
-        return self._chunked(ds, batch, plan)
+        return self._chunked(ds, batch, plan, fns, arrays)
 
-    def _chunked(self, ds: Dataset, batch: Any, plan: RunPlan) -> Dataset:
-        fns, arrays = self._parts()
+    def _chunked(
+        self, ds: Dataset, batch: Any, plan: RunPlan, fns: tuple,
+        arrays: tuple,
+    ) -> Dataset:
         rows, chunk = plan.rows, plan.chunk_rows
         starts = list(range(0, rows - chunk, chunk)) + [rows - chunk]
         with span("workflow.run", n=ds.n, chunks=len(starts),
@@ -697,6 +777,11 @@ class RowwiseRun(Transformer):
             "keystone_workflow_run_items_total",
             "items that went through a RowwiseRun in chunks",
         ).inc(by=ds.n)
+        reg.counter(
+            "keystone_workflow_run_folded_items_total",
+            "items that went through a RowwiseRun in which a function "
+            "absorbed its successors",
+        ).inc(by=ds.n if len(fns) < len(self.nodes) else 0)
         reg.counter(
             "keystone_workflow_run_chunks_total",
             "chunk programs RowwiseRun dispatched",

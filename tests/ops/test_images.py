@@ -2,6 +2,7 @@
 reference, PoolerSuite, WindowerSuite)."""
 
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -159,3 +160,145 @@ def test_gray_and_pixel_scalers():
     np.testing.assert_allclose(gray, 254.99, atol=0.2)
     scaled = np.asarray(PixelScaler().apply(jnp.asarray(img)))
     np.testing.assert_allclose(scaled, 1.0)
+
+
+# --- the Convolver's function standing for Convolver → rectifier → pooler
+
+
+def _three(conv, rect, pool):
+    """(functions, arrays) of the three nodes, as a RowwiseRun has them."""
+    return tuple(zip(*(n.rowwise() for n in (conv, rect, pool))))
+
+
+def _one_after_another(fns, arrays, x):
+    for fn, arr in zip(fns, arrays):
+        x = fn(arr, x)
+    return np.asarray(x)
+
+
+def _byte_images(rng, n, width, height, channels):
+    return jnp.asarray(
+        rng.uniform(0, 255, (n, width, height, channels)).round(), jnp.float32)
+
+
+FOLD_GEOMETRIES = {
+    # RandomPatchCifar's: 27 × 27 maps, windows rows 0–13 and 13–26 (row
+    # 13 in both, the second cut at the edge)
+    "cifar": dict(width=32, height=32, channels=3, k=6, pool=(13, 14)),
+    # a stride under the pool size on a map that is not square: 12 × 8,
+    # windows [0,4) [3,7) [6,10) [9,12) by [0,4) [3,7) [6,8)
+    "overlap": dict(width=14, height=10, channels=2, k=3, pool=(3, 4)),
+    # windows that leave rows of the map out: 11 × 11, [0,4) and [5,9)
+    "gaps": dict(width=12, height=12, channels=1, k=2, pool=(5, 4)),
+}
+
+
+@pytest.mark.parametrize("normalize", [False, True], ids=["raw", "norm"])
+@pytest.mark.parametrize("whiten", [False, True], ids=["plain", "zca"])
+@pytest.mark.parametrize("geometry,filters,n,alpha,max_val,tile", [
+    ("cifar", 16, 1, 0.25, 0.0, None),
+    ("cifar", 144, 3, 0.25, 0.0, 128),    # a last filter tile of 16
+    ("cifar", 16, 5, 0.0, 0.0, None),     # a last image tile of one
+    ("overlap", 16, 3, 0.25, 0.1, None),  # max_val > 0
+    ("overlap", 144, 2, 0.0, 0.0, 128),
+    ("gaps", 16, 4, 0.25, 0.0, None),
+], ids=["cifar-16", "cifar-144-tiles", "cifar-alpha0", "overlap-maxval",
+        "overlap-144-tiles", "gaps"])
+def test_folded_convolve_rectify_pool_matches_the_three_functions(
+        geometry, filters, n, alpha, max_val, tile, whiten, normalize,
+        monkeypatch):
+    from keystone_tpu.ops.images import pallas_kernels
+    from keystone_tpu.workflow.api import fold_rowwise
+
+    if tile:
+        monkeypatch.setattr(pallas_kernels, "CONV_FILTER_TILE", tile)
+    g = FOLD_GEOMETRIES[geometry]
+    rng = np.random.default_rng(11)
+    patch = g["k"] * g["k"] * g["channels"]
+    w = jnp.asarray(rng.standard_normal((filters, patch)), jnp.float32)
+    whitener = None
+    if whiten:
+        whitener = ZCAWhitenerEstimator(eps=0.1).fit_single(
+            jnp.asarray(rng.standard_normal((200, patch)), jnp.float32))
+    fns, arrays = _three(
+        Convolver(w, g["width"], g["height"], g["channels"],
+                  whitener=whitener, normalize_patches=normalize),
+        SymmetricRectifier(max_val=max_val, alpha=alpha),
+        Pooler(*g["pool"]),
+    )
+    folded, folded_arrays = fold_rowwise(fns, arrays)
+    assert [type(f).__name__ for f in folded] == ["_ConvolveRectifyPool"]
+    # several images, the last a pad row of zeros
+    x = _byte_images(rng, n, g["width"], g["height"], g["channels"])
+    x = x.at[n - 1].set(0.0) if n > 1 else x
+    want = _one_after_another(fns, arrays, x)
+    got = _one_after_another(folded, folded_arrays, x)
+    assert got.shape == want.shape and want.shape[-1] == 2 * filters
+    assert np.linalg.norm(got - want) <= 1e-5 * np.linalg.norm(want)
+    assert np.abs(got - want).max() <= 1e-5 * np.abs(want).max()
+
+
+@pytest.mark.parametrize("n,group", [(5, 2), (4, 2), (3, 8)])
+def test_folded_function_makes_its_patches_a_group_of_images_at_a_time(
+        n, group, monkeypatch):
+    """More images than ``PATCH_GROUP`` go through in equal groups (five
+    as three groups of two, the last padded with a zero image whose
+    sums are dropped): the same rows as all at once."""
+    from keystone_tpu.ops.images import core
+    from keystone_tpu.workflow.api import fold_rowwise
+
+    rng = np.random.default_rng(14)
+    w = jnp.asarray(rng.standard_normal((16, 108)), jnp.float32)
+    fns, arrays = fold_rowwise(*_three(
+        Convolver(w, 32, 32, 3), SymmetricRectifier(alpha=0.25),
+        Pooler(13, 14)))
+    x = _byte_images(rng, n, 32, 32, 3)
+    at_once = _one_after_another(fns, arrays, x)
+    monkeypatch.setattr(core, "PATCH_GROUP", group)
+    assert fns[0]._groups(n) == (-(-n // group), -(-n // -(-n // group)))
+    patches, sums = jax.eval_shape(fns[0].held, arrays[0], x)
+    assert patches.shape == (min(n, fns[0]._groups(n)[1]), 736, 128)
+    assert sums.shape == (n, 8, 16)
+    np.testing.assert_allclose(
+        _one_after_another(fns, arrays, x), at_once, rtol=1e-6)
+
+
+@pytest.mark.parametrize("pool", [
+    Pooler(13, 14, pool_fn=lambda w: jnp.max(w, axis=(1, 2))),
+    Pooler(13, 14, pixel_fn=jnp.square),
+    Pooler(4, 14),   # windows more than two deep
+], ids=["pool_fn", "pixel_fn", "deep"])
+def test_a_pooler_with_its_own_function_takes_the_three_functions(pool):
+    from keystone_tpu.workflow.api import RowwiseRun, fold_rowwise
+
+    rng = np.random.default_rng(12)
+    w = jnp.asarray(rng.standard_normal((8, 108)), jnp.float32)
+    nodes = [Convolver(w, 32, 32, 3), SymmetricRectifier(alpha=0.25), pool]
+    fns, arrays = _three(*nodes)
+    assert fold_rowwise(fns, arrays) == (fns, arrays)
+    x = _byte_images(rng, 2, 32, 32, 3)
+    want = Dataset.from_array(x)
+    for node in nodes:
+        want = node.apply_batch(want)
+    got = RowwiseRun(nodes).apply_batch(Dataset.from_array(x))
+    np.testing.assert_array_equal(
+        np.asarray(got.array()), np.asarray(want.array()))
+
+
+def test_fast_keeps_its_meaning_in_the_folded_function():
+    """``fast`` asks the backend's default precision of the kernel's
+    products as it does of the convolution's, and nothing else changes."""
+    from keystone_tpu.workflow.api import fold_rowwise
+
+    rng = np.random.default_rng(13)
+    w = jnp.asarray(rng.standard_normal((16, 108)), jnp.float32)
+    x = _byte_images(rng, 2, 32, 32, 3)
+    out = []
+    for fast in (False, True):
+        fns, arrays = fold_rowwise(*_three(
+            Convolver(w, 32, 32, 3, fast=fast), SymmetricRectifier(alpha=0.25),
+            Pooler(13, 14)))
+        assert fns[0].conv.fast == fast
+        out.append(_one_after_another(fns, arrays, x))
+    # on the CPU the default precision is float32 too
+    np.testing.assert_allclose(out[0], out[1], rtol=1e-5)

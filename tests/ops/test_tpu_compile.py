@@ -1,0 +1,97 @@
+"""Kernels of the benchmark's paths compiled for a TPU v5e that is
+described and not attached, at the published widths: what Mosaic refuses
+(a slice off the tiling, more VMEM than a kernel may use, a block it
+cannot partition) fails here and costs no chip time. Nothing runs, so
+nothing here says anything about results or times.
+
+The topology is described inside a fixture, never at import: only the
+worker that runs this file loads the TPU's library (keep such tests in
+this one file)."""
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+# RandomPatchCifar at 10,000 filters: 79 filter tiles of 128, the last of
+# 16; a chunk's image tiles whole, and a last image tile of one
+@pytest.mark.parametrize("rows", [64, 3])
+def test_conv_rectify_pool_compiles_at_the_published_widths(one_chip, rows):
+    from keystone_tpu.ops.images.pallas_kernels import conv_rectify_pool
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+
+    sizes = [169, 13, 169, 13, 1, 13, 169, 13, 169]
+    stops = [sum(sizes[:i + 1]) for i in range(9)]
+    segments = [(b - a, b) for a, b in zip(sizes, stops)]
+    windows = [[0, 1, 3, 4], [1, 2, 4, 5], [3, 4, 6, 7], [4, 5, 7, 8]]
+
+    def kernel(patches, w, bias):
+        return conv_rectify_pool(
+            patches, w, bias, segments=segments, windows=windows,
+            max_val=0.0, alpha=0.25, interpret=False)
+
+    compiled = jax.jit(kernel).lower(
+        shape(rows, 736, 128), shape(128, 10000), shape(1, 10000)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+    assert (compiled.memory_analysis().output_size_in_bytes
+            >= rows * 8 * 10000 * 4)
+
+
+def test_folded_chunk_program_writes_no_map_at_the_published_widths(one_chip):
+    """A RowwiseRun's chunk program for RandomPatchCifar, folded, 3,136
+    of 12,544 rows: it holds the kernel, and no fusion of it writes a
+    four-dimensional float32 array whose last dimension is the filters
+    — what the three functions' maps were, and what the benchmark's
+    ``conv_roofline_pct.cfit`` takes for the convolution (a loop's
+    stacked sums of that shape read 829% there: my chip run, PR 32)."""
+    import json
+    import os
+    import re
+
+    from keystone_tpu.ops.images import core, pallas_kernels
+    from keystone_tpu.workflow import api
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    fns, arrays = api.fold_rowwise(
+        (core._Convolve(6, 3, True, 10.0, False), core._Rectify(0.0, 0.25),
+         core._Pool(13, 14, None, None), core._vectorize),
+        ((shape((10000, 6, 6, 3)), shape((10000,)), shape((10000,))),
+         (), (), ()),
+    )
+    compile_kernel = pallas_kernels.auto_interpret
+    pallas_kernels.auto_interpret = lambda interpret=None: False
+    try:
+        text = api._run_chunk.lower(
+            fns, 3136, arrays, shape((12544, 80000)),
+            shape((12544, 32, 32, 3)), shape((), jnp.int32),
+            shape((), jnp.int32),
+        ).compile().as_text()
+    finally:
+        pallas_kernels.auto_interpret = compile_kernel
+    assert "tpu_custom_call" in text
+    metric = os.path.join(
+        os.path.dirname(__file__), "..", "..", "benchmark", "metrics",
+        "conv_roofline_pct.cfit.json")
+    with open(metric) as f:
+        pattern = json.load(f)["args"]["pattern"].format(num_filters=10000)
+    taken = [line.strip()[:120] for line in text.splitlines()
+             if re.match(pattern, line.strip())]
+    assert not taken, taken
+    assert not re.search(r"f32\[\d+,27,27,[12]0000\]", text)

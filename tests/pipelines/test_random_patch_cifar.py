@@ -73,6 +73,54 @@ def test_fit_through_build_pipeline_against_the_plain_reference(mesh8):
     assert rel_err(np.asarray(scores.array()), want) < 2e-5
 
 
+def test_folded_fit_scores_as_the_unfolded_nodes_do(mesh8, monkeypatch):
+    """build_pipeline(...).fit() with the Convolver's function standing
+    for Convolver → rectifier → pooler, against the same fit with the
+    three functions left as they are (nothing absorbed): the held-out
+    scores before MaxClassifier agree to 1e-5, and so do the fitted
+    pipeline's scores through its own nodes one by one."""
+    import jax.numpy as jnp
+
+    from benchmark.reference import rel_err
+    from keystone_tpu.loaders.cifar import LabeledImages
+    from keystone_tpu.ops.images import core
+    from keystone_tpu.parallel.dataset import Dataset
+    from keystone_tpu.pipelines.images.random_patch_cifar import (
+        build_pipeline,
+    )
+    from keystone_tpu.workflow.api import RowwiseRun
+    from keystone_tpu.workflow.executor import PipelineEnv
+
+    rng = np.random.default_rng(6)
+    x, xt = _seeded_images(192, rng), _seeded_images(40, rng)
+    y = rng.permutation(np.arange(192) % 10).astype(np.int32)
+    conf = RandomCifarConfig(num_filters=32, lam=30.0, block_size=64, seed=9)
+
+    def scores(node_by_node=False):
+        PipelineEnv.get_or_create().reset()
+        train = LabeledImages(labels=Dataset.from_array(jnp.asarray(y)),
+                              images=Dataset.from_array(jnp.asarray(x)))
+        fitted = build_pipeline(train, conf).fit()
+        ops = [fitted.graph.operators[n] for n in fitted._topo]
+        assert isinstance(ops[0], RowwiseRun)
+        out = Dataset.from_array(jnp.asarray(xt))
+        for op in ops[:-1]:
+            if node_by_node and isinstance(op, RowwiseRun):
+                out = op._node_by_node(out)
+            else:
+                out = op.batch_transform([out])
+        return ops[0].folded, np.asarray(out.array())
+
+    folded, got = scores()
+    assert folded and got.shape == (40, 10) and np.std(got) > 0.05
+    _, through_nodes = scores(node_by_node=True)
+    assert rel_err(got, through_nodes) < 1e-5
+    monkeypatch.setattr(core._Convolve, "absorb", lambda self, rest: None)
+    folded, want = scores()
+    assert not folded
+    assert rel_err(got, want) < 1e-5
+
+
 def test_sample_patches_is_the_sampler_over_the_windower():
     """build_filters gathers the sampled patches alone: the same rows as
     Sampler over ImageVectorizer over Windower, which makes them all."""
