@@ -35,6 +35,7 @@ hundredth of the maps — so it normalizes the patches themselves,
 from __future__ import annotations
 
 import dataclasses
+from functools import partial
 from typing import Any, Callable, List, Optional, Sequence
 
 import jax
@@ -578,6 +579,24 @@ class Windower(FunctionNode):
         return Dataset.from_array(stacked)
 
 
+@partial(jax.jit, static_argnames=("num", "px", "py"))
+def _random_crops(imgs, x0, y0, *, num: int, px: int, py: int):
+    """Crop j = imgs[j // num, x0_j : x0_j + px, y0_j : y0_j + py],
+    gathered on the device where the images are: one gather of whole
+    windows, a pixel's channels beside its y (one minor axis of Y·C
+    values), as ``random_patch_cifar._gather_patches`` takes its
+    patches."""
+    n, x_dim, y_dim = imgs.shape[:3]
+    channels = int(np.prod(imgs.shape[3:], dtype=np.int64))
+    rows = imgs.reshape(n, x_dim, y_dim * channels)
+    crops = jax.vmap(
+        lambda i, x, y: jax.lax.dynamic_slice(
+            rows, (i, x, y * channels), (1, px, py * channels)
+        )[0]
+    )(jnp.arange(x0.shape[0]) // num, x0, y0)
+    return crops.reshape((-1, px, py) + imgs.shape[3:])
+
+
 @dataclasses.dataclass(eq=False)
 class RandomPatcher(Transformer):
     """Random crops for train augmentation (reference:
@@ -590,18 +609,28 @@ class RandomPatcher(Transformer):
     seed: int = 0
     vmap_batch = False
 
+    def offsets(self, n: int, x_dim: int, y_dim: int) -> np.ndarray:
+        """(n · num_patches, 2): each crop's (x, y) origin, image by
+        image. One draw; the values are those of one ``rng.integers``
+        call for x and one for y, crop after crop."""
+        rng = np.random.default_rng(self.seed)
+        high = np.array(
+            [x_dim - self.patch_size_x + 1, y_dim - self.patch_size_y + 1]
+        )
+        return rng.integers(0, high, size=(n * self.num_patches, 2))
+
     def apply_batch(self, ds: Dataset) -> Dataset:
         ds = ds.to_array_mode()
-        imgs = np.asarray(ds.padded()[: ds.n])
-        rng = np.random.default_rng(self.seed)
-        out = []
-        px, py = self.patch_size_x, self.patch_size_y
-        for img in imgs:
-            for _ in range(self.num_patches):
-                x = rng.integers(0, img.shape[0] - px + 1)
-                y = rng.integers(0, img.shape[1] - py + 1)
-                out.append(img[x : x + px, y : y + py])
-        return Dataset.from_array(jnp.asarray(np.stack(out)))
+        imgs = ds.array()
+        off = self.offsets(imgs.shape[0], imgs.shape[1], imgs.shape[2])
+        return Dataset.from_array(
+            _random_crops(
+                imgs, jnp.asarray(off[:, 0], jnp.int32),
+                jnp.asarray(off[:, 1], jnp.int32),
+                num=self.num_patches, px=self.patch_size_x,
+                py=self.patch_size_y,
+            )
+        )
 
     def apply(self, img):
         raise TypeError("RandomPatcher is a batch augmentation node")

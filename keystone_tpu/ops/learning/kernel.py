@@ -18,6 +18,21 @@ the sharded example axis, the small (b, b) solve goes to the host in f64
 broadcast variables. The reference's every-25-blocks lineage checkpoint
 becomes a cadenced atomic host snapshot of the model that ``fit`` resumes
 from after preemption (``checkpoint_path``; utils/checkpoint.py).
+
+Observability: host spans ``solver.krr.prep`` (arrays, the train set's
+norms, the block schedule), ``solver.krr.dispatch`` (the fit's device
+programs enqueued; on the per-block paths its host loop, with
+``solver.krr.kernel_block`` / ``.residual`` / ``.host_solve`` /
+``.update`` inside it where ``solve="host"``) and
+``solver.krr.converged`` (the read of "is the model finite" that the
+fit waits on: above ``block_ls._EIGH_FALLBACK_MAX_WIDTH`` columns a
+Cholesky breakdown has no fall-back and shows as a non-finite model); on
+the device ``jax.named_scope`` names ``krr.kernel_block`` /
+``krr.residual`` / ``krr.solve`` / ``krr.update``; counters
+``keystone_solver_krr_fits_total``, ``_krr_block_steps_total``,
+``_krr_kernel_blocks_total`` (column blocks really generated: a cached
+fit generates each once) and ``_krr_path_total{path}`` (``scan`` |
+``cached`` | ``block`` | ``host``).
 """
 
 from __future__ import annotations
@@ -30,6 +45,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from keystone_tpu.observability.registry import get_global_registry
+from keystone_tpu.observability.tracing import span
 from keystone_tpu.ops.learning.block_ls import (
     _f32_mm,
     _psd_solve_device,
@@ -60,12 +77,21 @@ def _rbf_block_body(X, X_norms, gamma, mask, start, width):
     """K(:, B) for a contiguous train block: exp(−γ(‖x‖²+‖x_B‖²−2x·x_B)).
     Pad rows AND pad columns are zeroed — exp(·) of a zero pad vector is
     nonzero and would pollute the Gauss-Seidel solves."""
-    Xb = jax.lax.dynamic_slice_in_dim(X, start, width, axis=0)
-    nb = jax.lax.dynamic_slice_in_dim(X_norms, start, width, axis=0)
-    mask_b = jax.lax.dynamic_slice_in_dim(mask, start, width, axis=0)
-    d2 = X_norms[:, None] + nb[None, :] - 2.0 * _cross_mm_x3(X, Xb)
-    K = jnp.exp(-gamma * jnp.maximum(d2, 0.0))
-    return K * mask[:, None] * mask_b[None, :]
+    with jax.named_scope("krr.kernel_block"):
+        Xb = jax.lax.dynamic_slice_in_dim(X, start, width, axis=0)
+        nb = jax.lax.dynamic_slice_in_dim(X_norms, start, width, axis=0)
+        mask_b = jax.lax.dynamic_slice_in_dim(mask, start, width, axis=0)
+        d2 = X_norms[:, None] + nb[None, :] - 2.0 * _cross_mm_x3(X, Xb)
+        K = jnp.exp(-gamma * jnp.maximum(d2, 0.0))
+        return K * mask[:, None] * mask_b[None, :]
+
+
+@jax.jit
+def _row_norms(X):
+    """‖x‖² of every row in one pass: eager, ``X ** 2`` is a second copy
+    of the train set (2 GB at 125,000 x 4,096) before it is summed."""
+    X = X.astype(jnp.float32)
+    return jnp.sum(X * X, axis=1)
 
 
 @partial(jax.jit, static_argnames=("width",))
@@ -97,9 +123,7 @@ class GaussianKernelTransformer(Transformer):
             self.train_mask = (
                 jnp.arange(self.train_X.shape[0]) < self.n_train
             ).astype(jnp.float32)
-        self._norms = jnp.sum(
-            self.train_X.astype(jnp.float32) ** 2, axis=1
-        )
+        self._norms = _row_norms(self.train_X)
 
     def apply(self, x):
         """kernel row of a single test point vs the whole train set."""
@@ -149,7 +173,7 @@ class KernelMatrix:
         self.transformer = transformer
         self.ds = ds
         self._X = ds.padded().astype(jnp.float32)
-        self._norms = jnp.sum(self._X * self._X, axis=1)
+        self._norms = _row_norms(self._X)
         self._mask = ds.mask()
         self.cache_blocks = cache_blocks
         self._cache: Dict[tuple, jnp.ndarray] = {}
@@ -191,7 +215,11 @@ class GaussianKernelGenerator(Estimator):
 
     def fit(self, data: Dataset) -> GaussianKernelTransformer:
         ds = data.to_array_mode()
-        X = ds.padded().astype(jnp.float32) * ds.mask()[:, None]
+        X = ds.padded().astype(jnp.float32)
+        if ds.n < ds.padded_n:
+            # zero the pad rows; with none, the transformer (and the
+            # fitted model) holds the array it was given and no copy
+            X = X * ds.mask()[:, None]
         return GaussianKernelTransformer(X, ds.n, self.gamma, ds.mask())
 
 
@@ -216,22 +244,25 @@ def _krr_block_body(X, X_norms, gamma, mask, W, Y, start, lam, width):
     driver-solve → broadcast round trip (KernelRidgeRegression.scala:
     86-235) with zero host synchronization."""
     K_block = _rbf_block_body(X, X_norms, gamma, mask, start, width)
-    # contract the example axis without a .T relayout of the n×b block
-    resid = jax.lax.dot_general(
-        K_block, W, (((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=jax.lax.Precision.HIGHEST,
-    )
-    K_bb = jax.lax.dynamic_slice_in_dim(K_block, start, width, axis=0)
-    Wb_old = jax.lax.dynamic_slice_in_dim(W, start, width, axis=0)
-    y_b = jax.lax.dynamic_slice_in_dim(Y, start, width, axis=0)
-    rhs = y_b - (resid - _f32_mm(K_bb.T, Wb_old))
+    with jax.named_scope("krr.residual"):
+        # contract the example axis without a .T relayout of the n×b block
+        resid = jax.lax.dot_general(
+            K_block, W, (((0,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST,
+        )
+        K_bb = jax.lax.dynamic_slice_in_dim(K_block, start, width, axis=0)
+        Wb_old = jax.lax.dynamic_slice_in_dim(W, start, width, axis=0)
+        y_b = jax.lax.dynamic_slice_in_dim(Y, start, width, axis=0)
+        rhs = y_b - (resid - _f32_mm(K_bb.T, Wb_old))
     # one refinement step: each extra step is a triangular-solve pair
     # (~3 ms at b=4096), and Gauss-Seidel tolerates per-block solves at
     # f32+1-refine accuracy (validated against the host-f64 path by
     # tests/ops/test_kernel.py)
-    Wb_new = _psd_solve_device(K_bb, rhs, lam, refine=1)
-    return jax.lax.dynamic_update_slice_in_dim(W, Wb_new, start, axis=0)
+    with jax.named_scope("krr.solve"):
+        Wb_new = _psd_solve_device(K_bb, rhs, lam, refine=1)
+    with jax.named_scope("krr.update"):
+        return jax.lax.dynamic_update_slice_in_dim(W, Wb_new, start, axis=0)
 
 
 @partial(jax.jit, static_argnames=("width",), donate_argnums=(4,))
@@ -273,25 +304,33 @@ def _krr_cached_epoch_scan(X, X_norms, gamma, mask, W, Y,
         return c, (Kb, Ab)
 
     _, (Kcols, Ab) = jax.lax.scan(build, jnp.float32(0), jnp.arange(nb))
-    Lb = jnp.linalg.cholesky(Ab)
+    with jax.named_scope("krr.solve"):
+        Lb = jnp.linalg.cholesky(Ab)
 
     def step(W, bi):
         s = bi * width
-        Kcol = jax.lax.dynamic_index_in_dim(Kcols, bi, 0, keepdims=False)
-        resid = jax.lax.dot_general(
-            Kcol, W, (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32, precision=hp,
-        )
-        K_bb = jax.lax.dynamic_slice_in_dim(Kcol, s, width, axis=0)
-        Wb_old = jax.lax.dynamic_slice_in_dim(W, s, width, axis=0)
-        y_b = jax.lax.dynamic_slice_in_dim(Y, s, width, axis=0)
-        rhs = y_b - (resid - _f32_mm(K_bb.T, Wb_old))
-        L = jax.lax.dynamic_index_in_dim(Lb, bi, 0, keepdims=False)
-        # refine=1 matches the uncached scan's _psd_solve_device call
-        # (validated by the same f64-parity tests); the helper carries
-        # the eigh-breakdown fallback and its >8192 gating
-        Wb_new = _psd_solve_with_factor(K_bb + lam * eye, L, rhs, refine=1)
-        return jax.lax.dynamic_update_slice_in_dim(W, Wb_new, s, axis=0), None
+        with jax.named_scope("krr.residual"):
+            Kcol = jax.lax.dynamic_index_in_dim(Kcols, bi, 0, keepdims=False)
+            resid = jax.lax.dot_general(
+                Kcol, W, (((0,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32, precision=hp,
+            )
+            K_bb = jax.lax.dynamic_slice_in_dim(Kcol, s, width, axis=0)
+            Wb_old = jax.lax.dynamic_slice_in_dim(W, s, width, axis=0)
+            y_b = jax.lax.dynamic_slice_in_dim(Y, s, width, axis=0)
+            rhs = y_b - (resid - _f32_mm(K_bb.T, Wb_old))
+        with jax.named_scope("krr.solve"):
+            L = jax.lax.dynamic_index_in_dim(Lb, bi, 0, keepdims=False)
+            # refine=1 matches the uncached scan's _psd_solve_device call
+            # (validated by the same f64-parity tests); the helper carries
+            # the eigh-breakdown fallback and its width gating
+            Wb_new = _psd_solve_with_factor(
+                K_bb + lam * eye, L, rhs, refine=1
+            )
+        with jax.named_scope("krr.update"):
+            return jax.lax.dynamic_update_slice_in_dim(
+                W, Wb_new, s, axis=0
+            ), None
 
     W, _ = jax.lax.scan(step, W, block_idx)
     return W
@@ -310,6 +349,35 @@ def _krr_epoch_scan(X, X_norms, gamma, mask, W, Y, starts, lam, *, width):
 
     W, _ = jax.lax.scan(step, W, starts)
     return W
+
+
+def _count_krr_fit(path: str):
+    """Count one fit started on ``path``; the callable it returns counts
+    block steps of that fit and the column blocks generated for them."""
+    reg = get_global_registry()
+    reg.counter(
+        "keystone_solver_krr_fits_total",
+        "kernel ridge regression fits started",
+    ).inc()
+    reg.counter(
+        "keystone_solver_krr_path_total",
+        "kernel ridge regression fits by the path taken",
+        labelnames=("path",),
+    ).inc((path,))
+    block_steps = reg.counter(
+        "keystone_solver_krr_block_steps_total",
+        "Gauss-Seidel block updates of the dual model",
+    )
+    generated = reg.counter(
+        "keystone_solver_krr_kernel_blocks_total",
+        "kernel column blocks generated on the device",
+    )
+
+    def count_step(steps: int = 1, kernel_blocks: int = 1) -> None:
+        block_steps.inc(by=steps)
+        generated.inc(by=kernel_blocks)
+
+    return count_step
 
 
 @dataclasses.dataclass(eq=False)
@@ -391,31 +459,22 @@ class KernelRidgeRegression(LabelEstimator):
         return order
 
     def fit(self, data: Dataset, labels: Dataset) -> KernelBlockLinearMapper:
-        from keystone_tpu.utils.profiling import PhaseTimer
-
         if self.solve not in ("device", "host"):
             raise ValueError(f"solve must be 'device' or 'host', got {self.solve!r}")
-        # per-phase wall clock, published as registry metrics
-        # (keystone_phase_seconds_total{timer="krr_fit"}) — the
-        # scrapeable version of the reference's kernelGen/residual/
-        # localSolve/modelUpdate log lines (KernelRidgeRegression.scala:
-        # 213-221); device-path phases are enqueue time (dispatch is
-        # async), host-path phases include the blocking f64 solve
-        timer = PhaseTimer("krr_fit")
-        data = data.to_array_mode()
-        labels = labels.to_array_mode()
-        transformer = self.kernel_generator.fit(data)
-        X = transformer.train_X
-        n = data.n
-        n_pad = X.shape[0]
-        Y = labels.padded().astype(jnp.float32)
-        k = Y.shape[1]
-
-        blocks = [
-            (s, min(s + self.block_size, n_pad) - s)
-            for s in range(0, n_pad, self.block_size)
-        ]
-        W = jnp.zeros((n_pad, k), jnp.float32)
+        with span("solver.krr.prep"):
+            data = data.to_array_mode()
+            labels = labels.to_array_mode()
+            transformer = self.kernel_generator.fit(data)
+            X = transformer.train_X
+            n = data.n
+            n_pad = X.shape[0]
+            Y = labels.padded().astype(jnp.float32)
+            k = Y.shape[1]
+            blocks = [
+                (s, min(s + self.block_size, n_pad) - s)
+                for s in range(0, n_pad, self.block_size)
+            ]
+            W = jnp.zeros((n_pad, k), jnp.float32)
 
         ckpt = None
         start_epoch, start_pos = 0, 0
@@ -437,118 +496,145 @@ class KernelRidgeRegression(LabelEstimator):
                 start_epoch = int(state["epoch"])
                 start_pos = int(state["pos"])
 
-        if (
+        one_program = (
             self.solve == "device"
             and ckpt is None
             and self.block_callback is None
             and len({wd for _, wd in blocks}) == 1
-        ):
-            # fast path: every epoch's whole block schedule as one
-            # scanned program, one dispatch for the entire fit
-            order = [
-                i
-                for epoch in range(self.num_epochs)
-                for i in self._epoch_order(epoch, len(blocks))
-            ]
-            width = blocks[0][1]
-            use_cached = self.cache_kernel
-            if use_cached is None:
-                from keystone_tpu.ops.learning.weighted_ls import (
-                    _device_memory_limit,
+        )
+        if one_program:
+            W = self._fit_one_program(transformer, W, Y, blocks)
+        else:
+            W = self._fit_block_loop(
+                transformer, W, Y, blocks, ckpt, start_epoch, start_pos
+            )
+        with span("solver.krr.converged"):
+            finite = bool(jnp.all(jnp.isfinite(W)))
+        if not finite:
+            raise FloatingPointError(
+                "KernelRidgeRegression: the fitted model is not finite — "
+                f"a Cholesky factorisation of K_BB + {self.lam}·I broke "
+                "down in float32 (blocks wider than "
+                "block_ls._EIGH_FALLBACK_MAX_WIDTH have no eigh fall-back); "
+                "raise lam or use solve='host'"
+            )
+        return KernelBlockLinearMapper(W, self.block_size, transformer, n)
+
+    def _fit_one_program(self, transformer, W, Y, blocks):
+        """Every epoch's whole block schedule as one scanned program,
+        one dispatch for the entire fit."""
+        n_pad = transformer.train_X.shape[0]
+        order = [
+            i
+            for epoch in range(self.num_epochs)
+            for i in self._epoch_order(epoch, len(blocks))
+        ]
+        width = blocks[0][1]
+        use_cached = self.cache_kernel
+        if use_cached is None:
+            from keystone_tpu.ops.learning.weighted_ls import (
+                _device_memory_limit,
+            )
+            # cache bytes: stacked column blocks + factor bank +
+            # one (n_pad, b) transient; leave room for X/W/Y and
+            # the eigh fallback workspace
+            cache_bytes = 4 * (
+                n_pad * n_pad
+                + len(blocks) * width * width
+                + n_pad * width
+            )
+            use_cached = (
+                self.num_epochs > 1
+                and cache_bytes <= 0.6 * _device_memory_limit()
+            )
+        count_step = _count_krr_fit("cached" if use_cached else "scan")
+        count_step(
+            steps=len(order),
+            kernel_blocks=len(blocks) if use_cached else len(order),
+        )
+        with span("solver.krr.dispatch", blocks=len(order)):
+            if use_cached:
+                return _krr_cached_epoch_scan(
+                    transformer.train_X, transformer._norms,
+                    transformer.gamma, transformer.train_mask,
+                    W, Y, jnp.asarray(order, jnp.int32), self.lam,
+                    width=width,
                 )
-                # cache bytes: stacked column blocks + factor bank +
-                # one (n_pad, b) transient; leave room for X/W/Y and
-                # the eigh fallback workspace
-                cache_bytes = 4 * (
-                    n_pad * n_pad
-                    + len(blocks) * width * width
-                    + n_pad * width
-                )
-                use_cached = (
-                    self.num_epochs > 1
-                    and cache_bytes <= 0.6 * _device_memory_limit()
-                )
-            with timer.phase("epoch_scan"):
-                if use_cached:
-                    W = _krr_cached_epoch_scan(
-                        transformer.train_X, transformer._norms,
-                        transformer.gamma, transformer.train_mask,
-                        W, Y, jnp.asarray(order, jnp.int32), self.lam,
-                        width=width,
-                    )
-                else:
-                    all_starts = jnp.asarray(
-                        [blocks[i][0] for i in order], jnp.int32
-                    )
-                    W = _krr_epoch_scan(
-                        transformer.train_X, transformer._norms,
-                        transformer.gamma, transformer.train_mask,
-                        W, Y, all_starts, self.lam, width=width,
-                    )
-            timer.publish()
-            return KernelBlockLinearMapper(
-                W, self.block_size, transformer, n
+            all_starts = jnp.asarray(
+                [blocks[i][0] for i in order], jnp.int32
+            )
+            return _krr_epoch_scan(
+                transformer.train_X, transformer._norms,
+                transformer.gamma, transformer.train_mask,
+                W, Y, all_starts, self.lam, width=width,
             )
 
+    def _fit_block_loop(self, transformer, W, Y, blocks, ckpt,
+                        start_epoch, start_pos):
+        """One block update at a time from the host: host solves,
+        checkpoint ticks, callbacks, ragged widths. K(:, B) is
+        regenerated on each visit."""
         if self.cache_kernel:
-            # the cached program is the single-dispatch scan; the
-            # per-block loop below (host solves, checkpoint ticks,
-            # callbacks, ragged widths) regenerates K(:, B) each visit
+            # the cached program is the single-dispatch scan
             import warnings
 
             warnings.warn(
                 "cache_kernel=True has no effect with solve='host', "
                 "checkpoint_path, block_callback, or non-uniform block "
                 "widths — falling back to per-block kernel regeneration",
-                stacklevel=2,
+                stacklevel=3,
             )
-
+        count_step = _count_krr_fit(
+            "block" if self.solve == "device" else "host"
+        )
         done = 0
         order, order_epoch = [], -1
-        for epoch, pos, nxt in two_level_schedule(
-            self.num_epochs, len(blocks), (start_epoch, start_pos)
-        ):
-            if epoch != order_epoch:
-                order = self._epoch_order(epoch, len(blocks))
-                order_epoch = epoch
-            s, wd = blocks[order[pos]]
-            if self.solve == "device":
-                # whole block update — kernel block, residual, solve,
-                # model scatter — stays in the async dispatch stream
-                with timer.phase("block_step"):
+        with span("solver.krr.dispatch"):
+            for epoch, pos, nxt in two_level_schedule(
+                self.num_epochs, len(blocks), (start_epoch, start_pos)
+            ):
+                if epoch != order_epoch:
+                    order = self._epoch_order(epoch, len(blocks))
+                    order_epoch = epoch
+                s, wd = blocks[order[pos]]
+                if self.solve == "device":
+                    # whole block update — kernel block, residual, solve,
+                    # model scatter — stays in the async dispatch stream
                     W = _krr_block_step(
                         transformer.train_X, transformer._norms,
                         transformer.gamma, transformer.train_mask,
                         W, Y, s, self.lam, width=wd,
                     )
-            else:
-                with timer.phase("kernel_block"):
-                    K_block = transformer.train_block(s, wd)  # (n_pad, b)
-                with timer.phase("residual"):
-                    resid, K_bb = _krr_residual(K_block, W, s, width=wd)
-                    Wb_old = jax.lax.dynamic_slice_in_dim(W, s, wd, axis=0)
-                    y_b = jax.lax.dynamic_slice_in_dim(Y, s, wd, axis=0)
-                    rhs = y_b - (resid - _f32_mm(K_bb.T, Wb_old))
-                # pad rows inside the block: K_bb row/col is zero there,
-                # λI makes the system nonsingular, W stays 0 via rhs=0
-                with timer.phase("host_solve"):
-                    Wb_new = jnp.asarray(
-                        psd_solve_host(K_bb, np.asarray(rhs), self.lam),
-                        jnp.float32,
-                    )
-                with timer.phase("model_update"):
-                    W = _krr_update_model(W, Wb_new, s, width=wd)
-            done += 1
-            if ckpt is not None:
-                ckpt.tick(lambda: {
-                    "W": np.asarray(W), "epoch": nxt[0], "pos": nxt[1],
-                })
-            if self.block_callback is not None:
-                self.block_callback(done)
+                else:
+                    W = self._host_block_step(transformer, W, Y, s, wd)
+                count_step()
+                done += 1
+                if ckpt is not None:
+                    ckpt.tick(lambda: {
+                        "W": np.asarray(W), "epoch": nxt[0], "pos": nxt[1],
+                    })
+                if self.block_callback is not None:
+                    self.block_callback(done)
         if ckpt is not None:
             ckpt.clear()
-        timer.publish()
+        return W
 
-        return KernelBlockLinearMapper(
-            W, self.block_size, transformer, n
-        )
+    def _host_block_step(self, transformer, W, Y, s, wd):
+        """The reference's round trip: the column block and the residual
+        on the device, the (b, b) system on the host in float64."""
+        with span("solver.krr.kernel_block"):
+            K_block = transformer.train_block(s, wd)  # (n_pad, b)
+        with span("solver.krr.residual"):
+            resid, K_bb = _krr_residual(K_block, W, s, width=wd)
+            Wb_old = jax.lax.dynamic_slice_in_dim(W, s, wd, axis=0)
+            y_b = jax.lax.dynamic_slice_in_dim(Y, s, wd, axis=0)
+            rhs = y_b - (resid - _f32_mm(K_bb.T, Wb_old))
+        # pad rows inside the block: K_bb row/col is zero there,
+        # λI makes the system nonsingular, W stays 0 via rhs=0
+        with span("solver.krr.host_solve"):
+            Wb_new = jnp.asarray(
+                psd_solve_host(K_bb, np.asarray(rhs), self.lam),
+                jnp.float32,
+            )
+        with span("solver.krr.update"):
+            return _krr_update_model(W, Wb_new, s, width=wd)

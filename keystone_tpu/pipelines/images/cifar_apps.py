@@ -19,12 +19,14 @@ from keystone_tpu.evaluation import (
     MulticlassClassifierEvaluator,
 )
 from keystone_tpu.loaders.cifar import LabeledImages
+from keystone_tpu.observability.tracing import span
 from keystone_tpu.ops.images import (
     CenterCornerPatcher,
     Convolver,
     GrayScaler,
     ImageVectorizer,
     Pooler,
+    RandomImageTransformer,
     RandomPatcher,
     SymmetricRectifier,
 )
@@ -154,6 +156,13 @@ def random_patch_cifar_kernel(
 class RandomCifarAugmentedConfig(RandomCifarConfig):
     augment_patch_size: int = 24
     augment_copies: int = 10
+    # a 24 x 24 crop gives a 19 x 19 map: the 32 x 32 application's
+    # Pooler(13, 14) has one window on it (range(7, 19, 13)), 2·F
+    # features; 10 / 9 gives 2 x 2 windows, 8·F, the 4,096 features of
+    # 512 filters that arXiv:1602.05310 states for this pipeline. The
+    # Scala file could not be opened when this was set (PERF.md, PR 33)
+    pool_size: int = 10
+    pool_stride: int = 9
 
 
 def random_patch_cifar_augmented(
@@ -216,6 +225,62 @@ class RandomCifarAugmentedKernelConfig(RandomCifarAugmentedConfig):
     flip_chance: float = 0.5
 
 
+def augment_train(train: LabeledImages, conf: RandomCifarAugmentedKernelConfig):
+    """(crops, their ±1 label indicators): ``augment_copies`` random
+    crops an image, each flipped with ``flip_chance``, and each source
+    label repeated per crop (the reference's LabelAugmenter). Made on
+    the device where the images are."""
+    aug_size = conf.augment_patch_size
+    patcher = RandomPatcher(
+        conf.augment_copies, aug_size, aug_size, seed=conf.seed
+    )
+    flipper = RandomImageTransformer(
+        flip_chance=conf.flip_chance, seed=conf.seed + 1
+    )
+    images = flipper.apply_batch(patcher.apply_batch(train.images))
+    labels = ClassLabelIndicators(NUM_CLASSES)(
+        Dataset.from_array(
+            jnp.repeat(train.labels.array(), conf.augment_copies)
+        )
+    )
+    return images, labels
+
+
+def build_augmented_kernel_pipeline(
+    train: LabeledImages, conf: RandomCifarAugmentedKernelConfig
+) -> Pipeline:
+    """The application's predictor, lazy: crops, flips and filters are
+    made here from the training images (reference: eagerly, at
+    pipeline-construction time), the featurizer, the scaler and the
+    kernel solver fit at ``.fit()``. Scores come out unmerged: the
+    augmented evaluator merges an image's copies."""
+    with span("cifar.augment", copies=conf.augment_copies):
+        aug_images, aug_labels = augment_train(train, conf)
+    with span("cifar.filters", filters=conf.num_filters):
+        filters, whitener = build_filters(aug_images, conf)
+    aug_size = conf.augment_patch_size
+    featurizer = (
+        Convolver(
+            filters, aug_size, aug_size, NUM_CHANNELS,
+            whitener=whitener, normalize_patches=True,
+        )
+        .and_then(SymmetricRectifier(alpha=conf.alpha))
+        .and_then(Pooler(conf.pool_stride, conf.pool_size))
+        .and_then(ImageVectorizer())
+    )
+    return featurizer.and_then(StandardScaler(), aug_images).and_then(
+        KernelRidgeRegression(
+            GaussianKernelGenerator(conf.gamma),
+            conf.lam,
+            conf.block_size,
+            conf.num_epochs,
+            block_permuter=conf.seed,
+        ),
+        aug_images,
+        aug_labels,
+    )
+
+
 def random_patch_cifar_augmented_kernel(
     train: LabeledImages,
     test: LabeledImages,
@@ -225,48 +290,8 @@ def random_patch_cifar_augmented_kernel(
     regression; train crops get an extra random horizontal flip, test
     copies are merged by the augmented evaluator (reference:
     RandomPatchCifarAugmentedKernel.scala:33-120)."""
-    from keystone_tpu.ops.images import RandomImageTransformer
-
+    pipeline = build_augmented_kernel_pipeline(train, conf)
     aug_size = conf.augment_patch_size
-    patcher = RandomPatcher(
-        conf.augment_copies, aug_size, aug_size, seed=conf.seed
-    )
-    flipper = RandomImageTransformer(
-        flip_chance=conf.flip_chance, seed=conf.seed + 1
-    )
-    aug_images = flipper.apply_batch(patcher.apply_batch(train.images))
-    # LabelAugmenter equivalent: each source label repeated per crop
-    aug_labels_int = np.repeat(
-        np.asarray(train.labels.array()), conf.augment_copies
-    )
-    aug_labels = ClassLabelIndicators(NUM_CLASSES)(
-        Dataset.from_array(jnp.asarray(aug_labels_int))
-    )
-
-    filters, whitener = build_filters(aug_images, conf)
-    pipeline = (
-        Convolver(
-            filters, aug_size, aug_size, NUM_CHANNELS,
-            whitener=whitener, normalize_patches=True,
-        )
-        .and_then(SymmetricRectifier(alpha=conf.alpha))
-        .and_then(Pooler(conf.pool_stride, conf.pool_size))
-        .and_then(ImageVectorizer())
-        .and_then(Cacher())
-        .and_then(StandardScaler(), aug_images)
-        .and_then(
-            KernelRidgeRegression(
-                GaussianKernelGenerator(conf.gamma),
-                conf.lam,
-                conf.block_size,
-                conf.num_epochs,
-                block_permuter=conf.seed,
-            ),
-            aug_images,
-            aug_labels,
-        )
-    )
-
     test_patcher = CenterCornerPatcher(
         aug_size, aug_size, horizontal_flips=True
     )

@@ -142,6 +142,27 @@ def test_patchers():
     assert np.asarray(out2.array()).shape == (6, 4, 4, 3)
 
 
+@pytest.mark.parametrize("shape,size", [((5, 9, 9, 3), (4, 4)),
+                                        ((3, 8, 11, 2), (8, 5)),
+                                        ((4, 7, 6), (3, 6))])
+def test_random_patcher_is_the_loop_it_replaced(shape, size):
+    """One draw of all origins and one gather on the device give the
+    crops that the host loop gave: an ``rng.integers`` call for x and
+    one for y, crop after crop, each a slice of its image."""
+    imgs = np.random.default_rng(11).standard_normal(shape).astype(np.float32)
+    num, (px, py), seed = 3, size, 2147483659
+    rng = np.random.default_rng(seed)
+    want = []
+    for img in imgs:
+        for _ in range(num):
+            x = rng.integers(0, img.shape[0] - px + 1)
+            y = rng.integers(0, img.shape[1] - py + 1)
+            want.append(img[x:x + px, y:y + py])
+    out = RandomPatcher(num, px, py, seed=seed).apply_batch(Dataset.of(imgs))
+    assert out.n == len(want) and out.is_array
+    np.testing.assert_array_equal(np.asarray(out.array()), np.stack(want))
+
+
 def test_vectorizer_channel_major_layout():
     img = np.arange(2 * 3 * 2, dtype=np.float32).reshape(2, 3, 2)
     vec = np.asarray(channel_major_vectorize(jnp.asarray(img)))

@@ -187,3 +187,140 @@ def test_krr_device_solve_matches_host_solve():
     W_dev = np.asarray(dc.replace(base, solve="device").fit(Xd, Yd).model)
     W_host = np.asarray(dc.replace(base, solve="host").fit(Xd, Yd).model)
     np.testing.assert_allclose(W_dev, W_host, rtol=5e-4, atol=5e-5)
+
+
+# -- spans, counters and the copies a fit no longer makes (PR 33) ---------
+
+
+def _krr_counters():
+    from keystone_tpu.observability.registry import get_global_registry
+
+    out = {}
+    for family in get_global_registry().collect():
+        if family.name.startswith("keystone_solver_krr_"):
+            for s in family.samples:
+                out[(family.name, tuple(sorted(s.labels.items())))] = s.value
+    return out
+
+
+# 96 rows in blocks of 32 (three equal blocks) or 40 (a ragged last one),
+# two epochs: (estimator settings, the path, the spans inside .dispatch,
+# column blocks generated)
+@pytest.mark.parametrize("settings,path,inner,generated", [
+    (dict(block_size=32, cache_kernel=False), "scan", [], 6),
+    (dict(block_size=32, cache_kernel=True), "cached", [], 3),
+    (dict(block_size=40), "block", [], 6),
+    (dict(block_size=32, solve="host"), "host",
+     ["host_solve", "kernel_block", "residual", "update"], 6),
+])
+def test_krr_spans_and_counters_name_the_path(settings, path, inner,
+                                              generated):
+    """Once a fit: .prep, .dispatch and .converged; the per-block host
+    path's four spans once a block step; the counters say which path ran,
+    how many block steps, and how many column blocks were really
+    generated (a cached fit generates each once)."""
+    from keystone_tpu.observability.tracing import (
+        disable_tracing,
+        enable_tracing,
+    )
+
+    rng = np.random.default_rng(21)
+    X = Dataset.from_array(rng.standard_normal((96, 5)).astype(np.float32))
+    Y = Dataset.from_array(rng.standard_normal((96, 3)).astype(np.float32))
+    est = KernelRidgeRegression(
+        GaussianKernelGenerator(gamma=0.2), lam=0.3, num_epochs=2,
+        **settings)
+    steps = 2 * -(-96 // settings["block_size"])
+    before = _krr_counters()
+    tracer = enable_tracing()
+    try:
+        tracer.clear()
+        model = est.fit(X, Y)
+        names = [s.name[len("solver.krr."):] for s in tracer.recent()
+                 if s.name.startswith("solver.krr.")]
+    finally:
+        disable_tracing()
+    after = _krr_counters()
+    delta = {k: after[k] - before.get(k, 0.0) for k in after
+             if after[k] != before.get(k, 0.0)}
+    assert np.all(np.isfinite(np.asarray(model.model)))
+    assert sorted(set(names)) == sorted(
+        ["prep", "dispatch", "converged"] + inner)
+    for name in ("prep", "dispatch", "converged"):
+        assert names.count(name) == 1
+    for name in inner:
+        assert names.count(name) == steps
+    assert delta == {
+        ("keystone_solver_krr_fits_total", ()): 1,
+        ("keystone_solver_krr_path_total", (("path", path),)): 1,
+        ("keystone_solver_krr_block_steps_total", ()): steps,
+        ("keystone_solver_krr_kernel_blocks_total", ()): generated,
+    }
+
+
+def test_krr_block_steps_metric_reads_the_program_s_counters(monkeypatch):
+    """benchmark/metrics/krr_block_steps_per_fit.kfit.json, through the
+    benchmark's own reader, on a registry of this test's own: nothing
+    before a fit, then the block steps a fit."""
+    import json
+    import os
+
+    from benchmark.readers import counter_ratio
+    from keystone_tpu.observability import registry
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "metrics",
+                           "krr_block_steps_per_fit.kfit.json")) as f:
+        metric = json.load(f)
+    assert metric["reader"] == "counter_ratio"
+    monkeypatch.setattr(registry, "_global_registry",
+                        registry.MetricsRegistry())
+    assert counter_ratio.read(None, **metric["args"]) is None
+    rng = np.random.default_rng(22)
+    X = Dataset.from_array(rng.standard_normal((64, 4)).astype(np.float32))
+    Y = Dataset.from_array(rng.standard_normal((64, 2)).astype(np.float32))
+    est = KernelRidgeRegression(
+        GaussianKernelGenerator(gamma=0.2), lam=0.3, block_size=16,
+        num_epochs=1)
+    est.fit(X, Y)
+    est.fit(X, Y)
+    assert counter_ratio.read(None, **metric["args"]) == 4.0
+
+
+def test_krr_model_holds_the_rows_it_was_given_and_no_copy():
+    """With no pad row the fitted model's train set IS the array the
+    solver was given (2 GB a model at 125,000 x 4,096); pad rows are
+    zeroed in a copy as before."""
+    rng = np.random.default_rng(23)
+    x = jnp.asarray(rng.standard_normal((48, 4)).astype(np.float32))
+    whole = GaussianKernelGenerator(gamma=0.1).fit(Dataset.from_array(x))
+    assert whole.train_X is x
+    padded = GaussianKernelGenerator(gamma=0.1).fit(
+        Dataset.from_array(x, n=40))
+    assert padded.train_X is not x
+    assert np.all(np.asarray(padded.train_X)[40:] == 0.0)
+    np.testing.assert_array_equal(
+        np.asarray(padded.train_X)[:40], np.asarray(x)[:40])
+    np.testing.assert_allclose(
+        np.asarray(whole._norms), (np.asarray(x) ** 2).sum(axis=1),
+        rtol=1e-6)
+
+
+def test_krr_breakdown_without_a_fallback_is_an_error(monkeypatch):
+    """A block wider than the eigh fall-back's limit whose Cholesky
+    breaks down gives a non-finite model: fit says so and returns
+    none (duplicated rows, no ridge: K_BB is singular)."""
+    from keystone_tpu.ops.learning import block_ls
+
+    monkeypatch.setattr(block_ls, "_EIGH_FALLBACK_MAX_WIDTH", 8)
+    rng = np.random.default_rng(24)
+    x = rng.standard_normal((16, 3)).astype(np.float32)
+    x = np.concatenate([x, x])  # every row twice
+    y = rng.standard_normal((32, 2)).astype(np.float32)
+    est = KernelRidgeRegression(
+        GaussianKernelGenerator(gamma=0.1), lam=-1e-3, block_size=32,
+        num_epochs=1)
+    with pytest.raises(FloatingPointError, match="not finite"):
+        est.fit(Dataset.from_array(jnp.asarray(x)),
+                Dataset.from_array(jnp.asarray(y)))

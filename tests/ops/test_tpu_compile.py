@@ -8,6 +8,8 @@ The topology is described inside a fixture, never at import: only the
 worker that runs this file loads the TPU's library (keep such tests in
 this one file)."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
@@ -95,3 +97,86 @@ def test_folded_chunk_program_writes_no_map_at_the_published_widths(one_chip):
              if re.match(pattern, line.strip())]
     assert not taken, taken
     assert not re.search(r"f32\[\d+,27,27,[12]0000\]", text)
+
+
+def test_folded_chunk_program_compiles_at_the_augmented_crops(one_chip):
+    """The same run at its second geometry (cifar-krr-fit): 24 x 24
+    crops, one filter tile of 512, 2 x 2 sum windows of 10 at stride 9
+    that overlap by one position, so that the map is cut into nine
+    rectangles; a chunk of 15,625 of 125,000 rows."""
+    from keystone_tpu.ops.images import core, pallas_kernels
+    from keystone_tpu.workflow import api
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    fns, arrays = api.fold_rowwise(
+        (core._Convolve(6, 3, True, 10.0, False), core._Rectify(0.0, 0.25),
+         core._Pool(9, 10, None, None), core._vectorize),
+        ((shape((512, 6, 6, 3)), shape((512,)), shape((512,))),
+         (), (), ()),
+    )
+    assert len(fns) == 2  # folded: the three functions as one
+    compile_kernel = pallas_kernels.auto_interpret
+    pallas_kernels.auto_interpret = lambda interpret=None: False
+    try:
+        compiled = api._run_chunk.lower(
+            fns, 15625, arrays, shape((125000, 4096)),
+            shape((125000, 24, 24, 3)), shape((), jnp.int32),
+            shape((), jnp.int32),
+        ).compile()
+    finally:
+        pallas_kernels.auto_interpret = compile_kernel
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    assert not re.search(r"f32\[\d+,19,19,(512|1024)\]", text)  # no map
+
+
+def test_krr_block_program_and_the_roofline_metric_s_pattern(one_chip):
+    """One Gauss-Seidel block step of cifar-krr-fit at the published
+    widths (125,000 rows of 4,096 features, a block of 5,000, 10
+    classes): it fits the chip beside three kept models, and the
+    benchmark's ``krr_kernel_roofline_pct.kfit`` takes exactly one of
+    its operations for the column block — the fusion that holds the
+    cross term and writes K(:, B) — and nothing that reads it (a
+    mistaken pattern read 829% in PR 32). The solve metric's pattern
+    takes none of the row-sized operations."""
+    import json
+    import os
+
+    from keystone_tpu.ops.learning import kernel
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    n, d, b, k = 125000, 4096, 5000, 10
+    compiled = kernel._krr_block_step.lower(
+        shape((n, d)), shape((n,)), 2e-4, shape((n,)), shape((n, k)),
+        shape((n, k)), shape((), jnp.int32), 0.1, width=b,
+    ).compile()
+    memory = compiled.memory_analysis()
+    # the rows in, one (n, b) column block as the only large temporary:
+    # the exponential is fused into the cross term's output
+    assert memory.argument_size_in_bytes < 2.2e9
+    assert 4 * n * b <= memory.temp_size_in_bytes < 1.2 * 4 * n * b
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY "):]
+    ops = [line.strip() for line in entry.splitlines() if " = " in line]
+    metrics = os.path.join(os.path.dirname(__file__), "..", "..",
+                           "benchmark", "metrics")
+    names = {"rows": n, "block_size": b, "num_classes": k}
+
+    def pattern(metric):
+        with open(os.path.join(metrics, metric + ".json")) as f:
+            return re.compile(json.load(f)["args"]["pattern"].format(**names))
+
+    taken = [op for op in ops
+             if pattern("krr_kernel_roofline_pct.kfit").search(op)]
+    assert len(taken) == 1, [op[:160] for op in taken]
+    assert "krr.kernel_block/dot_general" in taken[0]
+    assert pattern("krr_kernel_device_ms_per_fit.kfit").pattern == \
+        pattern("krr_kernel_roofline_pct.kfit").pattern
+    solve = pattern("krr_solve_device_ms_per_fit.kfit")
+    rowsized = [op for op in ops if "[125000," in op]
+    assert rowsized and not any(solve.search(op) for op in rowsized)
+    assert any(solve.search(op) and "krr.solve" in op for op in ops)
