@@ -584,8 +584,9 @@ def _random_crops(imgs, x0, y0, *, num: int, px: int, py: int):
     """Crop j = imgs[j // num, x0_j : x0_j + px, y0_j : y0_j + py],
     gathered on the device where the images are: one gather of whole
     windows, a pixel's channels beside its y (one minor axis of Y·C
-    values), as ``random_patch_cifar._gather_patches`` takes its
-    patches."""
+    values), a loop of a step a crop on the chip:
+    ``random_patch_cifar._gather_windows`` takes its patches by a form
+    that has none, into another layout than crops want (PERF.md, PR 34)."""
     n, x_dim, y_dim = imgs.shape[:3]
     channels = int(np.prod(imgs.shape[3:], dtype=np.int64))
     rows = imgs.reshape(n, x_dim, y_dim * channels)

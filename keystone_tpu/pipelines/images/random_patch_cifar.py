@@ -31,11 +31,13 @@ from keystone_tpu.ops.images import (
 )
 from keystone_tpu.ops.learning import (
     BlockLeastSquaresEstimator,
+    ZCAWhitener,
     ZCAWhitenerEstimator,
 )
 from keystone_tpu.ops.stats import StandardScaler
 from keystone_tpu.ops.util.nodes import ClassLabelIndicators, MaxClassifier
 from keystone_tpu.parallel.dataset import Dataset
+from keystone_tpu.utils.precision import mm
 from keystone_tpu.workflow.api import Pipeline
 
 NUM_CLASSES = 10
@@ -60,33 +62,90 @@ class RandomCifarConfig:
     seed: int = 0
 
 
-def _normalize_rows(mat: np.ndarray, alpha: float) -> np.ndarray:
-    """Stats.normalizeRows (reference: utils/Stats.scala:112-123). The
-    centred matrix is made once and scaled in place: on 100,000 patches
-    this is host time every fit, with the chip idle under it."""
-    means = np.nan_to_num(mat.mean(axis=1))
+def _normalize_rows(mat, alpha: float):
+    """Stats.normalizeRows (reference: utils/Stats.scala:112-123), on
+    the device in the sample's own float32."""
+    means = jnp.nan_to_num(jnp.mean(mat, axis=1))
     centred = mat - means[:, None]
-    var = np.einsum("ij,ij->i", centred, centred) / (mat.shape[1] - 1)
-    sds = np.sqrt(var + alpha)
-    sds = np.where(np.isnan(sds), np.sqrt(alpha), sds)
-    centred /= sds[:, None]
-    return centred
+    var = jnp.sum(centred * centred, axis=1) / (mat.shape[1] - 1)
+    sds = jnp.sqrt(var + alpha)
+    sds = jnp.where(jnp.isnan(sds), jnp.sqrt(alpha), sds)
+    return centred / sds[:, None]
+
+
+# Windows gathered a step of the gather's scan: five steps for the
+# sample of 100,000, where a step an index was a loop of 100,000 on the
+# chip (0.24 s a fit for 0.014 now; whole, 0.012: PERF.md, PR 34). A
+# step's whole-image rows are GATHER_SLAB x X x Y*C floats (246 MB at
+# 32 x 32 x 3) on any backend, a CPU under the tests among them.
+GATHER_SLAB = 20_000
+
+
+def _windows(rows, img, x0, y0, size: int, channels: int):
+    """Window j = rows[img_j, x0_j : x0_j + size, y0_j*C : (y0_j + size)*C]
+    of ``rows`` (n, X, Y*C), with no loop over j: one gather of whole
+    images, then one one-hot product an axis, which at ``highest`` is
+    exact (every output is one input times 1.0, plus zeros)."""
+    _, X, W = rows.shape
+    whole = jnp.take(rows, img, axis=0)  # (m, X, Y*C)
+    at_x = (
+        x0[:, None, None] + jnp.arange(size)[None, :, None]
+        == jnp.arange(X)[None, None, :]
+    ).astype(rows.dtype)  # (m, size, X)
+    strips = jnp.einsum(
+        "jax,jxw->jaw", at_x, whole, precision=jax.lax.Precision.HIGHEST
+    )
+    at_y = (
+        y0[:, None, None] * channels
+        + jnp.arange(size * channels)[None, None, :]
+        == jnp.arange(W)[None, :, None]
+    ).astype(rows.dtype)  # (m, Y*C, size*C)
+    return jnp.einsum(
+        "jaw,jwb->jab", strips, at_y, precision=jax.lax.Precision.HIGHEST
+    )
 
 
 @partial(jax.jit, static_argnames=("size",))
-def _gather_patches(imgs, img, x0, y0, *, size: int):
+def _gather_windows(imgs, img, x0, y0, *, size: int):
     """Patch j = imgs[img_j, x0_j : x0_j + size, y0_j : y0_j + size, :],
-    vectorised channel-major. One gather of whole windows: indexed value
-    by value (36 index pairs a patch) it took the TPU compiler six
-    minutes (PERF.md, PR 31)."""
+    vectorised channel-major, GATHER_SLAB patches a scan step. The
+    images' rows are laid out once, outside the scan (inside it the TPU
+    compiler copied all of them every step)."""
     n, X, Y, C = imgs.shape
     rows = imgs.reshape(n, X, Y * C)  # a window's y and c are contiguous
-    patches = jax.vmap(
-        lambda i, x, y: jax.lax.dynamic_slice(
-            rows, (i, x, y * C), (1, size, size * C)
-        )[0]
-    )(img, x0, y0).reshape(-1, size, size, C)
-    return jnp.transpose(patches, (0, 2, 1, 3)).reshape(img.shape[0], -1)
+    m = img.shape[0]
+    slab = min(GATHER_SLAB, m)
+
+    def slabs(index):  # (steps, slab), the last step padded with index 0
+        return jnp.pad(index, (0, -m % slab)).reshape(-1, slab)
+
+    patches = jax.lax.map(
+        lambda at: _windows(rows, *at, size, C),
+        (slabs(img), slabs(x0), slabs(y0)),
+    ).reshape(-1, size, size, C)[:m]
+    return jnp.transpose(patches, (0, 2, 1, 3)).reshape(m, -1)
+
+
+def _draw_windows(ds: Dataset, conf: RandomCifarConfig):
+    """``Sampler(WHITENER_SAMPLE, seed)``'s draw over the windows in
+    ``Windower``'s order (image, then x, then y), on the host as the
+    configuration states it: image, x and y of each sampled window."""
+    k = conf.patch_size
+    _, X, Y, _ = ds.padded().shape
+    xs = np.arange(0, X - k + 1, conf.patch_steps)
+    ys = np.arange(0, Y - k + 1, conf.patch_steps)
+    per_image = len(xs) * len(ys)
+    total = ds.n * per_image
+    rng = np.random.default_rng(conf.seed)
+    idx = np.sort(
+        rng.choice(total, size=min(WHITENER_SAMPLE, total), replace=False)
+    )
+    img, pos = idx // per_image, idx % per_image
+    return (
+        img.astype(np.int32),
+        xs[pos // len(ys)].astype(np.int32),
+        ys[pos % len(ys)].astype(np.int32),
+    )
 
 
 def sample_patches(train_images: Dataset, conf: RandomCifarConfig):
@@ -97,43 +156,44 @@ def sample_patches(train_images: Dataset, conf: RandomCifarConfig):
     patches of one chip's 12,544 images, 729 slices stacked, do not fit
     the chip (PERF.md, PR 31); the sample is 43 MB."""
     ds = Dataset.of(train_images).to_array_mode()
-    imgs = ds.padded()
-    k = conf.patch_size
-    xs = np.arange(0, imgs.shape[1] - k + 1, conf.patch_steps)
-    ys = np.arange(0, imgs.shape[2] - k + 1, conf.patch_steps)
-    per_image = len(xs) * len(ys)
-    total = ds.n * per_image
-    rng = np.random.default_rng(conf.seed)
-    idx = np.sort(
-        rng.choice(total, size=min(WHITENER_SAMPLE, total), replace=False)
+    return _gather_windows(
+        ds.padded(), *jax.device_put(_draw_windows(ds, conf)),
+        size=conf.patch_size,
     )
-    img, pos = idx // per_image, idx % per_image
-    return _gather_patches(
-        imgs, jnp.asarray(img, jnp.int32),
-        jnp.asarray(xs[pos // len(ys)], jnp.int32),
-        jnp.asarray(ys[pos % len(ys)], jnp.int32), size=k,
-    )
+
+
+@partial(jax.jit, static_argnames=("size",))
+def _filter_bank(imgs, img, x0, y0, pick, eps, *, size: int):
+    """Everything of ``build_filters`` after the host's two draws, as
+    one program: the sampled patches, their rows normalised, the ZCA
+    fitted on them (``ZCAWhitenerEstimator.fit_single``'s arithmetic,
+    traced), the picked rows whitened, scaled to unit norm and taken
+    back through the whitener's transpose."""
+    base = _normalize_rows(_gather_windows(imgs, img, x0, y0, size=size), 10.0)
+    whitener = ZCAWhitenerEstimator(eps=eps).fit_single(base)
+    unnorm = whitener.apply(jnp.take(base, pick, axis=0))
+    norms = jnp.sqrt(jnp.sum(unnorm * unnorm, axis=1))
+    filters = mm(unnorm / (norms[:, None] + 1e-10), whitener.whitener.T)
+    return filters, whitener.whitener, whitener.means
 
 
 def build_filters(train_images: Dataset, conf: RandomCifarConfig):
     """Sample patches, normalize, fit ZCA, emit whitened filter bank
-    (reference: RandomPatchCifar.scala:45-57)."""
-    sample = sample_patches(train_images, conf)
-    base = _normalize_rows(np.asarray(sample, np.float64), 10.0)
-    whitener = ZCAWhitenerEstimator(eps=conf.whitening_epsilon).fit_single(
-        jnp.asarray(base, jnp.float32)
+    (reference: RandomPatchCifar.scala:45-57). The host draws the
+    sample's windows and the filters' rows, every call, by the
+    configuration's rule; from their one upload to the bank nothing
+    leaves the device."""
+    ds = Dataset.of(train_images).to_array_mode()
+    windows = _draw_windows(ds, conf)
+    sampled = windows[0].shape[0]
+    pick = np.random.default_rng(conf.seed).choice(
+        sampled, size=min(conf.num_filters, sampled), replace=False
+    ).astype(np.int32)
+    filters, whitener, means = _filter_bank(
+        ds.padded(), *jax.device_put((*windows, pick)),
+        conf.whitening_epsilon, size=conf.patch_size,
     )
-    rng = np.random.default_rng(conf.seed)
-    idx = rng.choice(
-        base.shape[0], size=min(conf.num_filters, base.shape[0]),
-        replace=False,
-    )
-    unnorm = np.asarray(whitener.apply(jnp.asarray(base[idx], jnp.float32)))
-    norms = np.sqrt((unnorm**2).sum(axis=1))
-    filters = (unnorm / (norms[:, None] + 1e-10)) @ np.asarray(
-        whitener.whitener
-    ).T
-    return jnp.asarray(filters, jnp.float32), whitener
+    return filters, ZCAWhitener(whitener, means)
 
 
 def build_pipeline(

@@ -180,3 +180,53 @@ def test_krr_block_program_and_the_roofline_metric_s_pattern(one_chip):
     rowsized = [op for op in ops if "[125000," in op]
     assert rowsized and not any(solve.search(op) for op in rowsized)
     assert any(solve.search(op) and "krr.solve" in op for op in ops)
+
+
+def _while_bounds(text):
+    """{while: the integer constants of its condition}: a counted
+    loop's trip count is among them."""
+    computations = {
+        m.group(1): m.group(2) for m in re.finditer(
+            r"^(?:ENTRY )?%(\S+) \(.*?\{\n(.*?)^\}", text, re.S | re.M)
+    }
+    return {
+        m.group(1): {int(c) for c in re.findall(
+            r"constant\((\d+)\)", computations.get(m.group(2), ""))}
+        for m in re.finditer(
+            r"%(\S+) = .*? while\(.*?condition=%([^,\s]+)", text)
+    }
+
+
+@pytest.mark.parametrize(
+    "images,num_filters",
+    [((12544, 32, 32, 3), 10000), ((125000, 24, 24, 3), 512)],
+    ids=["cifar-fit", "cifar-krr-fit"],
+)
+def test_filter_program_holds_no_loop_over_the_sample(
+        one_chip, images, num_filters):
+    """``build_filters``' one program at both published geometries,
+    100,000 sampled windows: it compiles, no ``while`` of it runs a step
+    a sampled patch or a step a picked row (the gather it replaced was
+    such a loop, 100,000 steps of 2.0-2.3 µs on the chip: PERF.md, PR
+    34; the scan over slabs has ten, the SVD's loops 108 at most), and
+    its temporaries stay under 3.3 GB, what the old gather asked
+    beside the kernel solver's kept models."""
+    from keystone_tpu.pipelines.images import random_patch_cifar as app
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    sample = shape((app.WHITENER_SAMPLE,), jnp.int32)
+    compiled = app._filter_bank.lower(
+        shape(images), sample, sample, sample,
+        shape((num_filters,), jnp.int32), 0.1, size=6,
+    ).compile()
+    bounds = _while_bounds(compiled.as_text())
+    assert any(
+        -(-app.WHITENER_SAMPLE // app.GATHER_SLAB) in b for b in bounds.values()
+    ), bounds  # the scan over slabs is there
+    for name, constants in bounds.items():
+        assert not constants & {app.WHITENER_SAMPLE, num_filters}, (
+            name, constants)
+        assert max(constants, default=0) <= 108, (name, constants)
+    assert compiled.memory_analysis().temp_size_in_bytes < 3.3e9

@@ -2,6 +2,7 @@
 pipelines/images/cifar/RandomPatchCifar.scala)."""
 
 import numpy as np
+import pytest
 
 from keystone_tpu.pipelines.images.random_patch_cifar import (
     RandomCifarConfig,
@@ -144,3 +145,154 @@ def test_sample_patches_is_the_sampler_over_the_windower():
         vecs = ImageVectorizer().apply_batch(Windower(steps, size).apply(images))
         want = np.asarray(Sampler(500, seed=4).apply(vecs).array())
         np.testing.assert_array_equal(got, want)
+
+
+def _host_filter_bank(images, conf, sample_size):
+    """The path ``build_filters`` took until PR 34, stated in float64
+    numpy: the sample's windows by fancy indexing, ``normalizeRows``,
+    the ZCA by SVD, the drawn rows whitened, scaled and taken back
+    through the whitener's transpose. Returns (sample, filters,
+    whitener, means)."""
+    k, steps = conf.patch_size, conf.patch_steps
+    n, X, Y, _ = images.shape
+    xs, ys = np.arange(0, X - k + 1, steps), np.arange(0, Y - k + 1, steps)
+    per_image = len(xs) * len(ys)
+    total = n * per_image
+    idx = np.sort(np.random.default_rng(conf.seed).choice(
+        total, size=min(sample_size, total), replace=False))
+    img, pos = idx // per_image, idx % per_image
+    sample = np.stack([
+        images[i, x:x + k, y:y + k, :].transpose(1, 0, 2).ravel()
+        for i, x, y in zip(img, xs[pos // len(ys)], ys[pos % len(ys)])
+    ])
+    mat = sample.astype(np.float64)
+    centred = mat - mat.mean(axis=1)[:, None]
+    var = (centred ** 2).sum(axis=1) / (mat.shape[1] - 1)
+    base = centred / np.sqrt(var + 10.0)[:, None]
+    means = base.mean(axis=0)
+    _, s, vt = np.linalg.svd(base - means, full_matrices=False)
+    scale = 1.0 / np.sqrt(s * s / (len(base) - 1.0) + conf.whitening_epsilon)
+    whitener = (vt.T * scale) @ vt
+    pick = np.random.default_rng(conf.seed).choice(
+        len(base), size=min(conf.num_filters, len(base)), replace=False)
+    unnorm = (base[pick] - means) @ whitener
+    norms = np.sqrt((unnorm ** 2).sum(axis=1))
+    filters = (unnorm / (norms[:, None] + 1e-10)) @ whitener.T
+    return sample, filters, whitener, means
+
+
+@pytest.mark.parametrize(
+    "side,steps,rows,sample_size,num_filters",
+    [
+        (32, 1, 6, 600, 16),  # RandomPatchCifar's geometry
+        (24, 1, 8, 600, 16),  # the augmented applications' crops
+        (32, 2, 6, 500, 40),
+        (24, 2, 6, 300, 700),  # more filters asked than sampled: all rows
+        (24, 3, 3, 600, 147),  # 147 windows in all: the sample is all
+    ],
+)
+def test_filter_bank_against_the_float64_host_path(
+        monkeypatch, side, steps, rows, sample_size, num_filters):
+    """One device program against the host path it replaced (float64
+    numpy, kept above): bank and whitener to 1e-5, relative Frobenius,
+    and as many filters as ``min(num_filters, sample)``."""
+    import jax.numpy as jnp
+
+    from benchmark.reference import rel_err
+    from keystone_tpu.parallel.dataset import Dataset
+    from keystone_tpu.pipelines.images import random_patch_cifar as app
+
+    rng = np.random.default_rng(side + steps)
+    images = _seeded_images(rows, rng)[:, :side, :side, :]
+    conf = RandomCifarConfig(num_filters=num_filters, patch_steps=steps, seed=11)
+    monkeypatch.setattr(app, "WHITENER_SAMPLE", sample_size)
+    filters, whitener = app.build_filters(
+        Dataset.from_array(jnp.asarray(images)), conf)
+    sample, want, want_whitener, want_means = _host_filter_bank(
+        images, conf, sample_size)
+    assert filters.shape == (min(num_filters, len(sample)), 108)
+    assert filters.dtype == jnp.float32
+    assert rel_err(np.asarray(filters), want) < 1e-5
+    assert rel_err(np.asarray(whitener.whitener), want_whitener) < 1e-5
+    assert np.abs(np.asarray(whitener.means) - want_means).max() < 1e-6
+    got = np.asarray(app.sample_patches(
+        Dataset.from_array(jnp.asarray(images)), conf))
+    np.testing.assert_array_equal(got, sample)
+
+
+@pytest.mark.parametrize("slab", [50, 10_000])
+def test_window_gather_against_fancy_indexing(monkeypatch, slab):
+    """Every window of every image (so each edge: x0 and y0 at 0 and at
+    the last position), whole and in slabs whose last one is padded,
+    equal bit for bit to the slices themselves."""
+    import jax.numpy as jnp
+
+    from keystone_tpu.pipelines.images import random_patch_cifar as app
+
+    rng = np.random.default_rng(3)
+    images = rng.normal(100, 50, (3, 10, 9, 3)).astype(np.float32)
+    k = 4
+    img, x0, y0 = (a.ravel().astype(np.int32) for a in np.meshgrid(
+        np.arange(3), np.arange(10 - k + 1), np.arange(9 - k + 1),
+        indexing="ij"))
+    assert x0.max() == 6 and y0.max() == 5 and len(img) == 126
+    monkeypatch.setattr(app, "GATHER_SLAB", slab)
+    app._gather_windows.clear_cache()  # the slab is read when tracing
+    try:
+        got = np.asarray(app._gather_windows(
+            jnp.asarray(images), img, x0, y0, size=k))
+    finally:
+        app._gather_windows.clear_cache()
+    want = np.stack([
+        images[i, x:x + k, y:y + k, :].transpose(1, 0, 2).ravel()
+        for i, x, y in zip(img, x0, y0)
+    ])
+    np.testing.assert_array_equal(got, want)
+
+
+class _HostNumpy:
+    """numpy as ``random_patch_cifar`` sees it, refusing device arrays:
+    on the CPU a read-back is a view and no transfer guard sees it."""
+
+    def __getattr__(self, name):
+        import jax
+
+        member = getattr(np, name)
+        if isinstance(member, type) or not callable(member):
+            return member
+
+        def refusing(*args, **kwargs):
+            values = list(args) + list(kwargs.values())
+            assert not any(isinstance(v, jax.Array) for v in values), name
+            return member(*args, **kwargs)
+
+        return refusing
+
+
+def test_build_filters_reads_nothing_back_and_compiles_once(monkeypatch):
+    """Between the index put and the returned arrays nothing comes back
+    to the host (the guard is what a TPU enforces; the numpy stand-in
+    is what the CPU can show), the bank and the whitener are device
+    arrays, and a second fit's other indices reuse the one program."""
+    import jax
+    import jax.numpy as jnp
+
+    from keystone_tpu.parallel.dataset import Dataset
+    from keystone_tpu.pipelines.images import random_patch_cifar as app
+
+    images = Dataset.from_array(jnp.asarray(
+        _seeded_images(5, np.random.default_rng(8))))
+    monkeypatch.setattr(app, "WHITENER_SAMPLE", 400)
+    monkeypatch.setattr(app, "np", _HostNumpy())
+    before = app._filter_bank._cache_size()
+    banks = []
+    with jax.transfer_guard_device_to_host("disallow"):
+        for seed in (1, 2, 3):
+            conf = RandomCifarConfig(num_filters=24, seed=seed)
+            filters, whitener = app.build_filters(images, conf)
+            assert isinstance(filters, jax.Array)
+            assert isinstance(whitener.whitener, jax.Array)
+            assert isinstance(whitener.means, jax.Array)
+            banks.append(filters)
+    assert app._filter_bank._cache_size() - before == 1
+    assert not np.allclose(np.asarray(banks[0]), np.asarray(banks[1]))
