@@ -8,8 +8,8 @@ KeystoneML's operator decisions (auto-caching, solver selection) run on
   (``RegistryHistogram``: Prometheus ``le`` buckets that aggregate
   exactly across scrapes and replicas), built on the
   ``Counter``/``LatencyRecorder`` primitives in ``utils/profiling.py``.
-  ``ServingMetrics`` registers itself here; the executor, auto-cache
-  profiler, ``PhaseTimer``, and the request gateway publish here.
+  ``ServingMetrics`` registers itself here; the executor, the solvers,
+  the runtime's compile listeners and the request gateway publish here.
 - ``span`` / ``Tracer`` (tracing.py): the one span call. Always a
   ``ks:<name>`` TraceMe on the JAX profiler's clock (recorded while a
   profiler session runs); with ``enable_tracing()`` also a ring of
@@ -23,7 +23,8 @@ KeystoneML's operator decisions (auto-caching, solver selection) run on
 
 The serving engine's per-bucket compile/dispatch counters, the
 micro-batcher's queue depth and request latency, workflow executor node
-spans, and auto-cache phase timings all land here, so the bucket
+spans, the auto-cache profile's spans and the runtime's compile counts
+all land here, so the bucket
 autoscaler (``serving/autoscale.py``) and any external scraper read one
 consistent surface.
 """

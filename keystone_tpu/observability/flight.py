@@ -52,7 +52,13 @@ def debugz_status(trace_id: Optional[str] = None) -> Dict:
     tail-sampled into a flight record, but the fleet router's
     cross-process stitch (``observability/stitch.py``) still needs
     their span tree while the ring holds it — pinned forensics when
-    they exist, the ring as the fallback."""
+    they exist, the ring as the fallback. ``"compile_log"`` is the
+    runtime's record of this process's last compilation phases
+    (``parallel/runtime.py: compile_log``): which function was traced,
+    lowered, loaded from the cache or compiled, for how long, and which
+    ``keystone_tpu`` frame asked."""
+    from keystone_tpu.parallel.runtime import compile_log
+
     records: List[FlightRecord] = []
     for rec in recorders():
         records.extend(rec.records())
@@ -65,6 +71,7 @@ def debugz_status(trace_id: Optional[str] = None) -> Dict:
             s.to_dict() for s in get_tracer().spans_for_trace(trace_id)
         ]
     doc["records"] = [r.to_dict() for r in records]
+    doc["compile_log"] = compile_log()
     return doc
 
 

@@ -34,10 +34,13 @@ runtime and touches no network.
 
 from __future__ import annotations
 
+import collections
+import functools
 import logging
 import os
 import sys
-from typing import IO, Optional, Sequence, Tuple
+import threading
+from typing import IO, Any, Deque, Dict, List, Optional, Sequence, Tuple
 
 import jax
 import numpy as np
@@ -70,6 +73,249 @@ _cache_dir: Optional[str] = None
 _aot_dir: Optional[str] = None
 
 
+# -- compilation telemetry ---------------------------------------------------
+
+# the last phases kept for compile_log(): one small record each
+COMPILE_LOG_CAPACITY = 1024
+
+# jax.monitoring's timed phases of one compilation (jax/_src/dispatch.py:
+# a scalar event at a phase's entry, a duration event at its exit, same
+# thread, LIFO) -> the phase's name here
+_COMPILE_PHASES = {
+    "/jax/core/compile/jaxpr_trace_duration": "trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+    "/jax/core/compile/backend_compile_duration": "compile",
+}
+# fired inside the backend phase when the executable came from the
+# persistent cache (jax/_src/compiler.py)
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
+
+class _OpenPhase:
+    """A compilation phase between its entry and its exit event."""
+
+    __slots__ = (
+        "phase", "fun", "start_s", "owner", "where", "span", "nested_s",
+        "cache_hit",
+    )
+
+    def __init__(self, phase, fun, start_s, owner, where, span):
+        self.phase = phase
+        self.fun = fun
+        self.start_s = start_s  # epoch seconds, jax's own reading
+        self.owner = owner
+        self.where = where
+        self.span = span
+        self.nested_s = 0.0  # seconds of the phases nested in this one
+        self.cache_hit = False
+
+
+def _asker(frame) -> Tuple[str, str]:
+    """Who asked for a compilation: ``("program", "module:function")``
+    of the innermost ``keystone_tpu`` frame on the calling thread's
+    stack, else ``("other", "")`` (a harness's generator, a plain
+    reference, user code). This module asks for no compilation, so its
+    own frames (the listener's) do not count."""
+    while frame is not None:
+        module = frame.f_globals.get("__name__", "")
+        if module.startswith("keystone_tpu.") and module != __name__:
+            code = frame.f_code
+            return "program", "%s:%s" % (
+                module, getattr(code, "co_qualname", code.co_name)
+            )
+        frame = frame.f_back
+    return "other", ""
+
+
+def _guarded(listener):
+    """A listener that raises is caught and counted, never propagated
+    into the compilation that fired it."""
+
+    @functools.wraps(listener)
+    def safe(self, event, *args, **kwargs):
+        try:
+            listener(self, event, *args, **kwargs)
+        except Exception:
+            logger.debug("compile listener failed on %s", event,
+                         exc_info=True)
+            try:
+                *_, errors = self.families()
+                errors.inc((listener.__name__,))
+            except Exception:
+                pass
+
+    return safe
+
+
+class _CompileTelemetry:
+    """The ``jax.monitoring`` listeners behind the
+    ``keystone_runtime_*`` families, ``compile_log()`` and the
+    ``runtime.trace`` / ``.lower`` / ``.compile`` spans. The events fire
+    where jax traces, lowers or compiles and never per dispatch, so a
+    warm step pays nothing; the frame walk, the thread-local stack and
+    the log's lock are here and nowhere on a hot path."""
+
+    def __init__(self):
+        self._local = threading.local()
+        self._lock = threading.Lock()
+        self._log: Deque[Dict[str, Any]] = (
+            collections.deque(maxlen=COMPILE_LOG_CAPACITY)
+        )  # guarded-by: _lock
+
+    @staticmethod
+    def families():
+        """(requests, backend seconds, trace/lower seconds, listener
+        errors) of the global registry: get-or-create, so that a
+        registry reset between two compilations loses no event."""
+        from keystone_tpu.observability.registry import get_global_registry
+
+        reg = get_global_registry()
+        return (
+            reg.counter(
+                "keystone_runtime_compile_requests_total",
+                "backend-compile phases: XLA ran (compiled) or the "
+                "executable came from the persistent cache (cache_hit)",
+                labelnames=("outcome", "owner"),
+            ),
+            reg.counter(
+                "keystone_runtime_backend_seconds_total",
+                "seconds of backend-compile phases (for a cache_hit: "
+                "retrieval and deserialisation)",
+                labelnames=("outcome", "owner"),
+            ),
+            reg.counter(
+                "keystone_runtime_trace_lower_seconds_total",
+                "self seconds of jaxpr tracing and of lowering to MLIR",
+                labelnames=("phase", "owner"),
+            ),
+            reg.counter(
+                "keystone_runtime_listener_errors_total",
+                "compile listeners that raised (caught, not propagated)",
+                labelnames=("listener",),
+            ),
+        )
+
+    def _stack(self) -> List[_OpenPhase]:
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
+
+    @_guarded
+    def on_entry(self, event: str, value: float, **kwargs) -> None:
+        phase = _COMPILE_PHASES.get(event)
+        if phase is None:
+            return
+        from keystone_tpu.observability.tracing import get_tracer
+
+        fun = str(kwargs.get("fun_name", ""))
+        owner, where = _asker(sys._getframe(1))
+        span = get_tracer().start_span("runtime." + phase, fun=fun)
+        self._stack().append(
+            _OpenPhase(phase, fun, float(value), owner, where, span)
+        )
+
+    @_guarded
+    def on_event(self, event: str, **kwargs) -> None:
+        if event != _CACHE_HIT_EVENT:
+            return
+        for open_phase in reversed(self._stack()):
+            if open_phase.phase == "compile":
+                open_phase.cache_hit = True
+                return
+
+    @_guarded
+    def on_exit(self, event: str, seconds: float, **kwargs) -> None:
+        phase = _COMPILE_PHASES.get(event)
+        if phase is None:
+            return
+        from keystone_tpu.observability.tracing import get_tracer
+
+        seconds = float(seconds)
+        stack = self._stack()
+        # LIFO: the innermost open phase of this kind; one above it
+        # whose exit never came (its listener raised) is closed on the way
+        for i in range(len(stack) - 1, -1, -1):
+            if stack[i].phase == phase:
+                break
+        else:
+            return  # its entry was not seen: nothing to close or count
+        for abandoned in reversed(stack[i + 1:]):
+            get_tracer().end_span(abandoned.span)
+        done = stack[i]
+        del stack[i:]
+        outcome = None
+        if phase == "compile":
+            outcome = "cache_hit" if done.cache_hit else "compiled"
+            done.span.set_attr("outcome", outcome)
+        get_tracer().end_span(done.span)
+        # nested phases (an outer jit's trace contains its callees')
+        # count once: each phase its own seconds, never the sum
+        self_s = max(seconds - done.nested_s, 0.0)
+        if stack:
+            stack[-1].nested_s += seconds
+        requests, backend_s, trace_lower_s, _ = self.families()
+        if phase == "compile":
+            requests.inc((outcome, done.owner))
+            backend_s.inc((outcome, done.owner), self_s)
+        else:
+            trace_lower_s.inc((phase, done.owner), self_s)
+        record = {
+            "fun_name": done.fun,
+            "phase": phase,
+            "outcome": outcome,
+            "seconds": seconds,
+            "self_seconds": self_s,
+            "start_s": done.start_s,
+            "owner": done.owner,
+            "where": done.where,
+        }
+        with self._lock:
+            self._log.append(record)
+
+    def log(self) -> List[Dict[str, Any]]:
+        with self._lock:
+            return [dict(r) for r in self._log]
+
+
+_telemetry: Optional[_CompileTelemetry] = None
+
+
+def install_compile_telemetry() -> None:
+    """Count, name and time every trace, lowering, cache load and XLA
+    compile of this process from inside it (idempotent; called by
+    ``setup_compilation_cache``, so every entry point that sets the
+    cache up has it): the ``keystone_runtime_*`` counter families
+    (registered here, so a process that compiled nothing reads 0 and not
+    nothing), ``compile_log()`` and the ``runtime.trace`` /
+    ``runtime.lower`` / ``runtime.compile`` spans. One listener set,
+    through the public ``jax.monitoring`` register functions."""
+    global _telemetry
+    if _telemetry is not None:
+        return
+    from jax import monitoring
+
+    telemetry = _CompileTelemetry()
+    telemetry.families()
+    monitoring.register_scalar_listener(telemetry.on_entry)
+    monitoring.register_event_listener(telemetry.on_event)
+    monitoring.register_event_duration_secs_listener(telemetry.on_exit)
+    _telemetry = telemetry
+
+
+def compile_log() -> List[Dict[str, Any]]:
+    """The last ``COMPILE_LOG_CAPACITY`` compilation phases, oldest
+    first — "which step recompiled, and who asked": ``fun_name``,
+    ``phase`` (``trace`` | ``lower`` | ``compile``), ``outcome``
+    (``compiled`` | ``cache_hit``; None for a trace or a lowering),
+    ``seconds`` as jax reports them and ``self_seconds`` (less the
+    phases nested inside), ``start_s`` (epoch), ``owner`` (``program``
+    when a ``keystone_tpu`` frame asked, else ``other``) and ``where``
+    (that frame as ``module:function``). Empty until
+    ``install_compile_telemetry``. Served under ``/debugz``."""
+    return [] if _telemetry is None else _telemetry.log()
+
+
 def setup_compilation_cache(min_compile_time_secs: float = 0.0) -> str:
     """Wire up JAX's persistent XLA compilation cache (idempotent).
 
@@ -89,6 +335,7 @@ def setup_compilation_cache(min_compile_time_secs: float = 0.0) -> str:
 
     Returns the cache dir in use."""
     global _cache_dir
+    install_compile_telemetry()
     if _cache_dir is not None:
         return _cache_dir
     cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")

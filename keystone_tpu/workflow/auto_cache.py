@@ -182,29 +182,22 @@ def profile_nodes(
     (reference: generalizeProfiles:104 — per-node least squares of
     time/memory vs scale).
 
-    Each scale pass is timed through a ``PhaseTimer`` published into the
-    global ``MetricsRegistry``
-    (``keystone_phase_seconds_total{timer="auto_cache_profile"}``) and
-    wrapped in a tracer span, so the cost the optimizer itself pays to
-    decide cache placement is visible on the same plane as the serving
-    numbers it optimizes for."""
-    from keystone_tpu.observability.tracing import get_tracer
-    from keystone_tpu.utils.profiling import PhaseTimer
+    Each scale pass is one ``auto_cache.profile`` span (attrs ``scale``,
+    ``nodes``), so the cost the optimizer itself pays to decide cache
+    placement is on the profiler's clock and in ``/tracez`` like every
+    other span of the package."""
+    from keystone_tpu.observability.tracing import span
 
-    timer = PhaseTimer("auto_cache_profile")
     per_scale: Dict[int, _ScaledProfiler] = {}
     for scale in scales:
         prof = _ScaledProfiler(graph, scale)
-        with timer.phase(f"scale_{scale}"), get_tracer().span(
-            "auto_cache.profile", scale=scale, nodes=len(nodes)
-        ):
+        with span("auto_cache.profile", scale=scale, nodes=len(nodes)):
             for n in nodes:
                 try:
                     prof.execute(n)
                 except _SourceDependent:
                     continue
         per_scale[scale] = prof
-    timer.publish()
 
     profiles: Dict[NodeId, Profile] = {}
     for n in nodes:

@@ -35,6 +35,14 @@ else:
 
 import pytest  # noqa: E402
 
+# Every entry point that sets the compile cache up installs the
+# runtime's compile listeners, and some tests call one: install them for
+# all, so that what a test sees of them (``runtime.*`` spans in the ring
+# of an enabled tracer) does not depend on which test ran before it.
+from keystone_tpu.parallel import runtime as _runtime  # noqa: E402
+
+_runtime.install_compile_telemetry()
+
 
 def pytest_collection_modifyitems(config, items):
     """One shared gate for @pytest.mark.needs_mesh8 — sharded tests skip

@@ -3,7 +3,11 @@
 import json
 import threading
 
+from profiler_events import parent_names as _parent_names
+from profiler_events import profiled as _profiled
+
 from keystone_tpu.observability.tracing import (
+    DEFAULT_CAPACITY,
     Tracer,
     disable_tracing,
     enable_tracing,
@@ -261,6 +265,7 @@ def test_enable_tracing_capacity_swap_is_atomic_with_writers():
         stop.set()
         for t in threads:
             t.join()
+        enable_tracing(capacity=DEFAULT_CAPACITY)  # for the tests after
         disable_tracing()
         tr.clear()
     assert errors == []
@@ -276,6 +281,7 @@ def test_enable_tracing_preserves_recent_spans_across_resize():
         assert any(s.name == "keep-me" for s in tr.recent())
         assert tr._ring.maxlen == 16
     finally:
+        enable_tracing(capacity=DEFAULT_CAPACITY)  # for the tests after
         disable_tracing()
         tr.clear()
 
@@ -368,51 +374,6 @@ class _NoLock:
 
     def __exit__(self, *exc):
         return False
-
-
-def _profiled(tmp_path, fn):
-    """Run ``fn`` under a profiler session; the ``ks:`` events of the
-    calling thread as (start ns, end ns, name), parents before children."""
-    import glob
-
-    import jax
-    from jax.profiler import ProfileData
-
-    options = jax.profiler.ProfileOptions()
-    options.python_tracer_level = 0
-    jax.profiler.start_trace(str(tmp_path), profiler_options=options)
-    try:
-        with jax.profiler.TraceAnnotation("test:calling-thread"):
-            fn()
-    finally:
-        jax.profiler.stop_trace()
-    (path,) = glob.glob(
-        str(tmp_path / "plugins" / "profile" / "*" / "*.xplane.pb")
-    )
-    for plane in ProfileData.from_file(path).planes:
-        for line in plane.lines:
-            events = [
-                (int(e.start_ns), int(e.start_ns + e.duration_ns), e.name)
-                for e in line.events
-            ]
-            if any(n == "test:calling-thread" for _, _, n in events):
-                return sorted(
-                    (e for e in events if e[2].startswith("ks:")),
-                    key=lambda e: (e[0], -e[1]),
-                )
-    raise AssertionError("the calling thread's line is not in the trace")
-
-
-def _parent_names(events):
-    """name of each event's innermost enclosing event (None at the top),
-    in the events' order."""
-    out, stack = [], []
-    for s, e, name in events:
-        while stack and stack[-1][1] <= s:
-            stack.pop()
-        out.append(stack[-1][2] if stack else None)
-        stack.append((s, e, name))
-    return out
 
 
 def _count(events, name):
@@ -571,8 +532,11 @@ def test_bucketed_batch_spans_do_not_grow_with_items(tmp_path, monkeypatch):
         sub = tmp_path / str(n)
         sub.mkdir()
         events = _profiled(sub, _bucketed(monkeypatch, items))
+        # the first pass compiles (``ks:runtime.*``: the runtime's
+        # compile spans); what is held here is the workflow's own
         counts[n] = {
             name: _count(events, name) for name in {e[2] for e in events}
+            if not name.startswith("ks:runtime.")
         }
     want = {"ks:workflow.upload": 1, "ks:workflow.stack": 3,
             "ks:workflow.apply": 3, "ks:workflow.slice": 3}
@@ -644,7 +608,9 @@ def test_dataset_item_paths_carry_one_span_each():
         assert _counter("keystone_workflow_h2d_bytes_total") == 4 * 12
         arrays.items()
         arrays.map(lambda x: x)
-        names = [s.name for s in tr.recent()]
+        # (a first run compiles: the runtime's own spans aside)
+        names = [s.name for s in tr.recent()
+                 if not s.name.startswith("runtime.")]
         assert names.count("workflow.to_array") == 1
         assert names.count("workflow.map_items") == 1
         # items() once for itself, once inside map

@@ -8,7 +8,6 @@ import numpy as np
 import pytest
 
 from keystone_tpu.parallel.dataset import Dataset
-from keystone_tpu.utils.profiling import PhaseTimer
 from keystone_tpu.workflow.api import Pipeline, Transformer
 from keystone_tpu.workflow.executor import PipelineEnv
 
@@ -71,30 +70,39 @@ def test_prefix_state_persistence_across_reset(tmp_path, mesh8):
     )
 
 
-def test_phase_timer_and_instrumentation(mesh8):
-    timer = PhaseTimer("test")
-    with timer.phase("work"):
-        pass
-    assert "work" in timer.times
-
+def test_auto_cache_profile_spans_and_instrumentation(mesh8):
     from keystone_tpu.observability.tracing import (
         disable_tracing,
         enable_tracing,
         get_tracer,
     )
     from keystone_tpu.ops.stats import LinearRectifier
+    from keystone_tpu.workflow.auto_cache import profile_nodes
+    from keystone_tpu.workflow.graph import EMPTY_GRAPH
+    from keystone_tpu.workflow.operators import DatasetOperator
 
     pipe = LinearRectifier(0.0).to_pipeline()
     result = pipe.apply(np.ones((4, 3), np.float32))
+    ds = Dataset.of(np.ones((8, 2), np.float32))
+    graph, data = EMPTY_GRAPH.add_node(DatasetOperator(ds), ())
+    graph, node = graph.add_node(LinearRectifier(0.0), (data,))
+    graph, _ = graph.add_sink(node)
     tracer = enable_tracing()
     tracer.clear()
     try:
+        profiles = profile_nodes(graph, [data, node], scales=(2, 4))
         result.get()
     finally:
         disable_tracing()
+    spans = get_tracer().recent()
+    # the optimizer's own cost: one span a scale pass, and no second timer
+    passes = [s for s in spans if s.name == "auto_cache.profile"]
+    assert [s.attrs["scale"] for s in passes] == [2, 4]
+    assert all(s.attrs["nodes"] == 2 and s.duration_s > 0 for s in passes)
+    assert profiles[node].ns >= 0
     # per-node wall time is the node span's duration: one span per node
     # that did work, opened around the node's own batch_transform
-    nodes = [s for s in get_tracer().recent() if s.name.startswith("node:")]
+    nodes = [s for s in spans if s.name.startswith("node:")]
     assert [s.name for s in nodes] == ["node:LinearRectifier"]
     assert nodes[0].duration_s > 0 and nodes[0].attrs["node_id"]
 
