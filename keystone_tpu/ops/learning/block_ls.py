@@ -13,7 +13,8 @@ over the mesh's data axis) instead of a Seq of per-block RDDs; a block is a
 static column slice. Each block update is a single jitted program:
 
     R⁺   = R + X_b W_b            (undo this block's contribution)
-    G    = X_bᵀ X_b               (per-shard MXU matmul + psum over "data")
+    G    = X_bᵀ X_b               (per-shard MXU matmuls + psums over "data";
+                                   the upper block triangle only: _sym_gram)
     W_b' = (G + λI)⁻¹ X_bᵀ R⁺      (f64 host solve — see hostsolve.py)
     R    = R⁺ − X_b W_b'
 
@@ -37,8 +38,10 @@ Observability: host spans ``solver.prep``, then per block step
 ``jax.named_scope`` names ``solver.residual_plus`` / ``solver.gram`` /
 ``solver.rhs`` / ``solver.solve`` / ``solver.residual`` / ``solver.prep``;
 counters ``keystone_solver_fits_total``, ``_block_steps_total``,
-``_gram_builds_total`` (Grams really built), ``_factor_reuses_total``
-(hostsolve.py has the host solve's).
+``_gram_builds_total`` (Grams really built), ``_gram_pairs_computed_total``
+over ``_gram_pairs_total`` (the share of a full product's column pairs
+those Grams multiplied), ``_factor_reuses_total`` (hostsolve.py has the
+host solve's).
 """
 
 from __future__ import annotations
@@ -82,6 +85,61 @@ def _f32_mm(a, b):
         a, b, (((a.ndim - 1,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
         precision=jax.lax.Precision.HIGHEST if f32_in else None,
+    )
+
+
+# Widest block whose Gram is still one full product (``_sym_gram``).
+_GRAM_LEAF = 512
+
+
+def _gram_cut(width: int) -> int:
+    """Where ``_sym_gram`` cuts a block of ``width`` columns in two: the
+    multiple of 128 (one MXU width) nearest the middle, the larger half
+    first on a tie; 0 for a block at or under the leaf, which is not
+    cut."""
+    return 0 if width <= _GRAM_LEAF else 128 * ((width + 128) // 256)
+
+
+def _gram_pairs(width: int):
+    """(column pairs ``_sym_gram`` multiplies, column pairs of the whole
+    Gram) for a block of ``width`` columns: Σ over its products of left
+    width × right width, and width². Follows the same cuts."""
+    cut = _gram_cut(width)
+    if not cut:
+        return width * width, width * width
+    return (
+        _gram_pairs(cut)[0] + cut * (width - cut)
+        + _gram_pairs(width - cut)[0],
+        width * width,
+    )
+
+
+def _sym_gram(Xb):
+    """X_bᵀX_b from its upper block triangle, for every solve that forms
+    a block Gram (f32 accumulation and ``_f32_mm``'s precision policy:
+    f32 rows at HIGHEST, bf16 rows on the native path).
+
+    A block wider than ``_GRAM_LEAF`` is cut in two (``_gram_cut``) and
+        G = [[sym(X₁), X₁ᵀX₂], [(X₁ᵀX₂)ᵀ, sym(X₂)]]
+    recursively; at or under the leaf, the one full product. Every entry
+    is the same dot product over the same rows at the same precision as
+    in the full product, the lower off-diagonal blocks are the exact
+    transposes of the upper, and the MXU work is (1 + 2^−d)/2 of the
+    full product after d cuts (0.5625 at width 4,096). The column slices
+    fuse into the products (no row-sized copy), and each product's
+    contraction over a sharded example axis is still a per-shard MXU
+    matmul + a psum over the data axis."""
+    cut = _gram_cut(Xb.shape[1])
+    if not cut:
+        return _f32_mm(Xb.T, Xb)
+    X1, X2 = Xb[:, :cut], Xb[:, cut:]
+    G12 = _f32_mm(X1.T, X2)
+    return jnp.concatenate(
+        [
+            jnp.concatenate([_sym_gram(X1), G12], axis=1),
+            jnp.concatenate([G12.T, _sym_gram(X2)], axis=1),
+        ],
+        axis=0,
     )
 
 
@@ -189,9 +247,12 @@ def _block_step(X, R, Wb, mu, mask, start, lam, *, width: int, n: int,
 
 def _gram_rhs(Xb, mu_b, R_plus, n):
     """The block's centered Gram and right-hand side (traced inside the
-    block programs), under the names a device trace finds them by."""
+    block programs), under the names a device trace finds them by. Of
+    X_bᵀX_b the upper block triangle is multiplied and the lower blocks
+    are its transposes (``_sym_gram``); the centering follows the
+    assembly."""
     with jax.named_scope("solver.gram"):
-        gram = _f32_mm(Xb.T, Xb) - n * jnp.outer(mu_b, mu_b)
+        gram = _sym_gram(Xb) - n * jnp.outer(mu_b, mu_b)
     return gram, _rhs(Xb, mu_b, R_plus)
 
 
@@ -221,7 +282,10 @@ def _block_stats(X, R, Wb, mu, mask, start, *, width: int, n: int):
         rhs_c = X_bᵀR⁺ − μ_b·(1ᵀR⁺)
     (pad rows of X and R are zero, so sums over all rows equal sums over
     valid rows). One XLA program; the contractions over the sharded example
-    axis lower to per-shard MXU matmuls + a psum over the "data" axis.
+    axis lower to per-shard MXU matmuls + a psum over the "data" axis. The
+    Gram is built from its upper block triangle: square products of column
+    slices of ``X`` itself, the lower blocks their transposes
+    (``_sym_gram``).
     ``start`` is traced so every equal-width block shares this compilation.
     """
     Xb, mu_b, R_plus = _block_residual_plus(X, R, Wb, mu, mask, start, width)
@@ -334,10 +398,27 @@ def _host_block_rebuild(Xb, R, Wb, mask, *, n: int):
     return R - contrib, mu_b
 
 
+def _count_gram_pairs(widths: Sequence[int], times: int = 1) -> None:
+    """Count, for ``times`` Grams of each of ``widths``, the column pairs
+    ``_sym_gram`` multiplies beside those of the whole Grams (their ratio
+    is the share of the full product's MXU work a fit spends)."""
+    pairs = [_gram_pairs(w) for w in widths]
+    reg = get_global_registry()
+    reg.counter(
+        "keystone_solver_gram_pairs_computed_total",
+        "column pairs multiplied for block Grams (upper block triangle)",
+    ).inc(by=times * sum(computed for computed, _ in pairs))
+    reg.counter(
+        "keystone_solver_gram_pairs_total",
+        "column pairs of the block Grams built (width squared a Gram)",
+    ).inc(by=times * sum(whole for _, whole in pairs))
+
+
 def _count_fit() -> Callable[..., None]:
     """Count one fit started; the callable it returns counts one block
-    step of that fit, and with it either the Gram the step built or,
-    with ``reused_factor``, the kept factor it solved against."""
+    step of that fit on a block of ``width`` columns, and with it either
+    the Gram the step built (and its column pairs) or, with
+    ``reused_factor``, the kept factor it solved against."""
     reg = get_global_registry()
     reg.counter(
         "keystone_solver_fits_total", "block least-squares fits started"
@@ -355,9 +436,13 @@ def _count_fit() -> Callable[..., None]:
         "block steps solved against a factor kept from an earlier sweep",
     )
 
-    def count_step(reused_factor: bool = False) -> None:
+    def count_step(width: int, reused_factor: bool = False) -> None:
         steps.inc()
-        (reuses if reused_factor else grams).inc()
+        if reused_factor:
+            reuses.inc()
+        else:
+            grams.inc()
+            _count_gram_pairs((width,))
 
     return count_step
 
@@ -637,7 +722,7 @@ class BlockLeastSquaresEstimator(LabelEstimator):
                     R = _residual_update(
                         X, R_plus, Wb[s], mu, mask, s, width=w
                     )
-            count_step(reused_factor=kept is not None)
+            count_step(w, reused_factor=kept is not None)
             done += 1
             if ckpt is not None:
                 ckpt.tick(lambda: snapshot(*nxt))
@@ -756,7 +841,7 @@ class BlockLeastSquaresEstimator(LabelEstimator):
                         it == self.num_iter - 1 and bi == nb - 1
                     ),
                 )
-            count_step()
+            count_step(widths[bi])
             del Xb  # release this slab's HBM as soon as XLA is done
             limiter.add(Wb[bi])
             done += 1

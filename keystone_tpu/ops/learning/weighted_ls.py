@@ -22,7 +22,10 @@ driver round trip, no distributed System.gc(). That is ``solve="chol"``.
 ``solve="pcg"`` (what ``auto`` takes at wide blocks) never forms a class
 covariance: all C systems share one matrix-free preconditioned CG
 (``_pcg_block_core``). Its statistics read the original rows through
-one-hot GEMMs; its matvec needs each row with its own class's vector
+one-hot GEMMs and one population Gram X_bᵀX_b, of which the upper block
+triangle is multiplied and the lower blocks are its transposes
+(``block_ls._sym_gram``; the chol path's ``_pop_stats`` builds the same
+Gram the same way); its matvec needs each row with its own class's vector
 only, and reads one of two row layouts, chosen a fit from what the
 estimator can see (``_sorted_layout``): ``sorted`` — the block's rows
 gathered once a block step into class order, tiles of consecutive rows
@@ -42,9 +45,11 @@ names ``wls.setup`` / ``wls.stats`` / ``wls.precond`` / ``wls.sort`` /
 ``wls.cg`` / ``wls.update``; counters ``keystone_solver_wls_fits_total``,
 ``keystone_solver_wls_path_total{solve,layout}`` (the path ``auto``
 took), ``keystone_solver_wls_sorted_fits_total`` (fits whose matvec ran
-on sorted rows) and ``keystone_solver_wls_pcg_iterations_total`` (the
+on sorted rows), ``keystone_solver_wls_pcg_iterations_total`` (the
 iterations a fit reports, added where ``convergence_check`` reads them
-anyway).
+anyway) and block_ls's ``keystone_solver_gram_pairs_computed_total`` /
+``_gram_pairs_total`` (the column pairs a fit's population Grams
+multiply, beside those of the whole Grams).
 """
 
 from __future__ import annotations
@@ -59,7 +64,12 @@ import numpy as np
 
 from keystone_tpu.observability.registry import get_global_registry
 from keystone_tpu.observability.tracing import span
-from keystone_tpu.ops.learning.block_ls import BlockLinearMapper, _f32_mm
+from keystone_tpu.ops.learning.block_ls import (
+    BlockLinearMapper,
+    _count_gram_pairs,
+    _f32_mm,
+    _sym_gram,
+)
 from keystone_tpu.parallel.dataset import Dataset
 from keystone_tpu.workflow.api import LabelEstimator
 
@@ -133,7 +143,7 @@ def _group_rows(X, Y, idx, wt, joint_label_mean):
 def _pop_stats(X, R, mask, start, *, width, n):
     Xb = jax.lax.dynamic_slice_in_dim(X, start, width, axis=1)
     pop_mean = jnp.einsum("nb->b", Xb * mask[:, None]) / n
-    pop_cov = _f32_mm(Xb.T, Xb) / n - jnp.outer(pop_mean, pop_mean)
+    pop_cov = _sym_gram(Xb) / n - jnp.outer(pop_mean, pop_mean)
     pop_xtr = _f32_mm(Xb.T, R) / n
     return pop_mean, pop_cov, pop_xtr
 
@@ -376,7 +386,10 @@ def _pcg_block_core(X, R, P, Wb, inv_counts, valid, start, w, lam,
     - the statistics' per-class contractions ride ONE-HOT GEMMs: with
       P (n, C) the 0/1 class-membership matrix, classMean = PᵀX_b and
       resLocal = (R ⊙ P)·1 — no host-side index building, no per-chunk
-      padding pathology for skewed classes (ADVICE r3);
+      padding pathology for skewed classes (ADVICE r3); the population
+      Gram X_bᵀX_b is built from its upper block triangle
+      (``block_ls._sym_gram``: 0.5625 of the full product's MXU work
+      at b = 4,096, every entry the same dot product);
     - the CG matvec's class-restricted products, z_i = x_i·v_{y_i} and
       Σ_{i in c} x_i z_i, need each row with its OWN class's vector
       only. With ``sort`` = (order, kcls) from ``_class_sorted_rows``
@@ -456,8 +469,8 @@ def _pcg_block_core(X, R, P, Wb, inv_counts, valid, start, w, lam,
     # -- population stats + per-class moments (pad rows of X and R are
     # zero by the Dataset padding contract) -------------------------------
     with jax.named_scope("wls.stats"):
+        gram = _sym_gram(Xb)
         if bf16_data:
-            gram = _dot00(Xb, Xb)
             # ONE X_b read for all three moment contractions: class sums
             # (one-hot columns), XᵀR (3 limbs), and Xᵀ(P⊙r) (3 limbs)
             # own-class residual per row
@@ -473,10 +486,6 @@ def _pcg_block_core(X, R, P, Wb, inv_counts, valid, start, w, lam,
                 _sum3(G[:, 4 * C_:], axis=1).T * inv_counts[:, None]
             )  # (C, b)
         else:
-            gram = jax.lax.dot_general(
-                Xb, Xb, (((0,), (0,)), ((), ())),
-                preferred_element_type=f32, precision=hp,
-            )
             pop_xtr = mm_bf16_f32_00(R) / n  # (b, C)
             cmean = jax.lax.dot_general(
                 Pf, Xb, (((0,), (0,)), ((), ())),
@@ -730,8 +739,10 @@ def _class_chunk_stats_gathered(
     return class_cov, class_mean, class_xtr, res_local_mean
 
 
-def _count_fit(solve: str, layout: str) -> None:
-    """Count one weighted fit started and the path it takes."""
+def _count_fit(solve: str, layout: str, widths, num_iter: int) -> None:
+    """Count one weighted fit started, the path it takes and the column
+    pairs of the Grams it builds: one a block of ``widths`` a sweep."""
+    _count_gram_pairs(widths, times=num_iter)
     reg = get_global_registry()
     reg.counter(
         "keystone_solver_wls_fits_total",
@@ -890,7 +901,8 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
                     "(solve='auto' or 'pcg'); the chol path gathers "
                     "class-grouped layouts from a device-resident X"
                 )
-            _count_fit("pcg", "host_blocks")
+            _count_fit("pcg", "host_blocks", data.block_widths,
+                       self.num_iter)
             with span("solver.wls.dispatch"):
                 model = self._fit_pcg_host(data, labels)
             self._check_convergence(model.solver_info)
@@ -933,7 +945,8 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
         with span("solver.wls.layout"):
             tile = _sorted_layout(X, Y, mask, max(wd for _, wd in blocks),
                                   len(blocks) * self.num_iter)
-        _count_fit("pcg", "sorted" if tile else "original")
+        _count_fit("pcg", "sorted" if tile else "original",
+                   [wd for _, wd in blocks], self.num_iter)
         window = _SORT_WINDOW if tile else 0
         if len({wd for _, wd in blocks}) == 1:
             # uniform widths (every real config: block_size divides D or
@@ -1099,7 +1112,8 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
             )
         else:
             use_grouped = self.layout == "grouped"
-        _count_fit("chol", "grouped" if use_grouped else "gathered")
+        _count_fit("chol", "grouped" if use_grouped else "gathered",
+                   [wd for _, wd in blocks], self.num_iter)
         # clamp to 1 so empty-class divisions stay finite; their zero wt
         # rows already zero the numerators, and their delta is masked out
         counts_j = jnp.asarray(np.maximum(counts, 1), jnp.float32)

@@ -369,3 +369,68 @@ def test_host_factor_solves_later_rhs_as_a_whole_solve_would(
         (G + lam * np.eye(6))[np.ix_(ok, ok)] @ W1[ok], rhs1[ok],
         rtol=1e-9, atol=1e-9,
     )
+
+
+# blocks of 640 (``_sym_gram`` cuts them 384 | 256) and a last one of 256
+@pytest.mark.parametrize("solve", ["device", "host"])
+def test_block_ls_on_blocks_wider_than_the_gram_leaf(solve, solver_counters):
+    from keystone_tpu.ops.learning import block_ls
+
+    X, Y = _bank_problem(4, n=2048, d=1536)
+    Y /= np.sqrt(1536, dtype=np.float32)
+    sweeps, lam = 2, 5.0
+    est = BlockLeastSquaresEstimator(640, num_iter=sweeps, lam=lam, solve=solve)
+    W = np.asarray(est.fit(Dataset.of(X), Dataset.of(Y)).W)
+    np.testing.assert_allclose(
+        W, _bcd_f64(X, Y, 640, sweeps, lam), rtol=1e-4, atol=1e-5)
+    # a Gram every block step on the device, one a block with the host's
+    # kept factors: counted as before, and its column pairs beside it
+    fits_of_grams = sweeps if solve == "device" else 1
+    assert solver_counters("gram_builds") == 3 * fits_of_grams
+    assert solver_counters("block_steps") == 3 * sweeps
+    wide, _ = block_ls._gram_pairs(640)
+    assert wide == 384 * 384 + 384 * 256 + 256 * 256
+    assert solver_counters("gram_pairs_computed") == \
+        fits_of_grams * (2 * wide + 256 * 256)
+    assert solver_counters("gram_pairs") == \
+        fits_of_grams * (2 * 640 * 640 + 256 * 256)
+
+
+def test_gram_pairs_share_metrics_read_the_program_s_counters(
+        solver_counters):
+    """The benchmark's ``gram_pairs_share.*`` are data files over two
+    counters: 1.0 while every block is at or under the leaf, 0.5625 for
+    blocks of 4,096, and no Gram more is counted for either."""
+    import json
+    import os
+
+    from benchmark.readers import counter_ratio
+    from keystone_tpu.ops.learning import block_ls
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        manifest = json.load(f)
+    for name, cell in [("gram_pairs_share.wfit", "weighted-bcd-fit"),
+                       ("gram_pairs_share.cfit", "cifar-fit"),
+                       ("gram_pairs_share.fit", "timit-fit")]:
+        with open(os.path.join(root, "benchmark", "metrics",
+                               name + ".json")) as f:
+            metric = json.load(f)
+        assert metric == {"reader": "counter_ratio", "args": {
+            "numerator": "keystone_solver_gram_pairs_computed_total",
+            "denominator": "keystone_solver_gram_pairs_total"}}
+        assert [m for m in manifest["per_layer"] if m["name"] == name] == [{
+            "name": name, "unit": "share", "better": "lower",
+            "source": "program_counter", "layer": "Solvers",
+            "moves": "fit_rows_per_s", "workloads": [cell]}]
+    assert counter_ratio.read(None, **metric["args"]) is None  # no fit yet
+    X, Y = _bank_problem(5, n=64, d=24, k=2)
+    BlockLeastSquaresEstimator(8, lam=0.1).fit(Dataset.of(X), Dataset.of(Y))
+    assert solver_counters("gram_builds") == 3
+    assert counter_ratio.read(None, **metric["args"]) == 1.0
+    block_ls._count_gram_pairs([4096, 4096], times=3)
+    assert solver_counters("gram_builds") == 3
+    assert solver_counters("gram_pairs") == 3 * 64 + 6 * 4096 ** 2
+    assert solver_counters("gram_pairs_computed") == \
+        3 * 64 + 6 * 0.5625 * 4096 ** 2

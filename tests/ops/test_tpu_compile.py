@@ -230,3 +230,62 @@ def test_filter_program_holds_no_loop_over_the_sample(
             name, constants)
         assert max(constants, default=0) <= 108, (name, constants)
     assert compiled.memory_analysis().temp_size_in_bytes < 3.3e9
+
+
+@pytest.mark.parametrize("cell", ["timit-fit", "weighted-bcd-fit"])
+def test_gram_products_are_square_fusions_that_read_the_rows(one_chip, cell):
+    """The block Gram from its upper block triangle at the published
+    shapes — ``_block_stats`` as ``timit-fit`` runs it (65,536 rows of
+    16,384 features, a traced start, width 4,096) and the helper alone
+    at ``weighted-bcd-fit``'s 327,680 x 4,096 rows: every product under
+    the scope is one output fusion with a square ``f32`` result whose
+    FIRST operand is the parameter ``X`` (the column slices fuse into
+    the products: no row-sized copy, 51 MB of temporaries where a
+    materialised half block is 0.5 GB and 2.7 GB), so the benchmark's
+    ``gram_roofline_pct.fit`` takes all fifteen or, in a program without
+    them, none (a pattern that took a part of the work read 829% in PR
+    32). ``as_text()`` leaves operand types out, which the trace's names
+    carry: the pattern is held against the line with X's type put back."""
+    import json
+    import os
+
+    from keystone_tpu.ops.learning import block_ls
+
+    def shape(dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    if cell == "timit-fit":
+        n, d, b, k, scope = 65536, 16384, 4096, 147, "solver.gram"
+        lowered = block_ls._block_stats.lower(
+            shape((n, d)), shape((n, k)), shape((b, k)), shape((d,)),
+            shape((n,)), shape((), jnp.int32), width=b, n=n)
+    else:
+        n, d, b, scope = 327680, 4096, 4096, "wls.stats"
+
+        def gram(X):
+            with jax.named_scope(scope):
+                return block_ls._sym_gram(X)
+
+        lowered = jax.jit(gram).lower(shape((n, d)))
+    compiled = lowered.compile()
+    # the one full product needs no temporary at either shape
+    assert compiled.memory_analysis().temp_size_in_bytes < 100e6
+    text = compiled.as_text()
+    entry = text[text.index("ENTRY "):]
+    products = [line.strip() for line in entry.splitlines()
+                if scope + "/dot_general" in line
+                and re.search(r" (fusion|convolution)\(", line)]
+    sizes = []
+    for op in products:
+        found = re.match(r"%\S+ = f32\[(\d+),(\d+)\]\S* fusion\(%X\.", op)
+        assert found and found.group(1) == found.group(2), op[:200]
+        sizes.append(int(found.group(1)))
+    assert sorted(sizes) == [512] * 12 + [1024] * 2 + [2048]
+    assert sum(s * s for s in sizes) == block_ls._gram_pairs(b)[0]
+    with open(os.path.join(
+            os.path.dirname(__file__), "..", "..", "benchmark", "metrics",
+            "gram_roofline_pct.fit.json")) as f:
+        pattern = re.compile(json.load(f)["args"]["pattern"])
+    typed = f"fusion(f32[{n},{d}]{{1,0:T(8,128)}} %X."
+    assert all(pattern.search(op.replace("fusion(%X.", typed))
+               for op in products)

@@ -166,6 +166,39 @@ def test_block_weighted_pcg_agrees_with_chol():
     )
 
 
+@pytest.mark.parametrize("solve", ["pcg", "chol"])
+def test_block_weighted_fits_blocks_wider_than_the_gram_leaf(
+        solve, monkeypatch):
+    """Blocks of 640 columns: the population Gram is built from its upper
+    block triangle (``block_ls._sym_gram``: 384 | 256, three products)
+    and both solvers fit the reference translation's model as before;
+    the fit counts the pairs it multiplied and no block-solver Gram."""
+    from keystone_tpu.observability import registry
+    from keystone_tpu.ops.learning import block_ls
+
+    assert block_ls._gram_cut(640) == 384
+    X, Y, _ = _weighted_problem(n=1500, D=1280, C=3, seed=5)
+    lam, w, sweeps = 0.1, 0.5, 2
+    # a registry of this test's own: the counts are of this fit alone
+    monkeypatch.setattr(registry, "_global_registry",
+                        registry.MetricsRegistry())
+    est = BlockWeightedLeastSquaresEstimator(
+        640, sweeps, lam, w, solve=solve, pcg_tol=1e-6)
+    model = est.fit(Dataset.of(X), Dataset.of(Y))
+    W_ref, b_ref = ref_block_weighted_bcd(X, Y, 640, sweeps, lam, w)
+    np.testing.assert_allclose(np.asarray(model.W), W_ref, atol=5e-4)
+    np.testing.assert_allclose(np.asarray(model.intercept), b_ref, atol=2e-3)
+
+    def count(name):
+        return registry.get_global_registry().counter(
+            "keystone_solver_" + name + "_total").get()
+
+    # two blocks, two sweeps: four Grams of 640 columns
+    assert count("gram_pairs") == 4 * 640 * 640
+    assert count("gram_pairs_computed") == 4 * block_ls._gram_pairs(640)[0]
+    assert count("gram_builds") == 0
+
+
 def test_block_weighted_skewed_classes_gathered_layout(mesh8):
     """Heavy class imbalance on EVERY physical path: the chol solver's
     grouped and (explicitly forced) gathered layouts, and the ungrouped
