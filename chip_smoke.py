@@ -12,6 +12,8 @@ points a user calls, in ONE process that holds the chip for every phase:
   kernels  lower each Pallas kernel through Mosaic at the shapes this
            configuration produces, under the vmap the extractors use,
            and compare with its own interpret=True result;
+  chunks   the chunk programs of a shape group at VOC's image size, at
+           the planner's chunk, against the same rows four at a time;
   fit      write a seeded synthetic ImageNet-layout data set (one tar of
            JPEGs per synset + a WNID->class file), build native/*.so
            from source, then run the application the way bin/run-pipeline
@@ -28,6 +30,7 @@ particular when jax finds no TPU. Phase seconds are set-up facts of one
 cold or warm run, not speeds.
 
     python3 chip_smoke.py          # on the chip: chiprun -- python3 chip_smoke.py
+    python3 chip_smoke.py chunks   # the device's phase and the named ones
 """
 
 from __future__ import annotations
@@ -714,6 +717,105 @@ def phase_serve(
     )
 
 
+# -- chunks ------------------------------------------------------------------
+
+CHUNK_IMAGES = 32  # of 375 x 500: twice what a shape group's chunk takes
+CHUNK_SHAPE = (375, 500)
+
+
+def phase_chunks(state: dict) -> str:
+    """A shape group's chunk programs at VOC's image size (dense SIFT, the
+    PCA projection, the 256-word Fisher kernel: the run ``voc-fit``
+    makes) against the same rows FOUR at a time: the planner's chunk
+    (``parallel/chunks.py: planned_rows``, held to ``PROGRAM_BYTES``)
+    must give every image the rows the small program gives it. Programs
+    over 24 and more such images computed wrong rows for some of them on
+    a v5e (PERF.md section 6, PR 37): whoever lifts the cap fails here
+    first. Then, reported and not failed: the Fisher statistics' time by
+    the kernel and by the XLA program, and each function ALONE over all
+    32 images at once, on inputs the small programs made: which function
+    goes wrong beyond the cap, and for which rows."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from keystone_tpu.ops.images.fisher_vector import _FisherRows
+    from keystone_tpu.ops.images.sift import _SiftRows
+    from keystone_tpu.ops.learning.pca import _project_columns
+    from keystone_tpu.parallel import chunks
+
+    n, (h, w) = CHUNK_IMAGES, CHUNK_SHAPE
+    key = jax.random.PRNGKey(SEED)
+    coarse = jax.random.uniform(key, (n, h // 5, w // 5, 1))
+    images = jax.image.resize(coarse, (n, h, w, 1), "linear") \
+        + 0.02 * jax.random.normal(jax.random.fold_in(key, 1), (n, h, w, 1))
+    basis = jnp.asarray(np.linalg.qr(np.random.default_rng(SEED)
+                                     .standard_normal((128, 80)))[0],
+                        jnp.float32)
+    sift = _SiftRows(3, 4, 4, 0)
+    fns = [sift, _project_columns, _FisherRows(True, 1e-4)]
+
+    def small(fn, arr, x):  # four rows a program
+        return jnp.concatenate([
+            chunks.rows_program(fn)(arr, x[s:s + 4]) for s in range(0, n, 4)])
+
+    desc = small(sift, (), images)
+    reduced = small(_project_columns, basis, desc)
+    words = reduced[0][:, :: reduced.shape[2] // 256][:, :256]  # (80, 256)
+    var = jnp.broadcast_to(
+        jnp.var(reduced[0], axis=1, keepdims=True), words.shape)
+    gmm = (words, var, jnp.full((256,), 1.0 / 256, jnp.float32))
+    arrays = [(), basis, gmm]
+    want = small(fns[2], gmm, reduced)
+
+    chunk, _ = chunks.planned_rows(fns, arrays, images, keep=True)
+    got = jnp.concatenate([
+        chunks.take_chunk(fns, chunk, arrays, images, s)
+        for s in range(0, n, chunk)])
+
+    def per_row(a, b):
+        a, b = np.asarray(a).reshape(n, -1), np.asarray(b).reshape(n, -1)
+        return np.linalg.norm(a - b, axis=1) / np.maximum(
+            np.linalg.norm(b, axis=1), 1e-30)
+
+    err = per_row(got, want)
+    report = {"planned_chunk": int(chunk),
+              "planned_vs_four_at_a_time": float(err.max())}
+    if not (np.isfinite(err).all() and err.max() < 1e-3):
+        raise SmokeFailure(
+            f"chunks: {chunk} images a program differ from four a program "
+            f"in rows {np.flatnonzero(~(err < 1e-3)).tolist()} "
+            f"(worst {err.max():.3g})")
+    # the Fisher statistics by the kernel and by the plain XLA program
+    # (``GMMFisherVectorEstimator._choice`` follows this reading): four
+    # images a call, host clock round block_until_ready, the best of five
+    for name, fused in (("kernel", True), ("xla", False)):
+        program = chunks.rows_program(_FisherRows(fused, 1e-4))
+        jax.block_until_ready(program(gmm, reduced[:4]))
+        best = float("inf")
+        for _ in range(5):
+            t0 = time.perf_counter()
+            jax.block_until_ready(program(gmm, reduced[:4]))
+            best = min(best, time.perf_counter() - t0)
+        report[f"fisher_{name}_ms_per_image"] = round(best / 4 * 1e3, 4)
+    # beyond the cap: each function alone over all 32 rows at once
+    for name, fn, arr, x, right in (
+            ("sift", sift, (), images, desc),
+            ("project", _project_columns, basis, desc, reduced),
+            ("fisher", fns[2], gmm, reduced, want)):
+        try:
+            e = per_row(chunks.rows_program(fn)(arr, x), right)
+            report[f"{name}_{n}_at_once"] = {
+                "worst": float(np.nan_to_num(e, nan=np.inf).max()),
+                "wrong_rows": np.flatnonzero(~(e < 1e-3)).tolist()}
+        except Exception as e:  # noqa: BLE001 — reported, not failed
+            report[f"{name}_{n}_at_once"] = {"raised": repr(e)[:300]}
+    os.makedirs(OUT_DIR, exist_ok=True)
+    with open(os.path.join(OUT_DIR, "chunks.json"), "w") as f:
+        json.dump(report, f, indent=1)
+    return " ".join(f"{k}={v}" for k, v in report.items())
+
+
 # -- main --------------------------------------------------------------------
 
 
@@ -726,12 +828,21 @@ def main() -> int:
     COUNTER.install()
     state: dict = {}
     t_start = time.perf_counter()
-    for name, fn in (
+    phases = (
         ("device", phase_device),
         ("kernels", phase_kernels),
+        ("chunks", phase_chunks),
         ("fit", phase_fit),
         ("serve", phase_serve),
-    ):
+    )
+    only = set(sys.argv[1:])  # phases by name; the device's always runs
+    if only - {name for name, _ in phases}:
+        print(f"chip_smoke.py: no such phase among {sorted(only)}",
+              file=sys.stderr)
+        return 2
+    for name, fn in phases:
+        if only and name != "device" and name not in only:
+            continue
         try:
             run_phase(name, fn, state)
         except Exception as e:  # noqa: BLE001 — any failure fails the smoke

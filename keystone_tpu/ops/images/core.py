@@ -503,13 +503,23 @@ class PixelScaler(Transformer):
 
     def apply_batch(self, ds: Dataset) -> Dataset:
         if ds.is_array:
-            return Dataset.from_array(
-                ds.padded().astype(jnp.float32) / 255.0, n=ds.n
-            )
+            return Dataset.from_array(_scale_pixels((), ds.padded()), n=ds.n)
         return self._bucketed_batch(ds)
+
+    def rowwise(self):
+        return _scale_pixels, ()
 
     def eq_key(self):
         return ("pixel_scaler",)
+
+
+def _scale_pixels(arrays, x):
+    """PixelScaler's rows-in, rows-out function."""
+    del arrays
+    return x.astype(jnp.float32) / 255.0
+
+
+_scale_pixels.groups_only = True
 
 
 class GrayScaler(Transformer):
@@ -522,13 +532,24 @@ class GrayScaler(Transformer):
 
     def apply_batch(self, ds: Dataset) -> Dataset:
         if ds.is_array:
-            w = jnp.asarray(GRAYSCALE_WEIGHTS, jnp.float32)
-            out = (ds.padded().astype(jnp.float32) @ w)[..., None]
-            return Dataset.from_array(out, n=ds.n)
+            return Dataset.from_array(_to_gray((), ds.padded()), n=ds.n)
         return self._bucketed_batch(ds)
+
+    def rowwise(self):
+        return _to_gray, ()
 
     def eq_key(self):
         return ("gray_scaler",)
+
+
+def _to_gray(arrays, x):
+    """GrayScaler's rows-in, rows-out function."""
+    del arrays
+    w = jnp.asarray(GRAYSCALE_WEIGHTS, jnp.float32)
+    return (x.astype(jnp.float32) @ w)[..., None]
+
+
+_to_gray.groups_only = True
 
 
 @dataclasses.dataclass(eq=False)

@@ -225,6 +225,32 @@ class SIFTExtractor(Transformer):
         )
         return quantized.T  # (128, numDescriptors)
 
+    def rowwise(self):
+        return _SiftRows(
+            self.step, self.bin, self.num_scales, self.scale_step), ()
+
     @property
     def descriptor_dims(self) -> int:
         return DESCRIPTOR_DIMS
+
+
+@dataclasses.dataclass(frozen=True)
+class _SiftRows:
+    """The SIFTExtractor's rows-in, rows-out function over images of one
+    shape: extractors of equal settings share the compiled programs."""
+
+    step: int
+    bin: int
+    num_scales: int
+    scale_step: int
+    groups_only = True
+
+    def __call__(self, arrays, imgs):
+        del arrays
+        return jax.vmap(SIFTExtractor(
+            self.step, self.bin, self.num_scales, self.scale_step).apply
+        )(imgs)
+
+    # an image's descriptors are live twice, descriptor-major as the
+    # scales leave them and transposed as the node hands them over
+    held = __call__

@@ -31,8 +31,6 @@ from keystone_tpu.ops.learning.kmeans import (
     KMeansPlusPlusEstimator,
 )
 from keystone_tpu.ops.learning.gmm import (
-    FusedGMMEstimator,
-    OptimizableGMMEstimator,
     GaussianMixtureModel,
     GaussianMixtureModelEstimator,
 )
@@ -82,7 +80,6 @@ __all__ = [
     "GaussianMixtureModelEstimator",
     "KMeansModel",
     "EllLeastSquaresEstimator",
-    "FusedGMMEstimator",
     "EllLinearMapper",
     "KMeansPlusPlusEstimator",
     "LeastSquaresDenseGradient",
@@ -97,7 +94,6 @@ __all__ = [
     "LogisticRegressionEstimator",
     "LogisticRegressionModel",
     "NaiveBayesEstimator",
-    "OptimizableGMMEstimator",
     "NaiveBayesModel",
     "PCAEstimator",
     "PCATransformer",

@@ -4,20 +4,16 @@ Reference: nodes/learning/GaussianMixtureModel.scala (batch Mahalanobis +
 shifted-softmax posterior + aggressive thresholding, :19-97, csv load
 :97-110) and GaussianMixtureModelEstimator.scala:25-203 (k-means++ or
 random init, variance flooring, incremental log-sum-exp cost, min-cluster
-guard). The E/M steps are jitted device matmuls; the reference's
-incremental LSE trick is the standard logsumexp here.
+guard).
 
-Two physical EM implementations exist, like the reference's scala/enceval
-pair (nodes/learning/external/GaussianMixtureModelEstimator.scala):
-``GaussianMixtureModelEstimator`` steps EM from the host (one small jitted
-program per iteration, cost read back each step — easy to introspect),
-and ``FusedGMMEstimator`` runs the ENTIRE EM as one ``lax.while_loop``
-program that never leaves the device (convergence test, min-cluster
-guard, and variance flooring all in-graph) — the enceval-native analogue,
-where "native" on TPU means fused XLA. ``OptimizableGMMEstimator`` picks
-between them at k >= 32 the way the reference flips to the native
-implementation for large vocabularies (nodes/images/FisherVector
-.scala:84-94).
+One estimator. The sample stays on the device from the moment it is
+handed over: the k-means++ draw is one device program fed by the host's
+uniforms (``_gmm_init``), the EM one ``lax.while_loop`` with the
+convergence test, the cluster floor and the variance floors in the loop
+(``_gmm_em``), and neither writes a row's posteriors out: an E-step is a
+blocked pass of ``fv_pallas.gmm_stats`` that hands back Σq, Σxq, Σx²q and
+the cost. The reference's scala/enceval pair of EMs (and the host-stepped
+and fused pair that stood for it here) computed the same thing twice.
 """
 
 from __future__ import annotations
@@ -30,11 +26,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from keystone_tpu.ops.learning.kmeans import KMeansPlusPlusEstimator
+from keystone_tpu.observability.registry import get_global_registry
+from keystone_tpu.observability.tracing import span
 from keystone_tpu.parallel.dataset import Dataset
-from keystone_tpu.utils.precision import mm
 from keystone_tpu.workflow.api import Estimator, Transformer
-from keystone_tpu.workflow.node_optimization import Optimizable
 
 KMEANS_PLUS_PLUS_INITIALIZATION = "kmeans++"
 RANDOM_INITIALIZATION = "random"
@@ -119,15 +114,131 @@ def _log_likelihoods_dk(X, mu_dk, var_dk, weights):
     )
 
 
-def _log_likelihoods(X, mu, var, weights):
-    """Back-compat wrapper taking (k, d) mu/var."""
-    return _log_likelihoods_dk(X, mu.T, var.T, weights)
+STOP_REASONS = ("tolerance", "max_iter", "cluster_floor")
+
+
+def _stats(xt, mu, var, weights, threshold, hard=False):
+    """Σq (k,), Σxq (k, d), Σx²q (k, d) and Σ log-likelihood over the
+    columns of ``xt`` (d, n), by the blocked kernel; ``mu`` / ``var`` are
+    (k, d)."""
+    from keystone_tpu.ops.images.fv_pallas import gmm_stats
+
+    s0, s1, s2, lse = gmm_stats(
+        xt[None], mu.T, var.T, weights, threshold, hard=hard
+    )
+    return s0[0], s1[0].T, s2[0].T, lse[0]
+
+
+def _variance_floor(xt, var_floors):
+    """The larger of ``var_floors[0]`` of each dimension's variance over
+    the sample and ``var_floors[1]`` (the reference's small and absolute
+    variance thresholds)."""
+    mean = jnp.mean(xt, axis=1)
+    var_global = jnp.mean(xt * xt, axis=1) - mean * mean
+    return jnp.maximum(var_floors[0] * var_global, var_floors[1])
+
+
+@partial(jax.jit, static_argnames=("k",))
+def _gmm_init(xt, first, uniforms, spare, var_floors, *, k: int):
+    """k-means++ seeds, one Lloyd round and the moments of its clusters,
+    all on the device (GaussianMixtureModelEstimator.scala:60-90 through
+    KMeansPlusPlus.scala:83-140): ``first`` is the first seed's column,
+    ``uniforms`` (k - 1,) the host generator's draws, one a seed, each
+    placed on the running D² distribution by a cumulative sum; ``spare``
+    the columns taken where every point already is a seed. Returns (mu,
+    var, weights, var_lb, seeds)."""
+    d, n = xt.shape
+    hp = jax.lax.Precision.HIGHEST
+    with jax.named_scope("gmm.init"):
+        half_sq = 0.5 * jnp.sum(xt * xt, axis=0)
+
+        def seed(j, state):
+            seeds, dist = state
+            c = jax.lax.dynamic_slice_in_dim(xt, seeds[j], 1, axis=1)[:, 0]
+            new = half_sq - jnp.matmul(c, xt, precision=hp) + 0.5 * (c @ c)
+            dist = jnp.minimum(dist, new)
+            cdf = jnp.cumsum(jnp.maximum(dist, 0.0))
+            pick = jnp.searchsorted(cdf, uniforms[j] * cdf[-1], side="right")
+            pick = jnp.where(cdf[-1] > 0, jnp.minimum(pick, n - 1), spare[j])
+            return seeds.at[j + 1].set(pick.astype(jnp.int32)), dist
+
+        seeds, _ = jax.lax.fori_loop(
+            0, k - 1, seed,
+            (jnp.zeros((k,), jnp.int32).at[0].set(first),
+             jnp.full((n,), jnp.inf, jnp.float32)),
+        )
+        centres = jnp.take(xt, seeds, axis=1).T  # (k, d)
+        ones, flat = jnp.ones_like(centres), jnp.full((k,), 1.0 / k)
+        # one Lloyd round, then the hard assignment to its means
+        mass, s1, _, _ = _stats(xt, centres, ones, flat, 0.0, hard=True)
+        centres = s1 / jnp.maximum(mass, 1.0)[:, None]
+        mass, s1, s2, _ = _stats(xt, centres, ones, flat, 0.0, hard=True)
+        inv = 1.0 / jnp.maximum(mass, 1.0)
+        mu = inv[:, None] * s1
+        var = inv[:, None] * s2 - mu * mu
+        var_lb = _variance_floor(xt, var_floors)
+        return mu, jnp.maximum(var, var_lb), mass / n, var_lb, seeds
+
+
+@partial(jax.jit, static_argnames=("k",))
+def _gmm_init_random(xt, draws, var_floors, *, k: int):
+    """The reference's random start: means uniform in the box of the
+    data, variances a tenth of its squared sides, equal weights."""
+    lo, hi = jnp.min(xt, axis=1), jnp.max(xt, axis=1)
+    mu = draws * (hi - lo) + lo
+    var = 0.1 * jnp.ones_like(mu) * (hi - lo) ** 2
+    var_lb = _variance_floor(xt, var_floors)
+    return mu, jnp.maximum(var, var_lb), jnp.full((k,), 1.0 / k), var_lb
+
+
+@partial(jax.jit, static_argnames=("max_iterations",))
+def _gmm_em(xt, mu, var, w, var_lb, rules, *, max_iterations: int):
+    """The whole EM as one device program (no host read until it ends):
+    each round is one blocked pass over the sample for the cost and the
+    thresholded posteriors' statistics, then the reference's tests in its
+    order — stop, before the update, where the cost rose by less than
+    ``stop_tolerance`` of itself or a cluster fell under
+    ``min_cluster_size`` — and the M-step with the variance floor.
+    ``rules`` is (stop_tolerance, min_cluster_size, weight_threshold).
+    Returns (mu, var, w, rounds begun, index into STOP_REASONS)."""
+    n = xt.shape[1]
+    tol, floor, threshold = rules[0], rules[1], rules[2]
+
+    def cond(state):
+        return (state[0] < max_iterations) & (state[5] < 0)
+
+    def body(state):
+        i, mu, var, w, prev_cost, _ = state
+        with jax.named_scope("gmm.estep"):
+            q_sum, s1, s2, lse = _stats(xt, mu, var, w, threshold)
+        with jax.named_scope("gmm.mstep"):
+            cost = lse / n
+            converged = (cost - prev_cost) < tol * jnp.abs(prev_cost)
+            unbalanced = jnp.any(q_sum < floor)
+            reason = jnp.where(converged, 0, jnp.where(unbalanced, 2, -1))
+            inv = 1.0 / jnp.maximum(q_sum, 1e-30)
+            mu_new = inv[:, None] * s1
+            var_new = jnp.maximum(inv[:, None] * s2 - mu_new * mu_new, var_lb)
+            keep = lambda new, old: jnp.where(reason >= 0, old, new)
+            return (
+                i + 1, keep(mu_new, mu), keep(var_new, var),
+                keep(q_sum / n, w), keep(cost, prev_cost),
+                reason.astype(jnp.int32),
+            )
+
+    i, mu, var, w, _, reason = jax.lax.while_loop(
+        cond, body,
+        (jnp.int32(0), mu, var, w, jnp.float32(-jnp.inf), jnp.int32(-1)),
+    )
+    return mu, var, w, i, jnp.where(reason < 0, 1, reason)
 
 
 @dataclasses.dataclass(eq=False)
 class GaussianMixtureModelEstimator(Estimator):
     """Local EM over the (collected) sample, mirroring
-    GaussianMixtureModelEstimator.scala:25 parameter-for-parameter."""
+    GaussianMixtureModelEstimator.scala:25 parameter-for-parameter. The
+    fitted model carries ``fit_info``: the rounds the EM began, why it
+    stopped (one of ``STOP_REASONS``) and the k-means++ seeds' rows."""
 
     k: int
     max_iterations: int = 100
@@ -139,202 +250,60 @@ class GaussianMixtureModelEstimator(Estimator):
     initialization_method: str = KMEANS_PLUS_PLUS_INITIALIZATION
     seed: int = 0
 
-    def _initialize(self, X, xsq):
-        """Shared init for both physical EMs: k-means++ (or random) seeds
-        + variance floor (GaussianMixtureModelEstimator.scala:60-90)."""
-        n, d = X.shape
-        mean_global = jnp.mean(X, axis=0)
-        var_global = jnp.mean(xsq, axis=0) - mean_global * mean_global
+    def draws(self, n: int) -> tuple:
+        """The host generator's part of a k-means++ start over ``n``
+        rows, all at once: (the first seed's row, the k - 1 uniforms that
+        place the others, the rows taken where no distance is left), in
+        the order ``KMeansPlusPlusEstimator``'s seeding loop draws them."""
+        rng = np.random.default_rng(self.seed)
+        first = int(rng.integers(0, n))
+        uniforms = rng.random(self.k - 1)
+        return first, uniforms, rng.integers(0, n, self.k - 1)
 
+    def initialize(self, xt):
+        """(mu, var, weights, var_lb, seeds) of the start over the
+        columns of ``xt`` (d, n); ``seeds`` is None for a random start."""
+        d, n = xt.shape
+        floors = jnp.asarray(
+            [self.small_variance_threshold,
+             self.absolute_variance_threshold], jnp.float32)
         if self.initialization_method == KMEANS_PLUS_PLUS_INITIALIZATION:
-            km = KMeansPlusPlusEstimator(self.k, 1, seed=self.seed)
-            assign = km.fit(np.asarray(X)).apply_batch(
-                Dataset.from_array(X)
-            ).padded()
-            mass = jnp.sum(assign, axis=0)
-            inv = 1.0 / jnp.maximum(mass, 1.0)
-            weights = mass / n
-            mu = inv[:, None] * mm(assign.T, X)
-            var = inv[:, None] * mm(assign.T, xsq) - mu * mu
-        else:  # RANDOM_INITIALIZATION
-            rng = np.random.default_rng(self.seed)
-            col_min = jnp.min(X, axis=0)
-            col_range = jnp.max(X, axis=0) - col_min
-            mu = (
-                jnp.asarray(rng.uniform(size=(self.k, d)), jnp.float32)
-                * col_range[None, :]
-                + col_min[None, :]
+            first, uniforms, spare = self.draws(n)
+            return _gmm_init(
+                xt, first, jnp.asarray(uniforms, jnp.float32),
+                jnp.asarray(spare, jnp.int32), floors, k=self.k,
             )
-            var = 0.1 * jnp.ones((self.k, d)) * (col_range * col_range)[None, :]
-            weights = jnp.full((self.k,), 1.0 / self.k)
-
-        var_lb = jnp.maximum(
-            self.small_variance_threshold * var_global,
-            self.absolute_variance_threshold,
-        )
-        var = jnp.maximum(var, var_lb[None, :])
-        return mu, var, weights, var_lb
+        rng = np.random.default_rng(self.seed)
+        draws = jnp.asarray(rng.uniform(size=(self.k, d)), jnp.float32)
+        return _gmm_init_random(xt, draws, floors, k=self.k) + (None,)
 
     def fit(self, data) -> GaussianMixtureModel:
-        if isinstance(data, Dataset):
-            X = np.asarray(data.array(), np.float32)
-        else:
-            X = np.asarray(data, np.float32)
-        X = jnp.asarray(X)
-        n = X.shape[0]
-        xsq = X * X
-        mu, var, weights, var_lb = self._initialize(X, xsq)
-
-        prev_cost = None
-        for _ in range(self.max_iterations):
-            llh = _log_likelihoods(X, mu, var, weights)
-            cost = float(
-                jnp.mean(jax.scipy.special.logsumexp(llh, axis=1))
+        x = data.array() if isinstance(data, Dataset) else data
+        xt = jnp.asarray(x, jnp.float32).T  # (d, n): rows on the lanes
+        reg = get_global_registry()
+        with span("gmm.init", n=xt.shape[1], k=self.k):
+            mu, var, weights, var_lb, seeds = self.initialize(xt)
+        with span("gmm.em", n=xt.shape[1], k=self.k):
+            mu, var, weights, rounds, reason = _gmm_em(
+                xt, mu, var, weights, var_lb,
+                jnp.asarray([self.stop_tolerance, self.min_cluster_size,
+                             self.weight_threshold], jnp.float32),
+                max_iterations=self.max_iterations,
             )
-            if prev_cost is not None and (
-                cost - prev_cost
-            ) < self.stop_tolerance * abs(prev_cost):
-                break
-            prev_cost = cost
-            # E-step: shifted softmax + thresholding
-            q = jnp.exp(llh - jnp.max(llh, axis=1, keepdims=True))
-            q = q / jnp.sum(q, axis=1, keepdims=True)
-            q = jnp.where(q > self.weight_threshold, q, 0.0)
-            q = q / jnp.sum(q, axis=1, keepdims=True)
-            # M-step with min-cluster guard
-            q_sum = jnp.sum(q, axis=0)
-            if bool(jnp.any(q_sum < self.min_cluster_size)):
-                break  # "Unbalanced clustering, try less centers"
-            weights = q_sum / n
-            inv = 1.0 / q_sum
-            mu = inv[:, None] * mm(q.T, X)
-            var = inv[:, None] * mm(q.T, xsq) - mu * mu
-            var = jnp.maximum(var, var_lb[None, :])
-
-        return GaussianMixtureModel(
+            # the fit's one host read, after the loop has ended
+            rounds, reason = int(rounds), STOP_REASONS[int(reason)]
+        reg.counter("keystone_gmm_fits_total", "GMM fits").inc()
+        reg.counter(
+            "keystone_gmm_em_iterations_total",
+            "EM rounds begun (each one pass over the sample)",
+        ).inc(by=rounds)
+        reg.counter(
+            "keystone_gmm_stop_total", "why an EM stopped",
+            labelnames=("reason",),
+        ).inc((reason,))
+        model = GaussianMixtureModel(
             mu.T, var.T, weights, self.weight_threshold
         )
-
-
-@partial(
-    jax.jit,
-    static_argnames=(
-        "max_iterations", "min_cluster_size", "stop_tolerance",
-        "weight_threshold",
-    ),
-)
-def _fused_em(
-    X, mu0, var0, w0, var_lb, *, max_iterations: int,
-    min_cluster_size: int, stop_tolerance: float, weight_threshold: float,
-):
-    """Whole EM as ONE device program: lax.while_loop with the convergence
-    test, aggressive posterior thresholding, min-cluster guard, and
-    variance flooring all in-graph — zero host syncs until the caller
-    reads the result. Semantics identical to the host-stepped loop in
-    ``GaussianMixtureModelEstimator.fit`` (both break BEFORE applying an
-    update when converged or unbalanced)."""
-    n = X.shape[0]
-    xsq = X * X
-
-    def cond(state):
-        i, mu, var, w, prev_cost, done = state
-        return (i < max_iterations) & ~done
-
-    def body(state):
-        i, mu, var, w, prev_cost, done = state
-        llh = _log_likelihoods_dk(X, mu.T, var.T, w)
-        cost = jnp.mean(jax.scipy.special.logsumexp(llh, axis=1))
-        converged = (cost - prev_cost) < stop_tolerance * jnp.abs(prev_cost)
-
-        q = jnp.exp(llh - jnp.max(llh, axis=1, keepdims=True))
-        q = q / jnp.sum(q, axis=1, keepdims=True)
-        q = jnp.where(q > weight_threshold, q, 0.0)
-        q = q / jnp.sum(q, axis=1, keepdims=True)
-        q_sum = jnp.sum(q, axis=0)
-        unbalanced = jnp.any(q_sum < min_cluster_size)
-
-        stop = converged | unbalanced
-        inv = 1.0 / jnp.maximum(q_sum, 1e-30)
-        hp = jax.lax.Precision.HIGHEST
-        mu_new = inv[:, None] * jnp.matmul(q.T, X, precision=hp)
-        var_new = (
-            inv[:, None] * jnp.matmul(q.T, xsq, precision=hp)
-            - mu_new * mu_new
-        )
-        var_new = jnp.maximum(var_new, var_lb[None, :])
-        w_new = q_sum / n
-
-        keep = lambda new, old: jnp.where(stop, old, new)
-        return (
-            i + 1,
-            keep(mu_new, mu),
-            keep(var_new, var),
-            keep(w_new, w),
-            jnp.where(stop, prev_cost, cost),
-            stop,
-        )
-
-    _, mu, var, w, _, _ = jax.lax.while_loop(
-        cond, body,
-        (jnp.int32(0), mu0, var0, w0, jnp.float32(-jnp.inf),
-         jnp.bool_(False)),
-    )
-    return mu, var, w
-
-
-@dataclasses.dataclass(eq=False)
-class FusedGMMEstimator(GaussianMixtureModelEstimator):
-    """Second physical EM implementation — the enceval-native analogue
-    (reference: nodes/learning/external/GaussianMixtureModelEstimator
-    .scala): the full EM runs as one fused device program. Same init,
-    same parameters, same stopping semantics as the host-stepped EM."""
-
-    def fit(self, data) -> GaussianMixtureModel:
-        if isinstance(data, Dataset):
-            X = np.asarray(data.array(), np.float32)
-        else:
-            X = np.asarray(data, np.float32)
-        X = jnp.asarray(X)
-        mu, var, weights, var_lb = self._initialize(X, X * X)
-        mu, var, weights = _fused_em(
-            X, mu, var, weights, var_lb,
-            max_iterations=self.max_iterations,
-            min_cluster_size=self.min_cluster_size,
-            stop_tolerance=self.stop_tolerance,
-            weight_threshold=self.weight_threshold,
-        )
-        return GaussianMixtureModel(
-            mu.T, var.T, weights, self.weight_threshold
-        )
-
-
-@dataclasses.dataclass(eq=False)
-class OptimizableGMMEstimator(GaussianMixtureModelEstimator, Optimizable):
-    """Physical-choice wrapper: the fused device EM at k >= 32, the
-    host-stepped EM below — mirroring the reference's switch to the
-    native implementation for large vocabularies
-    (nodes/images/FisherVector.scala:84-94)."""
-
-    native_k_threshold: int = 32
-
-    def _chosen(self) -> GaussianMixtureModelEstimator:
-        cls = (
-            FusedGMMEstimator
-            if self.k >= self.native_k_threshold
-            else GaussianMixtureModelEstimator
-        )
-        fields = {
-            f.name: getattr(self, f.name)
-            for f in dataclasses.fields(GaussianMixtureModelEstimator)
-        }
-        return cls(**fields)
-
-    @property
-    def default(self) -> Estimator:
-        return self._chosen()
-
-    def optimize(self, samples, n_total: int) -> Estimator:
-        return self._chosen()
-
-    def fit(self, data) -> GaussianMixtureModel:
-        return self._chosen().fit(data)
+        model.fit_info = {"iterations": rounds, "reason": reason,
+                          "seeds": seeds}
+        return model

@@ -17,12 +17,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from keystone_tpu.observability.registry import get_global_registry
+from keystone_tpu.observability.tracing import span
 from keystone_tpu.ops.learning.cost import CostModel
 from keystone_tpu.parallel import linalg as plinalg
 from keystone_tpu.parallel.dataset import Dataset
 from keystone_tpu.utils.precision import mm
 from keystone_tpu.workflow.api import Estimator, Transformer
-from keystone_tpu.workflow.node_optimization import Optimizable
 
 
 def enforce_matlab_pca_sign_convention(pca: jnp.ndarray) -> jnp.ndarray:
@@ -60,20 +61,51 @@ class BatchPCATransformer(Transformer):
 
     def apply_batch(self, ds: Dataset) -> Dataset:
         if ds.is_array:
-            x = ds.padded()  # (n, d, m)
-            with jax.named_scope("pca.project"):
-                out = jnp.einsum("dk,ndm->nkm", self.pca_mat, x)
-            return Dataset.from_array(out, n=ds.n)
+            return Dataset.from_array(
+                _project_columns(self.pca_mat, ds.padded()), n=ds.n)
         return ds.map(self.apply)
+
+    def rowwise(self):
+        return _project_columns, self.pca_mat
+
+
+def _project_columns(pca_mat, x):
+    """BatchPCATransformer's rows-in, rows-out function: (n, d, m)
+    descriptor matrices onto the (d, dims) basis."""
+    with jax.named_scope("pca.project"):
+        return jnp.einsum("dk,ndm->nkm", pca_mat, x)
+
+
+_project_columns.groups_only = True
+
+
+@jax.jit
+def _centered_gram(data_mat):
+    """(X - mean)ᵀ (X - mean) of the rows, float32 at HIGHEST: the one
+    pass over the sample a PCA fit makes."""
+    with jax.named_scope("pca.cov"):
+        centered = data_mat - jnp.mean(data_mat, axis=0)
+        return jnp.matmul(
+            centered.T, centered, precision=jax.lax.Precision.HIGHEST
+        )
 
 
 def _compute_pca(data_mat: jnp.ndarray, dims: int) -> jnp.ndarray:
-    """Center, SVD, sign convention, truncate (reference:
-    PCA.scala:180-203 computePCA)."""
-    means = jnp.mean(data_mat, axis=0)
-    centered = data_mat - means
-    _, _, vt = jnp.linalg.svd(centered, full_matrices=False)
-    pca = enforce_matlab_pca_sign_convention(vt.T)
+    """Center, principal directions, sign convention, truncate
+    (reference: PCA.scala:180-203 computePCA, which takes the right
+    singular vectors of the centered sample from LAPACK on the driver).
+    Here the sample stays on the device: its centered Gram is one device
+    program, and the driver's part is the float64 ``eigh`` of that (d, d)
+    matrix, whose eigenvectors by falling eigenvalue are those singular
+    vectors. Span ``pca.fit``, counter ``keystone_pca_fits_total``."""
+    with span("pca.fit", n=data_mat.shape[0], d=data_mat.shape[1]):
+        gram = np.asarray(_centered_gram(jnp.asarray(data_mat)), np.float64)
+        _, vecs = np.linalg.eigh(gram)
+        pca = enforce_matlab_pca_sign_convention(
+            jnp.asarray(vecs[:, ::-1], jnp.float32))
+    get_global_registry().counter(
+        "keystone_pca_fits_total", "PCA fits from a sample's centered Gram"
+    ).inc()
     return pca[:, :dims]
 
 
@@ -183,10 +215,12 @@ def _columns_dataset(data: Dataset) -> Dataset:
     """Flatten a dataset of (d, m) descriptor matrices into one (N, d)
     array of descriptor columns (reference: LocalColumnPCAEstimator —
     flatMap(matrixToColArray))."""
-    cols: List[np.ndarray] = []
-    for m in data.items():
-        cols.append(np.asarray(m).T)
-    return Dataset.from_array(jnp.asarray(np.concatenate(cols, axis=0)))
+    if data.is_array:
+        x = data.array()  # (n, d, m), on the device and staying there
+        return Dataset.from_array(
+            jnp.transpose(x, (0, 2, 1)).reshape(-1, x.shape[1]))
+    return Dataset.from_array(
+        jnp.concatenate([jnp.asarray(m).T for m in data.items()]))
 
 
 @dataclasses.dataclass(eq=False)
@@ -221,9 +255,13 @@ class DistributedColumnPCAEstimator(Estimator, CostModel):
 
 
 @dataclasses.dataclass(eq=False)
-class ColumnPCAEstimator(Estimator, Optimizable):
+class ColumnPCAEstimator(Estimator):
     """Cost-model choice between local and distributed column PCA
-    (reference: PCA.scala:118-156 — OptimizableEstimator)."""
+    (reference: PCA.scala:118-156 — OptimizableEstimator), made at the
+    fit from the sample it is handed: its size is then known, and no pass
+    over a sample of the images is spent on asking (the reference's
+    optimizer rule runs the featurizer on a sample to choose). One machine
+    has nothing to distribute over and fits locally."""
 
     dims: int
     num_machines: Optional[int] = None
@@ -235,9 +273,6 @@ class ColumnPCAEstimator(Estimator, Optimizable):
         ]
 
     def fit(self, data: Dataset):
-        # consult the cost model eagerly (reference default is the
-        # distributed estimator, PCA.scala:128; the graph-level
-        # NodeOptimizationRule replaces this node when sampling is possible)
         return self.optimize([data], data.n).fit(data)
 
     def fit_datasets(self, datasets):
@@ -252,6 +287,8 @@ class ColumnPCAEstimator(Estimator, Optimizable):
         machines = self.num_machines or max(
             len(jax.devices()), 1
         )
+        if machines == 1:
+            return LocalColumnPCAEstimator(self.dims)
         from keystone_tpu.ops.learning.cost import (
             TPU_CPU_WEIGHT,
             TPU_MEM_WEIGHT,

@@ -21,6 +21,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from keystone_tpu.observability.registry import get_global_registry
+from keystone_tpu.observability.tracing import span
 from keystone_tpu.parallel.dataset import Dataset
 from keystone_tpu.utils.precision import mm
 from keystone_tpu.workflow.api import Estimator, FunctionNode, Transformer
@@ -178,8 +180,32 @@ class NormalizeRows(Transformer):
 
     def apply_batch(self, ds: Dataset) -> Dataset:
         x = ds.padded()
+        return Dataset.from_array(_NormalizeRows(self.floor)((), x), n=ds.n)
+
+    def rowwise(self):
+        return _NormalizeRows(float(self.floor)), ()
+
+
+@dataclasses.dataclass(frozen=True)
+class _NormalizeRows:
+    """NormalizeRows' rows-in, rows-out function."""
+
+    floor: float
+    groups_only = True
+
+    def __call__(self, arrays, x):
+        del arrays
         nrm = jnp.linalg.norm(x, axis=-1, keepdims=True)
-        return Dataset.from_array(x / jnp.maximum(nrm, self.floor), n=ds.n)
+        return x / jnp.maximum(nrm, self.floor)
+
+
+def _signed_sqrt(arrays, x):
+    """SignedHellingerMapper's rows-in, rows-out function."""
+    del arrays
+    return jnp.sign(x) * jnp.sqrt(jnp.abs(x))
+
+
+_signed_sqrt.groups_only = True
 
 
 @dataclasses.dataclass(eq=False)
@@ -192,8 +218,10 @@ class SignedHellingerMapper(Transformer):
         return jnp.sign(x) * jnp.sqrt(jnp.abs(x))
 
     def apply_batch(self, ds: Dataset) -> Dataset:
-        x = ds.padded()
-        return Dataset.from_array(jnp.sign(x) * jnp.sqrt(jnp.abs(x)), n=ds.n)
+        return Dataset.from_array(_signed_sqrt((), ds.padded()), n=ds.n)
+
+    def rowwise(self):
+        return _signed_sqrt, ()
 
     def eq_key(self):
         return ("signed_hellinger",)
@@ -322,7 +350,16 @@ class TermFrequency(Transformer):
 class ColumnSampler(Transformer):
     """Sample ``num_cols`` columns of each (d, m) matrix datum — used to
     subsample per-image descriptor sets before PCA/GMM fits (reference:
-    nodes/stats/Sampling.scala:12)."""
+    nodes/stats/Sampling.scala:12).
+
+    A batch of matrices is sampled on the device: the columns of every
+    datum are drawn on the host at once (datum i of this sampler's life
+    from ``default_rng((seed, i))``, the draw ``apply`` makes), put as one
+    index array, and taken a gather a chunk; the matrices themselves never
+    visit the host, and ragged ones (``Dataset`` shape groups) come
+    through a chunk at a time, each dropped once its columns are taken.
+    Span ``stats.column_sample``, scope ``stats.sample``, counter
+    ``keystone_sampled_columns_total``."""
 
     vmap_batch = False
 
@@ -331,16 +368,54 @@ class ColumnSampler(Transformer):
         self.seed = seed
         self._counter = 0
 
-    def apply(self, m):
-        arr = np.asarray(m)
+    def draw(self, columns: int) -> np.ndarray:
+        """The next datum's column indices, given how many it has."""
         # independent draw per datum (reference samples per image)
         rng = np.random.default_rng((self.seed, self._counter))
         self._counter += 1
-        idx = rng.integers(0, arr.shape[1], self.num_cols)
-        return jnp.asarray(arr[:, idx])
+        return rng.integers(0, columns, self.num_cols)
+
+    def apply(self, m):
+        arr = np.asarray(m)
+        return jnp.asarray(arr[:, self.draw(arr.shape[1])])
+
+    def apply_batch(self, ds: Dataset) -> Dataset:
+        groups = ds.grouped()
+        if groups is None:  # items that are no single arrays
+            return ds.map(self.apply)
+        with span("stats.column_sample", n=ds.n, cols=self.num_cols):
+            columns = np.empty(ds.n, np.int64)
+            for places, one in groups.group_rows():
+                columns[places] = one.shape[2]
+            idx = jnp.asarray(
+                np.stack([self.draw(int(m)) for m in columns]), jnp.int32)
+            parts, order = [], []
+            for places, rows in groups.chunks():
+                parts.append(_take_columns(rows, idx, jnp.asarray(places)))
+                order.append(places)
+            out = parts[0] if len(parts) == 1 else jnp.concatenate(parts)
+            order = np.concatenate(order)
+            if not np.array_equal(order, np.arange(ds.n)):
+                out = jnp.take(out, jnp.asarray(np.argsort(order)), axis=0)
+        get_global_registry().counter(
+            "keystone_sampled_columns_total",
+            "columns ColumnSampler took from batches of matrices",
+        ).inc(by=ds.n * self.num_cols)
+        return Dataset.from_array(out, n=ds.n)
 
     def eq_key(self):
         return ("column_sampler", self.num_cols, self.seed)
+
+
+@jax.jit
+def _take_columns(rows, idx, places):
+    """Columns ``idx[places[j]]`` of matrix j of ``rows`` (c, d, m)."""
+    with jax.named_scope("stats.sample"):
+        cols = jnp.take(idx, places, axis=0)  # (c, s)
+        # a matrix at a time: one gather over the whole chunk would index
+        # into gigabytes (58 images' descriptors are 2.2 GB)
+        return jax.lax.map(
+            lambda one: jnp.take(one[0], one[1], axis=1), (rows, cols))
 
 
 class Sampler(FunctionNode):

@@ -132,11 +132,28 @@ class MatrixVectorizer(Transformer):
     def apply(self, m):
         return jnp.ravel(m, order="F")
 
+    def rowwise(self):
+        return _vectorize_matrices, ()
+
     def eq_key(self):
         return ("matrix_vectorizer",)
 
 
+def _vectorize_matrices(arrays, x):
+    """MatrixVectorizer's rows-in, rows-out function: (n, r, c) matrices
+    to (n, r c) column-major vectors."""
+    del arrays
+    return jnp.swapaxes(x, 1, 2).reshape(x.shape[0], -1)
+
+
+_vectorize_matrices.groups_only = True
+
+
 class FloatToDouble(Transformer):
+    """The reference's cast to the driver's float64. With jax's x64 off
+    (the default, and every pipeline here) there is no float64: the node
+    casts to float32, which on a float32 path is the identity."""
+
     def apply(self, x):
         return x.astype(jnp.float64) if jax.config.jax_enable_x64 else x.astype(jnp.float32)
 

@@ -1,6 +1,6 @@
 """``Dataset`` — the framework's N-example collection type (the RDD stand-in).
 
-Three physical modes:
+Four physical modes:
 
 - **array mode**: a pytree of arrays (usually one matrix) with a leading
   example axis, optionally zero-padded to a multiple of the mesh's data-shard
@@ -13,7 +13,16 @@ Three physical modes:
   mode as soon as shapes become uniform. The data decide, not the caller:
   ``uniform_array`` hands a node the items as one array when they all
   share one shape and dtype, and ``Transformer._bucketed_batch`` then
-  returns array mode; only items of two or more shapes stay items.
+  returns array mode; items of two or more shapes become shape groups.
+- **shape groups**: ragged items as one array a shape (``grouped``), each
+  with the places of its rows in the data set. Per-item nodes are not run
+  on it at once: ``then`` notes them, and whoever asks for the data
+  (``Cacher``, an estimator, ``padded`` / ``items``) has the noted run go
+  through a chunk of rows at a time, a chunk's size from bytes
+  (``parallel/chunks.py``), one program per (run, shape, chunk). Only
+  what is asked for is ever whole: a node read by two others is computed
+  once for each. Groups whose rows end up of one shape join into array
+  mode in the data set's order.
 - **host-blocks mode**: a feature matrix column-blocked into HOST-RAM
   numpy arrays (each (padded_n, w_i), C-contiguous). This is the
   out-of-aggregate-HBM training substrate: the reference caches features
@@ -42,18 +51,44 @@ import numpy as np
 
 from keystone_tpu.observability.registry import get_global_registry
 from keystone_tpu.observability.tracing import span
+from keystone_tpu.parallel import chunks as chunks_lib
 from keystone_tpu.parallel import mesh as mesh_lib
+from keystone_tpu.parallel.chunks import leading_dim as _leading_dim
 
 
-def _leading_dim(tree: Any) -> int:
-    # a BCOO (or any array-like) IS the array — don't descend into its
-    # pytree leaves (a BCOO's first leaf is the nse-length values array)
-    if hasattr(tree, "shape"):
-        return tree.shape[0]
-    leaves = jax.tree_util.tree_leaves(tree)
-    if not leaves:
-        raise ValueError("empty pytree")
-    return leaves[0].shape[0]
+def count_chunked(
+    items: int, chunks: int, padded: int, array_items: int, groups: int = 0
+) -> None:
+    """Publish one pass of items through chunk programs
+    (``Transformer._chunked_batch``, a shape-grouped ``Dataset``):
+    ``array_items`` of its ``items`` left as arrays, the others were cut
+    back into items, a slice each."""
+    reg = get_global_registry()
+    reg.counter(
+        "keystone_workflow_items_total",
+        "items through Transformer._bucketed_batch / _chunked_batch",
+    ).inc(by=items)
+    reg.counter(
+        "keystone_workflow_array_items_total",
+        "items of those that left in array mode, never cut into items",
+    ).inc(by=array_items)
+    reg.counter(
+        "keystone_workflow_chunks_total",
+        "jit(vmap) chunk dispatches of Transformer._bucketed_batch",
+    ).inc(by=chunks)
+    reg.counter(
+        "keystone_workflow_padded_rows_total",
+        "rows that filled a short chunk up to the chunk's shape: zeros, "
+        "or in a shape group rows computed a second time",
+    ).inc(by=padded)
+    reg.counter(
+        "keystone_workflow_item_slices_total",
+        "per-item slices that cut chunk outputs back into items",
+    ).inc(by=items - array_items)
+    reg.counter(
+        "keystone_workflow_shape_groups_total",
+        "shape groups that went through chunk programs as arrays",
+    ).inc(by=groups)
 
 
 class HostPuts:
@@ -101,17 +136,29 @@ class Dataset:
         arrays: Any = None,
         items: Optional[List[Any]] = None,
         host_blocks: Optional[List[np.ndarray]] = None,
+        groups: Optional[List[tuple]] = None,
+        steps: tuple = (),
         n: Optional[int] = None,
     ):
-        modes = sum(x is not None for x in (arrays, items, host_blocks))
+        modes = sum(
+            x is not None for x in (arrays, items, host_blocks, groups)
+        )
         if modes != 1:
             raise ValueError(
-                "exactly one of arrays/items/host_blocks required"
+                "exactly one of arrays/items/host_blocks/groups required"
             )
         self._arrays = arrays
         self._items = items
         self._host_blocks = host_blocks
-        if arrays is not None:
+        # shape groups: [(places of the rows in the data set, one array)]
+        # and the per-row functions noted for them, not yet run
+        self._groups = groups
+        self._steps = tuple(steps)
+        if groups is not None:
+            self._n = int(n) if n is not None else sum(
+                len(p) for p, _ in groups
+            )
+        elif arrays is not None:
             self._n = int(n) if n is not None else _leading_dim(arrays)
         elif host_blocks is not None:
             if not host_blocks:
@@ -126,6 +173,7 @@ class Dataset:
             self._n = len(items)
         self._cached = False
         self._uploaded: Any = None
+        self._grouped: Optional["Dataset"] = None
 
     # -- constructors ------------------------------------------------------
 
@@ -146,6 +194,14 @@ class Dataset:
     @staticmethod
     def from_items(items: Sequence[Any]) -> "Dataset":
         return Dataset(items=list(items))
+
+    @staticmethod
+    def from_groups(
+        groups: Sequence[tuple], n: Optional[int] = None, steps: tuple = ()
+    ) -> "Dataset":
+        """Ragged items as one array a shape: ``groups`` is [(places,
+        array)], row j of ``array`` being item ``places[j]``."""
+        return Dataset(groups=list(groups), steps=steps, n=n)
 
     @staticmethod
     def from_host_blocks(
@@ -241,6 +297,10 @@ class Dataset:
         return self._host_blocks is not None
 
     @property
+    def is_grouped(self) -> bool:
+        return self._groups is not None
+
+    @property
     def host_blocks(self) -> List[np.ndarray]:
         if self._host_blocks is None:
             raise ValueError("not a host-blocks dataset")
@@ -284,6 +344,8 @@ class Dataset:
     def items(self) -> List[Any]:
         if self._items is not None:
             return self._items
+        if self._groups is not None:
+            return self._group_items()
         with span("workflow.to_items", n=self._n):
             arrs = self.array()
             host = jax.tree_util.tree_map(np.asarray, arrs)
@@ -302,6 +364,8 @@ class Dataset:
         copy of data the device already has)."""
         if self._uploaded is not None:
             return self._uploaded
+        if self._groups is not None:
+            return self._joined()
         items = self.items()
         keys = {
             (x.shape, str(x.dtype))
@@ -326,6 +390,12 @@ class Dataset:
     def first(self) -> Any:
         if self._items is not None:
             return self._items[0]
+        if self._groups is not None:
+            for places, batch in self.groups():
+                at = np.flatnonzero(np.asarray(places) == 0)
+                if len(at):
+                    return jax.tree_util.tree_map(
+                        lambda a: a[int(at[0])], batch)
         return jax.tree_util.tree_map(lambda a: a[0], self.array())
 
     def take(self, k: int) -> List[Any]:
@@ -336,6 +406,13 @@ class Dataset:
     def to_array_mode(self) -> "Dataset":
         if self.is_array:
             return self
+        if self._groups is not None:
+            joined = self._joined()
+            if joined is None:
+                raise ValueError(
+                    "shape groups of several shapes have no one array"
+                )
+            return Dataset(arrays=joined, n=self._n)
         if self.is_host:
             # materializes the WHOLE feature matrix in HBM — the thing
             # host-blocks mode exists to avoid; legitimate only for
@@ -431,6 +508,8 @@ class Dataset:
         """Materialize device buffers now (reference: Cacher / rdd.cache)."""
         if self.is_array:
             jax.block_until_ready(self._arrays)
+        elif self._groups is not None:
+            jax.block_until_ready([b for _, b in self.groups()])
         self._cached = True
         return self
 
@@ -438,7 +517,185 @@ class Dataset:
     def is_cached(self) -> bool:
         return self._cached
 
+    # -- shape groups ------------------------------------------------------
+
+    def grouped(self) -> Optional["Dataset"]:
+        """This data set as shape groups: itself if it is one, its one
+        array as the one group, its items grouped by shape and dtype with
+        a ``np.stack`` and a put a group for those on the host (kept, as
+        ``uniform_array`` keeps its upload). None where an item is not a
+        single array."""
+        if self._groups is not None:
+            return self
+        if self.is_array:
+            x = self.array()
+            if not hasattr(x, "shape"):
+                return None
+            return Dataset.from_groups([(np.arange(self._n), x)], n=self._n)
+        if self.is_host:
+            return None
+        if self._uploaded is not None:
+            return Dataset.from_groups(
+                [(np.arange(self._n), self._uploaded)], n=self._n)
+        if self._grouped is not None:
+            return self._grouped
+        by_shape: dict = {}
+        for i, x in enumerate(self._items):
+            if not (hasattr(x, "shape") and hasattr(x, "dtype")):
+                return None
+            by_shape.setdefault((x.shape, str(x.dtype)), []).append(i)
+        h2d, host_items, groups = HostPuts(), 0, []
+        for places in by_shape.values():
+            rows = [self._items[i] for i in places]
+            if any(isinstance(x, jax.Array) for x in rows):
+                host_items += sum(not isinstance(x, jax.Array) for x in rows)
+                batch = jnp.stack([h2d.asarray(x) for x in rows])
+            else:
+                host_items += len(rows)
+                batch = h2d.asarray(np.stack(rows))
+            groups.append((np.asarray(places), batch))
+        h2d.count(host_items)
+        self._grouped = Dataset.from_groups(groups, n=self._n)
+        return self._grouped
+
+    def then(self, fn: Any, arrays: Any) -> "Dataset":
+        """These shape groups with one more per-row function noted
+        (``Transformer.rowwise``'s ``fn(arrays, batch)``); nothing runs
+        until the rows are asked for."""
+        return Dataset(
+            groups=self._groups, steps=self._steps + ((fn, arrays),),
+            n=self._n,
+        )
+
+    def group_rows(self) -> List[tuple]:
+        """[(places, shapes of one row)] of each group once the noted
+        functions have run, by ``jax.eval_shape`` alone."""
+        out = []
+        for places, batch in self._groups:
+            one = jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct((1,) + a.shape[1:], a.dtype),
+                batch)
+            if self._steps:
+                fns, arrays = zip(*self._steps)
+                one = chunks_lib.account_of(fns, arrays, batch)[1]
+            out.append((places, one))
+        return out
+
+    def _parts(self, batch: Any, keep: bool) -> tuple:
+        """(rows a part, shapes of one row of the result, rows each
+        program computes beyond the group's own, the (start, rows through
+        the noted functions) of each chunk as they are asked for) for one
+        group: the chunk's rows from bytes and kept a shape
+        (``chunks.planned_rows``; ``keep`` where the group's whole result
+        stays on the device), a group shorter than the chunk filled up to
+        it, one program per (function, shape, chunk), span
+        ``workflow.groups.chunk`` a chunk."""
+        fns, arrays = zip(*self._steps) if self._steps else ((), ())
+        chunk, one = chunks_lib.planned_rows(fns, arrays, batch, keep)
+        rows = _leading_dim(batch)
+        starts = chunks_lib.chunk_starts(rows, min(chunk, rows))
+
+        def run():
+            # two chunks in flight and no more: the host would dispatch a
+            # whole group ahead, and a chunk's outputs are allocated when
+            # it is dispatched (a fit's peak on a v5e read 13.8 GB with
+            # the host held back by compiling and up to 15.4 of 16.9
+            # without: PERF.md section 5, PR 37)
+            ahead = None
+            for start in starts:
+                with span("workflow.groups.chunk", n=chunk):
+                    part = chunks_lib.take_chunk(
+                        fns, chunk, arrays, batch, start)
+                    if ahead is not None:
+                        jax.block_until_ready(ahead)
+                    ahead = part
+                    yield start, part
+
+        return min(chunk, rows), one, len(starts) * chunk - rows, run()
+
+    def chunks(self):
+        """(places, rows) a chunk, the noted functions run on it: for a
+        reader that keeps a little of each chunk (a sampler) and lets the
+        rest go."""
+        for places, batch in self._groups:
+            chunk, _, _, parts = self._parts(batch, keep=False)
+            done = 0
+            for start, part in parts:
+                new = start + chunk - done  # the last chunk overlaps
+                if new < chunk:
+                    part = jax.tree_util.tree_map(
+                        lambda a: a[chunk - new:], part)
+                yield places[done:done + new], part
+                done += new
+
+    def groups(self) -> List[tuple]:
+        """[(places, array)], the noted functions run: each group through
+        all of them a chunk of rows at a time, written into the group's
+        result in place. Span ``workflow.groups`` once; the chunk counters
+        of ``count_chunked``."""
+        if not self._steps:
+            return self._groups
+        out, programs, twice = [], 0, 0
+        with span("workflow.groups", n=self._n, groups=len(self._groups)):
+            for places, batch in self._groups:
+                rows = len(places)
+                chunk, one, more, parts = self._parts(batch, keep=True)
+                if chunk == rows:
+                    res = next(parts)[1]
+                else:
+                    res = jax.tree_util.tree_map(
+                        lambda a: jnp.zeros((rows,) + a.shape[1:], a.dtype),
+                        one)
+                    for start, part in parts:
+                        res = chunks_lib._write_rows(res, part, start)
+                out.append((places, res))
+                programs += len(chunks_lib.chunk_starts(rows, chunk))
+                twice += more
+        count_chunked(self._n, programs, twice, self._n, len(out))
+        self._groups, self._steps = out, ()
+        return out
+
+    def _joined(self) -> Optional[Any]:
+        """The groups' rows as one array in the data set's order, where
+        they are all of one shape; else None. One concatenate and one
+        gather, none where the one group is in order already."""
+        if self._uploaded is not None:
+            return self._uploaded
+        groups = self.groups()
+        keys = {
+            chunks_lib.shape_key(b, lambda a: a.shape[1:]) for _, b in groups
+        }
+        if len(keys) != 1:
+            return None
+        places = np.concatenate([np.asarray(p) for p, _ in groups])
+        joined = groups[0][1] if len(groups) == 1 else (
+            jax.tree_util.tree_map(
+                lambda *parts: jnp.concatenate(parts),
+                *[b for _, b in groups]))
+        if not np.array_equal(places, np.arange(len(places))):
+            order = jnp.asarray(np.argsort(places))
+            joined = jax.tree_util.tree_map(
+                lambda a: jnp.take(a, order, axis=0), joined)
+        self._uploaded = joined
+        return joined
+
+    def _group_items(self) -> List[Any]:
+        """The groups cut into items, a slice each (and counted so)."""
+        out: List[Any] = [None] * self._n
+        with span("workflow.to_items", n=self._n):
+            for places, batch in self.groups():
+                for j, i in enumerate(places):
+                    out[int(i)] = jax.tree_util.tree_map(
+                        lambda a, j=j: a[j], batch)
+        count_chunked(self._n, 0, 0, 0)
+        return out
+
     def __repr__(self) -> str:
+        if self._groups is not None:
+            return (
+                f"Dataset(groups={len(self._groups)}, n={self._n}, "
+                f"noted={len(self._steps)})"
+            )
         if self.is_host:
             return (
                 f"Dataset(host_blocks, n={self._n}, "

@@ -39,7 +39,6 @@ from keystone_tpu.ops.stats import (
 from keystone_tpu.ops.util.cacher import Cacher
 from keystone_tpu.ops.util.nodes import (
     ClassLabelIndicatorsFromIntArrayLabels,
-    FloatToDouble,
     MatrixVectorizer,
 )
 from keystone_tpu.parallel.dataset import Dataset
@@ -57,17 +56,26 @@ class SIFTFisherConfig:
     desc_dim: int = 80
     vocab_size: int = 256
     scale_step: int = 0
-    num_pca_samples_per_image: int = 10
-    num_gmm_samples_per_image: int = 10
+    # descriptors sampled for the PCA and for the GMM over the whole
+    # training set (VOCSIFTFisher.scala: numPcaSamples, numGmmSamples)
+    num_pca_samples: int = 1_000_000
+    num_gmm_samples: int = 1_000_000
     num_classes: int = NUM_VOC_CLASSES
     seed: int = 0
     pca_file: Optional[str] = None
     gmm_files: Optional[tuple] = None
 
 
+def samples_per_image(num_samples: int, num_images: int) -> int:
+    """Columns ``ColumnSampler`` draws of each image's descriptors: the
+    Scala file's ``numSamples / numImgs`` (integer division), at least 1."""
+    return max(num_samples // max(num_images, 1), 1)
+
+
 def build_pipeline(
     training_data: Dataset, training_labels, conf: SIFTFisherConfig
 ) -> Pipeline:
+    num_images = Dataset.of(training_data).n
     sift_extractor = (
         PixelScaler()
         .and_then(GrayScaler())
@@ -82,7 +90,8 @@ def build_pipeline(
         )
     else:
         sampled = ColumnSampler(
-            conf.num_pca_samples_per_image, seed=conf.seed
+            samples_per_image(conf.num_pca_samples, num_images),
+            seed=conf.seed,
         )(sift_extractor(training_data))
         pca = ColumnPCAEstimator(conf.desc_dim).with_data(sampled)
         pca_featurizer = sift_extractor.and_then(pca)
@@ -93,7 +102,8 @@ def build_pipeline(
         fisher_featurizer = pca_featurizer.and_then(FisherVector(gmm))
     else:
         sampled = ColumnSampler(
-            conf.num_gmm_samples_per_image, seed=conf.seed + 1
+            samples_per_image(conf.num_gmm_samples, num_images),
+            seed=conf.seed + 1,
         )(pca_featurizer(training_data))
         fv = GMMFisherVectorEstimator(
             conf.vocab_size, seed=conf.seed
@@ -101,8 +111,9 @@ def build_pipeline(
         fisher_featurizer = pca_featurizer.and_then(fv)
 
     fisher_featurizer = (
-        fisher_featurizer.and_then(FloatToDouble())
-        .and_then(MatrixVectorizer())
+        # (the reference's FloatToDouble stood here: a cast to the driver's
+        # float64, which on this float32 path changed nothing)
+        fisher_featurizer.and_then(MatrixVectorizer())
         .and_then(NormalizeRows())
         .and_then(SignedHellingerMapper())
         .and_then(NormalizeRows())
@@ -145,10 +156,12 @@ def main(argv: Optional[list] = None) -> int:
     p.add_argument("--descDim", type=int, default=80)
     p.add_argument("--vocabSize", type=int, default=256)
     p.add_argument("--scaleStep", type=int, default=0)
+    p.add_argument("--numPcaSamples", type=int, default=1_000_000)
+    p.add_argument("--numGmmSamples", type=int, default=1_000_000)
     a = p.parse_args(argv)
     conf = SIFTFisherConfig(
         a.trainLocation, a.testLocation, a.labelPath, a.lam, a.descDim,
-        a.vocabSize, a.scaleStep,
+        a.vocabSize, a.scaleStep, a.numPcaSamples, a.numGmmSamples,
     )
     train = VOCLoader(conf.train_location, conf.label_path)
     test = VOCLoader(conf.test_location, conf.label_path)

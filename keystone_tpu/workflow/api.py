@@ -18,7 +18,7 @@ from __future__ import annotations
 import dataclasses
 import hashlib
 import pickle
-from functools import lru_cache, partial
+from functools import partial
 from typing import Any, Callable, Dict, List, Optional, Sequence, Union
 
 import jax
@@ -27,7 +27,20 @@ import numpy as np
 
 from keystone_tpu.observability.registry import get_global_registry
 from keystone_tpu.observability.tracing import span
-from keystone_tpu.parallel.dataset import Dataset, HostPuts, _leading_dim
+from keystone_tpu.parallel.chunks import (
+    _run_chunk,
+    account_of,
+    chunk_starts,
+    device_free_bytes as _device_free_bytes,
+    leading_dim as _leading_dim,
+    rows_a_chunk,
+    tree_bytes as _tree_bytes,
+    valid_rows as _valid_rows,
+)
+from keystone_tpu.parallel.dataset import (
+    Dataset,
+    count_chunked as _count_chunked,
+)
 from keystone_tpu.workflow.executor import GraphExecutor, PipelineEnv
 from keystone_tpu.workflow.expressions import (
     DatasetExpression,
@@ -59,8 +72,9 @@ from keystone_tpu.workflow.rules import UnusedBranchRemovalRule
 # PERF.md). What the node is handed decides the
 # rest: items of one shape and dtype, or an array, go through as slices of
 # one array and come back in array mode (``_chunked_batch``); items of two
-# or more shapes are grouped by shape and come back as items
-# (``_bucketed_batch``); a tracer, or an array of at most this many rows,
+# or more shapes become one array a shape (``Dataset.grouped``), on which
+# the node is noted and runs when its rows are asked for, a chunk sized
+# from bytes at a time; a tracer, or an array of at most this many rows,
 # is one ``vmap`` call.
 BUCKET_CHUNK = 128
 
@@ -83,35 +97,6 @@ def _zero_padded(batch: Any, rows: int) -> Any:
         return batch
     pad = jnp.zeros((short,) + batch.shape[1:], batch.dtype)
     return jnp.concatenate([batch, pad])
-
-
-def _count_chunked(
-    items: int, chunks: int, padded: int, array_items: int
-) -> None:
-    """Publish one call of ``Transformer._bucketed_batch`` or
-    ``_chunked_batch``: ``array_items`` of its ``items`` left in array
-    mode, the others were cut back into items, a slice each."""
-    reg = get_global_registry()
-    reg.counter(
-        "keystone_workflow_items_total",
-        "items through Transformer._bucketed_batch / _chunked_batch",
-    ).inc(by=items)
-    reg.counter(
-        "keystone_workflow_array_items_total",
-        "items of those that left in array mode, never cut into items",
-    ).inc(by=array_items)
-    reg.counter(
-        "keystone_workflow_chunks_total",
-        "jit(vmap) chunk dispatches of Transformer._bucketed_batch",
-    ).inc(by=chunks)
-    reg.counter(
-        "keystone_workflow_padded_rows_total",
-        "zero rows that padded a short chunk to the chunk's shape",
-    ).inc(by=padded)
-    reg.counter(
-        "keystone_workflow_item_slices_total",
-        "per-item slices that cut chunk outputs back into items",
-    ).inc(by=items - array_items)
 
 
 def _array_digest(a: np.ndarray) -> Any:
@@ -398,7 +383,14 @@ class Transformer(Chainable, TransformerOperator):
         given the functions that follow it in a run, returns ``(folded,
         k)`` where one function does its work and that of the next
         ``k``, or None; ``fn.held(arrays, batch)`` returns the arrays a
-        row keeps on the device beside its result."""
+        row keeps on the device beside its result. A node that gives a
+        function is also noted on ragged data (``Dataset.then``) and
+        runs, with the nodes noted before and after it, when the rows
+        are asked for; ``fn.groups_only`` (true) says that the function
+        is for that alone: on one array the node keeps its own
+        ``apply_batch`` and ``RowwiseRunRule`` leaves it out of runs
+        (the flagship's featurizers, whose programs on uniform batches
+        are as they were before shape groups)."""
         return None
 
     def _jitted_vmap(self):
@@ -421,50 +413,19 @@ class Transformer(Chainable, TransformerOperator):
     def _bucketed_batch(self, ds: Dataset) -> Dataset:
         """Items through ``jit(vmap(apply))`` in chunks. Items of one shape
         and dtype become one array, go through ``_chunked_batch`` and come
-        back in array mode; ragged items are grouped by shape, each group
-        in chunks, and come back as items. Spans: ``workflow.upload`` once,
-        ``workflow.stack`` / ``.apply`` / ``.slice`` per chunk — never one
-        per item."""
-        items = ds.items()
-        arrays = []
-        with span("workflow.upload", n=len(items)):
-            batch = ds.uniform_array()
-            if batch is None:
-                h2d = HostPuts()
-                arrays = [h2d.asarray(x) for x in items]
-                h2d.count(h2d.puts)  # an item is one array here: a put each
+        back in array mode; ragged items become one array a shape
+        (``Dataset.grouped``: a put a group) on which this node is noted,
+        to run when its rows are asked for. Spans: ``workflow.upload``
+        once, ``workflow.stack`` / ``.apply`` / ``.slice`` per chunk —
+        never one per item."""
+        with span("workflow.upload", n=ds.n):
+            batch = None if ds.is_grouped else ds.uniform_array()
+            groups = ds.grouped() if batch is None else None
         if batch is not None:
-            return self._chunked_batch(batch, len(items))
-        by_shape: Dict[tuple, List[int]] = {}
-        for i, a in enumerate(arrays):
-            by_shape.setdefault((a.shape, str(a.dtype)), []).append(i)
-        out: List[Any] = [None] * len(items)
-        fn = self._jitted_vmap()
-        chunks = padded = 0
-        for idxs in by_shape.values():
-            # a group larger than BUCKET_CHUNK goes through in chunks of
-            # that many items, the tail zero-padded to the same shape:
-            # the featurizer's in-flight intermediates are bounded by
-            # the chunk, not the dataset, and the group still compiles
-            # one program
-            chunk = min(len(idxs), BUCKET_CHUNK)
-            for s in range(0, len(idxs), chunk):
-                part = idxs[s : s + chunk]
-                with span("workflow.stack", n=len(part)):
-                    batch = _zero_padded(
-                        jnp.stack([arrays[i] for i in part]), chunk
-                    )
-                with span("workflow.apply", n=chunk):
-                    res = fn(batch)
-                with span("workflow.slice", n=len(part)):
-                    for j, i in enumerate(part):
-                        out[i] = jax.tree_util.tree_map(
-                            lambda a, j=j: a[j], res
-                        )
-                chunks += 1
-                padded += chunk - len(part)
-        _count_chunked(len(items), chunks, padded, array_items=0)
-        return Dataset.from_items(out)
+            return self._chunked_batch(batch, ds.n)
+        if groups is None:  # items that are no arrays
+            return ds.map(self.apply)
+        return groups.then(*(self.rowwise() or (_VmapRows(self), ())))
 
     def _chunked_batch(self, x: Any, n: int) -> Dataset:
         """The rows of one array through ``jit(vmap(apply))``, a chunk of
@@ -502,7 +463,12 @@ class Transformer(Chainable, TransformerOperator):
         return self.apply(inputs[0])
 
     def batch_transform(self, inputs: Sequence[Dataset]) -> Dataset:
-        return self.apply_batch(inputs[0])
+        ds = inputs[0]
+        if ds.is_grouped:
+            step = self.rowwise()
+            if step is not None:
+                return ds.then(*step)
+        return self.apply_batch(ds)
 
     def to_pipeline(self) -> Pipeline:
         g, src = EMPTY_GRAPH.add_source()
@@ -521,22 +487,17 @@ class Transformer(Chainable, TransformerOperator):
         return type(self).__name__
 
 
-def _device_free_bytes(batch: Any) -> Optional[int]:
-    """What the allocator of the device that holds ``batch`` could still
-    hand out: its limit less what is live. None where the backend keeps
-    no such account (the CPU)."""
-    device = next(iter(jax.tree_util.tree_leaves(batch)[0].devices()))
-    stats = device.memory_stats() or {}
-    if "bytes_limit" not in stats or "bytes_in_use" not in stats:
-        return None
-    return int(stats["bytes_limit"]) - int(stats["bytes_in_use"])
+@dataclasses.dataclass(frozen=True)
+class _VmapRows:
+    """``jit(vmap(node.apply))`` as a rows function for a node that gives
+    none of its own; it compares by the node, so two nodes of equal
+    settings compile twice."""
 
+    node: Any
 
-def _tree_bytes(tree: Any) -> int:
-    return sum(
-        int(np.prod(a.shape)) * a.dtype.itemsize
-        for a in jax.tree_util.tree_leaves(tree)
-    )
+    def __call__(self, arrays, x):
+        del arrays
+        return jax.vmap(self.node.apply)(x)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -565,32 +526,6 @@ class RunPlan:
         return self.rows * _tree_bytes(self.out_item)
 
 
-def _shape_key(tree: Any, shape: Callable) -> tuple:
-    """A pytree of arrays as a hashable (structure, shapes and dtypes)."""
-    leaves, treedef = jax.tree_util.tree_flatten(tree)
-    return treedef, tuple(
-        jax.ShapeDtypeStruct(shape(a), a.dtype) for a in leaves
-    )
-
-
-@lru_cache(maxsize=64)
-def _row_account(fns: tuple, arrays_key: tuple, one_key: tuple) -> tuple:
-    """(the bytes one row holds across the functions, the shapes of one
-    row of the last one's result), by ``jax.eval_shape``. Kept by the
-    functions and the shapes: a fit plans the same run again, and
-    tracing a folded function takes the host tens of milliseconds in
-    which the chip has nothing to do."""
-    arrays = jax.tree_util.tree_unflatten(*arrays_key)
-    one = jax.tree_util.tree_unflatten(*one_key)
-    item_bytes = 0
-    for fn, arr in zip(fns, arrays):
-        if hasattr(fn, "held"):
-            item_bytes += _tree_bytes(jax.eval_shape(fn.held, arr, one))
-        one = jax.eval_shape(fn, arr, one)
-        item_bytes += _tree_bytes(one)
-    return item_bytes, one
-
-
 def plan_rowwise_run(
     fns: Sequence[Callable], arrays: Sequence[Any], batch: Any,
     free_bytes: Optional[int],
@@ -607,20 +542,10 @@ def plan_rowwise_run(
     twice are fewer than there are chunks. The batch goes through whole
     where it fits, or where the backend gives no account of its memory."""
     rows = _leading_dim(batch)
-    item_bytes, one = _row_account(
-        tuple(fns),
-        _shape_key(arrays, lambda a: a.shape),
-        _shape_key(batch, lambda a: (1,) + a.shape[1:]),
-    )
+    item_bytes, one = account_of(fns, arrays, batch)
     whole = RunPlan(rows, rows, item_bytes, one)
-    if free_bytes is None:
-        return whole
-    budget = (free_bytes - whole.out_bytes) // 2
-    if rows * item_bytes <= budget:
-        return whole
-    fit = max(budget // max(item_bytes, 1), 1)
-    chunks = -(-rows // (1 << (int(fit).bit_length() - 1)))
-    return dataclasses.replace(whole, chunk_rows=-(-rows // chunks))
+    return dataclasses.replace(whole, chunk_rows=rows_a_chunk(
+        rows, item_bytes, whole.out_bytes, free_bytes))
 
 
 def fold_rowwise(fns: Sequence[Callable], arrays: Sequence[Any]) -> tuple:
@@ -645,36 +570,6 @@ def run_rowwise(fns, arrays, batch):
     for fn, arr in zip(fns, arrays):
         batch = fn(arr, batch)
     return batch
-
-
-def _valid_rows(part, start, n):
-    """``part`` with the rows at ``start`` + i >= ``n`` zeroed (the
-    Dataset's padding rule)."""
-    valid = start + jnp.arange(_leading_dim(part)) < n
-    return jax.tree_util.tree_map(
-        lambda r: jnp.where(
-            valid.reshape((-1,) + (1,) * (r.ndim - 1)), r, 0
-        ),
-        part,
-    )
-
-
-@partial(jax.jit, static_argnums=(0, 1), donate_argnums=(3,))
-def _run_chunk(fns, chunk_rows, arrays, out, batch, start, n):
-    """One chunk of a ``RowwiseRun``: rows [start, start + chunk_rows)
-    of ``batch`` through every function of the run, rows past ``n``
-    zeroed, written into ``out`` in place."""
-    part = jax.tree_util.tree_map(
-        lambda a: jax.lax.dynamic_slice_in_dim(a, start, chunk_rows), batch
-    )
-    for fn, arr in zip(fns, arrays):
-        part = fn(arr, part)
-    return jax.tree_util.tree_map(
-        lambda o, r: jax.lax.dynamic_update_slice_in_dim(
-            o, r.astype(o.dtype), start, 0
-        ),
-        out, _valid_rows(part, start, n),
-    )
 
 
 class RowwiseRun(Transformer):
@@ -718,7 +613,7 @@ class RowwiseRun(Transformer):
 
     def _node_by_node(self, ds: Dataset) -> Dataset:
         for node in self.nodes:
-            ds = node.apply_batch(ds)
+            ds = node.batch_transform([ds])
         return ds
 
     def _parts(self) -> tuple:
@@ -760,7 +655,7 @@ class RowwiseRun(Transformer):
         arrays: tuple,
     ) -> Dataset:
         rows, chunk = plan.rows, plan.chunk_rows
-        starts = list(range(0, rows - chunk, chunk)) + [rows - chunk]
+        starts = chunk_starts(rows, chunk)
         with span("workflow.run", n=ds.n, chunks=len(starts),
                   chunk_rows=chunk, chunk_bytes=plan.chunk_bytes):
             out = jax.tree_util.tree_map(

@@ -178,11 +178,12 @@ class RowwiseRunRule(Rule):
 
         def rowwise(n) -> bool:
             op = graph.operators.get(n)
-            return (
-                isinstance(op, Transformer)
-                and len(graph.dependencies[n]) == 1
-                and op.rowwise() is not None
-            )
+            if not isinstance(op, Transformer) \
+                    or len(graph.dependencies[n]) != 1:
+                return False
+            step = op.rowwise()
+            return step is not None \
+                and not getattr(step[0], "groups_only", False)
 
         users: Dict[object, List[object]] = {}
         for n, deps in graph.dependencies.items():

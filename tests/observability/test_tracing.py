@@ -573,18 +573,23 @@ def test_bucketed_batch_counters(monkeypatch):
         assert _counter("keystone_workflow_h2d_items_total") == 0
         assert _counter("keystone_workflow_h2d_transfers_total") == 0
         reset_global_registry()
-        # ragged: a put and a slice an item, as before; chunks 2 + 1 and 2
+        # ragged: one array a shape, a put a group, no item cut until
+        # items are asked for (and then a slice each, counted)
         ragged = host[:3] + [np.zeros((2, 6, 3), np.uint8)] * 2
         out = _bucketed(monkeypatch, ragged)()
-        assert not out.is_array and len(out.items()) == 5
+        assert out.is_grouped and len(out.groups()) == 2
         assert _counter("keystone_workflow_items_total") == 5
-        assert _counter("keystone_workflow_array_items_total") == 0
-        assert _counter("keystone_workflow_chunks_total") == 3
-        assert _counter("keystone_workflow_padded_rows_total") == 1
-        assert _counter("keystone_workflow_item_slices_total") == 5
+        assert _counter("keystone_workflow_array_items_total") == 5
+        assert _counter("keystone_workflow_shape_groups_total") == 2
+        assert _counter("keystone_workflow_chunks_total") == 2
+        assert _counter("keystone_workflow_padded_rows_total") == 0
+        assert _counter("keystone_workflow_item_slices_total") == 0
         assert _counter("keystone_workflow_h2d_items_total") == 5
-        assert _counter("keystone_workflow_h2d_transfers_total") == 5
+        assert _counter("keystone_workflow_h2d_transfers_total") == 2
         assert _counter("keystone_workflow_h2d_bytes_total") == 3 * 48 + 2 * 36
+        assert len(out.items()) == 5
+        assert _counter("keystone_workflow_items_total") == 10
+        assert _counter("keystone_workflow_item_slices_total") == 5
     finally:
         reset_global_registry()
 

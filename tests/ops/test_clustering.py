@@ -70,14 +70,50 @@ def test_gmm_csv_load(tmp_path):
     assert out.shape == (2,)
 
 
-def test_fused_gmm_matches_host_stepped_em():
-    """The fused lax.while_loop EM (enceval-native analogue) and the
-    host-stepped EM produce the same model from the same init/seed."""
-    from keystone_tpu.ops.learning import (
-        FusedGMMEstimator,
-        GaussianMixtureModelEstimator,
-        OptimizableGMMEstimator,
-    )
+def _stepped_em(est, X):
+    """The EM the estimator replaced, stepped from the host with the
+    posteriors written out whole (GaussianMixtureModelEstimator.scala's
+    loop): (means (k, d), variances, weights, rounds begun, reason), from
+    the estimator's own start."""
+    import jax.numpy as jnp
+
+    X = np.asarray(X, np.float64)
+    n, d = X.shape
+    mu, var, w, var_lb, _ = (
+        None if a is None else np.asarray(a, np.float64)
+        for a in est.initialize(jnp.asarray(X, jnp.float32).T))
+    prev, rounds, reason = None, 0, "max_iter"
+    for _ in range(est.max_iterations):
+        rounds += 1
+        llh = (-0.5 * (X * X) @ (1 / var).T + X @ (mu / var).T
+               - 0.5 * np.sum(mu * mu / var, 1)
+               - 0.5 * np.sum(np.log(2 * np.pi * var), 1) + np.log(w))
+        top = llh.max(1, keepdims=True)
+        e = np.exp(llh - top)
+        cost = float(np.mean(top[:, 0] + np.log(e.sum(1))))
+        if prev is not None and cost - prev < est.stop_tolerance * abs(prev):
+            reason = "tolerance"
+            break
+        prev = cost
+        q = e / e.sum(1, keepdims=True)
+        q = np.where(q > est.weight_threshold, q, 0.0)
+        q /= q.sum(1, keepdims=True)
+        q_sum = q.sum(0)
+        if np.any(q_sum < est.min_cluster_size):
+            reason = "cluster_floor"
+            break
+        w = q_sum / n
+        mu = (q.T @ X) / q_sum[:, None]
+        var = np.maximum((q.T @ (X * X)) / q_sum[:, None] - mu * mu, var_lb)
+    return mu, var, w, rounds, reason
+
+
+@pytest.mark.parametrize("case", ["tolerance", "max_iter", "cluster_floor"])
+def test_device_em_matches_the_stepped_em(case):
+    """The one-program EM (blocked statistics, no posteriors written
+    out) against the host-stepped EM it replaced, from the same start:
+    the model, the rounds begun and the reason it stopped."""
+    from keystone_tpu.ops.learning import GaussianMixtureModelEstimator
 
     rng = np.random.default_rng(0)
     centers = np.asarray([[0.0, 0.0], [5.0, 5.0], [-5.0, 5.0]], np.float32)
@@ -85,30 +121,54 @@ def test_fused_gmm_matches_host_stepped_em():
         rng.standard_normal((120, 2)).astype(np.float32) * 0.4 + c
         for c in centers
     ])
-    kwargs = dict(k=3, max_iterations=30, min_cluster_size=5, seed=1)
-    host = GaussianMixtureModelEstimator(**kwargs).fit(X)
-    fused = FusedGMMEstimator(**kwargs).fit(X)
-    np.testing.assert_allclose(
-        np.asarray(fused.means), np.asarray(host.means), rtol=1e-3,
-        atol=1e-3,
-    )
-    np.testing.assert_allclose(
-        np.asarray(fused.weights), np.asarray(host.weights), atol=1e-3
-    )
-    # both recover the true centers (column layout (d, k))
-    mu = np.sort(np.asarray(fused.means).T, axis=0)
-    np.testing.assert_allclose(mu, np.sort(centers, axis=0), atol=0.3)
+    kwargs = {
+        "tolerance": dict(k=3, max_iterations=30, min_cluster_size=5),
+        "max_iter": dict(k=3, max_iterations=2, min_cluster_size=5,
+                         stop_tolerance=-1.0),
+        "cluster_floor": dict(k=3, max_iterations=30,
+                              min_cluster_size=200),
+    }[case]
+    est = GaussianMixtureModelEstimator(seed=1, **kwargs)
+    got = est.fit(X)
+    mu, var, w, rounds, reason = _stepped_em(est, X)
+    assert got.fit_info["reason"] == reason == case
+    assert got.fit_info["iterations"] == rounds
+    np.testing.assert_allclose(np.asarray(got.means).T, mu, rtol=1e-4,
+                               atol=1e-4)
+    np.testing.assert_allclose(np.asarray(got.variances).T, var, rtol=1e-3,
+                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(got.weights), w, atol=1e-5)
+    if case == "tolerance":  # and it recovers the true centers
+        np.testing.assert_allclose(
+            np.sort(np.asarray(got.means).T, axis=0),
+            np.sort(centers, axis=0), atol=0.3)
 
 
-def test_optimizable_gmm_picks_fused_at_k32():
-    from keystone_tpu.ops.learning import (
-        FusedGMMEstimator,
-        GaussianMixtureModelEstimator,
-        OptimizableGMMEstimator,
-    )
+def test_device_kmeans_pp_draw_is_the_host_loops():
+    """The k-means++ start drawn on the device from the host generator's
+    uniforms takes the seeds ``KMeansPlusPlusEstimator``'s host loop
+    takes, and a random start needs none."""
+    import jax.numpy as jnp
 
-    small = OptimizableGMMEstimator(k=8)
-    big = OptimizableGMMEstimator(k=32)
-    assert type(small.default) is GaussianMixtureModelEstimator
-    assert type(big.default) is FusedGMMEstimator
-    assert type(big.optimize([], -1)) is FusedGMMEstimator
+    from keystone_tpu.ops.learning import GaussianMixtureModelEstimator
+    from keystone_tpu.ops.learning.gmm import RANDOM_INITIALIZATION
+
+    rng = np.random.default_rng(3)
+    X = rng.standard_normal((400, 5)).astype(np.float64)
+    k, seed = 7, 11
+    est = GaussianMixtureModelEstimator(k=k, seed=seed)
+    seeds = np.asarray(est.initialize(jnp.asarray(X, jnp.float32).T)[4])
+    host = np.random.default_rng(seed)
+    want, half, dist = [int(host.integers(0, len(X)))], \
+        0.5 * np.sum(X * X, axis=1), None
+    for j in range(k - 1):
+        c = X[want[j]]
+        new = half - X @ c + 0.5 * (c @ c)
+        dist = new if dist is None else np.minimum(new, dist)
+        p = np.maximum(dist, 0.0)
+        want.append(int(host.choice(len(X), p=p / p.sum())))
+    assert seeds.tolist() == want
+    rand = GaussianMixtureModelEstimator(
+        k=k, seed=seed, initialization_method=RANDOM_INITIALIZATION)
+    assert rand.initialize(jnp.asarray(X, jnp.float32).T)[4] is None
+    assert rand.fit(X).fit_info["seeds"] is None

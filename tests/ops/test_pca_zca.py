@@ -92,3 +92,51 @@ def test_zca_whitening_decorrelates():
     np.testing.assert_allclose(
         np.asarray(w.whitener), np.asarray(w.whitener).T, atol=1e-4
     )
+
+
+def test_column_pca_fit_stays_on_the_device_and_counts():
+    """A column PCA over an array of (d, m) matrices takes its columns
+    without a host copy (``_columns_dataset``), fits from the centered
+    Gram, matches the SVD of the centered sample up to sign, and leaves
+    ``pca.fit`` and ``keystone_pca_fits_total`` behind."""
+    import jax.numpy as jnp
+
+    from keystone_tpu.observability.registry import (
+        get_global_registry, reset_global_registry,
+    )
+    from keystone_tpu.observability.tracing import (
+        disable_tracing, enable_tracing,
+    )
+    from keystone_tpu.ops.learning.pca import _columns_dataset
+
+    rng = np.random.default_rng(4)
+    scale = np.linspace(3.0, 0.5, 6)[:, None]
+    mats = (rng.standard_normal((5, 6, 40)) * scale).astype(np.float32)
+    ds = Dataset.from_array(jnp.asarray(mats))
+    cols = _columns_dataset(ds)
+    np.testing.assert_array_equal(
+        np.asarray(cols.array()),
+        np.concatenate([m.T for m in mats]))
+    tr = enable_tracing()
+    tr.clear()
+    reset_global_registry()
+    try:
+        t = LocalColumnPCAEstimator(3).fit(ds)
+        names = [s.name for s in tr.recent()]
+        fits = sum(
+            s.value for f in get_global_registry().collect()
+            if f.name == "keystone_pca_fits_total"
+            for s in f.samples if s.suffix == "")
+    finally:
+        disable_tracing()
+        tr.clear()
+        reset_global_registry()
+    x = np.concatenate([m.T for m in mats]).astype(np.float64)
+    _, _, vt = np.linalg.svd(x - x.mean(0), full_matrices=False)
+    got = np.asarray(t.pca_mat)
+    assert got.shape == (6, 3)
+    np.testing.assert_allclose(np.abs(got), np.abs(vt[:3].T), atol=2e-4)
+    assert names.count("pca.fit") == 1 and fits == 1
+    # one machine has nothing to distribute over
+    assert isinstance(ColumnPCAEstimator(3, num_machines=1).optimize(
+        [Dataset.from_items(list(mats))], 5), LocalColumnPCAEstimator)

@@ -16,7 +16,7 @@ import pytest
 
 from keystone_tpu.ops.images.fisher_vector import (
     FisherVector,
-    ScalaGMMFisherVectorEstimator,
+    GMMFisherVectorEstimator,
 )
 from keystone_tpu.ops.images.lcs import LCSExtractor
 from keystone_tpu.ops.images.sift import SIFTExtractor
@@ -206,7 +206,7 @@ def test_fisher_vector_estimator_end_to_end():
     mats = [
         rng.standard_normal((8, 30)).astype(np.float32) for _ in range(4)
     ]
-    est = ScalaGMMFisherVectorEstimator(k=2, seed=0)
+    est = GMMFisherVectorEstimator(k=2, seed=0)
     fv = est.fit(Dataset.from_items(mats))
     out = fv.apply(mats[0])
     assert np.asarray(out).shape == (8, 4)

@@ -194,3 +194,65 @@ def test_random_fft_features_nonzero_threshold_remasks_pad_rows():
         parts.append(np.asarray(b.padded()))
     want = np.concatenate(parts, axis=1)
     np.testing.assert_allclose(got[:n], want[:n], rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("case", ["array", "ragged_items", "noted_node"])
+def test_column_sampler_on_the_device_draws_what_the_loop_drew(case):
+    """A batch of matrices sampled on the device — one array, ragged host
+    items (one array a shape), or shape groups with a node noted on them
+    that runs a chunk at a time — gives, item for item, the columns the
+    per-item loop it replaces draws for the same seed
+    (``default_rng((seed, i))`` for item i), in the items' order; and it
+    leaves the sampler's span and counter behind."""
+    from keystone_tpu.observability.registry import (
+        get_global_registry, reset_global_registry,
+    )
+    from keystone_tpu.observability.tracing import (
+        disable_tracing, enable_tracing,
+    )
+    from keystone_tpu.workflow.api import Transformer
+
+    class Twice(Transformer):
+        def apply(self, m):
+            return 2.0 * m
+
+        def rowwise(self):
+            return _twice, ()
+
+    rng = np.random.default_rng(0)
+    widths = [20] * 7 if case == "array" else [20, 31, 20, 17, 31, 20, 20]
+    mats = [rng.standard_normal((4, m)).astype(np.float32) for m in widths]
+    loop = ColumnSampler(6, seed=3)
+    scale = 2.0 if case == "noted_node" else 1.0
+    want = [np.asarray(loop.apply(scale * m)) for m in mats]
+    if case == "array":
+        ds = Dataset.from_array(jnp.asarray(np.stack(mats)))
+    else:
+        ds = Dataset.from_items(mats)
+        if case == "noted_node":
+            ds = Twice().batch_transform([ds.grouped()])
+            assert ds.is_grouped and ds._steps  # noted, not yet run
+    tr = enable_tracing()
+    tr.clear()
+    reset_global_registry()
+    try:
+        out = ColumnSampler(6, seed=3).apply_batch(ds)
+        names = [s.name for s in tr.recent()]
+        sampled = sum(
+            s.value for f in get_global_registry().collect()
+            if f.name == "keystone_sampled_columns_total"
+            for s in f.samples if s.suffix == "")
+    finally:
+        disable_tracing()
+        tr.clear()
+        reset_global_registry()
+    assert out.is_array and out.padded().shape == (7, 4, 6)
+    for got, w in zip(np.asarray(out.array()), want):
+        np.testing.assert_array_equal(got, w)
+    assert names.count("stats.column_sample") == 1
+    assert sampled == 7 * 6
+
+
+def _twice(arrays, x):
+    del arrays
+    return 2.0 * x

@@ -289,3 +289,122 @@ def test_gram_products_are_square_fusions_that_read_the_rows(one_chip, cell):
     typed = f"fusion(f32[{n},{d}]{{1,0:T(8,128)}} %X."
     assert all(pattern.search(op.replace("fusion(%X.", typed))
                for op in products)
+
+
+def _metric_args(name):
+    import json
+    import os
+
+    path = os.path.join(
+        os.path.dirname(__file__), "..", "..", "benchmark", "metrics",
+        name + ".json")
+    with open(path) as f:
+        return json.load(f)["args"]
+
+
+@pytest.fixture
+def mosaic(monkeypatch):
+    """The GMM statistics kernel compiled by Mosaic although jax's
+    default backend here is the CPU."""
+    from keystone_tpu.ops.images import fv_pallas
+
+    monkeypatch.setattr(
+        fv_pallas, "auto_interpret", lambda interpret=None: False)
+
+
+def test_fisher_kernel_at_the_published_widths_and_its_roofline_pattern(
+        one_chip, mosaic):
+    """VOCSIFTFisher's Fisher-vector node over a chunk of 16 images'
+    74,000 reduced descriptors (d 80, k 256), as a shape group runs it:
+    Mosaic takes the kernel with the descriptors as they are (no padded
+    or transposed copy of them in the program), and the benchmark's
+    ``fv_roofline_pct.vfit`` takes exactly its one custom call, and
+    ``fv_device_ms_per_image.vfit`` the program by its name."""
+    from keystone_tpu.ops.images.fisher_vector import _FisherRows
+    from keystone_tpu.parallel import chunks
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+
+    compiled = chunks.rows_program(_FisherRows(True, 1e-4)).lower(
+        (shape(80, 256), shape(80, 256), shape(256)), shape(16, 80, 74000)
+    ).compile()
+    text = compiled.as_text()
+    pattern = _metric_args("fv_roofline_pct.vfit")["pattern"]
+    taken = [line.strip() for line in text.splitlines()
+             if re.search(pattern, line.strip())]
+    assert len(taken) == 1 and "tpu_custom_call" in taken[0], taken
+    assert text.count("tpu_custom_call") == 1
+    # nothing of the descriptors' size but the argument itself
+    assert not re.search(r"= f32\[16,\d+,7\d{4}\]\S* (?!parameter)", text)
+    assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+    module = _metric_args("fv_device_ms_per_image.vfit")["pattern"]
+    assert re.search(module, text.splitlines()[0]), text.splitlines()[0]
+
+
+def test_em_program_at_the_published_sample_and_the_gmm_metrics_patterns(
+        one_chip, mosaic):
+    """The GMM's EM over 999,722 x 80 at 256 words as one device program:
+    the kernel is in the loop under the name ``gmm.estep`` (so the Fisher
+    kernel's roofline metric does not take it), no (n, k) array of
+    posteriors or likelihoods is anywhere in the program, and
+    ``gmm_device_ms_per_fit.vfit`` finds the program, and the k-means++
+    start, by name."""
+    from keystone_tpu.ops.learning import gmm
+
+    def shape(*dims, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    em = gmm._gmm_em.lower(
+        shape(80, 999722), shape(256, 80), shape(256, 80), shape(256),
+        shape(80), shape(3), max_iterations=100).compile()
+    text = em.as_text()
+    calls = [line.strip() for line in text.splitlines()
+             if "tpu_custom_call" in line]
+    assert len(calls) == 1 and calls[0].startswith("%gmm.estep"), calls
+    fv = _metric_args("fv_roofline_pct.vfit")["pattern"]
+    assert not [line for line in text.splitlines() if re.search(fv, line)]
+    assert not re.search(r"f32\[(999722|1000448),256\]", text)
+    assert not re.search(r"f32\[256,(999722|1000448)\]", text)
+    assert em.memory_analysis().temp_size_in_bytes < 64 << 20
+    init = gmm._gmm_init.lower(
+        shape(80, 999722), shape(dtype=jnp.int32), shape(255),
+        shape(255, dtype=jnp.int32), shape(2), k=256).compile()
+    assert init.as_text().count("tpu_custom_call") == 2  # the hard passes
+    named = re.compile(_metric_args("gmm_device_ms_per_fit.vfit")["pattern"])
+    for program in (em, init):
+        assert named.search(program.as_text().splitlines()[0])
+
+
+def test_sift_and_projection_of_a_voc_chunk_fit_beside_the_cache(one_chip):
+    """Dense SIFT and the PCA projection over 16 images of 500 x 375, the
+    programs a shape group's chunk runs (16 is what
+    ``chunks.PROGRAM_BYTES`` plans for these images): each compiles (the
+    binning kernel by Mosaic at this image's tiles) and holds under 2 GiB
+    of temporaries, over which a v5e computed wrong descriptors (PERF.md
+    section 6, PR 37)."""
+    from keystone_tpu.ops.images import pallas_kernels
+    from keystone_tpu.ops.images.sift import _SiftRows
+    from keystone_tpu.ops.learning.pca import _project_columns
+    from keystone_tpu.parallel import chunks
+
+    def shape(*dims):
+        return jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+
+    compile_kernel = pallas_kernels.auto_interpret
+    pallas_kernels.auto_interpret = lambda interpret=None: False
+    try:
+        sift = chunks.rows_program(_SiftRows(3, 4, 4, 0)).lower(
+            (), shape(16, 375, 500, 1)).compile()
+    finally:
+        pallas_kernels.auto_interpret = compile_kernel
+    assert sift.as_text().count("tpu_custom_call") >= 4  # one a scale
+    out = sift.memory_analysis().output_size_in_bytes
+    assert 16 * 128 * 70000 * 4 < out < 16 * 128 * 76000 * 4
+    assert sift.memory_analysis().temp_size_in_bytes < chunks.PROGRAM_BYTES
+    assert re.search(
+        _metric_args("sift_device_ms_per_image.vfit")["pattern"],
+        sift.as_text().splitlines()[0])
+    project = chunks.rows_program(_project_columns).lower(
+        shape(128, 80), shape(16, 128, 74000)).compile()
+    assert project.memory_analysis().temp_size_in_bytes < 1e9

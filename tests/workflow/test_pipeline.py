@@ -250,29 +250,60 @@ def test_fitted_pipeline_jit_batch_matches_executor():
 
 
 def test_bucketed_batch_chunks_large_shape_groups(monkeypatch):
-    """A shape group larger than BUCKET_CHUNK runs in chunks of that
-    many items (tail zero-padded to the same shape, so the group still
-    compiles once): results equal the per-item apply, in order, and no
-    dispatch ever sees more than a chunk."""
-    from keystone_tpu.workflow import api
+    """Ragged items become one array a shape, and a group too large for
+    what the device has free goes through in chunks whose rows follow
+    from bytes (the last one starting a chunk before the end, so the
+    group compiles once): results equal the per-item apply, in order, no
+    program sees more than a chunk, and nothing is cut into items until
+    items are asked for."""
+    from keystone_tpu.observability.registry import (
+        get_global_registry, reset_global_registry,
+    )
+    from keystone_tpu.parallel import chunks
 
-    monkeypatch.setattr(api, "BUCKET_CHUNK", 4)
-    seen = []
     rng = np.random.default_rng(0)
-    # 9 items of one shape (chunks 4+4+1, the tail padded to 4)
-    # interleaved with 4 of another (one dispatch of 4)
+    # 9 items of one shape interleaved with 4 of another
     items = [
         rng.standard_normal((3, 5) if i % 4 else (2, 7)).astype(np.float32)
         for i in range(13)
     ]
-    out = _row_sums(seen)._bucketed_batch(Dataset.from_items(items)).items()
-    assert len(out) == 13
-    for x, y in zip(items, out):
+    # a row of (3, 5) in, (5,) out holds 20 bytes across the run: room
+    # for 4 rows (half of what is free once the 9 x 20 result is out)
+    monkeypatch.setattr(
+        chunks, "device_free_bytes", lambda batch: 9 * 20 + 2 * 4 * 20)
+    seen = []
+    real = chunks.take_chunk
+
+    def spy(fns, chunk_rows, *rest):
+        seen.append(chunk_rows)
+        return real(fns, chunk_rows, *rest)
+
+    monkeypatch.setattr(chunks, "take_chunk", spy)
+    monkeypatch.setattr(chunks, "_planned", {})
+
+    def count(name):
+        return sum(
+            s.value for f in get_global_registry().collect()
+            if f.name == name for s in f.samples if s.suffix == "")
+
+    reset_global_registry()
+    try:
+        out = _row_sums([])._bucketed_batch(Dataset.from_items(items))
+        assert out.is_grouped and not out.is_array
+        groups = out.groups()
+        assert sorted(b.shape for _, b in groups) == [(4, 7), (9, 5)]
+        assert sorted(seen) == [4, 4, 4, 4]  # 9 rows: 3 chunks; 4 fit whole
+        assert count("keystone_workflow_shape_groups_total") == 2
+        assert count("keystone_workflow_chunks_total") == 4
+        assert count("keystone_workflow_array_items_total") == 13
+        assert count("keystone_workflow_item_slices_total") == 0
+        got = out.items()
+        assert count("keystone_workflow_item_slices_total") == 13
+    finally:
+        reset_global_registry()
+    assert len(got) == 13
+    for x, y in zip(items, got):
         np.testing.assert_allclose(np.asarray(y), x.sum(0) + 1.0, rtol=1e-6)
-    big = [s for s in seen if s[1:] == (3, 5)]
-    small = [s for s in seen if s[1:] == (2, 7)]
-    assert [s[0] for s in big] == [4, 4, 4]
-    assert [s[0] for s in small] == [4]
 
 
 def _row_sums(seen, pytree=False):
@@ -307,8 +338,8 @@ def _row_sums(seen, pytree=False):
 def test_bucket_vmap_keeps_one_shape_an_array(monkeypatch, case):
     """A bucket_vmap node handed items of one shape, or an array, works
     on slices of one array, a chunk a dispatch, and returns array mode
-    with no pad rows of its own; ragged items come back as items; inside
-    jit the whole input is one vmap call. Results equal the per-item
+    with no pad rows of its own; ragged items come back as one array a
+    shape; inside jit the whole input is one vmap call. Results equal the per-item
     apply, in order."""
     import jax
 
@@ -344,8 +375,8 @@ def test_bucket_vmap_keeps_one_shape_an_array(monkeypatch, case):
         out = node.apply_batch(Dataset.from_items(items))
     assert out.n == n
     if case == "ragged_items":
-        assert not out.is_array
-        assert sorted(seen) == [(3, 2, 7)] + [(4, 3, 5)] * 2
+        assert not out.is_array and out.is_grouped
+        assert sorted(b.shape for _, b in out.groups()) == [(3, 7), (6, 5)]
     else:
         assert out.is_array and out.padded_n == rows
         assert [s[0] for s in seen] == {
@@ -395,3 +426,93 @@ def test_flagship_featurizer_never_leaves_array_mode():
     )
     assert "workflow.apply" in names
     assert not names & {"workflow.map_items", "workflow.to_array"}
+
+
+def test_shape_group_chunks_stay_under_a_program_s_bytes(monkeypatch):
+    """A shape group's chunk never holds more than ``PROGRAM_BYTES`` in
+    flight (rows in, every function's output), whatever the device has
+    free, and a reader of groups that have nothing noted on them gets
+    them in such chunks too; results are the per-item apply's, in order."""
+    from keystone_tpu.parallel import chunks
+
+    monkeypatch.setattr(chunks, "_planned", {})
+    # a (3, 5) row in and a (5,) row out are 80 bytes: room for 4 rows
+    monkeypatch.setattr(chunks, "PROGRAM_BYTES", 4 * 80)
+    assert chunks.rows_that_fit(9, 20, 0, None, most_rows=5) == 4
+    assert chunks.rows_that_fit(9, 20, 0, None) == 9
+    assert chunks.rows_that_fit(4, 20, 0, 10 ** 9, most_rows=4) == 4
+    assert chunks.rows_a_chunk(9, 20, 0, 2 * 4 * 20) == 3
+    rng = np.random.default_rng(1)
+    items = [rng.standard_normal((3, 5)).astype(np.float32) for _ in range(9)]
+    out = _row_sums([])._bucketed_batch(
+        Dataset.from_groups([(np.arange(9), jnp.asarray(np.stack(items)))]))
+    parts = list(out.chunks())
+    assert [len(p) for p, _ in parts] == [4, 4, 1]
+    for (places, got) in parts:
+        for i, y in zip(places, np.asarray(got)):
+            np.testing.assert_allclose(y, items[int(i)].sum(0) + 1.0, rtol=1e-6)
+    # nothing noted: the rows themselves, 60 bytes each, 5 fit, 4 a chunk
+    plain = Dataset.from_groups([(np.arange(9), jnp.asarray(np.stack(items)))])
+    sizes = [len(p) for p, _ in plain.chunks()]
+    assert sizes == [4, 4, 1] and sum(sizes) == 9
+
+
+def test_a_shape_s_chunk_is_kept_for_its_later_groups(monkeypatch):
+    """The chunk planned when rows of a shape first come through a run is
+    the shape's: a later group of fewer rows is filled up to it and runs
+    the same programs, only its own rows coming back (and the rows
+    computed beside them counted); a group of more rows than the shape
+    has had, with room for a larger chunk, plans the shape again; what
+    is free at a later moment does not (on a v5e it counted the chunks
+    dispatched ahead and compiled new programs inside a timed fit)."""
+    from keystone_tpu.observability.registry import (
+        get_global_registry, reset_global_registry,
+    )
+    from keystone_tpu.parallel import chunks
+
+    monkeypatch.setattr(chunks, "_planned", {})
+    monkeypatch.setattr(chunks, "PROGRAM_BYTES", 4 * 80)  # 4 rows in flight
+    free = {"bytes": 10 ** 9}
+    monkeypatch.setattr(chunks, "device_free_bytes", lambda b: free["bytes"])
+    seen = []
+    real = chunks.take_chunk
+
+    def spy(fns, chunk_rows, *rest):
+        seen.append(chunk_rows)
+        return real(fns, chunk_rows, *rest)
+
+    monkeypatch.setattr(chunks, "take_chunk", spy)
+    rng = np.random.default_rng(2)
+    node = _row_sums([])  # one node: a plan is kept by its functions
+
+    def through(n):
+        items = [rng.standard_normal((3, 5)).astype(np.float32)
+                 for _ in range(n)]
+        del seen[:]
+        out = node._bucketed_batch(Dataset.from_groups(
+            [(np.arange(n), jnp.asarray(np.stack(items)))]))
+        got = np.asarray(out.array())
+        assert got.shape == (n, 5)
+        np.testing.assert_allclose(
+            got, np.stack([x.sum(0) + 1.0 for x in items]), rtol=1e-6)
+        return list(seen)
+
+    def padded():
+        return sum(
+            s.value for f in get_global_registry().collect()
+            if f.name == "keystone_workflow_padded_rows_total"
+            for s in f.samples if s.suffix == "")
+
+    reset_global_registry()
+    try:
+        assert through(2) == [2]  # the first group of the shape, whole
+        assert through(9) == [4, 4, 4]  # room for more: planned again
+        before = padded()
+        assert through(3) == [4]  # filled up to the shape's chunk
+        assert padded() - before == 1
+        assert through(7) == [4, 4]  # the last chunk starts at row 3
+        free["bytes"] = 7 * 20 + 2 * 2 * 20  # room for 2 rows beside 7 out
+        assert through(7) == [4, 4]  # the shape's chunk is kept
+        assert through(12) == [4, 4, 4]  # more rows, no larger chunk fits
+    finally:
+        reset_global_registry()

@@ -342,6 +342,31 @@ def test_rule_leaves_a_node_with_two_readers_whole():
     assert a.shape == (6, 2 * 2 * 16) and b.shape[0] == 6
 
 
+def test_rule_leaves_nodes_whose_function_is_for_shape_groups_alone():
+    """The flagship's featurizers say how they map rows (so that ragged
+    images can be noted on them) and say ``groups_only``: on one array
+    each keeps its own ``apply_batch`` and the rule folds none of them,
+    next to a row-wise run or alone."""
+    from keystone_tpu.ops.images.core import GrayScaler, PixelScaler
+    from keystone_tpu.ops.stats import NormalizeRows, SignedHellingerMapper
+
+    assert all(node.rowwise()[0].groups_only for node in (
+        PixelScaler(), GrayScaler(), NormalizeRows(),
+        SignedHellingerMapper()))
+    nodes = chain()
+    pipe = PixelScaler().and_then(nodes[0]).and_then(nodes[1]) \
+        .and_then(nodes[2]).and_then(nodes[3]) \
+        .and_then(NormalizeRows()).and_then(SignedHellingerMapper())
+    result = pipe(images(6))
+    labels = [op.label for op in result._executor.graph.operators.values()]
+    assert sorted(labels) == sorted([
+        "dataset", "PixelScaler",
+        "Convolver+SymmetricRectifier+Pooler+ImageVectorizer",
+        "NormalizeRows", "SignedHellingerMapper",
+    ])
+    assert result.get().array().shape[0] == 6
+
+
 def test_equal_settings_share_one_program():
     """A fit builds its filters anew: the chunk program is keyed by the
     nodes' settings and not by their arrays."""
