@@ -29,10 +29,12 @@ Span names, one per layer boundary and never one per item:
   ``workflow.run`` once a call and ``workflow.run.chunk`` once a chunk
   of a ``RowwiseRun`` that goes through in chunks of rows,
   ``workflow.map_items`` / ``.to_array`` / ``.to_items`` (``Dataset``);
-- solvers: ``solver.prep``, and per block step ``solver.block_stats``,
-  ``solver.readback``, ``solver.host_solve`` (attr ``fallback``),
-  ``solver.upload``, ``solver.residual_update`` (host solve) or
-  ``solver.block_step`` (device solve);
+- solvers: ``solver.prep``, ``solver.gram_ahead`` (host solve: a block's
+  Gram dispatched and its read-back started ahead of its turn), and
+  per block step ``solver.block_stats``, ``solver.readback``,
+  ``solver.host_solve`` (attr ``fallback``), ``solver.upload``,
+  ``solver.residual_update`` (host solve) or ``solver.block_step``
+  (device solve);
 - serving: ``gateway.admit`` → ``microbatch.coalesce`` →
   ``serving.dispatch`` (serial lanes) or → ``pipeline.host_prep`` /
   ``pipeline.upload`` / ``pipeline.compute`` / ``pipeline.deliver``

@@ -27,21 +27,33 @@ G depends on X and μ only, so with ``solve="host"`` and more than one sweep
 a fit builds, reads back and factors each block's G once, on the block's
 first visit in that call of ``fit()``, and keeps the float64 factor on the
 host until the call returns (the reference's weighted solver caches its
-BlockStatistics across passes the same way). Later visits run
-``_block_stats_rhs`` (R⁺ and X_bᵀR⁺, no G), read back the (b, k)
-right-hand side alone and solve against the kept factor.
+BlockStatistics across passes the same way). Every visit runs
+``_block_stats_rhs`` (R⁺ and X_bᵀR⁺); later visits read back the (b, k)
+right-hand side alone and solve against the kept factor. For the same
+reason G is built a block ahead: ``_block_stats_gram`` of the next block
+to be factored is dispatched, and its read-back started
+(``hostsolve.read_back_ahead``: the copy to the host, then the float64
+conversion on the read-back thread), once this block's G has been read
+back and deleted on the device and before this block's ``cho_factor``,
+so block i+1's Gram program, its 64 MB copy and its conversion run under
+block i's factorisation (block 0's right after ``solver.prep``). One
+Gram at most is on the device; the host holds a second while it factors.
 
-Observability: host spans ``solver.prep``, then per block step
-``solver.block_stats`` (either stats program) → ``solver.readback`` →
-``solver.host_solve`` → ``solver.upload`` → ``solver.residual_update``
-(``solve="host"``) or ``solver.block_step`` (device solve); on the device
-``jax.named_scope`` names ``solver.residual_plus`` / ``solver.gram`` /
-``solver.rhs`` / ``solver.solve`` / ``solver.residual`` / ``solver.prep``;
-counters ``keystone_solver_fits_total``, ``_block_steps_total``,
-``_gram_builds_total`` (Grams really built), ``_gram_pairs_computed_total``
-over ``_gram_pairs_total`` (the share of a full product's column pairs
-those Grams multiplied), ``_factor_reuses_total`` (hostsolve.py has the
-host solve's).
+Observability: host spans ``solver.prep``, ``solver.gram_ahead`` (the
+Gram's dispatch and the start of its read-back: once after the prep, then
+inside each factoring step but the last, between its read-back and its
+factorisation), then per block step ``solver.block_stats`` →
+``solver.readback`` → ``solver.host_solve`` → ``solver.upload`` →
+``solver.residual_update`` (``solve="host"``) or ``solver.block_step``
+(device solve); on the device ``jax.named_scope`` names
+``solver.residual_plus`` / ``solver.gram`` / ``solver.rhs`` /
+``solver.solve`` / ``solver.residual`` / ``solver.prep``; counters
+``keystone_solver_fits_total``, ``_block_steps_total``,
+``_gram_builds_total`` (Grams really built), ``_gram_prefetches_total``
+(those whose copy started under the previous block's factorisation),
+``_gram_pairs_computed_total`` over ``_gram_pairs_total`` (the share of a
+full product's column pairs those Grams multiplied),
+``_factor_reuses_total`` (hostsolve.py has the host solve's).
 """
 
 from __future__ import annotations
@@ -61,8 +73,10 @@ from keystone_tpu.parallel.dataset import Dataset
 from keystone_tpu.workflow.api import LabelEstimator, Transformer
 from keystone_tpu.ops.learning.hostsolve import (
     HostFactor,
-    psd_factor_solve_host,
+    factor_solve_host,
     psd_solve_factored_host,
+    read_back,
+    read_back_ahead,
 )
 from keystone_tpu.utils.checkpoint import (
     LoopCheckpointer,
@@ -251,9 +265,12 @@ def _gram_rhs(Xb, mu_b, R_plus, n):
     X_bᵀX_b the upper block triangle is multiplied and the lower blocks
     are its transposes (``_sym_gram``); the centering follows the
     assembly."""
+    return _centred_gram(Xb, mu_b, n), _rhs(Xb, mu_b, R_plus)
+
+
+def _centred_gram(Xb, mu_b, n):
     with jax.named_scope("solver.gram"):
-        gram = _sym_gram(Xb) - n * jnp.outer(mu_b, mu_b)
-    return gram, _rhs(Xb, mu_b, R_plus)
+        return _sym_gram(Xb) - n * jnp.outer(mu_b, mu_b)
 
 
 def _rhs(Xb, mu_b, R_plus):
@@ -265,7 +282,7 @@ def _rhs(Xb, mu_b, R_plus):
 
 def _block_residual_plus(X, R, Wb, mu, mask, start, width):
     """The block's column slice, its means, and R⁺ = R + X_b W_b (centered):
-    the opening of both stats programs."""
+    the opening of the right-hand side's program."""
     Xb = jax.lax.dynamic_slice_in_dim(X, start, width, axis=1)
     mu_b = jax.lax.dynamic_slice_in_dim(mu, start, width)
     with jax.named_scope("solver.residual_plus"):
@@ -273,32 +290,34 @@ def _block_residual_plus(X, R, Wb, mu, mask, start, width):
         return Xb, mu_b, R + contrib
 
 
-@partial(jax.jit, static_argnames=("width", "n"), donate_argnums=(1,))
-def _block_stats(X, R, Wb, mu, mask, start, *, width: int, n: int):
-    """Per-block Gram pass on the RAW (possibly bf16) feature matrix.
+@partial(jax.jit, static_argnames=("width", "n"))
+def _block_stats_gram(X, mu, start, *, width: int, n: int):
+    """The block's centred Gram alone, from the RAW (possibly bf16)
+    feature matrix and its means — nothing of the residual, so the host
+    solve can build it a block ahead of the block's turn (``fit``).
 
     Centering is algebraic — the centered block is never materialized:
-        G_c   = X_bᵀX_b − n·μ_bμ_bᵀ
-        rhs_c = X_bᵀR⁺ − μ_b·(1ᵀR⁺)
-    (pad rows of X and R are zero, so sums over all rows equal sums over
-    valid rows). One XLA program; the contractions over the sharded example
-    axis lower to per-shard MXU matmuls + a psum over the "data" axis. The
-    Gram is built from its upper block triangle: square products of column
-    slices of ``X`` itself, the lower blocks their transposes
-    (``_sym_gram``).
-    ``start`` is traced so every equal-width block shares this compilation.
-    """
-    Xb, mu_b, R_plus = _block_residual_plus(X, R, Wb, mu, mask, start, width)
-    gram, rhs = _gram_rhs(Xb, mu_b, R_plus, n)
-    return gram, rhs, R_plus
+        G_c = X_bᵀX_b − n·μ_bμ_bᵀ
+    (pad rows of X are zero, so sums over all rows equal sums over valid
+    rows). The contraction over the sharded example axis lowers to
+    per-shard MXU matmuls + a psum over the "data" axis; the Gram is built
+    from its upper block triangle, square products of column slices of
+    ``X`` itself, the lower blocks their transposes (``_sym_gram``).
+    ``start`` is traced so every equal-width block shares this
+    compilation. The name keeps ``block_stats`` and the first argument
+    ``X``: device-time readers find the solver's programs and the Gram's
+    products by them."""
+    Xb = jax.lax.dynamic_slice_in_dim(X, start, width, axis=1)
+    mu_b = jax.lax.dynamic_slice_in_dim(mu, start, width)
+    return _centred_gram(Xb, mu_b, n)
 
 
 @partial(jax.jit, static_argnames=("width",), donate_argnums=(1,))
 def _block_stats_rhs(X, R, Wb, mu, mask, start, *, width: int):
-    """``_block_stats`` without its Gram, for a block whose factor the
-    fit already holds: R⁺ and the centered right-hand side only. The name
-    keeps ``block_stats`` in it: device-time readers find the solver's
-    programs by name."""
+    """R⁺ and the centered right-hand side rhs_c = X_bᵀR⁺ − μ_b·(1ᵀR⁺),
+    every block step of the host solve (the Gram is ``_block_stats_gram``'s,
+    on a block's first visit only). The name keeps ``block_stats`` in it:
+    device-time readers find the solver's programs by name."""
     Xb, mu_b, R_plus = _block_residual_plus(X, R, Wb, mu, mask, start, width)
     return _rhs(Xb, mu_b, R_plus), R_plus
 
@@ -445,6 +464,14 @@ def _count_fit() -> Callable[..., None]:
             _count_gram_pairs((width,))
 
     return count_step
+
+
+def _count_gram_prefetch() -> None:
+    get_global_registry().counter(
+        "keystone_solver_gram_prefetches_total",
+        "block Grams dispatched and copied to the host ahead, before the "
+        "previous block's factorisation began",
+    ).inc()
 
 
 def _force_sync(x) -> None:
@@ -673,10 +700,29 @@ class BlockLeastSquaresEstimator(LabelEstimator):
         # a later sweep with none) until the call returns. b²·8 bytes of
         # host memory a block; the next fit builds its own.
         factors: Dict[int, HostFactor] = {}
-        done = 0
-        for it, pos, nxt in two_level_schedule(
+        steps = list(two_level_schedule(
             self.num_iter, len(blocks), (start_it, start_pos)
-        ):
+        ))
+        # solve="host": (start, read-back handle) of the Gram of the next
+        # block to be factored, dispatched and read back ahead of the
+        # block's turn (G needs X and μ only), under the previous block's
+        # factorisation. A block's first visits in a call are its first
+        # len(blocks) steps, one after another, so the next step's block
+        # is known. At most one Gram is on the device: the current one is
+        # read back, and so deleted, before the next is dispatched.
+        def gram_ahead(pos: int):
+            s, w = blocks[pos]
+            with span("solver.gram_ahead"):
+                return s, read_back_ahead(
+                    _block_stats_gram(X, mu, s, width=w, n=n)
+                )
+
+        ahead = (
+            gram_ahead(steps[0][1]) if self.solve == "host" and steps
+            else None
+        )
+        done = 0
+        for j, (it, pos, nxt) in enumerate(steps):
             s, w = blocks[pos]
             kept = factors.get(s)
             if self.solve == "device":
@@ -699,22 +745,28 @@ class BlockLeastSquaresEstimator(LabelEstimator):
                 # (b,b) solve on host in f64 (reference: driver-side
                 # NormalEquations solve) — see hostsolve.py, which has
                 # the solver.readback and solver.host_solve spans.
-                if kept is None:
-                    with span("solver.block_stats"):
-                        gram, rhs, R_plus = _block_stats(
-                            X, R, Wb[s], mu, mask, s, width=w, n=n
-                        )
-                    W_host, factor = psd_factor_solve_host(
-                        gram, rhs, self.lam
+                with span("solver.block_stats"):
+                    rhs, R_plus = _block_stats_rhs(
+                        X, R, Wb[s], mu, mask, s, width=w
                     )
-                    del gram  # b² floats of HBM, not held into later steps
+                if kept is None:
+                    ahead_s, gram = ahead
+                    if ahead_s != s:
+                        raise AssertionError(
+                            f"Gram of block {ahead_s} read back ahead for {s}"
+                        )
+                    # the Gram is off the device once it is read back
+                    G, R_host = read_back(gram, rhs)
+                    ahead = gram = None
+                    if j + 1 < len(steps):
+                        s_next = blocks[steps[j + 1][1]][0]
+                        if s_next != s and s_next not in factors:
+                            ahead = gram_ahead(steps[j + 1][1])
+                            _count_gram_prefetch()
+                    W_host, factor = factor_solve_host(G, R_host, self.lam)
                     if self.num_iter > 1:  # one sweep never comes back
                         factors[s] = factor
                 else:
-                    with span("solver.block_stats"):
-                        rhs, R_plus = _block_stats_rhs(
-                            X, R, Wb[s], mu, mask, s, width=w
-                        )
                     W_host = psd_solve_factored_host(kept, rhs)
                 with span("solver.upload"):
                     Wb[s] = jnp.asarray(W_host)

@@ -467,11 +467,30 @@ def test_block_ls_host_fit_spans_and_counters(tmp_path, mesh8):
             assert len(mine) == steps, phase
             assert {parents[e] for e in mine} == {node}, phase
         assert _count(events, "ks:solver.block_step") == 0
+        # each block's Gram dispatched ahead once: block 0's after the
+        # prep, each other's between the previous block's read-back and
+        # its factorisation
+        ahead = [e for e in events if e[2] == "ks:solver.gram_ahead"]
+        assert {parents[e] for e in ahead} == {node}
+        order = [
+            n[len("ks:solver."):] for _, _, n in sorted(events)
+            if n in ("ks:solver.prep", "ks:solver.gram_ahead",
+                     "ks:solver.readback", "ks:solver.host_solve")
+        ]
+        assert order == [
+            "prep", "gram_ahead",
+            "readback", "gram_ahead", "host_solve",
+            "readback", "gram_ahead", "host_solve",
+            "readback", "host_solve",
+        ] + ["readback", "host_solve"] * (steps - blocks)
         # node spans follow one another: the features' node is no parent
         assert parents[next(e for e in events if e[2] == node)] is None
         assert _counter("keystone_solver_fits_total") == 1
         # a Gram per block on its first visit, the kept factor after
         assert _counter("keystone_solver_gram_builds_total") == blocks
+        assert _counter("keystone_solver_gram_prefetches_total") == (
+            blocks - 1
+        )
         assert _counter("keystone_solver_factor_reuses_total") == (
             steps - blocks
         )

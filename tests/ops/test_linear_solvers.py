@@ -162,8 +162,9 @@ def _bcd_f64(X, Y, bs, num_iter, lam):
 
 
 def _per_step_host_fit(X, Y, bs, num_iter, lam):
-    """The host path as it was before the bank: ``_block_stats`` and a
-    whole ``psd_solve_host`` (read back, factor, solve) in every step."""
+    """The host path as it was before the bank, in series: the block's
+    Gram and right-hand side, then a whole ``psd_solve_host`` (read back,
+    factor, solve) in every step, with no Gram built ahead."""
     from keystone_tpu.ops.learning import block_ls
     from keystone_tpu.ops.learning.hostsolve import psd_solve_host
 
@@ -180,8 +181,9 @@ def _per_step_host_fit(X, Y, bs, num_iter, lam):
     for _ in range(num_iter):
         for s in starts:
             w = Wb[s].shape[0]
-            gram, rhs, R_plus = block_ls._block_stats(
-                Xp, R, Wb[s], mu, mask, s, width=w, n=n
+            gram = block_ls._block_stats_gram(Xp, mu, s, width=w, n=n)
+            rhs, R_plus = block_ls._block_stats_rhs(
+                Xp, R, Wb[s], mu, mask, s, width=w
             )
             Wb[s] = jnp.asarray(psd_solve_host(gram, rhs, lam))
             R = block_ls._residual_update(
@@ -206,8 +208,11 @@ def test_block_ls_host_bank_matches_f64_bcd_and_per_step_path(
     X, Y = _bank_problem(10)
     est = BlockLeastSquaresEstimator(4, num_iter=3, lam=lam, solve="host")
     W = np.asarray(est.fit(Dataset.of(X), Dataset.of(Y)).W)
-    # 3 blocks x 3 sweeps: a Gram per block, then the kept factor
+    # 3 blocks x 3 sweeps: a Gram per block, then the kept factor; the
+    # Grams of blocks 1 and 2 were built ahead, under the previous
+    # block's factorisation
     assert solver_counters("gram_builds") == 3
+    assert solver_counters("gram_prefetches") == 2
     assert solver_counters("factor_reuses") == 6
     assert solver_counters("host_solves") == 9
     assert solver_counters("block_steps") == 9
@@ -216,6 +221,90 @@ def test_block_ls_host_bank_matches_f64_bcd_and_per_step_path(
     )
     np.testing.assert_allclose(
         W, _per_step_host_fit(X, Y, 4, 3, lam), rtol=1e-5, atol=1e-6
+    )
+
+
+@pytest.mark.parametrize("num_iter", [1, 2])
+def test_block_ls_host_fit_builds_grams_ahead_one_at_a_time(
+    num_iter, solver_counters, monkeypatch
+):
+    """3 blocks: every Gram but block 0's is dispatched, with its copy to
+    the host started, under the previous block's factorisation, and no
+    Gram is on the device when the next one is dispatched."""
+    import jax
+
+    from keystone_tpu.ops.learning import block_ls, hostsolve
+
+    order = []
+    gram_program = block_ls._block_stats_gram
+    factor = hostsolve._factor
+
+    def gram_ahead(X, mu, start, *, width, n):
+        grams_live = [
+            a for a in jax.live_arrays()
+            if a.shape == (width, width) and not a.is_deleted()
+        ]
+        assert not grams_live, "a Gram still on the device"
+        order.append(("gram", start))
+        return gram_program(X, mu, start, width=width, n=n)
+
+    def factor_in_order(G, lam, sp):
+        order.append(("factor", G.shape[0]))
+        return factor(G, lam, sp)
+
+    monkeypatch.setattr(block_ls, "_block_stats_gram", gram_ahead)
+    monkeypatch.setattr(hostsolve, "_factor", factor_in_order)
+    X, Y = _bank_problem(17, d=15)
+    est = BlockLeastSquaresEstimator(
+        5, num_iter=num_iter, lam=0.1, solve="host"
+    )
+    W = np.asarray(est.fit(Dataset.of(X), Dataset.of(Y)).W)
+    steps = 3 * num_iter
+    assert solver_counters("gram_prefetches") == 2
+    assert solver_counters("gram_builds") == 3
+    assert solver_counters("factor_reuses") == steps - 3
+    assert solver_counters("host_solves") == steps
+    assert order == [
+        ("gram", 0), ("gram", 5), ("factor", 5), ("gram", 10),
+        ("factor", 5), ("factor", 5),
+    ]
+    np.testing.assert_allclose(
+        W, _bcd_f64(X, Y, 5, num_iter, 0.1), rtol=1e-4, atol=1e-5
+    )
+
+
+@pytest.mark.parametrize("dtype", [np.float32, "bfloat16"])
+def test_block_stats_gram_and_rhs_are_the_centred_products(dtype):
+    """The Gram-only program and the right-hand side's: G_c = X_bᵀX_b −
+    n·μ_bμ_bᵀ and X_bᵀR⁺ − μ_b·(1ᵀR⁺) of the centred block, against float64
+    products of the centred columns, pad rows and all."""
+    from keystone_tpu.ops.learning import block_ls
+
+    X, Y = _bank_problem(18, n=90, d=12)
+    data = Dataset.of(X.astype(dtype)).to_array_mode()
+    Xp, n, mask = data.padded(), data.n, data.mask()
+    mu, _, R = block_ls._prep(
+        Xp, Dataset.of(Y).to_array_mode().padded(), mask, n
+    )
+    X64 = np.asarray(jnp.asarray(X.astype(dtype), jnp.float32), np.float64)
+    Xc = X64 - X64.mean(0)
+    Rc = Y.astype(np.float64) - Y.mean(0)
+    Wb = jnp.asarray(np.random.default_rng(19).standard_normal((4, 3)),
+                     jnp.float32)
+    gram = block_ls._block_stats_gram(Xp, mu, 4, width=4, n=n)
+    rhs, R_plus = block_ls._block_stats_rhs(
+        Xp, R, Wb, mu, mask, 4, width=4
+    )
+    Xb = Xc[:, 4:8]
+    R_plus64 = Rc + Xb @ np.asarray(Wb, np.float64)
+    np.testing.assert_allclose(
+        np.asarray(gram), Xb.T @ Xb, rtol=1e-5, atol=1e-3
+    )
+    np.testing.assert_allclose(
+        np.asarray(R_plus)[:n], R_plus64, rtol=1e-5, atol=1e-4
+    )
+    np.testing.assert_allclose(
+        np.asarray(rhs), Xb.T @ R_plus64, rtol=1e-5, atol=1e-3
     )
 
 
@@ -245,14 +334,16 @@ class _Interrupt(Exception):
 
 
 @pytest.mark.parametrize(
-    "die_after,grams,reuses", [(4, 3, 2), (5, 3, 1), (7, 2, 0)]
+    "die_after,grams,reuses", [(1, 3, 5), (4, 3, 2), (5, 3, 1), (7, 2, 0)]
 )
 def test_block_ls_host_bank_resumed_fit_builds_each_gram_once(
     die_after, grams, reuses, tmp_path, solver_counters
 ):
     """A fit resumed from a checkpoint enters a later sweep with an empty
     bank: every block it still visits builds its Gram on the first of
-    those visits, whatever the sweep, and reuses the factor after."""
+    those visits, whatever the sweep, each but the first built ahead
+    under the previous block's factorisation, and reuses the factor
+    after; the model is the uninterrupted fit's."""
     import dataclasses
 
     X, Y = _bank_problem(12)
@@ -272,7 +363,8 @@ def test_block_ls_host_bank_resumed_fit_builds_each_gram_once(
         ).fit(Xd, Yd)
     before = {
         c: solver_counters(c)
-        for c in ("gram_builds", "factor_reuses", "block_steps")
+        for c in ("gram_builds", "gram_prefetches", "factor_reuses",
+                  "block_steps")
     }
     resumed = dataclasses.replace(
         base, checkpoint_path=path, checkpoint_every=1
@@ -282,6 +374,8 @@ def test_block_ls_host_bank_resumed_fit_builds_each_gram_once(
         9 - die_after
     )
     assert solver_counters("gram_builds") - before["gram_builds"] == grams
+    assert solver_counters("gram_prefetches") - before[
+        "gram_prefetches"] == grams - 1
     assert solver_counters("factor_reuses") - before["factor_reuses"] == (
         reuses
     )
@@ -317,10 +411,10 @@ def test_block_ls_keeps_no_factor_without_a_second_host_sweep(
     on the chip: neither keeps a factor, both build a Gram every step."""
     from keystone_tpu.ops.learning import block_ls
 
-    def no_rhs_program(*a, **k):
-        raise AssertionError("_block_stats_rhs ran with no factor kept")
+    def no_kept_factor(*a, **k):
+        raise AssertionError("solved against a kept factor")
 
-    monkeypatch.setattr(block_ls, "_block_stats_rhs", no_rhs_program)
+    monkeypatch.setattr(block_ls, "psd_solve_factored_host", no_kept_factor)
     X, Y = _bank_problem(15)
     est = BlockLeastSquaresEstimator(
         4, num_iter=num_iter, lam=0.1, solve=solve
@@ -328,6 +422,7 @@ def test_block_ls_keeps_no_factor_without_a_second_host_sweep(
     W = np.asarray(est.fit(Dataset.of(X), Dataset.of(Y)).W)
     assert solver_counters("factor_reuses") == 0
     assert solver_counters("gram_builds") == 3 * num_iter
+    assert solver_counters("gram_prefetches") == (2 if solve == "host" else 0)
     assert solver_counters("block_steps") == 3 * num_iter
     np.testing.assert_allclose(
         W, _bcd_f64(X, Y, 4, num_iter, 0.1), rtol=2e-3, atol=2e-4
@@ -342,7 +437,7 @@ def test_host_factor_solves_later_rhs_as_a_whole_solve_would(
     the first solution solves another right-hand side to the result of a
     whole solve, in the form the first solve took."""
     from keystone_tpu.ops.learning.hostsolve import (
-        psd_factor_solve_host,
+        factor_solve_host,
         psd_solve_factored_host,
         psd_solve_host,
     )
@@ -356,7 +451,7 @@ def test_host_factor_solves_later_rhs_as_a_whole_solve_would(
     if form == "eigh":
         rhs1[2] = rhs2[2] = 0.0
     lam = 0.0 if form == "eigh" else 0.05
-    W1, factor = psd_factor_solve_host(G, rhs1, lam)
+    W1, factor = factor_solve_host(G, rhs1, lam)
     assert factor.form == form
     assert solver_counters("host_solve_fallbacks") == (form == "eigh")
     np.testing.assert_array_equal(W1, psd_solve_host(G, rhs1, lam))
@@ -369,6 +464,31 @@ def test_host_factor_solves_later_rhs_as_a_whole_solve_would(
         (G + lam * np.eye(6))[np.ix_(ok, ok)] @ W1[ok], rhs1[ok],
         rtol=1e-9, atol=1e-9,
     )
+
+
+def test_read_back_ahead_is_read_back_and_frees_the_device_copy(
+    solver_counters
+):
+    """A Gram read back ahead, on the read-back thread, is the float64
+    copy ``read_back`` makes of it, its device array is deleted once the
+    host has it, and its bytes are counted once, beside the right-hand
+    side's."""
+    from keystone_tpu.ops.learning.hostsolve import (
+        read_back,
+        read_back_ahead,
+    )
+
+    rng = np.random.default_rng(20)
+    gram = jnp.asarray(rng.standard_normal((6, 6)), jnp.float32)
+    rhs = jnp.asarray(rng.standard_normal((6, 2)), jnp.float32)
+    G_now, _ = read_back(gram, rhs)
+    handle = read_back_ahead(gram)
+    G, R = read_back(handle, rhs)
+    assert gram.is_deleted()
+    assert G.dtype == R.dtype == np.float64
+    np.testing.assert_array_equal(G, G_now)
+    np.testing.assert_array_equal(R, np.asarray(rhs, np.float64))
+    assert solver_counters("readback_bytes") == 2 * (36 + 12) * 4
 
 
 # blocks of 640 (``_sym_gram`` cuts them 384 | 256) and a last one of 256

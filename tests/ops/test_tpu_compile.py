@@ -235,7 +235,7 @@ def test_filter_program_holds_no_loop_over_the_sample(
 @pytest.mark.parametrize("cell", ["timit-fit", "weighted-bcd-fit"])
 def test_gram_products_are_square_fusions_that_read_the_rows(one_chip, cell):
     """The block Gram from its upper block triangle at the published
-    shapes — ``_block_stats`` as ``timit-fit`` runs it (65,536 rows of
+    shapes — ``_block_stats_gram`` as ``timit-fit`` runs it (65,536 rows of
     16,384 features, a traced start, width 4,096) and the helper alone
     at ``weighted-bcd-fit``'s 327,680 x 4,096 rows: every product under
     the scope is one output fusion with a square ``f32`` result whose
@@ -255,10 +255,9 @@ def test_gram_products_are_square_fusions_that_read_the_rows(one_chip, cell):
         return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
 
     if cell == "timit-fit":
-        n, d, b, k, scope = 65536, 16384, 4096, 147, "solver.gram"
-        lowered = block_ls._block_stats.lower(
-            shape((n, d)), shape((n, k)), shape((b, k)), shape((d,)),
-            shape((n,)), shape((), jnp.int32), width=b, n=n)
+        n, d, b, scope = 65536, 16384, 4096, "solver.gram"
+        lowered = block_ls._block_stats_gram.lower(
+            shape((n, d)), shape((d,)), shape((), jnp.int32), width=b, n=n)
     else:
         n, d, b, scope = 327680, 4096, 4096, "wls.stats"
 
