@@ -45,15 +45,21 @@ inside each factoring step but the last, between its read-back and its
 factorisation), then per block step ``solver.block_stats`` →
 ``solver.readback`` → ``solver.host_solve`` → ``solver.upload`` →
 ``solver.residual_update`` (``solve="host"``) or ``solver.block_step``
-(device solve); on the device ``jax.named_scope`` names
-``solver.residual_plus`` / ``solver.gram`` / ``solver.rhs`` /
-``solver.solve`` / ``solver.residual`` / ``solver.prep``; counters
+(device solve), and after a device solve's last block
+``solver.converged`` (its one read: how many blocks' Cholesky broke
+down and fell back to the ridged factor, and whether the model is
+finite — a fit whose model is not raises); on the device
+``jax.named_scope`` names ``solver.residual_plus`` / ``solver.gram`` /
+``solver.rhs`` / ``solver.solve`` / ``solver.residual`` /
+``solver.prep``; counters
 ``keystone_solver_fits_total``, ``_block_steps_total``,
 ``_gram_builds_total`` (Grams really built), ``_gram_prefetches_total``
 (those whose copy started under the previous block's factorisation),
 ``_gram_pairs_computed_total`` over ``_gram_pairs_total`` (the share of a
 full product's column pairs those Grams multiplied),
-``_factor_reuses_total`` (hostsolve.py has the host solve's).
+``_factor_reuses_total``, ``_device_block_solves_total`` and
+``_factor_fallbacks_total`` (device solves, and those of them that fell
+back; hostsolve.py has the host solve's).
 """
 
 from __future__ import annotations
@@ -157,21 +163,36 @@ def _sym_gram(Xb):
     )
 
 
-# widest system that still carries the eigh fall-back (RandomPatchCifar's
+# widest system that carries the fall-back factorisation (RandomPatchCifar's
 # last block is 2,176 wide, the applications' blocks 4,096)
-_EIGH_FALLBACK_MAX_WIDTH = 2048
+_FALLBACK_MAX_WIDTH = 2048
+
+# the fall-back factors A + δ·I, δ = _FALLBACK_RIDGE·max(diag A) first
+# and _FALLBACK_GROWTH times more after each breakdown
+_FALLBACK_RIDGE = 1e-6
+_FALLBACK_GROWTH = 10.0
 
 
 def _psd_solve_with_factor(A, L, rhs, refine=2):
-    """A X = rhs given A's (already-ridged) Cholesky factor ``L``, f32
-    + ``refine`` iterative-refinement steps. Refinement recovers most
-    of the f64 accuracy the reference's driver-side LAPACK solve had
-    (mlmatrix NormalEquations; BlockLinearMapper.scala:234-240) without
-    a host round-trip: the solve stays inside the async dispatch
-    stream. Falls back to eigendecomposition with eigenvalue clamping
-    when Cholesky breaks down (indefiniteness from f32 rounding),
-    mirroring hostsolve.py. Shared by the fresh-factor path below and
-    the cached-KRR factor bank (kernel.py _krr_cached_epoch_scan)."""
+    """(X, fell back) with A X = rhs, given A's (already-ridged)
+    Cholesky factor ``L``: f32 + ``refine`` iterative-refinement steps.
+    Refinement recovers most of the f64 accuracy the reference's
+    driver-side LAPACK solve had (mlmatrix NormalEquations;
+    BlockLinearMapper.scala:234-240) without a host round-trip: the
+    solve stays inside the async dispatch stream. Where Cholesky breaks
+    down (an f32 Gram that rounding left indefinite, or a singular one at
+    lam 0: a feature column that is all zeros, features of lower rank
+    than the block), falls back to the factor of A + δI, refined against
+    A itself: δ starts at a millionth of A's largest diagonal entry and
+    grows tenfold while the factor breaks down, up to the width times
+    that entry, where A + δI is diagonally dominant and factors whatever
+    rounding did to A. Directions well above δ are solved as the
+    Cholesky path solves them, and a null direction whose right-hand
+    side is zero stays zero (PERF.md: an eigh fall-back with clamped
+    eigenvalues read 25 to 2,000 times the Cholesky path's error on such
+    blocks). "fell back" is a device bool, always false above the
+    fall-back's width. Shared by the fresh-factor path below and the
+    cached-KRR factor bank (kernel.py _krr_cached_epoch_scan)."""
     # full-f32 matmuls: refinement converges to the residual's noise
     # floor, so the default bf16 matmul passes would cap the recovered
     # accuracy ~3 digits short
@@ -186,33 +207,48 @@ def _psd_solve_with_factor(A, L, rhs, refine=2):
             W = W + solve(rhs - jnp.matmul(A, W, precision=hp))
         return W
 
-    if A.shape[0] > _EIGH_FALLBACK_MAX_WIDTH:
-        # No eigh fallback from a block of a few thousand columns up:
-        # lax.cond compiles BOTH branches, and eigh at (4096, 4096) took
-        # the TPU compiler ~8 min, a 309 MB executable and 40 GiB of
-        # host memory (PERF.md, PR 24), so the default solve could not
-        # be built at the block width the applications use; at
-        # (16384, 16384) its QR workspace OOMed the chip. Cholesky
-        # breakdown (f32-rounding indefiniteness at lam≈0) then surfaces
-        # as non-finite W, which large-width callers assert on;
-        # regularized fits at this scale are well inside chol's range.
+    if A.shape[0] > _FALLBACK_MAX_WIDTH:
+        # No fall-back from a block of a few thousand columns up:
+        # lax.cond compiles both branches into every block program, and
+        # the applications' wide blocks, regularized fits well inside
+        # chol's range, keep one factorisation. A breakdown there
+        # surfaces as a non-finite W, which the solvers raise on.
+        return chol_path(L), jnp.zeros((), jnp.bool_)
+
+    def ridged_path(L):
+        top = jnp.max(jnp.diagonal(A))
+        top = jnp.where(top > 0, top, 1.0)  # a zero Gram still factors
+        eye = jnp.eye(A.shape[0], dtype=A.dtype)
+
+        def broken(state):
+            delta, L = state
+            return ~jnp.all(jnp.isfinite(L)) & (delta < A.shape[0] * top)
+
+        def grow(state):
+            delta = state[0] * _FALLBACK_GROWTH
+            return delta, jax.scipy.linalg.cholesky(A + delta * eye,
+                                                    lower=True)
+
+        # the first step factors at _FALLBACK_RIDGE·top; L, broken, is
+        # where it starts
+        _, L = jax.lax.while_loop(
+            broken, grow, (_FALLBACK_RIDGE / _FALLBACK_GROWTH * top, L))
         return chol_path(L)
 
-    def eigh_path(L):
-        del L
-        w, V = jnp.linalg.eigh(A)
-        w = jnp.maximum(w, 1e-12 * jnp.maximum(w[-1], 1.0))
-        return jnp.matmul(
-            V, jnp.matmul(V.T, rhs, precision=hp) / w[:, None],
-            precision=hp,
-        )
-
-    return jax.lax.cond(jnp.all(jnp.isfinite(L)), chol_path, eigh_path, L)
+    factored = jnp.all(jnp.isfinite(L))
+    return jax.lax.cond(factored, chol_path, ridged_path, L), ~factored
 
 
 def _psd_solve_device(gram, rhs, lam, refine=2):
     """(gram + lam·I) X = rhs on device: factor, then the shared
     refined solve (see _psd_solve_with_factor)."""
+    return _psd_solve_flagged(gram, rhs, lam, refine)[0]
+
+
+def _psd_solve_flagged(gram, rhs, lam, refine=2):
+    """``_psd_solve_device``'s (X, fell back) — the block
+    solver's programs return the flag, and a fit reads the flags once,
+    after its last block (``_fit_health``)."""
     A = gram + lam * jnp.eye(gram.shape[0], dtype=gram.dtype)
     L = jax.scipy.linalg.cholesky(A, lower=True)
     return _psd_solve_with_factor(A, L, rhs, refine)
@@ -238,6 +274,10 @@ def _block_step(X, R, Wb, mu, mask, start, lam, *, width: int, n: int,
     is never read again, so its update (another N·b·k matmul + a full
     residual write) is elided; the returned residual is then stale and
     the caller must not use it.
+
+    Returns (W_b, R, fell back): the last a device bool, true where the
+    block's Cholesky broke down and the ridged factor solved it
+    (``_fit_health`` reads a fit's flags once, after its last block).
     """
     Xb = jax.lax.dynamic_slice_in_dim(X, start, width, axis=1)
     mu_b = jax.lax.dynamic_slice_in_dim(mu, start, width)
@@ -249,14 +289,14 @@ def _block_step(X, R, Wb, mu, mask, start, lam, *, width: int, n: int,
             R_plus = R + contrib
     gram, rhs = _gram_rhs(Xb, mu_b, R_plus, n)
     with jax.named_scope("solver.solve"):
-        Wb_new = _psd_solve_device(gram, rhs, lam)
+        Wb_new, fell_back = _psd_solve_flagged(gram, rhs, lam)
     if last_pass:
-        return Wb_new, R_plus
+        return Wb_new, R_plus, fell_back
     with jax.named_scope("solver.residual"):
         contrib_new = (
             _f32_mm(Xb, Wb_new) - mask[:, None] * _f32_mm(mu_b, Wb_new)
         )
-        return Wb_new, R_plus - contrib_new
+        return Wb_new, R_plus - contrib_new, fell_back
 
 
 def _gram_rhs(Xb, mu_b, R_plus, n):
@@ -384,7 +424,8 @@ def _host_block_step(Xb, R, Wb, mu_b, mask, lam, *, n: int,
     ``first_pass`` additionally computes the block's feature mean from
     the slab (the in-HBM path gets all means from one ``_prep`` pass;
     with X living on host, the mean pass rides the slab's first visit
-    — no extra transfer, one extra fused reduction)."""
+    — no extra transfer, one extra fused reduction). The fourth result
+    is ``_block_step``'s fall-back flag."""
     if first_pass:
         mu_b = (
             jnp.sum(Xb.astype(jnp.float32) * mask[:, None], axis=0) / n
@@ -396,14 +437,14 @@ def _host_block_step(Xb, R, Wb, mu_b, mask, lam, *, n: int,
             R_plus = R + contrib
     gram, rhs = _gram_rhs(Xb, mu_b, R_plus, n)
     with jax.named_scope("solver.solve"):
-        Wb_new = _psd_solve_device(gram, rhs, lam)
+        Wb_new, fell_back = _psd_solve_flagged(gram, rhs, lam)
     if last_pass:
-        return Wb_new, R_plus, mu_b
+        return Wb_new, R_plus, mu_b, fell_back
     with jax.named_scope("solver.residual"):
         contrib_new = (
             _f32_mm(Xb, Wb_new) - mask[:, None] * _f32_mm(mu_b, Wb_new)
         )
-        return Wb_new, R_plus - contrib_new, mu_b
+        return Wb_new, R_plus - contrib_new, mu_b, fell_back
 
 
 @partial(jax.jit, static_argnames=("n",), donate_argnums=(1,))
@@ -464,6 +505,46 @@ def _count_fit() -> Callable[..., None]:
             _count_gram_pairs((width,))
 
     return count_step
+
+
+@jax.jit
+def _fit_health(fell_back, W):
+    """(blocks whose Cholesky fell back, whether the model is
+    finite) as one int32 pair: a fit's one read after its last block."""
+    return jnp.stack([
+        jnp.sum(jnp.stack(fell_back).astype(jnp.int32)),
+        jnp.all(jnp.isfinite(W)).astype(jnp.int32),
+    ])
+
+
+def _check_fit(fell_back: List[Any], W) -> None:
+    """Read a device fit's fall-back flags and the model's finiteness
+    once, count them (``keystone_solver_device_block_solves_total``,
+    ``keystone_solver_factor_fallbacks_total``), and raise where the model
+    is not finite."""
+    with span("solver.converged", blocks=len(fell_back)):
+        fallbacks, finite = (int(v) for v in np.asarray(
+            _fit_health(fell_back, W)))
+    reg = get_global_registry()
+    reg.counter(
+        "keystone_solver_device_block_solves_total",
+        "block systems solved on the device (Cholesky, or the ridged "
+        "factor where it broke down)",
+    ).inc(by=len(fell_back))
+    reg.counter(
+        "keystone_solver_factor_fallbacks_total",
+        "device block solves whose Cholesky factor was not finite, "
+        "solved by the factor of A + delta I refined against A (delta from "
+        "1e-6 max(diag A), tenfold while the factor breaks down)",
+    ).inc(by=fallbacks)
+    if not finite:
+        raise FloatingPointError(
+            "BlockLeastSquaresEstimator: the fitted model is not finite — "
+            f"{fallbacks} of {len(fell_back)} block Cholesky factorisations "
+            "broke down in float32 and were factored again with a ridge, "
+            f"and blocks wider than {_FALLBACK_MAX_WIDTH} columns have no "
+            "such fall-back; raise lam or use solve='host'"
+        )
 
 
 def _count_gram_prefetch() -> None:
@@ -722,18 +803,20 @@ class BlockLeastSquaresEstimator(LabelEstimator):
             else None
         )
         done = 0
+        fell_back: List[Any] = []  # device solve: a flag a block step
         for j, (it, pos, nxt) in enumerate(steps):
             s, w = blocks[pos]
             kept = factors.get(s)
             if self.solve == "device":
                 # whole block update in one dispatch; the entire fit
-                # stays in the async stream — no host sync until the
-                # caller consumes W. On sweep 0 this block's model is
-                # zero in every path (including checkpoint resume: only
-                # never-completed blocks are revisited in sweep 0), so
-                # the old-contribution matmul is elided.
+                # stays in the async stream — no host sync until its
+                # last block's flags are read (_check_fit). On sweep 0
+                # this block's model is zero in every path (including
+                # checkpoint resume: only never-completed blocks are
+                # revisited in sweep 0), so the old-contribution matmul
+                # is elided.
                 with span("solver.block_step"):
-                    Wb[s], R = _block_step(
+                    Wb[s], R, flag = _block_step(
                         X, R, Wb[s], mu, mask, s, self.lam,
                         width=w, n=n, first_pass=(it == 0),
                         last_pass=(
@@ -741,6 +824,7 @@ class BlockLeastSquaresEstimator(LabelEstimator):
                             and pos == len(blocks) - 1
                         ),
                     )
+                fell_back.append(flag)
             else:
                 # (b,b) solve on host in f64 (reference: driver-side
                 # NormalEquations solve) — see hostsolve.py, which has
@@ -784,6 +868,8 @@ class BlockLeastSquaresEstimator(LabelEstimator):
             ckpt.clear()  # fit completed; stale state must not leak into
             # a later fit at the same path
         W = jnp.concatenate([Wb[s] for s, _ in blocks], axis=0)
+        if fell_back:
+            _check_fit(fell_back, W)
         return BlockLinearMapper(
             W,
             self.block_size,
@@ -873,6 +959,7 @@ class BlockLeastSquaresEstimator(LabelEstimator):
             self.num_iter, nb, (start_it, start_pos)
         ))
         done = 0
+        fell_back: List[Any] = []
         nxt = put(schedule[0][1]) if schedule else None
         limiter = _RunAheadLimiter()
         for j, (it, bi, nxt_state) in enumerate(schedule):
@@ -886,13 +973,14 @@ class BlockLeastSquaresEstimator(LabelEstimator):
                 else jnp.zeros((widths[bi],), jnp.float32)
             )
             with span("solver.block_step"):
-                Wb[bi], R, mu_bs[bi] = _host_block_step(
+                Wb[bi], R, mu_bs[bi], flag = _host_block_step(
                     Xb, R, Wb[bi], mu_arg, mask, self.lam, n=n,
                     first_pass=first,
                     last_pass=(
                         it == self.num_iter - 1 and bi == nb - 1
                     ),
                 )
+            fell_back.append(flag)
             count_step(widths[bi])
             del Xb  # release this slab's HBM as soon as XLA is done
             limiter.add(Wb[bi])
@@ -904,6 +992,8 @@ class BlockLeastSquaresEstimator(LabelEstimator):
         if ckpt is not None:
             ckpt.clear()
         W = jnp.concatenate([jnp.asarray(w) for w in Wb], axis=0)
+        if fell_back:
+            _check_fit(fell_back, W)
         mu = jnp.concatenate(mu_bs, axis=0)
         return BlockLinearMapper(
             W,

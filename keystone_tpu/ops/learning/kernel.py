@@ -25,7 +25,7 @@ programs enqueued; on the per-block paths its host loop, with
 ``solver.krr.kernel_block`` / ``.residual`` / ``.host_solve`` /
 ``.update`` inside it where ``solve="host"``) and
 ``solver.krr.converged`` (the read of "is the model finite" that the
-fit waits on: above ``block_ls._EIGH_FALLBACK_MAX_WIDTH`` columns a
+fit waits on: above ``block_ls._FALLBACK_MAX_WIDTH`` columns a
 Cholesky breakdown has no fall-back and shows as a non-finite model); on
 the device ``jax.named_scope`` names ``krr.kernel_block`` /
 ``krr.residual`` / ``krr.solve`` / ``krr.update``; counters
@@ -323,8 +323,8 @@ def _krr_cached_epoch_scan(X, X_norms, gamma, mask, W, Y,
             L = jax.lax.dynamic_index_in_dim(Lb, bi, 0, keepdims=False)
             # refine=1 matches the uncached scan's _psd_solve_device call
             # (validated by the same f64-parity tests); the helper carries
-            # the eigh-breakdown fallback and its width gating
-            Wb_new = _psd_solve_with_factor(
+            # the breakdown fall-back and its width gating
+            Wb_new, _ = _psd_solve_with_factor(
                 K_bb + lam * eye, L, rhs, refine=1
             )
         with jax.named_scope("krr.update"):
@@ -441,7 +441,7 @@ class KernelRidgeRegression(LabelEstimator):
     # kernel regeneration; diagonal factors come from one batched
     # Cholesky bank), 1.79× at 3 epochs, but the one-epoch fit pays
     # ~+14 ms of cache-build overhead. Same math (refine=1 Cholesky,
-    # eigh fallback; rel diff 6e-6), validated by the same parity tests.
+    # ridged fall-back; rel diff 6e-6), validated by the same parity tests.
 
     def _epoch_order(self, epoch: int, n_blocks: int) -> List[int]:
         """Block order for an epoch, seeded per (permuter, epoch) so a
@@ -515,7 +515,7 @@ class KernelRidgeRegression(LabelEstimator):
                 "KernelRidgeRegression: the fitted model is not finite — "
                 f"a Cholesky factorisation of K_BB + {self.lam}·I broke "
                 "down in float32 (blocks wider than "
-                "block_ls._EIGH_FALLBACK_MAX_WIDTH have no eigh fall-back); "
+                "block_ls._FALLBACK_MAX_WIDTH have no fall-back); "
                 "raise lam or use solve='host'"
             )
         return KernelBlockLinearMapper(W, self.block_size, transformer, n)
@@ -537,7 +537,7 @@ class KernelRidgeRegression(LabelEstimator):
             )
             # cache bytes: stacked column blocks + factor bank +
             # one (n_pad, b) transient; leave room for X/W/Y and
-            # the eigh fallback workspace
+            # the fall-back factor's workspace
             cache_bytes = 4 * (
                 n_pad * n_pad
                 + len(blocks) * width * width

@@ -14,7 +14,7 @@ from __future__ import annotations
 
 import dataclasses
 from collections import Counter
-from functools import partial
+from functools import lru_cache, partial
 from typing import Any, Callable, Optional
 
 import jax
@@ -74,19 +74,34 @@ class PaddedFFT(Transformer):
         return ("padded_fft",)
 
 
-@partial(jax.jit, static_argnames=("pad", "thresh"))
-def _fft_bank_chunk(chunk, signs, mask, *, pad: int, thresh: float):
-    """One fused program for a row chunk of RandomFFTFeatures — module
-    level so the jit cache is shared across instances and calls. ``mask``
-    re-zeroes pad rows when thresh > 0 would lift them (fused, so no
-    extra full-array pass; mirrors LinearRectifier.apply_batch)."""
-    f = signs.shape[0]
-    xs = chunk[:, None, :] * signs[None, :, :]
-    spec = jnp.real(jnp.fft.fft(xs, n=pad, axis=-1))[:, :, : pad // 2]
-    out = jnp.maximum(spec, thresh).reshape(chunk.shape[0], f * (pad // 2))
-    if thresh > 0:
-        out = out * mask[:, None]
-    return out
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+@partial(jax.jit, static_argnames=("thresh",))
+def _fft_bank(x, signs, cos, mask, *, thresh: float):
+    """All branches of RandomFFTFeatures as ONE product — module level
+    so the jit cache is shared across instances and calls: rectify(x·B),
+    where column f·(pad/2) + k of B is signs[f, j]·cos(2π·j·k / pad),
+    gives the real parts of the first pad/2 coefficients of each signed,
+    zero-padded row's DFT, in float32 at HIGHEST on the MXU. The output
+    is the product's own (rows, branches·pad/2) array; B, (d,
+    branches·pad/2), is its one temporary. ``mask`` re-zeroes pad rows
+    when thresh > 0 would lift them."""
+    d = cos.shape[0]
+    with jax.named_scope("fft.bank"):
+        basis = (signs.T[:, :, None] * cos[:, None, :]).reshape(d, -1)
+        out = jnp.maximum(jnp.matmul(x, basis, precision=_HIGHEST), thresh)
+        if thresh > 0:
+            out = out * mask[:, None]
+        return out
+
+
+@lru_cache(maxsize=8)
+def _cosines(d: int, pad: int) -> np.ndarray:
+    """(d, pad / 2) float32 cos(2π·j·k / pad), the angle reduced
+    modulo pad in integers and the cosine taken in float64."""
+    jk = (np.arange(d)[:, None] * np.arange(pad // 2)[None, :]) % pad
+    return np.cos(2.0 * np.pi * jk / pad).astype(np.float32)
 
 
 @dataclasses.dataclass(eq=False)
@@ -94,13 +109,16 @@ class RandomFFTFeatures(Transformer):
     """All ``num_ffts`` random-sign -> PaddedFFT -> rectify branches of
     the MnistRandomFFT featurization in ONE jitted program (reference
     composes per-branch pipelines, MnistRandomFFT.scala:28-37; the math
-    is identical — this is the batched physical plan: one (num_ffts, d)
-    sign matrix, one batched FFT, one reshape, instead of 3 x num_ffts
-    separate dispatches + a concatenate)."""
+    is identical — this is the batched physical plan: the kept half of
+    each branch's padded DFT as one product with a signed cosine basis
+    on the MXU, instead of 3 x num_ffts separate dispatches + a
+    concatenate; one row takes the product of its signed copies with the
+    cosines, and no basis is formed). Span ``fft.bank`` (the batch
+    path's dispatch), scope ``fft.bank``, counter
+    ``keystone_featurize_fft_rows_total``."""
 
     signs: Any  # (num_ffts, d)
     rectify_threshold: float = 0.0
-    row_chunk: int = 8192  # bounds the (chunk, num_ffts, pad) intermediate
 
     @staticmethod
     def create(
@@ -126,25 +144,25 @@ class RandomFFTFeatures(Transformer):
     def out_dim(self) -> int:
         return self.signs.shape[0] * (self._pad_len(self.signs.shape[1]) // 2)
 
+    def _cos(self, d: int) -> np.ndarray:
+        return _cosines(d, self._pad_len(d))
+
     def apply(self, x):
-        pad = self._pad_len(x.shape[-1])
-        xs = x[None, :] * self.signs  # (num_ffts, d)
-        spec = jnp.real(jnp.fft.fft(xs, n=pad, axis=-1))[:, : pad // 2]
+        spec = jnp.matmul(x[None, :] * self.signs, self._cos(x.shape[-1]),
+                          precision=_HIGHEST)
         return jnp.maximum(spec, self.rectify_threshold).reshape(-1)
 
     def apply_batch(self, ds: Dataset) -> Dataset:
         x = ds.padded()
-        pad = self._pad_len(x.shape[-1])
-        mask = ds.mask()
-        outs = [
-            _fft_bank_chunk(
-                x[s : s + self.row_chunk], self.signs,
-                mask[s : s + self.row_chunk],
-                pad=pad, thresh=self.rectify_threshold,
+        with span("fft.bank", n=ds.n, branches=self.signs.shape[0]):
+            out = _fft_bank(
+                x, self.signs, self._cos(x.shape[-1]), ds.mask(),
+                thresh=float(self.rectify_threshold),
             )
-            for s in range(0, x.shape[0], self.row_chunk)
-        ]
-        out = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=0)
+        get_global_registry().counter(
+            "keystone_featurize_fft_rows_total",
+            "rows through RandomFFTFeatures' batch path",
+        ).inc(by=ds.n)
         return Dataset.from_array(out, n=ds.n)
 
 

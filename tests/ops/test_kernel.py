@@ -308,12 +308,12 @@ def test_krr_model_holds_the_rows_it_was_given_and_no_copy():
 
 
 def test_krr_breakdown_without_a_fallback_is_an_error(monkeypatch):
-    """A block wider than the eigh fall-back's limit whose Cholesky
+    """A block wider than the fall-back's limit whose Cholesky
     breaks down gives a non-finite model: fit says so and returns
     none (duplicated rows, no ridge: K_BB is singular)."""
     from keystone_tpu.ops.learning import block_ls
 
-    monkeypatch.setattr(block_ls, "_EIGH_FALLBACK_MAX_WIDTH", 8)
+    monkeypatch.setattr(block_ls, "_FALLBACK_MAX_WIDTH", 8)
     rng = np.random.default_rng(24)
     x = rng.standard_normal((16, 3)).astype(np.float32)
     x = np.concatenate([x, x])  # every row twice

@@ -196,6 +196,99 @@ def test_random_fft_features_nonzero_threshold_remasks_pad_rows():
     np.testing.assert_allclose(got[:n], want[:n], rtol=1e-5, atol=1e-5)
 
 
+def _digit_like(n: int, seed: int) -> np.ndarray:
+    """(n, 784) float32 in 0..255, a fifth of the pixels inked."""
+    rng = np.random.default_rng(seed)
+    ink = rng.random((n, 784)) < 0.2
+    return np.round(ink * rng.random((n, 784)) * 255.0).astype(np.float32)
+
+
+def _fft_bank_f64(x: np.ndarray, num_ffts: int, seed: int) -> np.ndarray:
+    """The branches by their definition, in float64: the signs of
+    ``default_rng(seed + i)``, zero-padded to 1,024, numpy's FFT, the
+    real parts of the first 512 coefficients, rectified."""
+    out = []
+    for i in range(num_ffts):
+        s = np.random.default_rng(seed + i).integers(0, 2, size=784) * 2.0 - 1
+        spec = np.fft.fft(np.asarray(x, np.float64) * s, n=1024, axis=1)
+        out.append(np.maximum(spec.real[:, :512], 0.0))
+    return np.concatenate(out, axis=1)
+
+
+@pytest.mark.parametrize("path", ["batch", "one_row"])
+def test_random_fft_bank_matches_float64_fft(path):
+    """6 FFTs over 300 rows against the float64 FFT: the batch path's
+    product with the signed cosine basis, and one row's product of its
+    signed copies with the cosines, both at HIGHEST, keep float32's
+    precision."""
+    from keystone_tpu.ops.stats import RandomFFTFeatures
+
+    x = _digit_like(300, 4)
+    node = RandomFFTFeatures.create(784, 6, seed=21)
+    if path == "batch":
+        got = node.apply_batch(Dataset.from_array(jnp.asarray(x))).padded()
+    else:
+        x = x[:12]
+        one = jax.jit(node.apply)
+        got = np.stack([np.asarray(one(jnp.asarray(row))) for row in x])
+    got = np.asarray(got, np.float64)
+    want = _fft_bank_f64(x, 6, 21)
+    assert got.shape == (x.shape[0], 6 * 512)
+    assert np.linalg.norm(got - want) / np.linalg.norm(want) < 1e-6
+
+
+@pytest.mark.parametrize("thresh", [0.0, 0.25])
+def test_random_fft_one_row_is_the_batch_row(thresh):
+    """A row scored alone (no basis formed) is the batch path's row for
+    that row, to float32's rounding, at either threshold."""
+    from keystone_tpu.ops.stats import RandomFFTFeatures
+
+    x = _digit_like(4, 9)
+    node = RandomFFTFeatures.create(784, 3, seed=4, rectify_threshold=thresh)
+    batch = np.asarray(node.apply_batch(
+        Dataset.from_array(jnp.asarray(x))).padded())
+    for i in range(4):
+        row = np.asarray(node.apply(jnp.asarray(x[i])))
+        assert np.all(row >= thresh)
+        np.testing.assert_allclose(row, batch[i], rtol=1e-5,
+                                   atol=1e-5 * np.abs(batch[i]).max())
+
+
+@pytest.mark.parametrize("thresh", [0.0, 0.25])
+def test_random_fft_bank_pad_rows_stay_zero(thresh):
+    """Pad rows of a Dataset (n of padded_n valid) come out exactly zero
+    from the bank at any threshold, and the rows and the span and
+    counter of the batch path are left behind."""
+    from keystone_tpu.observability.registry import (
+        get_global_registry, reset_global_registry,
+    )
+    from keystone_tpu.observability.tracing import (
+        disable_tracing, enable_tracing,
+    )
+    from keystone_tpu.ops.stats import RandomFFTFeatures
+
+    x = np.zeros((16, 784), np.float32)
+    x[:11] = _digit_like(11, 6)
+    node = RandomFFTFeatures.create(784, 3, seed=2, rectify_threshold=thresh)
+    tr = enable_tracing()
+    tr.clear()
+    reset_global_registry()
+    try:
+        out = node.apply_batch(Dataset.from_array(jnp.asarray(x), n=11))
+        names = [s.name for s in tr.recent()]
+        rows = get_global_registry().counter(
+            "keystone_featurize_fft_rows_total").get()
+    finally:
+        disable_tracing()
+        tr.clear()
+        reset_global_registry()
+    got = np.asarray(out.padded())
+    assert got.shape == (16, 3 * 512) and out.n == 11
+    np.testing.assert_array_equal(got[11:], 0.0)
+    assert np.all(got[:11] >= thresh)
+    assert names.count("fft.bank") == 1 and rows == 11
+
+
 @pytest.mark.parametrize("case", ["array", "ragged_items", "noted_node"])
 def test_column_sampler_on_the_device_draws_what_the_loop_drew(case):
     """A batch of matrices sampled on the device — one array, ragged host
