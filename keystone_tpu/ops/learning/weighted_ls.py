@@ -21,19 +21,20 @@ driver round trip, no distributed System.gc(). That is ``solve="chol"``.
 
 ``solve="pcg"`` (what ``auto`` takes at wide blocks) never forms a class
 covariance: all C systems share one matrix-free preconditioned CG
-(``_pcg_block_core``). Its statistics read the original rows through
-one-hot GEMMs and one population Gram X_bᵀX_b, of which the upper block
-triangle is multiplied and the lower blocks are its transposes
-(``block_ls._sym_gram``; the chol path's ``_pop_stats`` builds the same
-Gram the same way); its matvec needs each row with its own class's vector
-only, and reads one of two row layouts, chosen a fit from what the
-estimator can see (``_sorted_layout``): ``sorted`` — the block's rows
-gathered once a block step into class order, tiles of consecutive rows
-against a window of 128 class vectors — where the copy fits the device
-beside X and the class counts keep every tile inside one window, and
-``original`` — one-hot GEMMs over all C classes, no copy, C/128 times
-the operations — elsewhere (sharded rows, host-RAM slabs, tight memory,
-classes so small that 16,384 sorted rows hold more than 128 of them).
+(``_pcg_block_core``). Its statistics are one population Gram X_bᵀX_b,
+of which the upper block triangle is multiplied and the lower blocks are
+its transposes (``block_ls._sym_gram``; the chol path's ``_pop_stats``
+builds the same Gram the same way), XᵀR on the original rows, and two
+class-restricted moments (class sums, own-residual sums); those moments
+and the matvec need each row with its own class only, and read one of
+two row layouts, chosen a fit from what the estimator can see
+(``_sorted_layout``): ``sorted`` — the block's rows gathered once a
+block step into class order, tiles of consecutive rows against a window
+of 128 classes — where the copy fits the device beside X and the class
+counts keep every tile inside one window, and ``original`` — one-hot
+GEMMs over all C classes, no copy, C/128 times the operations —
+elsewhere (sharded rows, host-RAM slabs, tight memory, classes so small
+that 16,384 sorted rows hold more than 128 of them).
 
 Observability: host spans ``solver.wls.prep`` (array mode, padding, the
 labels' cast), ``solver.wls.layout`` (pcg: the choice of the matvec's
@@ -45,7 +46,9 @@ names ``wls.setup`` / ``wls.stats`` / ``wls.precond`` / ``wls.sort`` /
 ``wls.cg`` / ``wls.update``; counters ``keystone_solver_wls_fits_total``,
 ``keystone_solver_wls_path_total{solve,layout}`` (the path ``auto``
 took), ``keystone_solver_wls_sorted_fits_total`` (fits whose matvec ran
-on sorted rows), ``keystone_solver_wls_pcg_iterations_total`` (the
+on sorted rows), ``keystone_solver_wls_sorted_stats_fits_total`` (fits
+whose class-restricted statistics read sorted rows),
+``keystone_solver_wls_pcg_iterations_total`` (the
 iterations a fit reports, added where ``convergence_check`` reads them
 anyway) and block_ls's ``keystone_solver_gram_pairs_computed_total`` /
 ``_gram_pairs_total`` (the column pairs a fit's population Grams
@@ -316,56 +319,113 @@ def _class_sorted_rows(P, tile):
     )
 
 
-def _sorted_class_products(Xs, kcls, C, window):
-    """The CG matvec's two data-sized products on class-sorted rows.
-    ``Xs`` (tiles, tile, b) holds the block's rows in class order and
-    ``kcls`` (tiles, tile) each row's class (C: none). Returns v (C, b)
-    -> Σ_{i in c} x_i (x_i·v_c), (C, b).
+def _own_class_entries(R, order, kcls):
+    """R[order, kcls] (tiles, tile): each sorted row's entry of R (n, C)
+    in its own class, taken by index; 0 for a row of no class and for
+    the pad rows that fill the last tile."""
+    C = R.shape[1]
+    return jnp.where(
+        kcls < C,
+        R.at[order, jnp.minimum(kcls, C - 1)].get(mode="promise_in_bounds"),
+        0.0,
+    )
 
-    Tile i's rows lie in classes [lo_i, lo_i + window) (``_sorted_layout``
-    holds the caller to that), so a row meets ``window`` vectors, not C:
-    T_i = X_i·V_iᵀ against the window's vectors (tile, window), all but
-    each row's own-class entry (z_i) zeroed by a one-hot local to the
-    window, then X_iᵀ(onehot_i ⊙ z_i) (window, b), added into rows
-    [lo_i, lo_i + window) of the result. Precision as on the original
-    rows: f32 data at HIGHEST, bf16 data against the three limbs of the
-    f32 side."""
+
+def _sorted_windows(Xs, kcls, C, window):
+    """The window scheme of the products on class-sorted rows. ``Xs``
+    (tiles, tile, b) holds the block's rows in class order and ``kcls``
+    (tiles, tile) each row's class (C: none). Tile i's rows lie in
+    classes [lo_i, lo_i + window) (``_sorted_layout`` holds the caller
+    to that), so a row meets ``window`` classes, not C. Returns
+
+    - ``wrows`` (tiles, window): the classes of each tile's window;
+    - ``own()``: (tiles, tile, window), each row's own class marked in
+      its tile's window (nothing for a row of no class);
+    - ``bdot(spec, a, c)``: a product batched over tiles, f32 accumulated
+      — f32 data at HIGHEST, bf16 data at the native pass, whose caller
+      hands it the three limbs of an f32 side;
+    - ``add(S)``: S (tiles, window, …) added into rows [lo_i, lo_i +
+      window) of a (C, …) result."""
     f32 = jnp.float32
     hp = jax.lax.Precision.HIGHEST
-    b = Xs.shape[2]
     bf16_data = Xs.dtype == jnp.bfloat16
     lo = kcls[:, 0]  # sorted: a tile's first row has its lowest class
     # a row of no class matches no column of the window
     kloc = jnp.where(kcls < C, kcls - lo[:, None], -1)
-    # the iterate is padded with ``window`` zero rows, so a window that
-    # starts at the last classes (or at C: a tile of pad rows) stays
-    # inside it
+    # the result is padded with ``window`` rows, so a window that starts
+    # at the last classes (or at C: a tile of pad rows) stays inside it
     wrows = lo[:, None] + jnp.arange(window, dtype=jnp.int32)
+
+    def own():
+        return kloc[:, :, None] == jnp.arange(window, dtype=jnp.int32)
 
     def bdot(spec, a, c):
         return jnp.einsum(spec, a, c, preferred_element_type=f32,
                           precision=None if bf16_data else hp)
 
+    def add(S):
+        out = jnp.zeros((C + window,) + S.shape[2:], f32).at[wrows].add(
+            S, mode="promise_in_bounds"
+        )
+        return out[:C]
+
+    return wrows, own, bdot, add
+
+
+def _sorted_class_products(Xs, kcls, C, window):
+    """The CG matvec's two data-sized products on class-sorted rows
+    (``_sorted_windows``). Returns v (C, b) -> Σ_{i in c} x_i (x_i·v_c),
+    (C, b): T_i = X_i·V_iᵀ against the window's vectors (tile, window),
+    all but each row's own-class entry (z_i) zeroed by a one-hot local
+    to the window, then X_iᵀ(onehot_i ⊙ z_i) (window, b), added into
+    rows [lo_i, lo_i + window) of the result. Precision as on the
+    original rows: f32 data at HIGHEST, bf16 data against the three
+    limbs of the f32 side."""
+    f32 = jnp.float32
+    b = Xs.shape[2]
+    bf16_data = Xs.dtype == jnp.bfloat16
+    wrows, own, bdot, add = _sorted_windows(Xs, kcls, C, window)
+
     def products(v):
         vp = jnp.concatenate([v, jnp.zeros((window, b), f32)])
         Vw = vp.at[wrows].get(mode="promise_in_bounds")  # (tiles, K, b)
-        own = kloc[:, :, None] == jnp.arange(window, dtype=jnp.int32)
         if bf16_data:
             T = _sum3(bdot("itb,ikb->itk", Xs, _limb3(Vw, 1)), axis=2)
         else:
             T = bdot("itb,ikb->itk", Xs, Vw)
         # onehot ⊙ z without z: a row's one own-class entry of T is z_i
-        oz = jnp.where(own, T, 0.0)  # (tiles, tile, K)
+        oz = jnp.where(own(), T, 0.0)  # (tiles, tile, K)
         if bf16_data:
             S = _sum3(bdot("itb,itk->ikb", Xs, _limb3(oz, 2)), axis=1)
         else:
             S = bdot("itb,itk->ikb", Xs, oz)
-        out = jnp.zeros((C + window, b), f32).at[wrows].add(
-            S, mode="promise_in_bounds"
-        )
-        return out[:C]
+        return add(S)
 
     return products
+
+
+def _sorted_class_moments(Xs, kcls, r, C, window):
+    """The statistics' class-restricted moments on class-sorted rows
+    (``_sorted_windows``): the class sums Σ_{i in c} x_i and the
+    own-residual sums Σ_{i in c} r_i x_i, (C, b) each, and Σ_{i in c}
+    r_i (C,), where ``r`` (tiles, tile) is each sorted row's residual in
+    its own class (0 for a row of no class). Two products a tile,
+    X_iᵀ onehot_i and X_iᵀ(onehot_i ⊙ r_i), each against (tile, window)
+    columns: 2·n·b·window operations a product where the one-hot
+    products over all classes spend 2·n·b·C, and one (tiles, tile,
+    window) operand held at a time, as the CG's products hold. f32 data
+    at HIGHEST; bf16 data against the one-hot as it is (0/1 is exact in
+    bf16) and the three limbs of onehot ⊙ r."""
+    _, own, bdot, add = _sorted_windows(Xs, kcls, C, window)
+    onehot = own()
+    orr = jnp.where(onehot, r[:, :, None], 0.0)  # (tiles, tile, window)
+    if Xs.dtype == jnp.bfloat16:
+        sums = bdot("itb,itk->ikb", Xs, onehot.astype(jnp.bfloat16))
+        rsums = _sum3(bdot("itb,itk->ikb", Xs, _limb3(orr, 2)), axis=1)
+    else:
+        sums = bdot("itb,itk->ikb", Xs, onehot.astype(jnp.float32))
+        rsums = bdot("itb,itk->ikb", Xs, orr)
+    return add(sums), add(rsums), add(jnp.einsum("itk->ik", orr))
 
 
 def _pcg_block_core(X, R, P, Wb, inv_counts, valid, start, w, lam,
@@ -374,37 +434,43 @@ def _pcg_block_core(X, R, P, Wb, inv_counts, valid, start, w, lam,
     """One whole weighted-BCD block update for ALL classes at once, as a
     single device program: population stats, shared-preconditioner
     inverse, batched matrix-free PCG over the C per-class systems, and
-    the residual update. The statistics and the update read the
-    ORIGINAL (ungrouped) rows; the CG matvec reads them too, or a
-    class-sorted copy of the block (``sort``, below).
+    the residual update. The update and the population statistics read
+    the ORIGINAL (ungrouped) rows; the class-restricted statistics and
+    the CG matvec read them too, or a class-sorted copy of the block
+    (``sort``, below).
 
     This replaced a design of class-grouped gathers and 8 class-chunks,
     each its own CG with triangular-solve preconditioning, whose
     chunked TRSMs were the largest single cost of the flagship fit.
     Here:
 
-    - the statistics' per-class contractions ride ONE-HOT GEMMs: with
-      P (n, C) the 0/1 class-membership matrix, classMean = PᵀX_b and
-      resLocal = (R ⊙ P)·1 — no host-side index building, no per-chunk
+    - the statistics' per-class contractions are products with the
+      class membership: with P (n, C) the 0/1 class-membership matrix,
+      classMean = PᵀX_b and resLocal = (R ⊙ P)·1 (one-hot GEMMs on the
+      original rows, windows on sorted ones, below) — no host-side
+      index building, no per-chunk
       padding pathology for skewed classes (ADVICE r3); the population
       Gram X_bᵀX_b is built from its upper block triangle
       (``block_ls._sym_gram``: 0.5625 of the full product's MXU work
       at b = 4,096, every entry the same dot product);
-    - the CG matvec's class-restricted products, z_i = x_i·v_{y_i} and
-      Σ_{i in c} x_i z_i, need each row with its OWN class's vector
-      only. With ``sort`` = (order, kcls) from ``_class_sorted_rows``
-      the block's rows are gathered ONCE, after the statistics, into
-      class order as (tiles, tile, b); a tile of consecutive sorted
-      rows meets a contiguous window of at most ``sort_window`` classes
-      (the caller checked the class counts: ``_sorted_layout``), so both
-      products are GEMMs batched over tiles against the window's
-      vectors: 2·n·b·sort_window operations each where the one-hot form
-      spends 2·n·b·C, and two reads of the copy an iteration. The copy
-      costs the block's bytes again, so the caller takes it only where
-      it fits (``_sorted_layout``). Without ``sort`` the same products
-      ride one-hot GEMMs over all C classes on the original rows,
-      (n,b)x(b,C)-shaped (3C via ``_limb3`` for bf16 rows): no copy, C
-      times the operations;
+    - the class-restricted products — the class sums PᵀX_b and
+      Xᵀ(P ⊙ r) among the statistics, z_i = x_i·v_{y_i} and
+      Σ_{i in c} x_i z_i in the CG matvec — need each row with its OWN
+      class only. With ``sort`` = (order, kcls) from
+      ``_class_sorted_rows`` the block's rows are gathered ONCE, after
+      the statistics that read R (the Gram, XᵀR, each row's own-class
+      residual), into class order as (tiles, tile, b); a tile of
+      consecutive sorted rows meets a contiguous window of at most
+      ``sort_window`` classes (the caller checked the class counts:
+      ``_sorted_layout``), so these products are GEMMs batched over
+      tiles against the window's columns (``_sorted_class_moments``,
+      ``_sorted_class_products``): 2·n·b·sort_window operations a
+      product where the one-hot form spends 2·n·b·C, and two reads of
+      the copy an iteration. The copy costs the block's bytes again, so
+      the caller takes it only where it fits (``_sorted_layout``).
+      Without ``sort`` the same products ride one-hot GEMMs over all C
+      classes on the original rows, (n,b)x(b,C)-shaped (3C via
+      ``_limb3`` for bf16 rows): no copy, C times the operations;
     - all C systems share one CG loop (the per-class solves are batched
       rows of the iterate), preconditioned by the explicit inverse of
       M = (1−w)·popCov + (λ+ε)I (see ``_precond_inverse``) applied as
@@ -470,7 +536,22 @@ def _pcg_block_core(X, R, P, Wb, inv_counts, valid, start, w, lam,
     # zero by the Dataset padding contract) -------------------------------
     with jax.named_scope("wls.stats"):
         gram = _sym_gram(Xb)
-        if bf16_data:
+        residual_mean = jnp.einsum("nc->c", R) / n
+        if sort is not None:
+            # XᵀR is dense in R and stays on the original rows, with the
+            # labelled rows' column sums as one more column of the same
+            # product (popMean, so that the preconditioner needs nothing
+            # of the copy); the class-restricted moments read the sorted
+            # copy (below), so of R they need each sorted row's own-class
+            # entry alone, taken by index (a row of no class, or a pad
+            # row, reads 0)
+            labelled = jnp.max(P, axis=1).astype(f32)
+            xtr = mm_bf16_f32_00(
+                jnp.concatenate([R, labelled[:, None]], axis=1)) / n
+            pop_xtr, pop_mean = xtr[:, :C], xtr[:, C]  # (b, C), (b,)
+            order, kcls = sort
+            r_sorted = _own_class_entries(R, order, kcls)
+        elif bf16_data:
             # ONE X_b read for all three moment contractions: class sums
             # (one-hot columns), XᵀR (3 limbs), and Xᵀ(P⊙r) (3 limbs)
             # own-class residual per row
@@ -493,18 +574,13 @@ def _pcg_block_core(X, R, P, Wb, inv_counts, valid, start, w, lam,
             ) * inv_counts[:, None]
             r = jnp.einsum("nc,nc->n", R, Pf)
             cxtr = mm_bf16_f32_00(Pf * r[:, None]).T * inv_counts[:, None]
-        # popMean = Σ_c n_c·classMean_c / n (P already excludes pad rows
-        # and empty classes contribute zero) — no extra X pass
-        counts = valid / inv_counts
-        pop_mean = jnp.einsum("c,cb->b", counts, cmean) / n
+        if sort is None:
+            rlm = jnp.einsum("nc,n->c", Pf, r) * inv_counts
+            # popMean = Σ_c n_c·classMean_c / n (P already excludes pad
+            # rows and empty classes contribute zero) — no extra X pass
+            counts = valid / inv_counts
+            pop_mean = jnp.einsum("c,cb->b", counts, cmean) / n
         pop_cov = gram / n - jnp.outer(pop_mean, pop_mean)
-        residual_mean = jnp.einsum("nc->c", R) / n
-        rlm = jnp.einsum("nc,n->c", Pf, r) * inv_counts
-        mean_diff = cmean - pop_mean[None, :]
-        jm = cmean * w + pop_mean[None, :] * (1.0 - w)
-        mmw = residual_mean * (1.0 - w) + w * rlm
-        joint_xtr = pop_xtr.T * (1.0 - w) + cxtr * w - jm * mmw[:, None]
-        rhs = joint_xtr - Wb.T * lam  # (C, b)
 
     with jax.named_scope("wls.precond"):
         Minv = _precond_inverse(pop_cov, w, lam)
@@ -521,16 +597,32 @@ def _pcg_block_core(X, R, P, Wb, inv_counts, valid, start, w, lam,
     else:
         with jax.named_scope("wls.sort"):
             # the copy is the block's bytes again: the barrier keeps its
-            # gather behind the statistics, whose (n, C) temporaries are
-            # dead by then (the gather depends on nothing else, and XLA
+            # gather behind every read of R, so that R (n, C) is dead by
+            # then in a fit of one block step, and behind the
+            # preconditioner, whose factorisation's temporaries are dead
+            # by then too (the gather depends on nothing else, and XLA
             # is free to schedule it first)
-            order, rhs, pop_cov = jax.lax.optimization_barrier(
-                (sort[0], rhs, pop_cov)
+            order, r_sorted, pop_xtr, pop_mean, Minv, residual_mean = (
+                jax.lax.optimization_barrier(
+                    (order, r_sorted, pop_xtr, pop_mean, Minv,
+                     residual_mean))
             )
-            class_xxv = _sorted_class_products(
-                Xb.at[order].get(mode="promise_in_bounds"), sort[1], C,
-                sort_window,
+            Xs = Xb.at[order].get(mode="promise_in_bounds")
+        with jax.named_scope("wls.stats"):
+            csum, crsum, rsum = _sorted_class_moments(
+                Xs, kcls, r_sorted, C, sort_window
             )
+            cmean = csum * inv_counts[:, None]
+            cxtr = crsum * inv_counts[:, None]
+            rlm = rsum * inv_counts
+        class_xxv = _sorted_class_products(Xs, kcls, C, sort_window)
+
+    with jax.named_scope("wls.stats"):
+        mean_diff = cmean - pop_mean[None, :]
+        jm = cmean * w + pop_mean[None, :] * (1.0 - w)
+        mmw = residual_mean * (1.0 - w) + w * rlm
+        joint_xtr = pop_xtr.T * (1.0 - w) + cxtr * w - jm * mmw[:, None]
+        rhs = joint_xtr - Wb.T * lam  # (C, b)
 
     def matvec(v):  # (C, b) -> (C, b)
         pv = (1.0 - w) * jnp.matmul(v, pop_cov, precision=hp)
@@ -757,6 +849,10 @@ def _count_fit(solve: str, layout: str, widths, num_iter: int) -> None:
         "keystone_solver_wls_sorted_fits_total",
         "weighted fits whose CG matvec ran on class-sorted rows",
     ).inc(by=int(layout == "sorted"))
+    reg.counter(
+        "keystone_solver_wls_sorted_stats_fits_total",
+        "weighted fits whose statistics read class-sorted rows",
+    ).inc(by=int(layout == "sorted"))
 
 
 # The sorted-rows matvec's shapes. A tile of _SORT_TILE class-sorted rows
@@ -804,8 +900,9 @@ def _sorted_layout(X, Y, mask, width: int, block_steps: int) -> int:
       holds none of these: XLA frees the statistics' arrays before the
       copy is made. Checked against the TPU compiler's own count at
       the flagship's shape (n 327,680, D 4,096, C 1,000; compiler |
-      here): one step 12.51 | 12.38 GB, two blocks of 2,048 15.66 |
-      15.66, one block twice 16.13 | 15.66;
+      here): one step 12.51 | 12.38 GB, two blocks of 2,048 14.98 |
+      15.66, one block twice 15.30 | 15.66 (the class-restricted
+      statistics read the copy and hold no (n, C) temporary);
     - the class counts keep every tile inside one window — the one
       read-back, made last.
     """

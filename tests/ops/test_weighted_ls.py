@@ -436,6 +436,103 @@ def test_sorted_rows_products_match_the_one_hot_products(case):
     np.testing.assert_allclose(got, want, rtol=2e-5, atol=2e-5)
 
 
+@pytest.mark.parametrize("case", [
+    "shuffled", "sorted_already", "empty_class", "pad_rows",
+    "no_class_rows", "many_windows", "one_tile", "bf16", "multi_hot",
+])
+def test_sorted_rows_moments_match_the_one_hot_moments(case):
+    """The statistics' class sums PᵀX, own-residual sums Xᵀ(P ⊙ r) and
+    Pᵀr on class-sorted tiles, with r each row's residual in its own
+    class taken by index, against the one-hot forms over all classes in
+    float64 (P: each row's first positive label, as the fit takes it)."""
+    import jax.numpy as jnp
+
+    from keystone_tpu.ops.learning import weighted_ls as wls
+
+    X, y, C, tile, window = _matvec_case(
+        "shuffled" if case == "multi_hot" else case)
+    if case == "bf16":
+        Xd = jnp.asarray(X, jnp.bfloat16)
+        X = np.asarray(Xd.astype(jnp.float32))
+    else:
+        Xd = jnp.asarray(X)
+    n = len(y)
+    Y = np.full((n, C), -1.0, np.float32)
+    Y[np.flatnonzero(y >= 0), y[y >= 0]] = 1.0
+    if case == "multi_hot":  # a later +1 on a third of the rows
+        for i in np.random.default_rng(0).choice(n, n // 3, replace=False):
+            if y[i] < C - 1:
+                Y[i, y[i] + 1:][0] = 1.0
+    P, _ = wls._membership(jnp.asarray(Y), jnp.ones((n,), jnp.float32))
+    order, kcls = wls._class_sorted_rows(P, tile)
+    R = np.random.default_rng(3).standard_normal((n, C)).astype(np.float32)
+    r = wls._own_class_entries(jnp.asarray(R), order, kcls)
+    sums, rsums, rtot = (np.asarray(a) for a in wls._sorted_class_moments(
+        Xd[order], kcls, r, C, window))
+    P64 = np.asarray(P, np.float64)
+    X64 = X.astype(np.float64)
+    r64 = (P64 * R).sum(1)
+    np.testing.assert_allclose(sums, P64.T @ X64, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(
+        rsums, (P64 * r64[:, None]).T @ X64, rtol=2e-5, atol=2e-5)
+    np.testing.assert_allclose(rtot, P64.T @ r64, rtol=2e-5, atol=2e-5)
+
+
+def _products_outside_loops(fn, *args):
+    """The operand shapes of every product in ``fn``'s program outside
+    its loops (nested calls followed, ``while`` bodies not)."""
+    import jax
+
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "while":
+                continue
+            if eqn.primitive.name == "dot_general":
+                found.append(tuple(tuple(v.aval.shape) for v in eqn.invars))
+            for p in eqn.params.values():
+                sub = getattr(p, "jaxpr", p)
+                if hasattr(sub, "eqns"):
+                    walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+@pytest.mark.parametrize("sorted_rows", [True, False])
+def test_statistics_meet_every_class_in_x_t_r_alone_on_sorted_rows(
+        sorted_rows):
+    """On class-sorted rows the one product of a block step's statistics
+    that meets every row's features with all C classes is XᵀR, dense in
+    R (with the labelled rows' column sums as one more column): the
+    class sums and the own-residual sums read the sorted copy against
+    windows of classes. On the original rows the one-hot products stay
+    as they were: XᵀR, PᵀX and Xᵀ(P ⊙ r)."""
+    from functools import partial
+
+    import jax.numpy as jnp
+
+    from keystone_tpu.ops.learning import weighted_ls as wls
+
+    n, b, C, tile, window = 96, 12, 6, 16, 4
+    X, Y, _ = _weighted_problem(n=n, D=b, C=C, seed=4)
+    mask = jnp.ones((n,), jnp.float32)
+    P, inv_counts, valid, _, R, sort = wls._pcg_setup_core(
+        jnp.asarray(Y), mask, 0.5, n, tile if sorted_rows else 0)
+    step = partial(wls._pcg_block_core, width=b, n=n,
+                   sort_window=window if sorted_rows else 0)
+    products = _products_outside_loops(
+        step, jnp.asarray(X), R, P, jnp.zeros((b, C)), inv_counts, valid,
+        0, 0.5, 0.05, sort)
+    every_class = sorted(p for p in products if (n, b) in p and any(
+        s[0] == n and s[1] >= C for s in p if s != (n, b)))
+    if sorted_rows:
+        assert every_class == [((n, b), (n, C + 1))], products  # Xᵀ[R | 1]
+    else:
+        assert every_class == [((n, C), (n, b))] + [((n, b), (n, C))] * 2
+
+
 @pytest.mark.parametrize("counts,tile,window,want", [
     # 1,000 classes of 235 to 420 rows: a 16,384-row tile meets 72
     ([235 + (i * 37) % 186 for i in range(1000)], 16384, 128, True),
@@ -667,3 +764,42 @@ def test_sorted_fits_share_metric_reads_the_program_s_counters(
     _no_room(monkeypatch, small_tiles)
     est.fit(Dataset.of(X), Dataset.of(Y))
     assert counter_ratio.read(None, **metric["args"]) == 0.5
+
+
+@pytest.mark.parametrize("layout", ["sorted", "original"])
+def test_sorted_stats_share_metric_reads_the_program_s_counters(
+        monkeypatch, small_tiles, layout):
+    """The benchmark's ``wls_sorted_stats_share.wfit`` is one data file:
+    its reader gives 1.0 after a fit whose statistics read class-sorted
+    rows and 0.0 after a fit on the original rows."""
+    import json
+    import os
+
+    from benchmark.readers import counter_ratio
+    from keystone_tpu.observability import registry
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "metrics",
+                           "wls_sorted_stats_share.wfit.json")) as f:
+        metric = json.load(f)
+    assert metric == {"reader": "counter_ratio", "args": {
+        "numerator": "keystone_solver_wls_sorted_stats_fits_total",
+        "denominator": "keystone_solver_wls_fits_total"}}
+    entry = [m for m in json.load(open(os.path.join(root, "BENCHMARK.json")))
+             ["per_layer"] if m["name"] == "wls_sorted_stats_share.wfit"]
+    assert entry == [{
+        "name": "wls_sorted_stats_share.wfit", "unit": "share",
+        "better": "higher", "source": "program_counter",
+        "layer": "Solvers", "moves": "fit_rows_per_s",
+        "workloads": ["weighted-bcd-fit"]}]
+    monkeypatch.setattr(registry, "_global_registry",
+                        registry.MetricsRegistry())
+    if layout == "original":
+        _no_room(monkeypatch, small_tiles)
+    X, Y, _ = _weighted_problem(n=120, D=16, C=3, seed=2)
+    BlockWeightedLeastSquaresEstimator(16, 1, 0.05, 0.5, solve="pcg").fit(
+        Dataset.of(X), Dataset.of(Y))
+    assert _path_total(layout) == 1
+    assert counter_ratio.read(None, **metric["args"]) == (
+        1.0 if layout == "sorted" else 0.0)

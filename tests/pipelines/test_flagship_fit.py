@@ -216,6 +216,8 @@ def test_spans_and_counters_appear_once_a_fit_and_name_the_path(
     if layout == "sorted":
         assert delta.pop(
             ("keystone_solver_wls_sorted_fits_total", ())) == 1
+        assert delta.pop(
+            ("keystone_solver_wls_sorted_stats_fits_total", ())) == 1
     if solve == "pcg":
         assert sorted(names) == ["solver.wls.converged",
                                  "solver.wls.dispatch", "solver.wls.layout",
