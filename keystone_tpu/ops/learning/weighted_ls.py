@@ -25,7 +25,9 @@ covariance: all C systems share one matrix-free preconditioned CG
 of which the upper block triangle is multiplied and the lower blocks are
 its transposes (``block_ls._sym_gram``; the chol path's ``_pop_stats``
 builds the same Gram the same way), XᵀR on the original rows, and two
-class-restricted moments (class sums, own-residual sums); those moments
+class-restricted moments (class sums, own-residual sums) — on a first
+block step from single-label ±1 indicator labels on sorted rows, XᵀR and
+the own-residual sums follow from the class sums; those moments
 and the matvec need each row with its own class only, and read one of
 two row layouts, chosen a fit from what the estimator can see
 (``_sorted_layout``): ``sorted`` — the block's rows gathered once a
@@ -48,6 +50,8 @@ names ``wls.setup`` / ``wls.stats`` / ``wls.precond`` / ``wls.sort`` /
 took), ``keystone_solver_wls_sorted_fits_total`` (fits whose matvec ran
 on sorted rows), ``keystone_solver_wls_sorted_stats_fits_total`` (fits
 whose class-restricted statistics read sorted rows),
+``keystone_solver_wls_label_moments_fits_total`` (fits whose first block
+step took its moments from the class sums),
 ``keystone_solver_wls_pcg_iterations_total`` (the
 iterations a fit reports, added where ``convergence_check`` reads them
 anyway) and block_ls's ``keystone_solver_gram_pairs_computed_total`` /
@@ -415,29 +419,34 @@ def _sorted_class_moments(Xs, kcls, r, C, window):
     products over all classes spend 2·n·b·C, and one (tiles, tile,
     window) operand held at a time, as the CG's products hold. f32 data
     at HIGHEST; bf16 data against the one-hot as it is (0/1 is exact in
-    bf16) and the three limbs of onehot ⊙ r."""
+    bf16) and the three limbs of onehot ⊙ r. With ``r`` None, the class
+    sums alone: one product."""
     _, own, bdot, add = _sorted_windows(Xs, kcls, C, window)
     onehot = own()
+    # 0/1 is exact in either dtype of the data
+    sums = add(bdot("itb,itk->ikb", Xs, onehot.astype(Xs.dtype)))
+    if r is None:
+        return sums
     orr = jnp.where(onehot, r[:, :, None], 0.0)  # (tiles, tile, window)
     if Xs.dtype == jnp.bfloat16:
-        sums = bdot("itb,itk->ikb", Xs, onehot.astype(jnp.bfloat16))
         rsums = _sum3(bdot("itb,itk->ikb", Xs, _limb3(orr, 2)), axis=1)
     else:
-        sums = bdot("itb,itk->ikb", Xs, onehot.astype(jnp.float32))
         rsums = bdot("itb,itk->ikb", Xs, orr)
-    return add(sums), add(rsums), add(jnp.einsum("itk->ik", orr))
+    return sums, add(rsums), add(jnp.einsum("itk->ik", orr))
 
 
 def _pcg_block_core(X, R, P, Wb, inv_counts, valid, start, w, lam,
-                    sort=None, *, width, n, max_iters=96, tol=1e-6,
-                    sort_window=0):
+                    sort=None, labels=None, *, width, n, max_iters=96,
+                    tol=1e-6, sort_window=0):
     """One whole weighted-BCD block update for ALL classes at once, as a
     single device program: population stats, shared-preconditioner
     inverse, batched matrix-free PCG over the C per-class systems, and
     the residual update. The update and the population statistics read
     the ORIGINAL (ungrouped) rows; the class-restricted statistics and
     the CG matvec read them too, or a class-sorted copy of the block
-    (``sort``, below).
+    (``sort``, below). ``labels`` = (class counts, jlm, row mask), with
+    ``sort`` only, marks the first block step of a fit whose labels are
+    single-label ±1 indicators (below).
 
     This replaced a design of class-grouped gathers and 8 class-chunks,
     each its own CG with triangular-solve preconditioning, whose
@@ -458,8 +467,9 @@ def _pcg_block_core(X, R, P, Wb, inv_counts, valid, start, w, lam,
       Σ_{i in c} x_i z_i in the CG matvec — need each row with its OWN
       class only. With ``sort`` = (order, kcls) from
       ``_class_sorted_rows`` the block's rows are gathered ONCE, after
-      the statistics that read R (the Gram, XᵀR, each row's own-class
-      residual), into class order as (tiles, tile, b); a tile of
+      the statistics that read R (the Gram, XᵀR, dense in R on the
+      original rows, each row's own-class residual), into class order
+      as (tiles, tile, b); a tile of
       consecutive sorted rows meets a contiguous window of at most
       ``sort_window`` classes (the caller checked the class counts:
       ``_sorted_layout``), so these products are GEMMs batched over
@@ -471,6 +481,15 @@ def _pcg_block_core(X, R, P, Wb, inv_counts, valid, start, w, lam,
       Without ``sort`` the same products ride one-hot GEMMs over all C
       classes on the original rows, (n,b)x(b,C)-shaped (3C via
       ``_limb3`` for bf16 rows): no copy, C times the operations;
+    - with ``labels`` the step starts from the initial residual of
+      labels whose valid rows each hold one +1 and −1 elsewhere, where
+      R = (Y − jlm)·mask = 2P − (1 + jlm)·mask exactly. So XᵀR =
+      2·(PᵀX_b)ᵀ − s ⊗ (1 + jlm) with s = X_bᵀmask, each row's
+      own-class residual is 1 − jlm_c, Xᵀ(P ⊙ r) = (1 − jlm_c)·(PᵀX_b)_c
+      and R's column means are (2·n_c − (1 + jlm_c)·Σ_k n_k) / n: the
+      class sums (one windowed product on the copy), the class counts
+      and s (one column reduction over X_b, before the copy, for the
+      preconditioner's popMean) give them all, and R is never read;
     - all C systems share one CG loop (the per-class solves are batched
       rows of the iterate), preconditioned by the explicit inverse of
       M = (1−w)·popCov + (λ+ε)I (see ``_precond_inverse``) applied as
@@ -536,8 +555,21 @@ def _pcg_block_core(X, R, P, Wb, inv_counts, valid, start, w, lam,
     # zero by the Dataset padding contract) -------------------------------
     with jax.named_scope("wls.stats"):
         gram = _sym_gram(Xb)
-        residual_mean = jnp.einsum("nc->c", R) / n
-        if sort is not None:
+        if labels is None:
+            residual_mean = jnp.einsum("nc->c", R) / n
+        if labels is not None:
+            # R = 2P − (1 + jlm)·mask: its column means follow from the
+            # class counts, and popMean is the kept rows' column sums
+            # (every kept row has a class), one reduction over X_b
+            # before the copy, so that the preconditioner needs nothing
+            # of it; XᵀR and the class-restricted moments follow from
+            # the copy's class sums (below)
+            counts, jlm, mask = labels
+            residual_mean = (
+                2.0 * counts - jnp.sum(counts) * (1.0 + jlm)) / n
+            pop_mean = jnp.einsum("nb->b", Xb * mask[:, None]) / n
+            order, kcls = sort
+        elif sort is not None:
             # XᵀR is dense in R and stays on the original rows, with the
             # labelled rows' column sums as one more column of the same
             # product (popMean, so that the preconditioner needs nothing
@@ -602,19 +634,35 @@ def _pcg_block_core(X, R, P, Wb, inv_counts, valid, start, w, lam,
             # preconditioner, whose factorisation's temporaries are dead
             # by then too (the gather depends on nothing else, and XLA
             # is free to schedule it first)
-            order, r_sorted, pop_xtr, pop_mean, Minv, residual_mean = (
-                jax.lax.optimization_barrier(
-                    (order, r_sorted, pop_xtr, pop_mean, Minv,
-                     residual_mean))
-            )
+            if labels is None:
+                order, r_sorted, pop_xtr, pop_mean, Minv, residual_mean = (
+                    jax.lax.optimization_barrier(
+                        (order, r_sorted, pop_xtr, pop_mean, Minv,
+                         residual_mean))
+                )
+            else:
+                order, pop_mean, Minv, residual_mean = (
+                    jax.lax.optimization_barrier(
+                        (order, pop_mean, Minv, residual_mean))
+                )
             Xs = Xb.at[order].get(mode="promise_in_bounds")
         with jax.named_scope("wls.stats"):
-            csum, crsum, rsum = _sorted_class_moments(
-                Xs, kcls, r_sorted, C, sort_window
-            )
-            cmean = csum * inv_counts[:, None]
-            cxtr = crsum * inv_counts[:, None]
-            rlm = rsum * inv_counts
+            if labels is None:
+                csum, crsum, rsum = _sorted_class_moments(
+                    Xs, kcls, r_sorted, C, sort_window
+                )
+                cmean = csum * inv_counts[:, None]
+                cxtr = crsum * inv_counts[:, None]
+                rlm = rsum * inv_counts
+            else:
+                csum = _sorted_class_moments(Xs, kcls, None, C, sort_window)
+                cmean = csum * inv_counts[:, None]
+                # every row of class c holds the residual 1 − jlm_c in c
+                rlm = (1.0 - jlm) * valid
+                cxtr = cmean * rlm[:, None]
+                pop_xtr = (
+                    2.0 * csum.T / n - pop_mean[:, None] * (1.0 + jlm)
+                )  # (b, C)
         class_xxv = _sorted_class_products(Xs, kcls, C, sort_window)
 
     with jax.named_scope("wls.stats"):
@@ -696,14 +744,15 @@ def _pcg_block_core(X, R, P, Wb, inv_counts, valid, start, w, lam,
     donate_argnums=(1,),
 )
 def _pcg_block_step(X, R, P, Wb, inv_counts, valid, start, w, lam,
-                    sort=None, *, width, n, max_iters=96, tol=1e-6,
-                    sort_window=0):
+                    sort=None, labels=None, *, width, n, max_iters=96,
+                    tol=1e-6, sort_window=0):
     """Single-block dispatch of ``_pcg_block_core`` (used for non-uniform
     tail blocks and host-RAM slabs; uniform-width fits go through
     ``_pcg_fit_full``)."""
     return _pcg_block_core(X, R, P, Wb, inv_counts, valid, start, w, lam,
-                           sort, width=width, n=n, max_iters=max_iters,
-                           tol=tol, sort_window=sort_window)
+                           sort, labels, width=width, n=n,
+                           max_iters=max_iters, tol=tol,
+                           sort_window=sort_window)
 
 
 def _membership(Y, mask):
@@ -729,10 +778,18 @@ def _membership(Y, mask):
 
 @jax.jit
 def _class_counts(Y, mask):
-    """Rows of each class (C,), for the host's choice of the matvec's
-    row layout (``_sorted_layout``): the one read-back a sorted fit
-    makes before its program is enqueued."""
-    return _membership(Y, mask)[1]
+    """Rows of each class (C,), and whether every row that ``mask``
+    keeps (1; 0 drops it) is a single-label ±1 indicator — one entry +1,
+    every other −1 — for the host's choices before the program is
+    enqueued (``_sorted_layout``): the one read-back a sorted fit
+    makes. Such labels give the fit's first block step its moments from
+    the class sums (``_pcg_block_core``'s ``labels``)."""
+    # the positives a row holds are the last column of the membership's
+    # running count (the same op, computed once)
+    positives = jnp.cumsum(Y > 0, axis=1)[:, -1]
+    row_single = (positives == 1) & jnp.all(jnp.abs(Y) == 1.0, axis=1)
+    single = jnp.all(jnp.where(mask == 1.0, row_single, mask == 0.0))
+    return _membership(Y, mask)[1], single
 
 
 def _pcg_setup_core(Y, mask, w, n, sort_tile=0):
@@ -744,14 +801,15 @@ def _pcg_setup_core(Y, mask, w, n, sort_tile=0):
         jlm = 2.0 * w + 2.0 * (1.0 - w) * counts / n - 1.0
         R = (Y - jlm[None, :]) * mask[:, None]
         sort = _class_sorted_rows(P, sort_tile) if sort_tile else None
-    return P, inv_counts, valid, jlm, R, sort
+    return P, counts, inv_counts, valid, jlm, R, sort
 
 
 @partial(jax.jit, static_argnames=("n", "sort_tile"))
 def _pcg_setup(Y, mask, w, *, n, sort_tile=0):
-    """One-hot class membership P (bf16, exact 0/1), per-class counts,
-    joint label mean, the initial residual and, with ``sort_tile``, the
-    rows' order by class — all on device (the r3 implementation synced
+    """One-hot class membership P (bf16, exact 0/1), per-class counts
+    (and their inverses and validity), joint label mean, the initial
+    residual and, with ``sort_tile``, the rows' order by class — all on
+    device (the r3 implementation synced
     class ids to host and built gather indices in a Python loop over
     classes, ~250 ms of the flagship fit). Dispatch wrapper for the
     ragged-block path; uniform fits use the fully fused
@@ -762,30 +820,33 @@ def _pcg_setup(Y, mask, w, *, n, sort_tile=0):
 @partial(
     jax.jit,
     static_argnames=("width", "n", "num_iter", "max_iters", "tol",
-                     "sort_tile", "sort_window"),
+                     "sort_tile", "sort_window", "label_moments"),
 )
 def _pcg_fit_full(X, Y, mask, starts, w, lam,
                   *, width, n, num_iter, max_iters=96, tol=1e-5,
-                  sort_tile=0, sort_window=0):
+                  sort_tile=0, sort_window=0, label_moments=False):
     """The ENTIRE weighted-BCD fit — label setup (with ``sort_tile``,
     the rows' order by class too), every epoch's scanned block updates,
     model concatenation, and the intercept — as ONE jitted program: a
-    single dispatch and zero host work per fit.
+    single dispatch and zero host work per fit. With ``label_moments``
+    (sorted rows and single-label ±1 indicator labels: ``_class_counts``)
+    the first block step runs before the scan and takes its moments
+    from the class sums (``_pcg_block_core``'s ``labels``).
     Returns (W (D, C), intercept (C,), max rel residual, max CG iters).
     """
-    P, inv_counts, valid, jlm, R, sort = _pcg_setup_core(
+    P, counts, inv_counts, valid, jlm, R, sort = _pcg_setup_core(
         Y, mask, w, n, sort_tile
     )
     C = Y.shape[1]
     nb = starts.shape[0]
     W0 = jnp.zeros((nb, width, C), jnp.float32)
 
-    def step(carry, xs):
+    def step(carry, xs, labels=None):
         R_c, Wstack = carry
         i, start = xs
         Wb_new, R_new, jm, rel, its = _pcg_block_core(
             X, R_c, P, Wstack[i], inv_counts, valid, start, w, lam,
-            sort, width=width, n=n, max_iters=max_iters, tol=tol,
+            sort, labels, width=width, n=n, max_iters=max_iters, tol=tol,
             sort_window=sort_window,
         )
         Wstack = jax.lax.dynamic_update_index_in_dim(
@@ -795,9 +856,20 @@ def _pcg_fit_full(X, Y, mask, starts, w, lam,
 
     idx = jnp.tile(jnp.arange(nb), num_iter)
     all_starts = jnp.tile(starts, num_iter)
-    (_, Wstack), (jms, rels, itss) = jax.lax.scan(
-        step, (R, W0), (idx, all_starts)
-    )
+    carry, outs = (R, W0), []
+    if label_moments:
+        # in a fit of one block step R_new is dead, and so is R
+        carry, out = step(carry, (idx[0], all_starts[0]),
+                          (counts, jlm, mask))
+        outs.append(jax.tree.map(lambda a: a[None], out))
+    first = len(outs)
+    if idx.shape[0] > first:
+        carry, out = jax.lax.scan(
+            step, carry, (idx[first:], all_starts[first:])
+        )
+        outs.append(out)
+    Wstack = carry[1]
+    jms, rels, itss = (jnp.concatenate(parts) for parts in zip(*outs))
     # blocks are contiguous ascending column ranges: stacking IS the
     # feature-axis concatenation
     W = Wstack.reshape(nb * width, C)
@@ -831,7 +903,8 @@ def _class_chunk_stats_gathered(
     return class_cov, class_mean, class_xtr, res_local_mean
 
 
-def _count_fit(solve: str, layout: str, widths, num_iter: int) -> None:
+def _count_fit(solve: str, layout: str, widths, num_iter: int,
+               label_moments: bool = False) -> None:
     """Count one weighted fit started, the path it takes and the column
     pairs of the Grams it builds: one a block of ``widths`` a sweep."""
     _count_gram_pairs(widths, times=num_iter)
@@ -853,6 +926,11 @@ def _count_fit(solve: str, layout: str, widths, num_iter: int) -> None:
         "keystone_solver_wls_sorted_stats_fits_total",
         "weighted fits whose statistics read class-sorted rows",
     ).inc(by=int(layout == "sorted"))
+    reg.counter(
+        "keystone_solver_wls_label_moments_fits_total",
+        "weighted fits whose first block step took its moments from "
+        "the class sums",
+    ).inc(by=int(label_moments))
 
 
 # The sorted-rows matvec's shapes. A tile of _SORT_TILE class-sorted rows
@@ -882,7 +960,8 @@ def _tiles_fit_window(counts, tile: int) -> bool:
     return bool(span.max() <= _SORT_WINDOW)
 
 
-def _sorted_layout(X, Y, mask, width: int, block_steps: int) -> int:
+def _sorted_layout(X, Y, mask, width: int,
+                   block_steps: int) -> tuple[int, bool]:
     """Whether this fit's CG matvec reads class-sorted rows, as the
     tile to sort into (0: the one-hot matvec on the original rows).
     Decided from what can be seen before the program is enqueued:
@@ -905,10 +984,14 @@ def _sorted_layout(X, Y, mask, width: int, block_steps: int) -> int:
       statistics read the copy and hold no (n, C) temporary);
     - the class counts keep every tile inside one window — the one
       read-back, made last.
+
+    Returns (tile, whether the first block step takes its moments from
+    the class sums): the second from the same read-back
+    (``_class_counts``), and only with a tile.
     """
     sharding = getattr(X, "sharding", None)  # a host array has none
     if sharding is not None and len(sharding.device_set) > 1:
-        return 0
+        return 0, False
     tile = min(_SORT_TILE, -(-X.shape[0] // 8) * 8)
     rows = -(-X.shape[0] // tile) * tile
     block = rows * width * X.dtype.itemsize
@@ -916,9 +999,11 @@ def _sorted_layout(X, Y, mask, width: int, block_steps: int) -> int:
     if block_steps > 1:
         need += 2.5 * Y.nbytes + (block if width < X.shape[1] else 0)
     if need > 0.9 * _device_memory_limit():
-        return 0
-    counts = np.asarray(_class_counts(Y, mask))
-    return tile if _tiles_fit_window(counts, tile) else 0
+        return 0, False
+    counts, single = jax.device_get(_class_counts(Y, mask))
+    if not _tiles_fit_window(counts, tile):
+        return 0, False
+    return tile, bool(single)
 
 
 @dataclasses.dataclass(eq=False)
@@ -932,7 +1017,12 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
     indexOf(label.max): multi-hot rows join exactly ONE class (the
     first positive) in BOTH solver paths. Arbitrary real-valued Y with
     unequal positive entries is outside the contract — the pcg path
-    keys on the first positive entry, not the largest."""
+    keys on the first positive entry, not the largest. Single-label
+    indicators (every valid row one +1, −1 elsewhere, as
+    ClassLabelIndicators makes them) on the pcg path's sorted rows give
+    the first block step its XᵀR and own-residual moments from the class
+    sums, the same model up to float32 summation order; other labels,
+    and every later block step, take the products with R."""
 
     block_size: int
     num_iter: int
@@ -1035,15 +1125,18 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
     def _fit_pcg(self, data, X, Y, n, blocks):
         """Batched all-class PCG (see ``_pcg_block_core``): one
         dispatch per fit or per block, and no host work but the choice
-        of the matvec's row layout (``_sorted_layout``)."""
+        of the matvec's row layout and of the first block step's
+        moments (``_sorted_layout``)."""
         w = self.mixture_weight
         mask = data.mask()
         C = Y.shape[1]
         with span("solver.wls.layout"):
-            tile = _sorted_layout(X, Y, mask, max(wd for _, wd in blocks),
-                                  len(blocks) * self.num_iter)
+            tile, single = _sorted_layout(
+                X, Y, mask, max(wd for _, wd in blocks),
+                len(blocks) * self.num_iter)
         _count_fit("pcg", "sorted" if tile else "original",
-                   [wd for _, wd in blocks], self.num_iter)
+                   [wd for _, wd in blocks], self.num_iter,
+                   label_moments=single)
         window = _SORT_WINDOW if tile else 0
         if len({wd for _, wd in blocks}) == 1:
             # uniform widths (every real config: block_size divides D or
@@ -1055,7 +1148,7 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
             W, intercept, pcg_rel, pcg_iters = _pcg_fit_full(
                 X, Y, mask, starts, w, self.lam, width=wd, n=n,
                 num_iter=self.num_iter, tol=self.pcg_tol, sort_tile=tile,
-                sort_window=window,
+                sort_window=window, label_moments=single,
             )
             return BlockLinearMapper(
                 W, self.block_size, explicit_intercept=intercept,
@@ -1063,9 +1156,11 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
                              "pcg_iterations": pcg_iters},
             )
         # ragged tail block: one dispatch per block
-        P, inv_counts, valid, jlm, R, sort = _pcg_setup(
+        P, counts, inv_counts, valid, jlm, R, sort = _pcg_setup(
             Y, mask, w, n=n, sort_tile=tile
         )
+        # the first block step alone starts from the labels' residual
+        labels = (counts, jlm, mask) if single else None
         Wb = {s: jnp.zeros((wd, C), jnp.float32) for s, wd in blocks}
         joint_means = {}
         pcg_rel = None  # max CG exit residual across block solves
@@ -1076,9 +1171,10 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
             for s, wd in blocks:
                 Wb[s], R, jm, rel, its = _pcg_block_step(
                     X, R, P, Wb[s], inv_counts, valid, s,
-                    w, self.lam, sort, width=wd, n=n, tol=self.pcg_tol,
-                    sort_window=window,
+                    w, self.lam, sort, labels, width=wd, n=n,
+                    tol=self.pcg_tol, sort_window=window,
                 )
+                labels = None
                 joint_means[s] = jm
                 pcg_rel = rel if pcg_rel is None else (
                     jnp.maximum(pcg_rel, rel)
@@ -1116,7 +1212,7 @@ class BlockWeightedLeastSquaresEstimator(LabelEstimator):
         blocks = list(zip(starts, widths))
         C = Y.shape[1]
 
-        P, inv_counts, valid, jlm, R, _ = _pcg_setup(Y, mask, w, n=n)
+        P, _, inv_counts, valid, jlm, R, _ = _pcg_setup(Y, mask, w, n=n)
         Wb = {s: jnp.zeros((wd, C), jnp.float32) for s, wd in blocks}
         joint_means = {}
         pcg_rel = None

@@ -518,7 +518,7 @@ def test_statistics_meet_every_class_in_x_t_r_alone_on_sorted_rows(
     n, b, C, tile, window = 96, 12, 6, 16, 4
     X, Y, _ = _weighted_problem(n=n, D=b, C=C, seed=4)
     mask = jnp.ones((n,), jnp.float32)
-    P, inv_counts, valid, _, R, sort = wls._pcg_setup_core(
+    P, _, inv_counts, valid, _, R, sort = wls._pcg_setup_core(
         jnp.asarray(Y), mask, 0.5, n, tile if sorted_rows else 0)
     step = partial(wls._pcg_block_core, width=b, n=n,
                    sort_window=window if sorted_rows else 0)
@@ -604,9 +604,9 @@ def test_sorted_layout_follows_the_bytes_the_program_holds(
 
     monkeypatch.setattr(wls, "_device_memory_limit", lambda: limit)
     monkeypatch.setattr(
-        wls, "_class_counts", lambda Y, mask: np.full(C, n // C))
+        wls, "_class_counts", lambda Y, mask: (np.full(C, n // C), True))
     assert wls._sorted_layout(
-        shaped(n, b), shaped(n, C), None, width, steps) == want
+        shaped(n, b), shaped(n, C), None, width, steps) == (want, want > 0)
 
 
 def _sorted_fit_case(case):
@@ -790,6 +790,190 @@ def test_sorted_stats_share_metric_reads_the_program_s_counters(
              ["per_layer"] if m["name"] == "wls_sorted_stats_share.wfit"]
     assert entry == [{
         "name": "wls_sorted_stats_share.wfit", "unit": "share",
+        "better": "higher", "source": "program_counter",
+        "layer": "Solvers", "moves": "fit_rows_per_s",
+        "workloads": ["weighted-bcd-fit"]}]
+    monkeypatch.setattr(registry, "_global_registry",
+                        registry.MetricsRegistry())
+    if layout == "original":
+        _no_room(monkeypatch, small_tiles)
+    X, Y, _ = _weighted_problem(n=120, D=16, C=3, seed=2)
+    BlockWeightedLeastSquaresEstimator(16, 1, 0.05, 0.5, solve="pcg").fit(
+        Dataset.of(X), Dataset.of(Y))
+    assert _path_total(layout) == 1
+    assert counter_ratio.read(None, **metric["args"]) == (
+        1.0 if layout == "sorted" else 0.0)
+
+
+# -- a first block step's moments from the class sums (PR 41) ------------
+
+
+def _label_moments_fits_total():
+    from keystone_tpu.observability.registry import get_global_registry
+
+    return get_global_registry().counter(
+        "keystone_solver_wls_label_moments_fits_total"
+    ).get()
+
+
+def _dense_moments_fit(monkeypatch, wls, est, X, Y):
+    """The same fit with the labels' test forced false: every block
+    step's statistics take the products with R, as the parent's did."""
+    counts_and_test = wls._class_counts
+    with monkeypatch.context() as m:
+        m.setattr(wls, "_class_counts",
+                  lambda Y, mask: (counts_and_test(Y, mask)[0], False))
+        return est.fit(Dataset.of(X), Dataset.of(Y))
+
+
+@pytest.mark.parametrize("layout", ["sorted", "original"])
+@pytest.mark.parametrize("blocks", [
+    "one_block", "two_blocks", "one_block_twice", "ragged_tail"])
+def test_first_step_moments_from_class_sums_fit_the_dense_model(
+        monkeypatch, small_tiles, layout, blocks):
+    """Single-label ±1 indicators: on sorted rows the first block step
+    derives XᵀR, Xᵀ(P ⊙ r) and R's means from the class sums and counts,
+    and the model is the dense moments' to float32 rounding; the counter
+    rises by one a fit there and not on the original rows."""
+    wls = small_tiles
+    X, Y, _ = _weighted_problem(n=190, D=16, C=6, seed=2)
+    block_size, num_iter = {
+        "one_block": (16, 1), "two_blocks": (8, 1),
+        "one_block_twice": (16, 2), "ragged_tail": (6, 1),  # 6, 6, 4
+    }[blocks]
+    if layout == "original":
+        _no_room(monkeypatch, wls)
+    est = BlockWeightedLeastSquaresEstimator(
+        block_size, num_iter, 0.1, 0.5, solve="pcg", pcg_tol=1e-7,
+        convergence_check="off")
+    before = _label_moments_fits_total()
+    model = est.fit(Dataset.of(X), Dataset.of(Y))
+    assert _path_total(layout) >= 1
+    assert _label_moments_fits_total() == before + (layout == "sorted")
+    dense = _dense_moments_fit(monkeypatch, wls, est, X, Y)
+    for got, want in ((model.W, dense.W),
+                      (model.intercept, dense.intercept)):
+        want = np.asarray(want)
+        np.testing.assert_allclose(
+            np.asarray(got), want, rtol=0,
+            atol=1e-5 * np.abs(want).max())
+
+
+@pytest.mark.parametrize("labels", ["multi_hot", "no_positive", "real"])
+def test_labels_other_than_single_indicators_take_the_dense_moments(
+        monkeypatch, small_tiles, labels):
+    """A multi-hot row, a row with no positive entry, real-valued Y:
+    the first block step keeps the products with R, bit for bit."""
+    wls = small_tiles
+    X, Y, _ = _weighted_problem(n=190, D=16, C=6, seed=2)
+    Y = Y.copy()
+    if labels == "multi_hot":
+        Y[7, (int(np.argmax(Y[7])) + 1) % 6] = 1.0
+    elif labels == "no_positive":
+        Y[7] = -1.0
+    else:
+        Y = Y * np.float32(0.75) + np.float32(0.01) * np.random.default_rng(
+            0).standard_normal(Y.shape).astype(np.float32)
+    est = BlockWeightedLeastSquaresEstimator(16, 1, 0.1, 0.5, solve="pcg")
+    before = _label_moments_fits_total()
+    model = est.fit(Dataset.of(X), Dataset.of(Y))
+    assert _label_moments_fits_total() == before
+    assert _path_total("sorted") >= 1
+    dense = _dense_moments_fit(monkeypatch, wls, est, X, Y)
+    np.testing.assert_array_equal(np.asarray(model.W), np.asarray(dense.W))
+    np.testing.assert_array_equal(
+        np.asarray(model.intercept), np.asarray(dense.intercept))
+
+
+@pytest.mark.parametrize("case,want", [
+    ("indicators", True), ("masked_rows_hold_anything", True),
+    ("multi_hot", False), ("no_positive", False), ("real", False),
+    ("half_masked_row", False),
+])
+def test_class_counts_tell_single_label_indicators(case, want):
+    import jax.numpy as jnp
+
+    from keystone_tpu.ops.learning import weighted_ls as wls
+
+    _, Y, y = _weighted_problem(n=40, D=4, C=5, seed=1)
+    mask = np.ones(40, np.float32)
+    if case == "masked_rows_hold_anything":
+        mask[-6:] = 0.0
+        Y[-6:-3], Y[-3:] = 0.0, 3.5
+    elif case == "multi_hot":
+        Y[3] = 1.0
+    elif case == "no_positive":
+        Y[3] = -1.0
+    elif case == "real":
+        Y[3, y[3]] = 0.9
+    elif case == "half_masked_row":
+        mask[3] = 0.5
+    counts, single = wls._class_counts(jnp.asarray(Y), jnp.asarray(mask))
+    assert bool(single) is want
+    np.testing.assert_array_equal(
+        np.asarray(counts), np.asarray(wls._membership(
+            jnp.asarray(Y), jnp.asarray(mask))[1]))
+
+
+def test_first_step_from_indicator_labels_meets_no_class_with_every_row():
+    """With ``labels`` the first block step's statistics make no product
+    of the rows with all C classes (XᵀR is derived) and one windowed
+    product on the sorted copy (the class sums) where the dense step
+    makes two."""
+    from functools import partial
+
+    import jax.numpy as jnp
+
+    from keystone_tpu.ops.learning import weighted_ls as wls
+
+    n, b, C, tile, window = 96, 12, 6, 16, 4
+    X, Y, _ = _weighted_problem(n=n, D=b, C=C, seed=4)
+    mask = jnp.ones((n,), jnp.float32)
+    P, counts, inv_counts, valid, jlm, R, sort = wls._pcg_setup_core(
+        jnp.asarray(Y), mask, 0.5, n, tile)
+    step = partial(wls._pcg_block_core, width=b, n=n, sort_window=window)
+    args = (jnp.asarray(X), R, P, jnp.zeros((b, C)), inv_counts, valid,
+            0, 0.5, 0.05, sort)
+
+    def shapes(labels):
+        products = _products_outside_loops(step, *args, labels)
+        every_class = [p for p in products if (n, b) in p and any(
+            s[0] == n and s[1] >= C for s in p if s != (n, b))]
+        windowed = [p for p in products if (n // tile, tile, b) in p]
+        return every_class, windowed
+
+    every_class, windowed = shapes((counts, jlm, mask))
+    assert every_class == [] and [sorted(p) for p in windowed] == [
+        [(n // tile, tile, window), (n // tile, tile, b)]], windowed
+    every_class, windowed = shapes(None)
+    assert len(every_class) == 1 and len(windowed) == 2
+
+
+@pytest.mark.parametrize("layout", ["sorted", "original"])
+def test_label_moments_share_metric_reads_the_program_s_counters(
+        monkeypatch, small_tiles, layout):
+    """The benchmark's ``wls_label_moments_share.wfit`` is one data file:
+    its reader gives 1.0 after a sorted fit of single-label indicators
+    and 0.0 after a fit on the original rows."""
+    import json
+    import os
+
+    from benchmark.readers import counter_ratio
+    from keystone_tpu.observability import registry
+
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    with open(os.path.join(root, "benchmark", "metrics",
+                           "wls_label_moments_share.wfit.json")) as f:
+        metric = json.load(f)
+    assert metric == {"reader": "counter_ratio", "args": {
+        "numerator": "keystone_solver_wls_label_moments_fits_total",
+        "denominator": "keystone_solver_wls_fits_total"}}
+    with open(os.path.join(root, "BENCHMARK.json")) as f:
+        entry = [m for m in json.load(f)["per_layer"]
+                 if m["name"] == "wls_label_moments_share.wfit"]
+    assert entry == [{
+        "name": "wls_label_moments_share.wfit", "unit": "share",
         "better": "higher", "source": "program_counter",
         "layer": "Solvers", "moves": "fit_rows_per_s",
         "workloads": ["weighted-bcd-fit"]}]

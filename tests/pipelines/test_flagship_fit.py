@@ -218,6 +218,10 @@ def test_spans_and_counters_appear_once_a_fit_and_name_the_path(
             ("keystone_solver_wls_sorted_fits_total", ())) == 1
         assert delta.pop(
             ("keystone_solver_wls_sorted_stats_fits_total", ())) == 1
+        # single-label ±1 indicators: the first step's moments from the
+        # class sums
+        assert delta.pop(
+            ("keystone_solver_wls_label_moments_fits_total", ())) == 1
     if solve == "pcg":
         assert sorted(names) == ["solver.wls.converged",
                                  "solver.wls.dispatch", "solver.wls.layout",
