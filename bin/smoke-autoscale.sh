@@ -4,7 +4,7 @@
 # router + supervisor + SLO-driven policy loop and spawns
 # serve-gateway replicas as child processes (port-0
 # {"listening": ...} handshake, --register self-registration, a
-# shared AOT store so scale-out starts warm). Then:
+# shared JAX_COMPILATION_CACHE_DIR so scale-out starts warm). Then:
 #   a. a `serve-loadgen --ramp` staircase drives the fleet past
 #      one replica's capacity — the supervisor must GROW the
 #      fleet (scale_up decision events + /fleetz shows >= 2
@@ -34,16 +34,20 @@ ROOT="$(cd "$(dirname "$0")/.." && pwd)"
 TMPDIR="$(mktemp -d)"
 AS_LOG="$TMPDIR/autoscale.log"
 VERDICT="$TMPDIR/verdict.json"
-AOT_CACHE="$TMPDIR/aot"
+XLA_CACHE="$TMPDIR/xc"
 REPLICA_LOGS="$TMPDIR/replicas"
 cleanup() {
     [[ -n "${AS_PID:-}" ]] && kill "$AS_PID" 2>/dev/null || true
     # give the supervisor a moment to drain its children, then sweep
-    # any stragglers — matched by THIS run's unique AOT-cache path on
-    # their command lines, so a concurrent fleet drill on the same
+    # any stragglers — matched by THIS run's unique compile-cache path
+    # in their environment, so a concurrent fleet drill on the same
     # box is never collateral
     sleep 3
-    pkill -f "serve-gateway.*$AOT_CACHE" 2>/dev/null || true
+    for pid in $(pgrep -f serve-gateway 2>/dev/null || true); do
+        tr '\0' '\n' <"/proc/$pid/environ" 2>/dev/null \
+            | grep -qx "JAX_COMPILATION_CACHE_DIR=$XLA_CACHE" \
+            && kill "$pid" 2>/dev/null || true
+    done
     rm -rf "$TMPDIR"
 }
 trap cleanup EXIT
@@ -53,14 +57,14 @@ D=48
 # ---- the subprocess drill - ----------------------------------------------
 echo "== serve-autoscale: router + subprocess replicas =="
 JAX_PLATFORMS=cpu PYTHONPATH="$ROOT" \
-    JAX_COMPILATION_CACHE_DIR="$TMPDIR/xc" \
+    JAX_COMPILATION_CACHE_DIR="$XLA_CACHE" \
     python -m keystone_tpu serve-autoscale \
     --min-replicas 1 --max-replicas 3 \
     --slo-latency-ms 200 --slo-fast-window 6 --slo-sample-interval 0.5 \
     --interval 1 --up-consecutive 2 --down-consecutive 3 \
     --up-cooldown 3 --down-cooldown 3 \
     --d "$D" --hidden "$D" --depth 2 --buckets 8 --lanes 1 \
-    --aot-cache "$AOT_CACHE" --replica-log-dir "$REPLICA_LOGS" \
+    --replica-log-dir "$REPLICA_LOGS" \
     --startup-timeout 240 \
     >"$AS_LOG" 2>&1 &
 AS_PID=$!
@@ -105,7 +109,7 @@ print(sum(1 for r in doc["replicas"] if r["ready"] and r["healthy"]))'
 }
 
 # the first replica registers and goes ready (cold start populates the
-# shared AOT store, so every LATER replica starts warm)
+# shared compile cache, so every LATER replica starts warm)
 for _ in $(seq 1 240); do
     [[ "$(ready_replicas 2>/dev/null || echo 0)" == "1" ]] && break
     kill -0 "$AS_PID" 2>/dev/null || {

@@ -5,8 +5,9 @@
 #      replicas that self-register (--register) after binding
 #      ephemeral ports (--gateway-port 0 prints the bound address as
 #      a parseable JSON line — no port races), both pointed at ONE
-#      shared KEYSTONE_AOT_CACHE so replica #2 must start warm
-#      (keystone_aot_cache_hits_total > 0 on its own /metrics);
+#      shared JAX_COMPILATION_CACHE_DIR so replica #2 must start warm
+#      (keystone_runtime_compile_requests_total{outcome="cache_hit"}
+#      > 0 on its own /metrics);
 #   2. chaos across hosts — serve-loadgen replays a synthetic trace
 #      through the ROUTER while replica #1's process is kill -9'd
 #      mid-load; the invariant checker must report green (zero lost
@@ -40,7 +41,7 @@ ROUTER_LOG="$TMPDIR/router.log"
 R1_LOG="$TMPDIR/replica1.log"
 R2_LOG="$TMPDIR/replica2.log"
 VERDICT="$TMPDIR/verdict.json"
-AOT_CACHE="$TMPDIR/aot"
+XLA_CACHE="$TMPDIR/xc"
 cleanup() {
     for pid in "${ROUTER_PID:-}" "${R1_PID:-}" "${R2_PID:-}"; do
         [[ -n "$pid" ]] && kill "$pid" 2>/dev/null || true
@@ -105,23 +106,23 @@ echo "router up on $ROUTER"
 start_replica() {  # start_replica <logfile> <extra args...>
     local log="$1"; shift
     JAX_PLATFORMS=cpu PYTHONPATH="$ROOT" \
-        KEYSTONE_AOT_CACHE="$AOT_CACHE" \
+        JAX_COMPILATION_CACHE_DIR="$XLA_CACHE" \
         python -m keystone_tpu serve-gateway --gateway-port 0 \
         "${GW_ARGS[@]}" --register "$ROUTER" "$@" >"$log" 2>&1 &
 }
 
-JAX_COMPILATION_CACHE_DIR="$TMPDIR/xc1" start_replica "$R1_LOG"
+start_replica "$R1_LOG"
 R1_PID=$!
 R1="$(wait_listen "$R1_LOG" "$R1_PID" replica1)"
-# replica 1 fully warm (and the shared AOT store populated) BEFORE
+# replica 1 fully warm (and the shared compile cache populated) BEFORE
 # replica 2 starts, so replica 2's warmup has executables to load
 for _ in $(seq 1 240); do
     fetch "$R1/readyz" >/dev/null 2>&1 && break
     sleep 0.5
 done
-echo "replica1 up on $R1 (cold start populated $AOT_CACHE)"
+echo "replica1 up on $R1 (cold start populated $XLA_CACHE)"
 
-JAX_COMPILATION_CACHE_DIR="$TMPDIR/xc2" start_replica "$R2_LOG"
+start_replica "$R2_LOG"
 R2_PID=$!
 R2="$(wait_listen "$R2_LOG" "$R2_PID" replica2)"
 for _ in $(seq 1 240); do
@@ -130,17 +131,18 @@ for _ in $(seq 1 240); do
 done
 echo "replica2 up on $R2"
 
-# the PR 8 follow-on: replica 2 must have started WARM off the shared
-# executable store — its own /metrics proves it
+# replica 2 must have started WARM off the shared compile cache — its
+# own /metrics proves it
 fetch "$R2/metrics" | PYTHONPATH="$ROOT" python -c '
 import sys
 from keystone_tpu.observability.prometheus import parse_samples
-hits = sum(v for n, _, v in parse_samples(sys.stdin.read())
-           if n == "keystone_aot_cache_hits_total")
-assert hits > 0, "replica 2 reported zero AOT cache hits: not a warm start"
-print(f"replica2 AOT cache hits: {hits:g}")
-' || { echo "FAIL: replica 2 did not start warm off the shared AOT store"; exit 1; }
-echo "PASS shared-AOT warm start"
+hits = sum(v for n, labels, v in parse_samples(sys.stdin.read())
+           if n == "keystone_runtime_compile_requests_total"
+           and labels.get("outcome") == "cache_hit")
+assert hits > 0, "replica 2 reported zero compile-cache hits: not a warm start"
+print(f"replica2 compile-cache hits: {hits:g}")
+' || { echo "FAIL: replica 2 did not start warm off the shared compile cache"; exit 1; }
+echo "PASS shared compile-cache warm start"
 
 # both replicas self-registered and probed ready
 for _ in $(seq 1 60); do
@@ -189,7 +191,7 @@ echo "PASS /fleetz shows killed replica unhealthy"
 # ---- 3. restart at the SAME port; half-open recovery -----------------------
 echo "== restart replica1; half-open recovery =="
 R1_PORT="${R1##*:}"
-JAX_COMPILATION_CACHE_DIR="$TMPDIR/xc1" start_replica "$R1_LOG.2" \
+start_replica "$R1_LOG.2" \
     --gateway-port "$R1_PORT"
 R1_PID=$!
 for _ in $(seq 1 240); do

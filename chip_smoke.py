@@ -597,15 +597,13 @@ def phase_serve(
     ]
     # wired as serve-gateway wires them; the whole fitted pipeline
     # (cast + featurize + model + top-5) is each bucket's program, so
-    # raw uint8 rides the wire and the staging buffers. The AOT store
-    # stays off.
+    # raw uint8 rides the wire and the staging buffers.
     gateway = Gateway(
         fitted,
         buckets=BUCKETS,
         n_lanes=LANES,
         pipeline_depth=2,
         warmup_example=np.zeros((IMG, IMG, 3), np.uint8),
-        aot_store=None,
     )
     server = GatewayServer(gateway, port=0, input_dtype=np.uint8).start()
     try:

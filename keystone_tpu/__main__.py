@@ -188,11 +188,6 @@ def main(argv=None) -> int:
               "streaming refit from POST /feedback, shadow-mirrored "
               "candidates, deterministic canary fractions, atomic "
               "promote with auto-rollback; keystone_tpu/lifecycle/)")
-        print("  serve-aot-build  (pre-populate the AOT serialized-"
-              "executable store: compile every bucket once and "
-              "serialize the executables so a brand-new host's "
-              "serve-gateway goes from exec() to serving with zero "
-              "XLA compiles; keystone_tpu/serving/aot.py)")
         print("  keystone-lint  (AST contract analyzer over this "
               "repo's own source: lock discipline, blocking-under-"
               "lock, strippable asserts, absent-not-zero metrics, "
@@ -269,10 +264,6 @@ def main(argv=None) -> int:
         from keystone_tpu.lifecycle.cli import main as lifecycle_main
 
         return lifecycle_main(argv[1:])
-    if app == "serve-aot-build":
-        from keystone_tpu.serving.aot import build_main
-
-        return build_main(argv[1:])
     if app == "keystone-lint":
         # stdlib-only path by design: the linter must run in hooks and
         # CI without paying the jax import (analysis/ never imports it)

@@ -549,7 +549,7 @@ class HotPathHostSyncRule(ScopedRule):
             isinstance(node, ast.Subscript)
             and isinstance(node.ctx, ast.Load)
             # bare-Name receivers only: `a[i]` in a row loop is the
-            # per-request device-op pattern; `self._aot[b]` and other
+            # per-request device-op pattern; `self._fns[b]` and other
             # attribute-rooted subscripts are dict/config lookups
             and isinstance(node.value, ast.Name)
             and isinstance(node.slice, ast.Name)

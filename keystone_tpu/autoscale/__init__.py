@@ -1,7 +1,7 @@
 """Autonomous fleet elasticity: the loop nobody was closing.
 
-PRs 8–11 built every primitive this package needs — the shared AOT
-executable store makes a fresh replica ~5× cheaper to start, the
+PRs 8–11 built every primitive this package needs — the shared
+persistent compile cache warms a fresh replica, the
 ``--register``/``{"listening": ...}`` handshake makes one spawnable
 and routable without port races, the router's federated ``/metrics``
 + ``/slz`` say how the FLEET is doing, and the per-request phase

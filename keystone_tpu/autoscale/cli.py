@@ -8,8 +8,9 @@ Stands up the whole closed loop:
    SLO at ``/slz`` — clients and the load generator point HERE);
 2. a ``Supervisor`` spawning ``serve-gateway`` replicas as
    subprocesses (``--gateway-port 0`` + the ``{"listening": ...}``
-   handshake, ``--register`` self-registration, a shared
-   ``--aot-cache`` so scale-out replicas start warm);
+   handshake, ``--register`` self-registration; replicas inherit the
+   environment, so a shared ``JAX_COMPILATION_CACHE_DIR`` starts
+   scale-out replicas warm);
 3. an ``Autoscaler`` control loop: scrape the router, decide from
    fleet p99 / SLO burn / per-replica load / the phase
    decomposition, and converge the fleet — scale-out under real
@@ -82,10 +83,6 @@ def build_parser():
     gw.add_argument("--buckets", default="4,16")
     gw.add_argument("--lanes", type=int, default=1)
     gw.add_argument("--max-delay-ms", type=float, default=2.0)
-    gw.add_argument("--aot-cache", default=None, metavar="DIR",
-                    help="shared AOT executable store for the "
-                    "replicas (scale-out starts warm; strongly "
-                    "recommended)")
     gw.add_argument("--replica-log-dir", default=None, metavar="DIR",
                     help="where replica stdout logs land (default: "
                     "$TMPDIR/keystone-autoscale)")
@@ -158,8 +155,6 @@ def main(argv: Optional[List[str]] = None) -> int:
         "--trace",
         *args.gateway_arg,
     ]
-    if args.aot_cache:
-        gw_args += ["--aot-cache", args.aot_cache]
 
     def emit_event(doc):
         print(json.dumps(doc), flush=True)

@@ -22,8 +22,9 @@ The artifact is one JSON file (``--out``); the control loop loads it
 with ``serve-autoscale --plan plan.json``.
 
 Replicas come from the same ``Supervisor`` the autoscaler uses:
-``--mode subprocess`` spawns real ``serve-gateway`` processes (share
-an AOT store to keep the K legs warm); the default ``--mode inproc``
+``--mode subprocess`` spawns real ``serve-gateway`` processes (they
+share the persistent compile cache, ``JAX_COMPILATION_CACHE_DIR``, so
+the K legs start warm); the default ``--mode inproc``
 builds them as in-process threads over the demo model — what CI
 and the tests run, same measurement harness, no per-replica JAX
 import.
@@ -234,14 +235,13 @@ def main(argv: Optional[List[str]] = None) -> int:
                        help="inproc: replicas as in-process threads "
                        "over the demo model (CI-friendly); "
                        "subprocess: real serve-gateway processes "
-                       "(share --aot-cache for warm legs)")
+                       "(they share JAX_COMPILATION_CACHE_DIR for "
+                       "warm legs)")
     fleet.add_argument("--d", type=int, default=64)
     fleet.add_argument("--hidden", type=int, default=64)
     fleet.add_argument("--depth", type=int, default=2)
     fleet.add_argument("--buckets", default="4,16")
     fleet.add_argument("--lanes", type=int, default=1)
-    fleet.add_argument("--aot-cache", default=None, metavar="DIR",
-                       help="shared AOT store for subprocess replicas")
     fleet.add_argument("--startup-timeout", type=float, default=180.0)
 
     out = ap.add_argument_group("output")
@@ -331,8 +331,6 @@ def _build_supervisor(args, router_url: str) -> Supervisor:
             "--depth", str(args.depth), "--buckets", args.buckets,
             "--lanes", str(args.lanes),
         ]
-        if args.aot_cache:
-            gw_args += ["--aot-cache", args.aot_cache]
         return Supervisor(
             SubprocessLauncher(router_url, gw_args),
             router_url,

@@ -50,8 +50,8 @@ from typing import Any, Callable, Dict, List, Optional, Sequence
 logger = logging.getLogger(__name__)
 
 # how long a spawned replica gets from exec() to its {"listening"}
-# handshake line (a cold start pays the JAX import + warmup; the AOT
-# store keeps this in single-digit seconds, but CI boxes are slow)
+# handshake line (a cold start pays the JAX import + warmup; the
+# persistent compile cache shortens the warmup, but CI boxes are slow)
 STARTUP_TIMEOUT_S = 180.0
 
 # graceful-drain bound before a retiring replica is killed outright
@@ -192,7 +192,8 @@ class SubprocessReplica:
 class SubprocessLauncher:
     """Spawn real ``serve-gateway`` replica processes (the production
     path — one process per replica, self-registering against the
-    router, sharing the AOT executable store so scale-out is warm)."""
+    router, sharing the persistent compile cache through the inherited
+    ``JAX_COMPILATION_CACHE_DIR`` so scale-out is warm)."""
 
     # serve-gateway --register handles its own roster entry; the
     # supervisor must not double-register

@@ -970,11 +970,11 @@ def main(argv=None) -> int:
                     "POST /predict/<model> routes by id, bare "
                     "/predict serves the spec's default model, GET "
                     "/planz reports plan-vs-actual. Each model gets "
-                    "its own gateway lanes, metrics under its own "
-                    "name, and an AOT store namespace; co-hosted "
-                    "models with IDENTICAL featurize chains share one "
-                    "engine that computes the prefix once per window "
-                    "(cross-model CSE). Ignores the single-model "
+                    "its own gateway lanes and metrics under its own "
+                    "name; co-hosted models with IDENTICAL featurize "
+                    "chains share one engine that computes the prefix "
+                    "once per window (cross-model CSE). Ignores the "
+                    "single-model "
                     "flags (--d/--hidden/--depth/--device-featurize/"
                     "--shard-model/--buckets/--lanes)")
     ap.add_argument("--optimize", action="store_true",
@@ -1056,16 +1056,10 @@ def main(argv=None) -> int:
                     "(default: all local devices); remaining devices "
                     "go to the data axis")
     ap.add_argument("--no-cache", action="store_true",
-                    help="run with NO persistence: skips both the "
-                    "persistent XLA compile cache and the AOT "
-                    "serialized-executable store, so every bucket "
-                    "pays a real trace + compile")
-    ap.add_argument("--aot-cache", default=None, metavar="DIR",
-                    help="AOT executable store dir (default: "
-                    "$KEYSTONE_AOT_CACHE, then "
-                    "~/.cache/keystone_tpu/aot); pre-populate with "
-                    "serve-aot-build for a zero-compile cold start. "
-                    "Ignored under --no-cache")
+                    help="run with NO persistence: skips the "
+                    "persistent XLA compile cache (the one executable "
+                    "cache), so every bucket pays a real trace + "
+                    "compile")
     args = ap.parse_args(argv)
     # one process per chip: fail here, naming the holder, rather than
     # late inside backend init (or hanging) when another replica on
@@ -1073,9 +1067,6 @@ def main(argv=None) -> int:
     claim_accelerator()
     if not args.no_cache:
         setup_compilation_cache()
-        from keystone_tpu.parallel.runtime import setup_aot_cache
-
-        setup_aot_cache(args.aot_cache)
 
     if args.slo_latency_ms is not None or args.trace:
         # the forensic chain (exemplars, flight records, burn gauges)
@@ -1255,7 +1246,6 @@ def main(argv=None) -> int:
                 canary_fraction=args.canary_fraction,
                 min_refit_samples=args.refit_min_samples,
                 interval_s=args.refit_interval_s or None,
-                aot_namespace="lifecycle/default",
             ),
             default=True,
         )

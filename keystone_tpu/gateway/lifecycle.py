@@ -71,10 +71,6 @@ MIN_REBUCKET_OBSERVATIONS = 64
 # holds >= SHED_BURN for SUSTAIN consecutive samples; relax once it
 # falls back under 1.0 (budget no longer being consumed too fast)
 SLO_SHED_BURN = 4.0
-
-# swap_model's "keep the current AOT store" default — None is a real
-# value there (it means: candidate engines get no store at all)
-_UNCHANGED = object()
 SLO_SUSTAIN_SAMPLES = 2
 SLO_PRESSURE = 0.75
 
@@ -137,11 +133,6 @@ class Gateway:
                        rebuild lane engines with the same fused stage;
                        ``warmup_example`` must be a RAW example in
                        this mode.
-    aot_store:         the serialized-executable store engine builds
-                       consult: ``"auto"`` (process-configured),
-                       ``None``/``False`` (off), or an explicit
-                       ``AotStore`` — the model zoo passes per-model
-                       NAMESPACED stores here.
     engine_factory:    optional override, ``callable(buckets) ->
                        (lane_name -> engine)`` — replaces the
                        ``fitted.compiled()`` factory for every engine
@@ -187,7 +178,6 @@ class Gateway:
         host_featurize=None,
         device_featurize=None,
         param_sharding=None,
-        aot_store="auto",
         engine_factory=None,
         max_pending: int = 1024,
         default_deadline_ms: Optional[float] = None,
@@ -225,17 +215,11 @@ class Gateway:
         self._max_delay_ms = max_delay_ms
         self._pipeline_depth = pipeline_depth
         self._host_featurize = host_featurize
-        # the AOT executable store every engine generation consults:
-        # "auto" (the process-configured store), None/False (off), or
-        # an explicit AotStore — the model zoo passes each model's
-        # NAMESPACED store here so co-hosted models never share a
-        # cache slot
-        self._aot_store = aot_store
         # engine-factory override: callable(buckets) -> (lane_name ->
         # engine). The zoo's cross-model CSE plane builds shared-prefix
         # multi-head engines this way; when set it fully replaces the
         # fitted.compiled() factory below (the override owns featurize/
-        # sharding/store wiring) but still rides every generation —
+        # sharding wiring) but still rides every generation —
         # initial build, rebuckets, warm-pool swaps
         self._engine_factory = engine_factory
         self._rebucket_k = rebucket_k or len(self._buckets)
@@ -338,7 +322,6 @@ class Gateway:
                 buckets=buckets, name=lane_name,
                 featurize=self._device_featurize,
                 param_sharding=self._param_sharding,
-                aot_store=self._aot_store,
             )
 
         return factory
@@ -520,29 +503,23 @@ class Gateway:
         construction claims the per-lane metrics labels
         (newest-claim-wins), so two generations building concurrently
         could rotate in an engine whose label another build claimed —
-        one swap at a time stays the invariant. With the AOT
-        executable store configured (``serving/aot.py``) the
-        "compiles" are deserializes and this returns in milliseconds;
-        either way the engines come back fully warmed and ready to
-        rotate in."""
+        one swap at a time stays the invariant. With the persistent
+        compile cache configured the compiles replay from disk; either
+        way the engines come back fully warmed and ready to rotate
+        in."""
         buckets = tuple(sorted(set(int(b) for b in buckets)))
         return self.pool.build_replacements(
             self._factory_for(buckets),
             warmup_example=self._warmup_example,
         )
 
-    def build_model_batcher(
-        self, fitted, *, name: str, aot_store=None
-    ) -> MicroBatcher:
+    def build_model_batcher(self, fitted, *, name: str) -> MicroBatcher:
         """One engine + micro-batcher for a DIFFERENT fitted pipeline
         over THIS gateway's serving config (buckets, device featurize,
         sharding, windowing) — the candidate plane the lifecycle loop
         points shadow and canary traffic at. Deliberately NOT a pool
         lane: the candidate serves copies/fractions, never owns
-        routing, and is closed by its controller. ``aot_store`` is the
-        candidate's own (typically per-version namespaced) store;
-        None means no store — a candidate must never populate the
-        incumbent's cache slots."""
+        routing, and is closed by its controller."""
         if self._engine_factory is not None:
             raise RuntimeError(
                 f"gateway {self.name} runs on an engine-factory "
@@ -554,7 +531,6 @@ class Gateway:
             name=name,
             featurize=self._device_featurize,
             param_sharding=self._param_sharding,
-            aot_store=aot_store if aot_store is not None else False,
         )
         return MicroBatcher(
             engine,
@@ -563,16 +539,15 @@ class Gateway:
             host_featurize=self._host_featurize,
         )
 
-    def swap_model(self, fitted, *, aot_store=_UNCHANGED) -> bool:
+    def swap_model(self, fitted) -> bool:
         """Re-point the gateway at a DIFFERENT fitted pipeline and
         rotate every lane onto engines built from it — the promotion
         (and rollback) primitive: build + warm outside the pool lock,
         then the same atomic per-lane ``swap_engine`` a rebucket uses,
         so in-flight windows finish on the old model and nothing is
         dropped. Returns False when ``close()`` won the race (nothing
-        rotated); on a build failure the previous fitted (and AOT
-        store, when ``aot_store`` was passed) is restored and the old
-        engines keep serving. Rolling BACK a promotion is just
+        rotated); on a build failure the previous fitted is restored
+        and the old engines keep serving. Rolling BACK a promotion is just
         ``swap_model(incumbent)`` — engines rebuilt from the identical
         fitted serve bitwise-identical outputs."""
         if self._engine_factory is not None:
@@ -582,17 +557,15 @@ class Gateway:
                 "its engines from a fitted pipeline"
             )
         with self._swap_lock:
-            prev_fitted, prev_store = self.fitted, self._aot_store
+            prev_fitted = self.fitted
             self.fitted = fitted
-            if aot_store is not _UNCHANGED:
-                self._aot_store = aot_store
             try:
                 ok = self._build_and_swap(self._buckets)
             except Exception:
-                self.fitted, self._aot_store = prev_fitted, prev_store
+                self.fitted = prev_fitted
                 raise
             if not ok:
-                self.fitted, self._aot_store = prev_fitted, prev_store
+                self.fitted = prev_fitted
             return ok
 
     def swap_engines(
@@ -600,7 +573,7 @@ class Gateway:
     ):
         """Rotate the next engine generation in: build + warm one
         replacement per lane (``build_engines`` — outside the pool
-        lock, from the AOT store when configured) and atomically
+        lock, from the compile cache when configured) and atomically
         re-point every lane's batcher (in-flight windows finish on the
         old engines; queued and future requests use the new ones).
 

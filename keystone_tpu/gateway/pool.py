@@ -451,7 +451,7 @@ class EnginePool:
         ``engines``: PREBUILT (and already-warmed) replacements, one
         per lane in lane order — the Gateway warm-pool path builds the
         next generation outside this lock (on a background builder
-        thread, from the AOT executable store when configured), so the
+        thread, from the persistent compile cache when configured), so the
         work under the lock here is just the atomic re-point.
 
         Engines are rebuilt under their lane's original name, so the
@@ -469,9 +469,9 @@ class EnginePool:
             replacements = list(engines)
         else:
             # build + warm OUTSIDE the pool lock: the generation build
-            # is seconds of XLA compile (milliseconds off the AOT
-            # store), and holding the lock for it would stall close()
-            # and every other lifecycle call behind one swap. The lock
+            # is seconds of XLA compile (less off the persistent
+            # compile cache), and holding the lock for it would stall
+            # close() and every other lifecycle call behind one swap. The lock
             # below covers only the atomic re-point — the same
             # work-split the Gateway warm pool uses. Swap-vs-swap
             # serialization is the caller's job (the Gateway holds its

@@ -4,17 +4,12 @@ loop, wired to a live Gateway.
 The controller owns the SIDE EFFECTS around the pure policy
 (``policy.tick``): it drains the feedback buffer into the
 ``RefitAccumulator``, solves candidates, builds their engines
-(``Gateway.build_model_batcher`` — same serving config as the lanes,
-per-version AOT namespace), arms/clears the pool's shadow mirror and
-canary router, and drives ``Gateway.swap_model`` on promotion and
-rollback. One ``tick()`` = one policy decision plus its effects;
-ticks run manually (``POST /lifecyclez {"tick": true}``, tests) or on the background interval thread (``interval_s``).
-
-Versioned snapshots: candidate v's engines build against
-``namespaced_store("<namespace>/v<version>")`` when the process has
-an AOT store configured, so every promoted version's executables land
-in their own namespace — rolling back (or paging the version back in)
-never recompiles and never collides with another version's slots.
+(``Gateway.build_model_batcher`` — same serving config as the lanes),
+arms/clears the pool's shadow mirror and canary router, and drives
+``Gateway.swap_model`` on promotion and rollback. One ``tick()`` = one
+policy decision plus its effects; ticks run manually (``POST
+/lifecyclez {"tick": true}``, tests) or on the background interval
+thread (``interval_s``).
 
 Rollback restores THREE things: the pool hooks (cleared), the refit
 state (``restore`` to the last-good snapshot, so a poisoned
@@ -63,7 +58,6 @@ class LifecycleController:
         min_refit_samples: int = 64,
         interval_s: Optional[float] = None,
         registry=None,
-        aot_namespace: Optional[str] = None,
         refit_lam: float = 1e-3,
         refit_chunk: int = 64,
         holdout_every: int = 8,
@@ -76,7 +70,6 @@ class LifecycleController:
         self._config = config
         self._canary_fraction = float(canary_fraction)
         self._min_refit_samples = int(min_refit_samples)
-        self._aot_namespace = aot_namespace or name
         self._metrics = LifecycleMetrics(registry=registry, model=name)
         self._refit = RefitAccumulator(
             base,
@@ -96,10 +89,8 @@ class LifecycleController:
         self._version = 0  # guarded-by: _tick_lock
         self._incumbent = gateway.fitted  # guarded-by: _tick_lock
         self._previous = None  # guarded-by: _tick_lock
-        self._previous_store = None  # guarded-by: _tick_lock
         self._candidate = None  # guarded-by: _tick_lock
         self._candidate_batcher = None  # guarded-by: _tick_lock
-        self._candidate_store = None  # guarded-by: _tick_lock
         self._mirror: Optional[ShadowMirror] = None  # guarded-by: _tick_lock
         self._canary: Optional[CanaryRouter] = None  # guarded-by: _tick_lock
         self._last_reason = "idle"  # guarded-by: _tick_lock
@@ -185,18 +176,12 @@ class LifecycleController:
             return self.status()
 
     def _start_candidate_locked(self) -> None:
-        from keystone_tpu.serving.aot import namespaced_store
-
         W, b = self._refit.solve()
         self._version += 1
         self._candidate = self._base.and_then(self._head_builder(W, b))
-        self._candidate_store = namespaced_store(
-            f"{self._aot_namespace}/v{self._version}"
-        )
         self._candidate_batcher = self._gateway.build_model_batcher(
             self._candidate,
             name=f"{self.name}-cand-v{self._version}",
-            aot_store=self._candidate_store,
         )
         self._solved_at_n = self._refit.n_accumulated
         self._state = PolicyState("candidate")
@@ -246,15 +231,11 @@ class LifecycleController:
         elif stage == "promoted":
             pool.set_canary(None)
             pool.set_mirror(None)
-            prev_store = getattr(self._gateway, "_aot_store", None)
-            ok = self._gateway.swap_model(
-                self._candidate, aot_store=self._candidate_store
-            )
+            ok = self._gateway.swap_model(self._candidate)
             if not ok:  # close() won the race; nothing rotated
                 self._close_candidate_locked()
                 return
             self._previous = self._incumbent
-            self._previous_store = prev_store
             self._incumbent = self._candidate
             self._last_good = self._refit.snapshot()
             self._metrics.record_promotion()
@@ -292,9 +273,7 @@ class LifecycleController:
                 self._rollback_effects_locked(reason)
                 self._state = PolicyState("rolled_back")
             elif self._previous is not None:
-                ok = self._gateway.swap_model(
-                    self._previous, aot_store=self._previous_store
-                )
+                ok = self._gateway.swap_model(self._previous)
                 if ok:
                     self._incumbent = self._previous
                     self._previous = None

@@ -144,16 +144,6 @@ def build_info() -> Dict:
     info = _static_build_info()
     info["uptime_s"] = round(time.time() - _PROCESS_START_S, 3)
     info["devices"] = device_obs.device_table()
-    try:
-        # late import: observability must not import serving at module
-        # load (serving imports observability); the block says whether
-        # THIS process can cold-start from serialized executables —
-        # {"dir": None} when no store is configured
-        from keystone_tpu.serving import aot
-
-        info["aot_cache"] = aot.status()
-    except Exception:
-        pass
     return info
 
 

@@ -76,7 +76,7 @@ class AttributionLedger:
     def __init__(self):
         self._lock = threading.Lock()
         self._cells: Dict[str, Dict[str, float]] = {}
-        # per-model staging/AOT bytes: a gauge (point-in-time), kept
+        # per-model staging bytes: a gauge (point-in-time), kept
         # out of the additive cells; None never stored (absent = absent)
         self._staging: Dict[str, float] = {}
 
@@ -95,7 +95,7 @@ class AttributionLedger:
                 cell[field] += float(v)
 
     def set_staging_bytes(self, model: str, nbytes: Optional[float]) -> None:
-        """Point-in-time staging/AOT byte footprint for one model
+        """Point-in-time staging byte footprint for one model
         (None clears — the series goes absent, never zero-stamped)."""
         with self._lock:
             if nbytes is None:
@@ -163,8 +163,8 @@ class AttributionLedger:
             if staging:
                 fams.append(MetricFamily(
                     "keystone_attr_staging_bytes", "gauge",
-                    "per-model staging/AOT byte footprint (host "
-                    "staging pools + serialized-executable namespaces)",
+                    "per-model staging byte footprint (host "
+                    "staging pools)",
                     [
                         Sample("", {"model": m}, v)
                         for m, v in sorted(staging.items())

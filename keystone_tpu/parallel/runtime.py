@@ -70,7 +70,6 @@ _decision: Optional[str] = None
 CHIP_LOCK_PATH = "/tmp/keystone_tpu_chip.lock"
 _chip_lock: Optional[IO[str]] = None
 _cache_dir: Optional[str] = None
-_aot_dir: Optional[str] = None
 
 
 # -- compilation telemetry ---------------------------------------------------
@@ -352,49 +351,6 @@ def setup_compilation_cache(min_compile_time_secs: float = 0.0) -> str:
     _cache_dir = cache_dir
     logger.info("persistent compilation cache at %s", cache_dir)
     return cache_dir
-
-
-def setup_aot_cache(cache_dir: Optional[str] = None) -> Optional[str]:
-    """Configure the AOT serialized-executable store dir (idempotent)
-    — the second half of the restart story. The persistent compilation
-    cache above removes the XLA *compile* from a restart but the
-    process still pays trace + lowering + cache replay per bucket;
-    with this store configured, ``CompiledPipeline.warmup``
-    deserializes each bucket's whole executable
-    (``serving/aot.py``) and a fresh replica goes from exec() to
-    serving without tracing anything. The dir resolves from the
-    argument, ``$KEYSTONE_AOT_CACHE``, then
-    ``~/.cache/keystone_tpu/aot``.
-
-    Returns the store dir, or None when it can't be created (the call
-    is then a no-op — serving works, cold starts just compile)."""
-    global _aot_dir
-    if _aot_dir is not None:
-        return _aot_dir
-    cache_dir = (
-        cache_dir
-        or os.environ.get("KEYSTONE_AOT_CACHE")
-        or os.path.join(
-            os.path.expanduser("~"), ".cache", "keystone_tpu", "aot"
-        )
-    )
-    try:
-        # 0700: the store dir is a trust boundary (entries are pickled
-        # executables — write access there is code execution in the
-        # server; serving/aot.py documents the contract). Pre-existing
-        # dirs keep the operator's chosen mode.
-        os.makedirs(cache_dir, mode=0o700, exist_ok=True)
-    except OSError as e:
-        logger.info("AOT executable cache unavailable: %s", e)
-        return None
-    _aot_dir = cache_dir
-    logger.info("AOT executable cache at %s", cache_dir)
-    return cache_dir
-
-
-def aot_cache_dir() -> Optional[str]:
-    """The configured AOT store dir (None until ``setup_aot_cache``)."""
-    return _aot_dir
 
 
 def claim_accelerator(lock_path: str = CHIP_LOCK_PATH) -> bool:

@@ -44,21 +44,15 @@ production inference engine:
   minimizes expected padding waste over the observed request-size
   histogram (the metrics-driven replacement for operator-chosen
   buckets).
-- ``AotStore`` (aot.py): the zero-cold-start layer — each bucket's
-  compiled executable serialized into a fingerprinted on-disk store at
-  warmup, deserialized + installed BEFORE any trace on the next
-  process/engine generation, with silent counted fallback to the
-  normal compile path on any miss or mismatch.
 
 Persistent-compile-cache setup lives in
 ``keystone_tpu.parallel.runtime.setup_compilation_cache`` (a restarted
-server warms from disk instead of recompiling); the AOT store dir is
-configured beside it (``setup_aot_cache``). The request plane in
+server warms from disk instead of recompiling): the package's one
+executable cache. The request plane in
 FRONT of these engines — admission control, replica lanes, live
 re-bucketing, HTTP — is ``keystone_tpu.gateway``.
 """
 
-from keystone_tpu.serving.aot import AotStore
 from keystone_tpu.serving.autoscale import padding_waste, suggest_buckets
 from keystone_tpu.serving.batching import MicroBatcher
 from keystone_tpu.serving.engine import CompiledPipeline
@@ -77,7 +71,6 @@ from keystone_tpu.serving.sharding import (
 )
 
 __all__ = [
-    "AotStore",
     "CompiledPipeline",
     "DEFAULT_RULES",
     "HostBufferPool",

@@ -1,12 +1,12 @@
 """The served demo model: a seeded chain of ``tanh(x @ W + b)`` nodes.
 
-``serve-gateway``, ``serve-loadgen --self-gateway``, ``serve-capacity-plan``,
-``serve-aot-build`` and a zoo spec's ``build`` all serve this one model
+``serve-gateway``, ``serve-loadgen --self-gateway``, ``serve-capacity-plan``
+and a zoo spec's ``build`` all serve this one model
 (serving a saved ``FittedPipeline`` from the command line is ROADMAP R6),
 and the serving plane's tests build their engines from it. The weights
 are a function of ``(d, hidden, depth, seed)`` alone, so two processes
-given the same arguments hold the same parameters and the same AOT model
-token.
+given the same arguments hold the same parameters, the same
+``featurize_token`` and the same compile-cache keys.
 """
 
 from __future__ import annotations
@@ -39,7 +39,7 @@ def build_pipeline(
     matmul nodes).
     ``seed`` varies the weights — the zoo spec loader uses it so two
     same-shaped models carry distinct params (and therefore distinct
-    AOT model tokens)."""
+    tokens and compiled programs)."""
     rng = np.random.default_rng(seed)
     dims = [d] + [hidden] * (depth - 1) + [d]
     pipe = None

@@ -31,9 +31,7 @@ executable holds only its weight shards. Composes with ``shard=``
 (rows over ``data``, weights over ``model``, one 2-D mesh) and with
 ``featurize=`` (the fused stage's params stay baked/replicated; pass
 rules matching them to split those too — they ride the same binder
-only for the model pipeline). The AOT fingerprint carries a
-``sharding_token`` so a mesh-sharded program can never collide with a
-replicated one (or with a different partitioning/mesh shape).
+only for the model pipeline).
 
 **Device-side featurization** (``featurize=``): a second fitted
 pipeline — a pure-JAX featurize chain such as the ``ops/images``
@@ -97,9 +95,7 @@ class _ParamBoundFn:
     model-sharded engine traces — to the engine's one-argument fn
     convention, binding the placed (sharded, committed) param tree.
     Every dispatch passes the same placed arrays; only the batch
-    varies. ``lower`` delegates for the cost-model/AOT path, and the
-    wrapped program may be a polymorphic jit fn OR a rigid stored
-    ``jax.stages.Compiled`` (see ``_is_stored_executable``)."""
+    varies. ``lower`` delegates for the cost-model path."""
 
     def __init__(self, fn, params):
         self.fn = fn
@@ -110,15 +106,6 @@ class _ParamBoundFn:
 
     def lower(self, staged):
         return self.fn.lower(self.params, staged)
-
-
-def _is_stored_executable(fn) -> bool:
-    """True when ``fn`` dispatches a shape/dtype-RIGID stored
-    executable (directly, or wrapped with its bound params) — the
-    discriminator for the off-spec TypeError detour in
-    ``compute_staged``."""
-    inner = fn.fn if isinstance(fn, _ParamBoundFn) else fn
-    return isinstance(inner, jax.stages.Compiled)
 
 
 class CompiledPipeline:
@@ -144,9 +131,7 @@ class CompiledPipeline:
                featurization): callers stage RAW examples (e.g. uint8
                images) and one compiled dispatch runs
                ``pipeline(featurize(raw))``. Must be traceable
-               (array-mode, pure JAX) like ``pipeline`` itself; the
-               AOT-store fingerprint covers it (one featurizer's
-               cached executable can never serve another's).
+               (array-mode, pure JAX) like ``pipeline`` itself.
     param_sharding: shard the MODEL over the mesh's model axis
                (serving/sharding.py): ``True`` resolves the default
                rule set against the pipeline's named params, a
@@ -172,7 +157,6 @@ class CompiledPipeline:
         mesh=None,
         metrics: Optional[ServingMetrics] = None,
         name: Optional[str] = None,
-        aot_store: Any = "auto",
         featurize: Any = None,
         param_sharding: Any = None,
         param_sharding_unmatched: str = "error",
@@ -252,20 +236,6 @@ class CompiledPipeline:
             peak_flops, peak_membw, n_devices=n_devices
         )
         self.donate = donate and jax.default_backend() in ("tpu", "gpu")
-        # AOT serialized-executable store (serving/aot.py): "auto" =
-        # the store setup_aot_cache configured for this process (None
-        # when none was — the library/test default), None/False =
-        # explicitly off, or a concrete AotStore. Engaged only at
-        # warmup; apply()'s lazy-compile path never consults it.
-        self._aot_store_cfg = aot_store
-        # bucket -> {"status": "hit"|"saved"|"miss"|"error", ...} from
-        # the last warmup that consulted the store
-        self._aot: Dict[int, Dict[str, Any]] = {}
-        # bucket -> polymorphic jit fallback created on demand when a
-        # bucket's installed STORED executable (shape/dtype-rigid)
-        # meets an off-spec input; the stored program keeps serving
-        # on-spec traffic, the side fn serves the strays
-        self._side_fns: Dict[int, Callable] = {}
         self._fns: Dict[int, Callable] = {}
         # a MicroBatcher worker and direct apply() callers may race to
         # create a bucket's jit fn; two fns would mean two traces, and
@@ -290,8 +260,7 @@ class CompiledPipeline:
         )
 
     def _make_jit(self, bucket: int) -> Callable:
-        """A fresh polymorphic jit fn for ``bucket`` (shared builder of
-        the dispatch table and the off-spec side path). With a fused
+        """A fresh polymorphic jit fn for ``bucket``. With a fused
         featurize stage, the whole featurize∘model composition traces
         into ONE program — XLA fuses across the boundary and the cast
         from the raw wire dtype happens on device, inside it."""
@@ -344,21 +313,6 @@ class CompiledPipeline:
                 return fn
             fn = self._make_jit(bucket)
             self._fns[bucket] = fn
-            return fn
-
-    def _side_fn(self, bucket: int) -> Callable:
-        """Polymorphic jit fallback for off-spec inputs on a bucket
-        whose installed program is a rigid stored executable — created
-        once per bucket, cached BESIDE (never instead of) it, so one
-        stray request can't cost on-spec traffic its zero-compile
-        program."""
-        fn = self._side_fns.get(bucket)
-        if fn is not None:
-            return fn
-        with self._fn_lock:
-            fn = self._side_fns.get(bucket)
-            if fn is None:
-                fn = self._side_fns[bucket] = self._make_jit(bucket)
             return fn
 
     # -- staging -----------------------------------------------------------
@@ -459,35 +413,7 @@ class CompiledPipeline:
             int(getattr(a, "nbytes", 0))
             for a in jax.tree_util.tree_leaves(staged)
         )
-        fn = self._fn(bucket)
-        try:
-            out = fn(staged)
-        except TypeError:
-            # a stored executable (jax.stages.Compiled) is shape/
-            # dtype-RIGID where a jit fn is polymorphic: an off-spec
-            # input (an x64-enabled caller, an integer feature batch)
-            # would trace its own program on a cold engine but raises
-            # here. Match the cold engine exactly: the installed
-            # executable KEEPS serving on-spec traffic (its
-            # zero-compile program is the whole feature — one stray
-            # request must not cost everyone a mid-serving retrace),
-            # and this request detours through a side jit fn that
-            # traces per-aval just like a cold engine's would. A
-            # TypeError from a plain jit fn means the REQUEST itself
-            # is malformed — that propagates unchanged.
-            if not _is_stored_executable(fn):
-                raise
-            report = self._aot.setdefault(bucket, {})
-            if not report.get("off_spec"):
-                # once per bucket, not once per request: a persistently
-                # off-spec client must not flood the log at line rate
-                report["off_spec"] = True
-                logger.warning(
-                    "engine %s: bucket %d saw input off the stored "
-                    "executable's spec; such requests serve via a "
-                    "side jit path", self.name, bucket,
-                )
-            out = self._side_fn(bucket)(staged)
+        out = self._fn(bucket)(staged)
         self.metrics.record_dispatch(bucket, rows, h2d_bytes=h2d_bytes)
         return out
 
@@ -598,211 +524,45 @@ class CompiledPipeline:
             raise ValueError(
                 f"unknown bucket(s) {unknown} (have {self.buckets})"
             )
-        store = self._resolve_aot_store()
-        token = feat_token = shard_token = identity = None
-        if store is not None:
-            from keystone_tpu.serving import aot as aot_lib
-
-            try:
-                # all warmup-invariant: hash the model (and the fused
-                # featurize stage, when one is configured — its
-                # parameters are constants inside the serialized
-                # program exactly like the model's weights, so one
-                # featurizer's cached executable must never serve
-                # another's) and probe the runtime once, not per bucket
-                token = aot_lib.pipeline_token(self.pipeline)
-                if self.featurize is not None:
-                    feat_token = aot_lib.pipeline_token(self.featurize)
-                if self.model_sharded:
-                    # the partitioning + mesh topology are part of the
-                    # program: a mesh-sharded executable must never
-                    # share an entry with a replicated one, nor with a
-                    # different spec tree or mesh shape
-                    from keystone_tpu.serving import (
-                        sharding as sharding_lib,
-                    )
-
-                    shard_token = sharding_lib.sharding_token(
-                        self.param_sharding, self.mesh
-                    )
-                identity = aot_lib.runtime_identity()
-            except Exception:
-                # a pipeline whose operators can't be fingerprinted
-                # must warm exactly like one with no store configured
-                # (absent-not-broken): counted, logged, compiled
-                store.record_error()
-                logger.info(
-                    "aot: could not fingerprint the pipeline; warming "
-                    "without the store", exc_info=True,
-                )
-                store = None
         times: Dict[int, float] = {}
         for b in want:
             zeros = treedef.unflatten(
                 [jnp.zeros((b,) + s, d) for s, d in specs]
             )
-            key = meta = None
-            if store is not None:
-                key, meta = aot_lib.bucket_key(
-                    specs, self.buckets, b,
-                    donate=self.donate, shard=self.shard,
-                    model_token=token, identity=identity,
-                    featurize_token=feat_token,
-                    sharding_token=shard_token,
-                    # a namespaced store (the model zoo's per-model
-                    # view) partitions its entries; plain stores keep
-                    # their pre-zoo fingerprints byte-identical
-                    namespace=getattr(store, "namespace", None),
-                )
-                # the zero-cold-start path: install the serialized
-                # executable BEFORE any trace of this bucket can
-                # happen; any miss/mismatch/deserialize failure falls
-                # through (counted) to the normal compile path below
-                load_s = self._try_install_aot(store, key, meta, b, zeros)
-                if load_s is not None:
-                    times[b] = load_s
-                    continue
             fn = self._fn(b)
             staged = self._stage(zeros, b, b, owned=True)
             # outside the timed window: the returned numbers are the
             # dispatch's compile wall, not cost-model extraction
-            compiled = self._register_cost_model(
-                b, fn, staged, want_executable=store is not None
-            )
-            if store is not None and compiled is not None:
-                # populate the store so the NEXT process (or the
-                # autoscaler's next-generation engine) starts hot
-                if store.save(key, compiled, meta) is not None:
-                    if self._aot.get(b, {}).get("status") == "error":
-                        # the report keeps the error visible (a broken
-                        # entry was REPLACED, not cleanly created)
-                        self._aot[b]["fallback"] = "saved"
-                    else:
-                        self._aot[b] = {"status": "saved"}
+            self._register_cost_model(b, fn, staged)
             t0 = time.perf_counter()
             out = fn(staged)
             jax.block_until_ready(out)
             times[b] = time.perf_counter() - t0
         return times
 
-    # -- AOT executable cache (serving/aot.py) ------------------------------
-
-    def _resolve_aot_store(self):
-        """The store warmup consults: the process-configured one for
-        the default ``"auto"``, None when disabled, or the explicit
-        ``AotStore`` the caller passed."""
-        if self._aot_store_cfg in (None, False):
-            return None
-        if self._aot_store_cfg == "auto":
-            from keystone_tpu.serving import aot as aot_lib
-
-            return aot_lib.configured_store()
-        return self._aot_store_cfg
-
-    def _try_install_aot(self, store, key, meta, bucket, zeros):
-        """Deserialize + install one bucket's stored executable and
-        VALIDATE it with one real dispatch. Returns the install wall
-        seconds on success, None on miss/error (the caller falls back
-        to the compile path). Never raises — absent-not-broken is the
-        serving-path contract."""
-        t0 = time.perf_counter()
-        loaded, outcome = store.load(key, meta)
-        if loaded is None:
-            # "miss" (no entry) or "error" (corrupt/mismatched entry) —
-            # the report must tell the same story the store counters do
-            self._aot[bucket] = {"status": outcome}
-            return None
-        if self.model_sharded:
-            # a model-sharded bucket program was serialized as the
-            # two-argument (params, batch) executable; re-bind this
-            # engine's placed params so it dispatches under the
-            # engine's one-argument convention
-            loaded = _ParamBoundFn(loaded, self._placed_params)
-        try:
-            # validate BEFORE publishing into _fns: warmup is callable
-            # on an engine already taking traffic, and a concurrent
-            # dispatcher must never be able to pick up an executable
-            # that hasn't survived one real dispatch
-            staged = self._stage(zeros, bucket, bucket, owned=True)
-            out = loaded(staged)
-            jax.block_until_ready(out)
-        except Exception:
-            # an entry that deserializes but won't run is as broken as
-            # a corrupt one: leave dispatch to trace normally
-            store.record_error()
-            self._aot[bucket] = {"status": "error"}
-            logger.info(
-                "aot: stored executable for bucket %d failed to "
-                "execute; recompiling", bucket, exc_info=True,
-            )
-            return None
-        with self._fn_lock:
-            self._fns[bucket] = loaded
-        self._register_cost_model_from(
-            bucket,
-            loaded.fn if isinstance(loaded, _ParamBoundFn) else loaded,
-        )
-        secs = time.perf_counter() - t0
-        # only a VALIDATED install counts as a hit, and the histogram
-        # gets the full deserialize+validate+install wall
-        store.record_hit(secs)
-        self._aot[bucket] = {
-            "status": "hit", "load_s": round(secs, 6),
-        }
-        return secs
-
-    def aot_report(self) -> Dict[int, Dict[str, Any]]:
-        """Per-bucket outcome of the AOT-store pass (empty when no
-        store was configured): ``hit`` (installed from the store —
-        zero trace, zero compile), ``saved`` (compiled normally,
-        executable serialized for the next process), ``miss`` (no
-        entry, compiled normally), ``error`` (entry present but
-        unusable — corrupt, mismatched, or failed its validation
-        dispatch — compiled normally; ``fallback: "saved"`` when the
-        recompile also repaired the store entry). A hit stays a hit
-        even if off-spec inputs later arrive: those detour through a
-        side jit fn while the stored executable keeps serving on-spec
-        traffic (see ``dispatch``'s TypeError handling)."""
-        return {b: dict(v) for b, v in self._aot.items()}
-
-    def _register_cost_model(
-        self, bucket: int, fn, staged, want_executable: bool = False
-    ):
+    def _register_cost_model(self, bucket: int, fn, staged) -> None:
         """Pull the bucket program's static XLA cost model — FLOPs,
         bytes accessed — and register it on the metrics (the
-        MFU/roofline/goodput input). Returns the AOT-compiled
-        ``jax.stages.Compiled`` when one was produced (the AOT store's
-        save input), else None.
+        MFU/roofline/goodput input).
 
         Reads ``fn.lower(staged).cost_analysis()``: lowering shares the
         jit TRACE cache (the compile-count contract holds, and the
         ``fn(staged)`` dispatch that follows retraces nothing) and the
-        analysis runs on the lowered module — no XLA compile. The AOT
-        *executable* cache is NOT shared with the jit dispatch path
-        (measured: an ``lower().compile()`` here would compile every
+        analysis runs on the lowered module — no XLA compile. The
+        ``lower().compile()`` executable is NOT shared with the jit
+        dispatch path (measured: compiling here would compile every
         bucket twice), so the executable — which also carries
         ``memory_analysis()``'s temp-HBM number — is built only when
         the persistent compilation cache is configured (the dispatch's
         own compile then replays from disk instead of paying the
-        program twice) or when the caller needs it for the AOT store
-        (``want_executable``; the store's whole point is that the
-        NEXT process pays nothing, so this one eating a cache-cold
-        double compile once at build time is the documented price —
-        ``serve-aot-build`` configures the compile cache to avoid even
-        that). Best-effort by design: backends whose lowering or
-        analyses fail (or report nothing) leave the model ABSENT —
+        program twice). Best-effort by design: backends whose lowering
+        or analyses fail (or report nothing) leave the model ABSENT —
         serving and the scrape surface must work identically without
         it."""
-        compiled = None
         try:
             lowered = fn.lower(staged)
-            # the executable is built BEFORE any cost-model extraction:
-            # the store-save path must get its Compiled even if a
-            # metrics-side analysis were ever to fail (compiled rides
-            # the assignment out through the except)
-            if want_executable or getattr(
-                jax.config, "jax_compilation_cache_dir", None
-            ):
+            compiled = None
+            if getattr(jax.config, "jax_compilation_cache_dir", None):
                 compiled = lowered.compile()
             model = device_obs.compiled_cost_model(lowered)
             if compiled is not None:
@@ -811,21 +571,6 @@ class CompiledPipeline:
         except Exception:
             logger.debug(
                 "no AOT cost analysis for bucket %d", bucket, exc_info=True
-            )
-        return compiled
-
-    def _register_cost_model_from(self, bucket: int, compiled) -> None:
-        """Cost model off an already-loaded executable (the AOT-store
-        hit path — there is no Lowered to analyze). Same best-effort
-        contract as ``_register_cost_model``."""
-        try:
-            self.metrics.set_cost_model(
-                bucket, device_obs.compiled_cost_model(compiled)
-            )
-        except Exception:
-            logger.debug(
-                "no cost analysis from the stored executable for "
-                "bucket %d", bucket, exc_info=True,
             )
 
     __call__ = apply

@@ -26,6 +26,8 @@ Two chains:
 
 from __future__ import annotations
 
+import dataclasses
+import hashlib
 from typing import Any, Optional, Tuple
 
 import numpy as np
@@ -175,7 +177,7 @@ def build_flagship_featurize_pipeline(
     sampled+projected descriptors → GMM); without it, a seeded
     warm-start stands in (``flagship_pipeline``) — deterministic
     parameters, identical graph, which is what gateway startup and the
-    AOT fingerprint tests need. Both paths freeze to
+    fingerprint tests need. Both paths freeze to
     the same pure-JAX branched DAG; ``feature_dim`` is probed off a
     zero image through ``_batch_run`` — the exact staging surface the
     serving engine fuses.
@@ -241,17 +243,113 @@ def build_flagship_featurize_pipeline(
     return fitted, feat_dim
 
 
-def featurize_token(fitted) -> str:
-    """Content digest of a fitted featurize chain — the zoo's CSE
-    grouping key (``zoo/cse.py``). Alias of ``aot.pipeline_token``:
-    two chains share a prefix iff the SAME fingerprint that partitions
-    the AOT store says they compute the same function (operator
-    classes + wiring + every parameter array), so "identical
-    featurize_token" carries the same never-serve-the-wrong-model
-    guarantee in both subsystems."""
-    from keystone_tpu.serving.aot import pipeline_token
+def _hash_update(h, value: Any) -> None:
+    """Deterministically fold one operator attribute into the token.
+    Arrays hash by shape/dtype/bytes (the weights are the program's
+    constants); containers recurse; primitives hash by repr; anything
+    else contributes its type name only — weaker, but the parameter
+    arrays carry the real identity.
 
-    return pipeline_token(fitted)
+    Every component is FRAMED (type tag + terminator): unframed
+    concatenation made distinct parameter sets collide — e.g.
+    ``(1, 23)`` and ``(12, 3)`` both fold to the bytes ``123`` — and a
+    token collision means two different chains grouped as one."""
+    import jax
+
+    if isinstance(value, (np.ndarray, np.generic, jax.Array)):
+        arr = np.asarray(value)
+        h.update(
+            b"a<" + str(arr.shape).encode() + b"|"
+            + str(arr.dtype).encode() + b"|"
+        )
+        h.update(arr.tobytes())
+        h.update(b">")
+    elif isinstance(value, (str, bytes, int, float, bool, type(None))):
+        h.update(b"p<" + repr(value).encode() + b">")
+    elif isinstance(value, dict):
+        h.update(b"d<")
+        for k in sorted(value, key=repr):
+            h.update(b"k<" + repr(k).encode() + b">")
+            _hash_update(h, value[k])
+        h.update(b">")
+    elif isinstance(value, (list, tuple)):
+        h.update(b"l<")
+        for v in value:
+            _hash_update(h, v)
+        h.update(b">")
+    elif dataclasses.is_dataclass(value) and not isinstance(value, type):
+        # a node held by another (RowwiseRun's nodes, a Convolver's
+        # whitener): its fields are parameters too
+        h.update(b"o<" + type(value).__qualname__.encode() + b"|")
+        for f in dataclasses.fields(value):
+            if not f.name.startswith("_"):
+                h.update(b"f<" + f.name.encode() + b">")
+                _hash_update(h, getattr(value, f.name, None))
+        h.update(b">")
+    else:
+        h.update(b"t<" + type(value).__qualname__.encode() + b">")
+
+
+def featurize_token(fitted) -> str:
+    """Content digest of a ``FittedPipeline`` — the zoo's CSE grouping
+    key (``zoo/cse.py``): operator classes in topological order, the
+    graph's wiring, and every operator's attribute values (parameter
+    arrays hashed by content). Two chains share a token iff they
+    compute the same function, so two pipelines with identical
+    architectures but different weights never group together.
+
+    Memoized on the pipeline object (the same lazily-attached-cache
+    idiom its operators use): a ``FittedPipeline`` is immutable once
+    fit (refits build new objects), so the memo can't go stale."""
+    cached = getattr(fitted, "_featurize_token", None)
+    if cached is not None:
+        return cached
+    h = hashlib.sha256()
+    for nid in fitted._topo:
+        op = fitted.graph.operators[nid]
+        # the WIRING is part of the function: same operators in the
+        # same topo order compute different things when the edges
+        # differ (a Join fed (A(x), x) vs (A(x), A(x))). Ids are hashed
+        # by repr — graphs built along different construction paths may
+        # token-differ for the same model (a harmless split), but two
+        # different computations can never token-collide.
+        h.update(
+            b"n<" + repr(nid).encode() + b"|"
+            + ",".join(
+                repr(d) for d in fitted.graph.dependencies[nid]
+            ).encode()
+            + b">"
+        )
+        h.update(b"op<" + type(op).__qualname__.encode() + b">")
+        if dataclasses.is_dataclass(op):
+            # declared fields only: transformers are dataclasses whose
+            # fields ARE the parameters
+            state = {
+                f.name: getattr(op, f.name, None)
+                for f in dataclasses.fields(op)
+            }
+        else:
+            state = getattr(op, "__dict__", None) or {}
+        for name in sorted(state):
+            if name.startswith("_"):
+                # lazily-attached caches (_vmapped_apply,
+                # _arr_digest_cache, ...) appear after first use; a
+                # token that shifted when the pipeline RAN would split
+                # one chain into two groups
+                continue
+            h.update(b"f<" + name.encode() + b">")
+            _hash_update(h, state[name])
+    h.update(
+        b"s<"
+        + repr(fitted.graph.sink_dependencies[fitted.sink]).encode()
+        + b">"
+    )
+    token = h.hexdigest()
+    try:
+        fitted._featurize_token = token
+    except Exception:
+        pass  # slots/frozen pipeline: just recompute next time
+    return token
 
 
 __all__ = [

@@ -16,8 +16,8 @@ partitioning without hand-written per-model specs:
 - ``named_params`` walks the pipeline's topo-ordered operators and
   extracts every array-valued dataclass field under a stable
   ``"<topo#>/<OpClass>/<field>"`` name — the namespace the rules match
-  against (the same fields ``aot.pipeline_token`` hashes, so the
-  param set and the model fingerprint can't drift apart);
+  against (the same fields ``featurize.featurize_token`` hashes, so
+  the param set and the model fingerprint can't drift apart);
 - ``match_partition_rules(rules, params)`` resolves each named param
   to the first matching rule's spec. Scalars (and one-element arrays)
   always stay replicated — partitioning a scalar is never right.
@@ -39,11 +39,7 @@ partitioning without hand-written per-model specs:
   of baked-in constants, so each device's executable holds only its
   shard of the weights. The binder patches an engine-private copy of
   the pipeline at trace time — the caller's fitted pipeline is never
-  touched, and concurrent traces serialize on the binder's lock;
-- ``sharding_token`` digests the resolved spec tree + mesh shape for
-  the AOT store fingerprint (a mesh-sharded program must never share a
-  serialized-executable entry with a replicated one — see
-  ``aot.bucket_key``).
+  touched, and concurrent traces serialize on the binder's lock.
 
 Composition: the spec tree rides a 2-D ``(data, model)`` mesh
 (``parallel/mesh.py``), so batch sharding (``shard=``) and model
@@ -56,7 +52,6 @@ from __future__ import annotations
 
 import copy
 import dataclasses
-import hashlib
 import re
 import threading
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
@@ -104,7 +99,7 @@ def _is_array(value: Any) -> bool:
 
 def _array_fields(op) -> List[Tuple[str, Any]]:
     """The array-valued parameter fields of one operator, in sorted
-    field order — the same field set ``aot.pipeline_token`` hashes
+    field order — the same field set ``featurize_token`` hashes
     (declared dataclass fields, else ``__dict__``, underscore-prefixed
     lazily-attached caches excluded)."""
     if dataclasses.is_dataclass(op):
@@ -288,27 +283,6 @@ def placed_shard_bytes(placed: Dict[str, jax.Array]) -> Dict[Any, int]:
     return per_device
 
 
-def sharding_token(
-    specs: Dict[str, PartitionSpec], mesh=None
-) -> str:
-    """Content digest of a resolved partitioning — the AOT-store
-    fingerprint component for mesh-sharded programs (``aot.bucket_key
-    (sharding_token=)``). Covers the spec of every named param AND the
-    mesh topology (axis names + sizes): the same rules over a 1x8 and
-    a 2x4 mesh compile different programs, and neither may ever load
-    the other's serialized executable."""
-    mesh = mesh or mesh_lib.current_mesh()
-    h = hashlib.sha256()
-    h.update(
-        b"mesh<"
-        + repr(tuple((str(a), int(s)) for a, s in mesh.shape.items())).encode()
-        + b">"
-    )
-    for name in sorted(specs):
-        h.update(f"p<{name}|{specs[name]}>".encode())
-    return h.hexdigest()
-
-
 class ParamBinder:
     """Functionalizes a fitted pipeline's parameters: ``run(params,
     arr)`` executes the pipeline's batched apply path with the named
@@ -319,7 +293,7 @@ class ParamBinder:
     The binder works on a PRIVATE copy of the pipeline (same graph
     topology, shallow-copied operator objects): trace-time substitution
     mutates operator fields, and the caller's fitted pipeline — shared
-    by every other lane, and the thing ``aot.pipeline_token``
+    by every other lane, and the thing ``featurize_token``
     fingerprints — must never observe a tracer in a field. Concurrent
     traces (two buckets warming on different threads) serialize on the
     binder lock; compiled dispatches never enter ``run`` and pay
@@ -331,7 +305,7 @@ class ParamBinder:
             for nid, op in fitted.graph.operators.items()
         }
         # drop the copied operators' lazily-attached caches (the
-        # underscore-prefixed convention ``aot.pipeline_token`` also
+        # underscore-prefixed convention ``featurize_token`` also
         # relies on): a shallow copy of an already-used pipeline would
         # otherwise SHARE e.g. ``_vmapped_apply`` — a jit closed over
         # the ORIGINAL operator — and substitution would silently not
@@ -417,5 +391,4 @@ __all__ = [
     "params_nbytes",
     "placed_shard_bytes",
     "resolve_param_sharding",
-    "sharding_token",
 ]

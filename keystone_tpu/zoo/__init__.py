@@ -7,13 +7,13 @@ The paper's query-optimizer ideas pointed at serving (ROADMAP
   specs (pipeline factory, buckets, lanes, SLO, optional featurize/
   sharding) plus the JSON spec format ``serve-gateway --zoo`` loads.
 - ``zoo/host.py`` — ``ModelZoo``: hosts one ``Gateway`` per model (or
-  per CSE group) with per-model AOT store namespaces, LRU resident-set
-  paging with pinning, and ``model``-labeled zoo metrics.
+  per CSE group) with LRU resident-set paging with pinning, and
+  ``model``-labeled zoo metrics.
 - ``zoo/optimizer.py`` — the pure placement planner: per-bucket XLA
   cost models + request-size histograms + the per-chip HBM budget in,
   ``PlacementPlan`` (buckets / lanes / replicated-vs-sharded) out.
 - ``zoo/cse.py`` — cross-model featurize CSE: co-hosted models whose
-  fused featurize chains carry identical ``pipeline_token``s share ONE
+  fused featurize chains carry identical ``featurize_token``s share ONE
   multi-head engine that computes the prefix once per window.
 """
 

@@ -4,10 +4,8 @@ KeystoneML's rule engine deduplicates common subexpressions across a
 training DAG; the serving-plane analogue is co-hosted models whose
 fused featurize chains are the SAME chain. Detection is by content,
 not by name: two models share a prefix iff their featurize pipelines'
-``pipeline_token``s — the SHA-256 digest of operator classes, wiring,
-and every parameter array — are equal (``featurize_groups``). That is
-exactly the fingerprint the AOT store trusts to keep one model's
-executable from serving another's predictions, so it is also the
+``featurize_token``s — the SHA-256 digest of operator classes, wiring,
+and every parameter array — are equal (``featurize_groups``), the
 proof two prefixes compute the same function.
 
 ``SharedPrefixEngine`` then hosts one whole group behind one engine:
@@ -23,13 +21,6 @@ every request's future resolves to a per-model dict and the zoo picks
 the measurement seam ``tests/zoo/test_cse.py`` gates on: one
 trace per bucket and one dispatch per window for the whole group,
 where solo hosting pays one of each PER MODEL.
-
-The AOT executable store is deliberately OFF here (``aot_store=None``
-forced): ``CompiledPipeline.warmup`` fingerprints ``self.pipeline``,
-which for a multi-head program is only the primary head — a stored
-entry under that token could later serve a plain single-model engine.
-Shared-prefix programs recompile per process (or replay from the
-persistent XLA compile cache) until the fingerprint covers head sets.
 """
 
 from __future__ import annotations
@@ -50,7 +41,7 @@ logger = logging.getLogger(__name__)
 def featurize_groups(
     featurizers: Dict[str, Any]
 ) -> List[Tuple[str, ...]]:
-    """Group model ids by identical featurize ``pipeline_token``.
+    """Group model ids by identical ``featurize_token``.
     ``featurizers`` maps model id -> fitted featurize pipeline (models
     without one simply aren't candidates — pass only those that have
     one). Returns sorted id tuples, groups of one included: the caller
@@ -62,8 +53,7 @@ def featurize_groups(
             token = featurize_token(fitted)
         except Exception:
             # an unfingerprintable chain can't PROVE it equals another,
-            # so it never shares — same absent-not-broken posture as
-            # the AOT store
+            # so it never shares
             logger.info(
                 "cse: featurize of %s not fingerprintable; hosting "
                 "solo", model_id, exc_info=True,
@@ -95,10 +85,9 @@ class SharedPrefixEngine(CompiledPipeline):
         if len(heads) < 1:
             raise ValueError("need at least one head")
         # deterministic head order: the traced program's output dict
-        # (and therefore its cost model and any serialized form) must
+        # (and therefore its cost model and its compile-cache key) must
         # not depend on dict insertion order at the call site
         self.heads = {mid: heads[mid] for mid in sorted(heads)}
-        kwargs.pop("aot_store", None)  # see module docstring
         # param sharding binds ONE pipeline's params; the multi-head
         # program would need a per-head binder — host sharded models
         # solo instead of silently sharding only the primary head
@@ -111,7 +100,6 @@ class SharedPrefixEngine(CompiledPipeline):
             pipeline=next(iter(self.heads.values())),
             buckets=buckets,
             featurize=featurize,
-            aot_store=None,
             **kwargs,
         )
         # -- per-model attribution inputs (observability/attribution) --
@@ -144,17 +132,13 @@ class SharedPrefixEngine(CompiledPipeline):
         to pure row-share splitting)."""
         return self._split_costs.get(bucket)
 
-    def _register_cost_model(
-        self, bucket: int, fn, staged, want_executable: bool = False
-    ):
+    def _register_cost_model(self, bucket: int, fn, staged) -> None:
         """On top of the whole-program cost model, extract the SPLIT
         one: the shared prefix lowered alone vs each head lowered over
         the prefix's output aval. Same best-effort contract — a backend
         reporting nothing leaves the split absent and attribution
         degrades to row share."""
-        compiled = super()._register_cost_model(
-            bucket, fn, staged, want_executable=want_executable
-        )
+        super()._register_cost_model(bucket, fn, staged)
         try:
             feat_run = self.featurize._batch_run
             prefix_model = device_obs.compiled_cost_model(
@@ -175,7 +159,6 @@ class SharedPrefixEngine(CompiledPipeline):
                 "no split cost model for shared bucket %d", bucket,
                 exc_info=True,
             )
-        return compiled
 
     def _make_jit(self, bucket: int):
         feat_run = self.featurize._batch_run

@@ -2,10 +2,8 @@
 
 Each model (or cross-model CSE group — see ``zoo/cse.py``) is hosted
 as one **unit**: a full ``Gateway`` (admission -> lanes -> micro-batch
--> engines) under the model's own name, its own bucket list, SLO, and
-a per-model **AOT store namespace** (``aot.namespaced_store(model_id)``
-— two models never share a cache slot, and the store GC accounts each
-namespace separately). Lifecycle:
+-> engines) under the model's own name, its own bucket list and SLO.
+Lifecycle:
 
 - **page-in** — a cold model's first request (or an explicit
   ``host()``) builds its artifacts and gateway OUTSIDE the zoo's
@@ -17,10 +15,9 @@ namespace separately). Lifecycle:
   the least-recently-used unpinned unit, whose gateway DRAINS ON A
   BACKGROUND THREAD — paging model B in never stalls model A's
   in-flight windows, and vice versa.
-- **pinning** — ``ModelSpec.pinned`` exempts a model from eviction
-  (and seeds the AOT GC's pinned set).
+- **pinning** — ``ModelSpec.pinned`` exempts a model from eviction.
 - **cross-model CSE** — models hosted together whose featurize
-  ``pipeline_token``s match are fused into ONE shared-prefix unit:
+  ``featurize_token``s match are fused into ONE shared-prefix unit:
   one engine computes the prefix once per window and fans activations
   to every member head (grouping is decided per ``host()`` call — a
   later solo page-in doesn't silently re-plumb a running unit).
@@ -48,7 +45,6 @@ from keystone_tpu.observability.attribution import (
     attribution_document,
 )
 from keystone_tpu.observability.drift import DriftDetector
-from keystone_tpu.serving import aot as aot_lib
 from keystone_tpu.zoo.cse import SharedPrefixEngine, featurize_groups
 from keystone_tpu.zoo.optimizer import (
     ChipBudget,
@@ -138,7 +134,6 @@ class ModelZoo:
         max_resident: Optional[int] = None,
         plan: Optional[PlacementPlan] = None,
         cse: bool = True,
-        aot_namespaces: bool = True,
         metrics_registry=None,
     ):
         if len(registry) == 0:
@@ -149,7 +144,6 @@ class ModelZoo:
         self.plan = plan
         self.max_resident = max_resident
         self._cse = cse
-        self._aot_namespaces = aot_namespaces
         self._lock = threading.Lock()
         self._units: Dict[Tuple[str, ...], _Unit] = {}
         self._by_model: Dict[str, _Unit] = {}  # guarded-by: _lock
@@ -335,15 +329,6 @@ class ModelZoo:
             "param_sharding": param_sharding,
         }
 
-    def _aot_store_for(self, model_id: str):
-        if not self._aot_namespaces:
-            return "auto"
-        store = aot_lib.namespaced_store(model_id)
-        # None (no store dir configured) must mean OFF, not "auto" —
-        # auto would fall back to the process store and put two
-        # models' entries in one undifferentiated namespace
-        return store if store is not None else None
-
     def _build_unit(self, ids: Tuple[str, ...]) -> _Unit:
         """Build one unit's gateway — engines compiled and warmed —
         entirely outside the zoo's resident lock."""
@@ -362,7 +347,6 @@ class ModelZoo:
                 pipeline_depth=spec.pipeline_depth,
                 device_featurize=built.featurize,
                 param_sharding=place["param_sharding"],
-                aot_store=self._aot_store_for(spec.model_id),
                 name=spec.model_id,
                 slo_latency_s=spec.slo_latency_s,
             )

@@ -36,9 +36,8 @@ from typing import Any, Callable, Dict, Iterator, Optional, Tuple
 
 import numpy as np
 
-# a model id rides in URL paths (/predict/<model>), Prometheus label
-# values, and AOT store namespaces — one conservative charset covers
-# all three
+# a model id rides in URL paths (/predict/<model>) and Prometheus
+# label values — one conservative charset covers both
 _ID_RE = re.compile(r"[A-Za-z0-9][A-Za-z0-9_.-]{0,63}")
 
 
@@ -75,7 +74,7 @@ class ModelSpec:
     registering a 100-model zoo must not materialize 100 parameter
     sets). ``expected_sizes`` seeds the placement optimizer before any
     live histogram exists; ``pinned`` exempts the model from LRU
-    eviction AND its AOT entries from store GC."""
+    eviction."""
 
     model_id: str
     build: Callable[[], BuiltModel]
@@ -97,8 +96,8 @@ class ModelSpec:
         if not _ID_RE.fullmatch(self.model_id or ""):
             raise ValueError(
                 f"model id {self.model_id!r} must match "
-                f"{_ID_RE.pattern} (it names URL routes, metric "
-                "labels, and AOT namespaces)"
+                f"{_ID_RE.pattern} (it names URL routes and metric "
+                "labels)"
             )
         self.buckets = tuple(sorted(set(int(b) for b in self.buckets)))
         if not self.buckets or any(b <= 0 for b in self.buckets):

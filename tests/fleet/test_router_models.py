@@ -72,7 +72,7 @@ def _make_zoo_replica(name, model_ids):
             default=(i == 0),
         ))
     zoo = ModelZoo(
-        registry, cse=False, aot_namespaces=False,
+        registry, cse=False,
         metrics_registry=reg,
     )
     zoo.host()

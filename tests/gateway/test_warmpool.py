@@ -1,20 +1,19 @@
 """Warm engine pool: prebuilt-engine swap (build outside the pool
 lock), background next-generation rotation, and the gateway lifecycle
-riding the AOT executable store end to end."""
+riding the persistent compile cache end to end."""
 
 import threading
-import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from compile_cache_fixtures import scratch_compile_cache
 
 from keystone_tpu.gateway.lifecycle import Gateway
 from keystone_tpu.gateway.metrics import GatewayMetrics
 from keystone_tpu.gateway.pool import EnginePool
 from keystone_tpu.observability.registry import MetricsRegistry
-from keystone_tpu.serving import aot
-from keystone_tpu.serving.aot import AotStore
 
 from gateway_fixtures import D, batch, reference
 
@@ -103,54 +102,49 @@ def test_background_swap_after_close_is_a_noop(fitted):
     assert fut.result(timeout=60) is False  # dropped, nothing rotated
 
 
-def test_gateway_lanes_and_next_generation_ride_the_aot_store(
-    fitted, tmp_path, monkeypatch
+def test_gateway_lanes_and_next_generation_ride_the_compile_cache(
+    fitted, tmp_path
 ):
-    """The zero-cold-start lifecycle: with the store configured, every
-    lane engine (and every next-generation engine a swap builds) warms
-    from serialized executables — zero traces after the store is
-    populated."""
-    from keystone_tpu.parallel import runtime
+    """A restarted gateway: with generation 0's programs on the disk,
+    every lane engine of a fresh gateway reads its programs off the
+    disk, the next generation a background swap builds runs no XLA
+    compile either, and the lanes serve the reference outputs."""
+    with scratch_compile_cache(tmp_path / "cache") as log:
+        with Gateway(
+            fitted, buckets=(4,), n_lanes=2, max_delay_ms=2.0,
+            warmup_example=WARM, name="cc-gw-0",
+            registry=MetricsRegistry(),
+        ):
+            pass
 
-    monkeypatch.setattr(runtime, "_aot_dir", None)
-    monkeypatch.setattr(aot, "_configured", None)
-    root = str(tmp_path / "aot")
-    assert runtime.setup_aot_cache(root) == root
-    store = aot.configured_store()
+        jax.clear_caches()
+        log.mark()
+        with Gateway(
+            fitted, buckets=(4,), n_lanes=2, max_delay_ms=2.0,
+            warmup_example=WARM, name="cc-gw-1",
+            registry=MetricsRegistry(),
+        ) as gw:
+            compiles = log.since_mark()
+            assert "jit(staged)" in {name for name, _ in compiles}
+            assert {outcome for _, outcome in compiles} == {"cache_hit"}
+            for lane in gw.pool.lanes:
+                # one trace per bucket, its executable off the disk
+                assert lane.engine.metrics.compile_count == 1
+            old = [lane.engine for lane in gw.pool.lanes]
 
-    # generation 0 populates (misses + saves); lane 1 already hits the
-    # entries lane 0 saved moments earlier
-    with Gateway(
-        fitted, buckets=(4,), n_lanes=2, max_delay_ms=2.0,
-        warmup_example=WARM, name="aot-gw-0",
-        registry=MetricsRegistry(),
-    ):
-        pass
-    saves0, hits0 = store.saves, store.hits
-    assert saves0 >= 1
-
-    # a brand-new "process" (fresh gateway, same store): every lane hits
-    with Gateway(
-        fitted, buckets=(4,), n_lanes=2, max_delay_ms=2.0,
-        warmup_example=WARM, name="aot-gw-1",
-        registry=MetricsRegistry(),
-    ) as gw:
-        assert store.hits >= hits0 + 2
-        for lane in gw.pool.lanes:
-            assert lane.engine.aot_report()[4]["status"] == "hit"
-            assert lane.engine.metrics.compile_count == 0
-        hits1 = store.hits
-        # the warm pool: a same-bucket background rotation deserializes
-        # the next generation instead of compiling it
-        t0 = time.perf_counter()
-        fut = gw.swap_engines((4,), background=True)
-        assert fut.result(timeout=60) is True
-        swap_s = time.perf_counter() - t0
-        assert store.hits >= hits1 + 2
-        for lane in gw.pool.lanes:
-            assert lane.engine.metrics.compile_count == 0
-        out = gw.predict(batch(1, seed=9)[0]).result(timeout=30)
-        assert np.asarray(out).shape == (3,)
-        # not a strict perf assert, just a sanity ceiling: a
-        # deserialize-based rotation must not take compile-scale time
-        assert swap_s < 30
+            log.mark()
+            fut = gw.swap_engines((4,), background=True)
+            assert fut.result(timeout=60) is True
+            assert {outcome for _, outcome in log.since_mark()} <= {
+                "cache_hit"
+            }
+            for lane, before in zip(gw.pool.lanes, old):
+                assert lane.engine is not before
+                assert lane.engine.metrics.compile_count == 1
+            xs = batch(3, seed=9)
+            rows = np.stack([
+                np.asarray(gw.predict(x).result(timeout=30)) for x in xs
+            ])
+    np.testing.assert_allclose(
+        rows, reference(fitted, xs), rtol=1e-5, atol=1e-6
+    )

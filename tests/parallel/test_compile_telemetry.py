@@ -9,6 +9,7 @@ import numpy as np
 import pytest
 from jax import monitoring
 from jax._src import monitoring as monitoring_src
+from compile_cache_fixtures import register_listeners, unregister_listeners
 from profiler_events import parent_names, profiled
 
 from keystone_tpu.observability.registry import (
@@ -32,33 +33,21 @@ _CACHE_KNOBS = (
 )
 
 
-def _register(telemetry):
-    monitoring.register_scalar_listener(telemetry.on_entry)
-    monitoring.register_event_listener(telemetry.on_event)
-    monitoring.register_event_duration_secs_listener(telemetry.on_exit)
-
-
-def _unregister(telemetry):
-    monitoring.unregister_scalar_listener(telemetry.on_entry)
-    monitoring.unregister_event_listener(telemetry.on_event)
-    monitoring.unregister_event_duration_listener(telemetry.on_exit)
-
-
 @pytest.fixture
 def telemetry(monkeypatch):
     """A fresh listener set, log and registry for one test; the
     process's own set is put back afterwards."""
     before = runtime._telemetry
     if before is not None:
-        _unregister(before)
+        unregister_listeners(before)
     monkeypatch.setattr(runtime, "_telemetry", None)
     reset_global_registry()
     runtime.install_compile_telemetry()
     mine = runtime._telemetry
     yield mine
-    _unregister(mine)
+    unregister_listeners(mine)
     if before is not None:
-        _register(before)
+        register_listeners(before)
     reset_global_registry()
 
 
@@ -121,7 +110,7 @@ def test_install_is_idempotent_and_registers_its_families(telemetry):
 def test_setup_compilation_cache_installs_the_listeners(monkeypatch):
     before = runtime._telemetry
     if before is not None:
-        _unregister(before)
+        unregister_listeners(before)
     monkeypatch.setattr(runtime, "_telemetry", None)
     # the cache itself is set up already or not: either way the call
     # installs the listeners, once
@@ -134,9 +123,9 @@ def test_setup_compilation_cache_installs_the_listeners(monkeypatch):
         assert runtime._telemetry is mine
         assert len(_mine(monitoring_src.get_scalar_listeners(), mine)) == 1
     finally:
-        _unregister(runtime._telemetry)
+        unregister_listeners(runtime._telemetry)
         if before is not None:
-            _register(before)
+            register_listeners(before)
 
 
 def test_compiled_at_first_call_cache_hit_after_clear(

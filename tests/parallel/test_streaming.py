@@ -92,7 +92,7 @@ def test_featurized_batches_rides_fused_engine(tar_dir):
     from keystone_tpu.serving.featurize import build_featurize_pipeline
 
     feat, feat_d = build_featurize_pipeline(img=16)
-    engine = feat.compiled(buckets=(8,), aot_store=False)
+    engine = feat.compiled(buckets=(8,))
     loader = StreamingImageNetLoader(
         loc, labels, decode_size=16, shard_index=0, num_shards=1
     )

@@ -171,12 +171,12 @@ FLAGSHIP_GRAPH_AT_7243142 = [
     ("gather", _STR, ["node10", "node18"]),
     ("VectorCombiner", _STR, ["node19"]),
 ]
-# The same seeded pipeline's token. Until PR 31 it read 8c0df507…: a node
-# held by another node (FisherVector's GaussianMixtureModel) gave the token
-# its type name and nothing of its arrays, so two flagships that differed
-# in their GMMs alone shared a token; since PR 31 serving/aot.py folds a
-# held node's fields in (a merged RowwiseRun's nodes need it), which moved
-# this value once and the graph above not at all.
+# The same seeded pipeline's token. It once read 8c0df507…: a node held
+# by another node (FisherVector's GaussianMixtureModel) gave the token its
+# type name and nothing of its arrays, so two flagships that differed in
+# their GMMs alone shared a token; the token now folds a held node's
+# fields in (a merged RowwiseRun's nodes need it), which moved this value
+# once and the graph above not at all.
 FLAGSHIP_TOKEN_AT_7243142 = (
     "d8344da50ba0b95752766b95380831d904994d40ee69f731ff22c1a14ae850cd"
 )
@@ -192,10 +192,12 @@ def test_flagship_pipeline_graph_is_what_it_was():
     """``flagship_pipeline`` (the one ``flagship-score`` runs) and the
     application share ``sift_prefix`` / ``lcs_prefix`` / ``fisher_branch``;
     the seeded pipeline's nodes, their order, their wiring and its
-    parameters (the AOT content token) are what they were when it spelt
+    parameters (the content token) are what they were when it spelt
     the dataflow itself: the same XLA programs, 59 dispatches a step."""
-    from keystone_tpu.serving.aot import pipeline_token
-    from keystone_tpu.serving.featurize import flagship_pipeline
+    from keystone_tpu.serving.featurize import (
+        featurize_token,
+        flagship_pipeline,
+    )
     from keystone_tpu.workflow.graph import NodeId, linearize
 
     pipe = flagship_pipeline(
@@ -213,13 +215,13 @@ def test_flagship_pipeline_graph_is_what_it_was():
                 [str(d) for d in graph.get_dependencies(gid)],
             ))
     assert got == FLAGSHIP_GRAPH_AT_7243142
-    assert pipeline_token(pipe.fit()) == FLAGSHIP_TOKEN_AT_7243142
+    assert featurize_token(pipe.fit()) == FLAGSHIP_TOKEN_AT_7243142
     # and a held node's arrays count: another GMM, another token
     other = pipe.fit()
     fv = next(op for op in other.graph.operators.values()
               if type(op).__name__ == "FisherVector")
     fv.gmm = dataclasses.replace(fv.gmm, means=fv.gmm.means + 1.0)
-    assert pipeline_token(other) != FLAGSHIP_TOKEN_AT_7243142
+    assert featurize_token(other) != FLAGSHIP_TOKEN_AT_7243142
 
 
 def test_flagship_features_separate_textures_and_keep_their_rank():

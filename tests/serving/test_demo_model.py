@@ -1,8 +1,8 @@
 """The served demo model is a function of ``(d, hidden, depth, seed)``:
-``serve-gateway``, ``serve-loadgen``, ``serve-capacity-plan``,
-``serve-aot-build`` and a zoo spec given the same arguments hold the same
-weights. The expected values were recorded at commit 7243142, where the
-model lived in ``keystone_tpu/serving/bench.py``."""
+``serve-gateway``, ``serve-loadgen``, ``serve-capacity-plan`` and a zoo
+spec given the same arguments hold the same weights. The expected values
+were recorded at commit 7243142, where the model lived in
+``keystone_tpu/serving/bench.py``."""
 
 import jax.numpy as jnp
 import numpy as np

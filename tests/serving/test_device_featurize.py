@@ -44,7 +44,7 @@ def raw_batch(n, seed=0):
 def fused_engine(model, featurize, buckets=(2, 4), name=None, **kw):
     feat, _ = featurize
     eng = model.compiled(
-        buckets=buckets, featurize=feat, name=name, aot_store=False, **kw
+        buckets=buckets, featurize=feat, name=name, **kw
     )
     eng.warmup(example=jnp.zeros((IMG, IMG, C), jnp.uint8))
     return eng
@@ -93,7 +93,7 @@ def test_h2d_bytes_accounts_raw_uint8(model, featurize):
     assert s["h2d_bytes_total"] == 4 * RAW_BYTES
     assert s["h2d_bytes_per_example"] == round(4 * RAW_BYTES / 3, 1)
 
-    plain = model.compiled(buckets=(2, 4), aot_store=False, name="dfz-f32")
+    plain = model.compiled(buckets=(2, 4), name="dfz-f32")
     plain.warmup(example=jnp.zeros((feat_d,), jnp.float32))
     feats = np.asarray(feat._batch_run(jnp.asarray(raw_batch(3))))[:3]
     plain.apply(feats, sync=True)
@@ -163,7 +163,7 @@ def test_flagship_branched_dag_fuses_and_matches_two_stage(flagship):
     feat, feat_d = flagship
     model = build_pipeline(d=feat_d, hidden=8, depth=2)
     eng = model.compiled(
-        buckets=(2, 4), featurize=feat, aot_store=False, name="dfz-fl"
+        buckets=(2, 4), featurize=feat, name="dfz-fl"
     )
     eng.warmup(example=jnp.zeros((FIMG, FIMG, C), jnp.uint8))
     assert eng.metrics.compile_count == len(eng.buckets)
@@ -200,7 +200,7 @@ def test_fused_flagship_program_publishes_its_device_series(
     chip's to give."""
     feat, feat_d = flagship
     eng = build_pipeline(d=feat_d, hidden=8, depth=2).compiled(
-        buckets=(2, 4), featurize=feat, aot_store=False,
+        buckets=(2, 4), featurize=feat,
         name="dfz-fl-series",
     )
     eng.warmup(example=jnp.zeros((FIMG, FIMG, C), jnp.uint8))

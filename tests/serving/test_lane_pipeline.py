@@ -527,7 +527,7 @@ def test_uint8_staging_pool_reuse_and_byte_accounting():
     )
     model = build_pipeline(d=feat_d, hidden=8, depth=2)
     engine = model.compiled(
-        buckets=(4,), featurize=feat, aot_store=False, name="u8-pool"
+        buckets=(4,), featurize=feat, name="u8-pool"
     )
     engine.warmup(example=jnp.zeros((img, img, ch), jnp.uint8))
     rng = np.random.default_rng(9)

@@ -223,24 +223,6 @@ def test_param_binder_on_already_used_pipeline():
     np.testing.assert_allclose(out0, 0.0, atol=1e-7)
 
 
-# -- sharding token --------------------------------------------------------
-
-@pytest.mark.needs_mesh8
-def test_sharding_token_varies_by_spec_and_mesh(fitted):
-    params = sharding.named_params(fitted)
-    specs = sharding.match_partition_rules(
-        sharding.DEFAULT_RULES, params
-    )
-    m18 = mesh_lib.make_mesh(n_data=1, n_model=8)
-    m24 = mesh_lib.make_mesh(n_data=2, n_model=4)
-    t = sharding.sharding_token(specs, m18)
-    assert t == sharding.sharding_token(specs, m18)  # deterministic
-    assert t != sharding.sharding_token(specs, m24)  # mesh topology
-    flipped = dict(specs)
-    flipped["0/_Affine/W"] = PS("model", None)
-    assert t != sharding.sharding_token(flipped, m18)  # spec tree
-
-
 # -- the model-sharded engine end to end -----------------------------------
 
 @pytest.mark.needs_mesh8
@@ -337,7 +319,6 @@ def test_model_sharded_rounds_buckets_to_data_shards(fitted):
 def test_model_sharded_warmup_then_no_new_compiles(fitted, mesh18):
     engine = CompiledPipeline(
         fitted, buckets=(4, 8), name="shd-warm", param_sharding=True,
-        aot_store=None,
     )
     engine.warmup(example=jnp.zeros((D,), jnp.float32))
     before = engine.metrics.compile_count

@@ -384,14 +384,14 @@ def test_equal_settings_share_one_program():
 
 
 def test_merged_run_keeps_its_nodes_weights_in_the_model_token():
-    """serving/aot.py keys compiled programs and shared engines by the
+    """serving/featurize.py's token groups shared engines by the
     fitted pipeline's content: the filters inside a merged run count."""
-    from keystone_tpu.serving.aot import pipeline_token
+    from keystone_tpu.serving.featurize import featurize_token
 
     def fitted(seed):
         nodes = chain(seed=seed)
         pipe = nodes[0].and_then(nodes[1]).and_then(nodes[2]).and_then(nodes[3])
         return pipe.and_then(StandardScaler(), images(8)).fit()
 
-    assert pipeline_token(fitted(0)) == pipeline_token(fitted(0))
-    assert pipeline_token(fitted(0)) != pipeline_token(fitted(1))
+    assert featurize_token(fitted(0)) == featurize_token(fitted(0))
+    assert featurize_token(fitted(0)) != featurize_token(fitted(1))

@@ -65,7 +65,7 @@ def test_shared_prefix_engine_matches_solo_per_model(featurize):
     assert sorted(out) == ["alpha", "beta"]
     for mid, head in heads.items():
         solo = CompiledPipeline(
-            head, buckets, featurize=feat, aot_store=None,
+            head, buckets, featurize=feat,
             donate=False, name=f"cse-solo-{mid}",
         )
         want = np.asarray(solo.apply(_raws(3, seed=3), sync=True))

@@ -48,14 +48,13 @@ def _zoo(specs, **kw):
     for spec in specs:
         reg.register(spec)
     kw.setdefault("cse", False)
-    kw.setdefault("aot_namespaces", False)
     kw.setdefault("metrics_registry", MetricsRegistry())
     return ModelZoo(reg, **kw)
 
 
 def _solo_want(head, x, featurize=None):
     eng = CompiledPipeline(
-        head, (2, 4), featurize=featurize, aot_store=None,
+        head, (2, 4), featurize=featurize,
         donate=False,
     )
     return np.asarray(eng.apply(np.asarray(x)[None], sync=True))[0]
@@ -123,7 +122,7 @@ def test_cse_group_shares_one_gateway_with_parity():
         for mid in heads:
             got = np.asarray(zoo.predict(x, mid).result(timeout=60))
             eng = CompiledPipeline(
-                heads[mid], (2, 4), featurize=feat, aot_store=None,
+                heads[mid], (2, 4), featurize=feat,
                 donate=False,
             )
             want = np.asarray(eng.apply(x[None], sync=True))[0]

@@ -46,7 +46,7 @@ def served_zoo():
     registry.register(_spec("alpha", 1, default=True, pinned=True))
     registry.register(_spec("beta", 2))
     zoo = ModelZoo(
-        registry, cse=False, aot_namespaces=False,
+        registry, cse=False,
         metrics_registry=reg,
     )
     zoo.host()
@@ -188,7 +188,7 @@ def test_server_requires_exactly_one_plane():
     registry = ModelRegistry()
     registry.register(_spec("solo", 1, default=True))
     zoo = ModelZoo(
-        registry, cse=False, aot_namespaces=False,
+        registry, cse=False,
         metrics_registry=MetricsRegistry(),
     )
     gw = Gateway(

@@ -55,7 +55,7 @@ def test_duplicate_id_and_second_default_rejected():
     "bad", ["", "-leading", "has space", "slash/id", "x" * 65]
 )
 def test_model_id_charset_enforced(bad):
-    # ids ride URL paths, metric labels, and AOT namespaces
+    # ids ride URL paths, and metric labels
     with pytest.raises(ValueError, match="model id"):
         _spec(bad)
 
