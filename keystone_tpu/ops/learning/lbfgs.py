@@ -113,7 +113,8 @@ def run_lbfgs_device(
     num_corrections: int = 10,
     convergence_tol: float = 1e-4,
     data: tuple = (),
-) -> jnp.ndarray:
+    counted: bool = False,
+):
     """The ENTIRE L-BFGS optimization as one device program: two-loop
     recursion over a ring-buffered (m, ...) history, Armijo backtracking
     via ``lax.while_loop``, convergence test in-graph. Zero host syncs —
@@ -126,11 +127,36 @@ def run_lbfgs_device(
     module-level function or bound method (not a fresh lambda) with the
     arrays in ``data``, or every call re-traces and re-compiles the
     whole nested-loop program (~70 s of XLA compile measured).
+
+    ``counted``: return ``(W, iterations, evaluations)``, the last two
+    int32 device scalars the program counts (iterations taken; calls of
+    ``device_vg``, the first included) — ``count_lbfgs`` adds them to
+    the registry once the caller reads them.
     """
-    return _lbfgs_device_run(
+    w, iterations, evaluations = _lbfgs_device_run(
         device_vg, num_iterations, num_corrections,
         jnp.float32(convergence_tol), jnp.asarray(w0, jnp.float32), *data
     )
+    return (w, iterations, evaluations) if counted else w
+
+
+def count_lbfgs(iterations, evaluations) -> None:
+    """Add one fused fit's iteration and evaluation counts (device
+    scalars from ``run_lbfgs_device(..., counted=True)``: reading them
+    waits for the fit) to ``keystone_solver_lbfgs_iterations_total`` and
+    ``keystone_solver_lbfgs_evaluations_total``."""
+    from keystone_tpu.observability.registry import get_global_registry
+
+    reg = get_global_registry()
+    reg.counter(
+        "keystone_solver_lbfgs_iterations_total",
+        "iterations of fused device L-BFGS fits",
+    ).inc(by=int(iterations))
+    reg.counter(
+        "keystone_solver_lbfgs_evaluations_total",
+        "loss-and-gradient evaluations of fused device L-BFGS fits, "
+        "line-search trials and the first included",
+    ).inc(by=int(evaluations))
 
 
 @partial(
@@ -152,11 +178,11 @@ def _lbfgs_device_run(
     Y = jnp.zeros((m,) + shape, jnp.float32)
 
     def cond(st):
-        it, w, f, g, S, Y, count, done = st
+        it, w, f, g, S, Y, count, done, _ = st
         return (it < num_iterations) & ~done
 
     def body(st):
-        it, w, f, g, S, Y, count, done = st
+        it, w, f, g, S, Y, count, done, evals = st
         n_hist = jnp.minimum(count, m)
 
         # two-loop recursion (ring buffer, newest first)
@@ -212,7 +238,7 @@ def _lbfgs_device_run(
                 f_try, g_try, w_try, ok, tries + 1,
             )
 
-        _, f_new, g_new, w_new, ok, _ = jax.lax.while_loop(
+        _, f_new, g_new, w_new, ok, tries = jax.lax.while_loop(
             ls_cond, ls_body,
             (jnp.float32(1.0), f, g, w, jnp.bool_(False), 0),
         )
@@ -232,13 +258,13 @@ def _lbfgs_device_run(
         keep = lambda new, old: jnp.where(ok, new, old)
         return (
             it + 1, keep(w_new, w), keep(f_new, f), keep(g_new, g),
-            S, Y, count, done,
+            S, Y, count, done, evals + tries,
         )
 
     st = (jnp.int32(0), w0, f0, g0, S, Y, jnp.int32(0),
-          jnp.bool_(False))
-    _, w, _, _, _, _, _, _ = jax.lax.while_loop(cond, body, st)
-    return w
+          jnp.bool_(False), jnp.int32(1))
+    it, w, _, _, _, _, _, _, evals = jax.lax.while_loop(cond, body, st)
+    return w, it, evals
 
 
 def run_lbfgs(
