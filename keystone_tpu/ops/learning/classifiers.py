@@ -23,6 +23,7 @@ from jax.experimental import sparse as jsparse
 
 from keystone_tpu.observability.registry import get_global_registry
 from keystone_tpu.observability.tracing import span
+from keystone_tpu.ops.learning import sparse_buckets
 from keystone_tpu.ops.learning.lbfgs import (
     count_lbfgs,
     run_lbfgs,
@@ -143,302 +144,81 @@ _jit_logistic_vg = jax.jit(_logistic_vg)
 # BCOO products (``bcoo_dot_general``) lower to a gather and a scatter-
 # add per non-zero and each direction; at a chip's share of a 65M-review
 # corpus (358M non-zeros) the TPU compiler fails on them (its stack
-# overflowed). The grouped path reads the BCOO's own arrays in row order
-# and a column-grouped copy derived from them once a fit, in a program
-# of its own (``SparseLogisticLayout.build``, scope ``logreg.layout``):
-# the entries cut into chunks of up to _SORT_CHUNK, each sorted by
-# column (a stable sort, so rows stay ascending inside a column), as row
-# ids and values; and for both orders a flag on each run's first entry
-# (a row, a column run) and the position of each run's last entry.
-#
-# Both directions of the gradient read them (scope ``logreg.grad``), a
-# block of _BLOCK entries at a time. Logits are row sums of v·W[c]: W's
-# columns come by 128-wide row gathers and a lane select
-# (``_take_windowed``; a scalar gather costs the TPU about three times
-# as much an index), summed by a segmented scan in row order and read at
-# each row's last entry. Xᵀ(P − Y) is the same over the column copy: R's
-# rows gathered the same way, v·R[r] scanned, read at each column run's
-# end and added over the chunks: no scatter-add, so no update waits on
-# another to the same hot column. Every sum is a compensated float32 sum
-# (a high and a low part) within one row or one column run. Softmax
-# logits enter only through their differences, so class 0's is held at 0
-# and k − 1 products a direction serve k classes (a row's residuals sum
-# to 0: G_0 = −Σ_{c>0} G_c).
-
-_LANES = 128
-_BLOCK = 1 << 19  # entries a step of the gradient's loops; padding unit
-_SORT_CHUNK = 1 << 24  # entries at most a sort of the column copy
-
-
-def _padded(e: int) -> tuple:
-    """(padded length, sort chunk) of e entries: chunks of at most
-    _SORT_CHUNK, each a whole number of _BLOCK, or one power of two for
-    a small matrix."""
-    if e <= _BLOCK:
-        ep = max(_LANES, 1 << (max(e, 1) - 1).bit_length())
-        return ep, ep
-    chunks = -(-e // _SORT_CHUNK)
-    chunk = -(-(-(-e // chunks)) // _BLOCK) * _BLOCK
-    return chunks * chunk, chunk
-
-
-def _entries(a, i, size: int, e: int, fill, col=None):
-    """Entries [i·size, (i+1)·size) of the first e of ``a`` ((E,), or
-    column ``col`` of (E, 2)), ``fill`` past e."""
-    if col is not None:
-        a = a[:, col] if e < size else a
-    if e < size:
-        return jnp.pad(a, (0, size - e), constant_values=fill)
-    start = i * size
-    src = jnp.minimum(start, e - size)
-    if col is None:
-        part = jax.lax.dynamic_slice(a, (src,), (size,))
-    else:
-        part = jax.lax.dynamic_slice(a, (src, col), (size, 1))[:, 0]
-    part = jnp.roll(part, src - start)
-    return jnp.where(jnp.arange(size) < e - start, part, fill)
-
-
-def _two_sum(a, b):
-    """(a + b rounded, its rounding error): error-free in float32."""
-    s = a + b
-    bb = s - a
-    return s, (a - (s - bb)) + (b - bb)
-
-
-def _add2(x, y):
-    """Sum of two (high, low) float32 pairs as a pair."""
-    s, e = _two_sum(x[0], y[0])
-    return s, x[1] + y[1] + e
-
-
-def _lane_scan(xb, fb):
-    """Inclusive sums along rows of _LANES within runs that begin where
-    ``fb`` is set, as (high, low) float32 pairs (a compensated sum: a
-    hot column's run of millions of residuals cancels to a gradient
-    thousands of times smaller, and a plain float32 scan leaves the
-    rounding of the partial sums in it), and whether a run began in the
-    row at or before each place (lane log-steps)."""
-    f = fb.astype(jnp.int32)
-    x = (xb, jnp.zeros_like(xb))
-    k = 1
-    while k < _LANES:
-        shifted = [jnp.pad(a, ((0, 0), (k, 0)))[:, :-k] for a in x]
-        fs = jnp.pad(f, ((0, 0), (k, 0)))[:, :-k]
-        keep = f == 0
-        x = _add2(x, [jnp.where(keep, a, 0.0) for a in shifted])
-        f = f | fs
-        k *= 2
-    return x[0], x[1], f.astype(jnp.uint8)
-
-
-def _carry(hi, lo, fb):
-    """Rows of lane scans joined: each row's last sum carried into the
-    rows after it that no run began in, by the same scan a level up.
-    Returns ``at(pos)``, the (high, low) pair of sums at positions
-    ``pos`` — read where they are needed, so that the joined (E,) arrays
-    are never made."""
-    b = hi.shape[0]
-    if b == 1:
-        prev = (jnp.zeros((1,), hi.dtype), jnp.zeros((1,), hi.dtype))
-    else:
-        pad = -b % _LANES
-        ch, cl = _segment_scan(
-            (jnp.pad(hi[:, -1], (0, pad)), jnp.pad(lo[:, -1], (0, pad))),
-            jnp.pad(fb[:, -1], (0, pad), constant_values=1))
-        prev = tuple(jnp.concatenate([jnp.zeros((1,), a.dtype), a[:b - 1]])
-                     for a in (ch, cl))
-    flat = [a.reshape(-1) for a in (hi, lo, fb)]
-
-    def at(pos):
-        row = pos // _LANES
-        keep = flat[2][pos] == 0
-        return _add2((flat[0][pos], flat[1][pos]),
-                     tuple(jnp.where(keep, p[row], 0.0) for p in prev))
-
-    return at
-
-
-def _segment_scan(x, start):
-    """Inclusive sums of a (high, low) pair of (E,) arrays within runs
-    that begin where ``start`` (E,) is set (position 0 begins one); E a
-    multiple of _LANES."""
-    hi, lo = x
-    h, l_, f = _lane_scan(hi.reshape(-1, _LANES), start.reshape(-1, _LANES))
-    h2, l2, _ = _lane_scan(lo.reshape(-1, _LANES), start.reshape(-1, _LANES))
-    h, l_ = _add2((h, l_), (h2, l2))
-    return _carry(h, l_, f)(jnp.arange(x[0].shape[0]))
-
-
-def _scan_blocks(step, steps: int):
-    """``_segment_scan`` of values made a block at a time: ``step(i)``
-    gives block i's (values, run starts). Returns ``at(pos)`` (as
-    ``_carry``)."""
-    hi, lo, fb = jax.lax.map(
-        lambda i: _lane_scan(*(a.reshape(-1, _LANES) for a in step(i))),
-        jnp.arange(steps))
-    return _carry(hi.reshape(-1, _LANES), lo.reshape(-1, _LANES),
-                  fb.reshape(-1, _LANES))
-
-
-def _take_windowed(table, idx):
-    """``table[idx]`` for a (T,) float32 table: a 128-wide row gather
-    and a lane select."""
-    t2 = jnp.pad(table, (0, -table.shape[0] % _LANES)).reshape(-1, _LANES)
-    rows = jnp.take(t2, idx >> 7, axis=0, mode="clip")
-    lane = jax.lax.broadcasted_iota(jnp.int32, rows.shape, 1)
-    return jnp.sum(jnp.where(lane == (idx & 127)[:, None], rows, 0.0), 1)
-
-
-def _run_ends(keys, ids):
-    """Position of the last entry of each of ``ids`` among sorted
-    ``keys``, or −1 where it has none."""
-    end = jnp.searchsorted(keys, ids, side="right") - 1
-    found = keys[jnp.maximum(end, 0)] == ids
-    return jnp.where((end >= 0) & found, end, -1).astype(jnp.int32)
-
-
-def _row_runs(rows, ep: int, n: int):
-    """(first-entry flags (ep,), last positions (n,)) of row-ordered
-    row ids; padding entries are runs of their own."""
-    first = jnp.concatenate([jnp.ones((1,), bool), rows[1:] != rows[:-1]])
-    first = jnp.pad(first.astype(jnp.uint8), (0, ep - rows.shape[0]),
-                    constant_values=1)
-    return first, _run_ends(rows, jnp.arange(n, dtype=rows.dtype))
+# overflowed). The grouped path lays the BCOO's entries out twice, once
+# a fit, in a program of its own (``SparseLogisticLayout.build``, scope
+# ``logreg.layout``): grouped by row bucket for X·W and by column bucket
+# for Xᵀ(P − Y), each sorted by its source within a bucket
+# (``sparse_buckets``). Both directions of the gradient are then the
+# bucket kernel (scope ``logreg.grad``): logits are gathered from W's
+# columns and summed into a bucket of rows at a time, Xᵀ(P − Y) gathers
+# the residuals and sums them into a bucket of columns — no scatter-add,
+# so no update waits on another to the same hot column, and every sum
+# is a float32 sum. Softmax logits enter only through their
+# differences, so class 0's is held at 0 and k − 1 products a direction
+# serve k classes (a row's residuals sum to 0: G_0 = −Σ_{c>0} G_c).
 
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(eq=False)
 class SparseLogisticLayout:
-    """What the grouped gradient reads beside the BCOO's own arrays (the
-    section's comment): ``row_start`` (E_p,) and ``row_end`` (n,);
-    ``crows``, ``cvals``, ``col_start`` (E_p,) and ``col_end`` (chunks,
-    d), positions within a chunk. E_p is the entries padded
-    (``_padded``); padding has value 0 and no row or column."""
+    """The grouped gradient's two ``BucketLayout``s of a sparse (n, d)
+    matrix: ``rows`` (destination row, source column: X·W) and ``cols``
+    (destination column, source row: Xᵀ(P − Y))."""
 
-    row_start: Any
-    row_end: Any
-    crows: Any
-    cvals: Any
-    col_start: Any
-    col_end: Any
+    rows: Any
+    cols: Any
 
     @staticmethod
     def build(x: jsparse.BCOO) -> "SparseLogisticLayout":
-        """The layout of an (n, d) BCOO whose rows are in order (the
-        vectorizer's), as one program."""
+        """The layouts of an (n, d) BCOO, entries in any order, as one
+        program."""
         with span("solver.logreg.layout", nnz=int(x.nse)):
             return _build_layout(x.data, x.indices, x.shape)
 
 
-@jax.jit
-def _rows_ordered(rows):
-    return jnp.all(rows[1:] >= rows[:-1])
-
-
-def _row_ordered(x: jsparse.BCOO) -> jsparse.BCOO:
-    """``x`` with its entries in row order: as it is where they are
-    (one read of a flag), else sorted."""
-    if x.nse == 0 or bool(_rows_ordered(x.indices[:, 0])):
-        return x
-    return x.sort_indices()
-
-
 def _logreg_layout(data, indices, shape):
     n, d = shape
-    e = data.shape[0]
-    ep, chunk = _padded(e)
     with jax.named_scope("logreg.layout"):
-        row_start, row_end = _row_runs(indices[:, 0], ep, n)
-
-        ids = jnp.arange(d, dtype=jnp.int32)
-
-        def sort_chunk(i):
-            ck, cr, cv = jax.lax.sort(
-                (_entries(indices, i, chunk, e, d, col=1),
-                 _entries(indices, i, chunk, e, n, col=0),
-                 _entries(data.astype(jnp.float32), i, chunk, e, 0.0)),
-                num_keys=1, is_stable=True)
-            first = jnp.concatenate([jnp.ones((1,), bool), ck[1:] != ck[:-1]])
-            return cr, cv, first.astype(jnp.uint8), _run_ends(ck, ids)
-
-        cr, cv, cfirst, cend = jax.lax.map(
-            sort_chunk, jnp.arange(ep // chunk))
         return SparseLogisticLayout(
-            row_start=row_start, row_end=row_end,
-            crows=cr.reshape(-1), cvals=cv.reshape(-1),
-            col_start=cfirst.reshape(-1), col_end=cend)
+            rows=sparse_buckets.bucket_layout(data, indices, 0, n, d),
+            cols=sparse_buckets.bucket_layout(data, indices, 1, d, n))
 
 
 _build_layout = jax.jit(_logreg_layout, static_argnums=(2,))
 
 
-def _row_products(W, data, indices, row_start, row_end):
-    """(k, n) row sums of v·W[c, :] over row-ordered BCOO arrays."""
-    e = data.shape[0]
-    ep = row_start.shape[0]
-    size = min(_BLOCK, ep)
-
-    def one(w):
-        def step(i):
-            c = _entries(indices, i, size, e, 0, col=1)
-            v = _entries(data.astype(jnp.float32), i, size, e, 0.0)
-            f = jax.lax.dynamic_slice(row_start, (i * size,), (size,))
-            return _take_windowed(w, c) * v, f
-
-        hi, lo = _scan_blocks(step, ep // size)(jnp.maximum(row_end, 0))
-        return jnp.where(row_end >= 0, hi + lo, 0.0)
-
-    return jax.lax.map(one, W.T)
-
-
 @partial(jax.jit, static_argnums=(2,))
 def _sparse_scores(data, indices, n: int, W):
-    """(n, k) = X·W of a row-ordered BCOO's arrays by the grouped path's
-    row products."""
-    row_start, row_end = _row_runs(indices[:, 0], _padded(data.shape[0])[0], n)
-    with jax.named_scope("logreg.grad"):
-        return _row_products(W, data, indices, row_start, row_end).T
+    """(n, k) = X·W of a BCOO's arrays by the bucket kernel."""
+    with jax.named_scope("logreg.scores"):
+        rows = sparse_buckets.bucket_layout(data, indices, 0, n, W.shape[0])
+        return jnp.stack([sparse_buckets.bucket_product(rows, w, n)
+                          for w in W.T], 1)
 
 
-def _sparse_logistic_vg(W, data, indices, layout, onehot, mask, n, reg):
+def _sparse_logistic_vg(W, layout, onehot, mask, n, reg):
     """``_logistic_vg`` on the grouped path: the same mean softmax
     cross-entropy + L2 and gradient, in the ``vg(W, *data)`` shape of
     the fused L-BFGS (module level: one compiled optimizer across
     fits)."""
-    nr = onehot.shape[0]
-    lay = layout
+    nr, d = onehot.shape[0], W.shape[0]
     with jax.named_scope("logreg.grad"):
-        z = _row_products(W[:, 1:] - W[:, :1], data, indices,
-                          lay.row_start, lay.row_end)  # (k − 1, nr)
-    logits = jnp.concatenate([jnp.zeros((nr, 1), jnp.float32), z.T], 1)
-    logz = jax.scipy.special.logsumexp(logits, axis=1)
+        z = [sparse_buckets.bucket_product(layout.rows, w, nr)
+             for w in (W[:, 1:] - W[:, :1]).T]
+    logits = jnp.stack([jnp.zeros((nr,), jnp.float32)] + z, 1)
+    top = jnp.max(logits, axis=1, keepdims=True)
+    e = jnp.exp(logits - top)
+    s = jnp.sum(e, axis=1, keepdims=True)
+    logz = jnp.log(s[:, 0]) + top[:, 0]
     ll = jnp.sum((logz - jnp.sum(logits * onehot, axis=1)) * mask)
-    resid = (jnp.exp(logits - logz[:, None]) - onehot) * mask[:, None]
+    # P − Y to a few ulps of itself: the label's entry is minus the other
+    # classes' share (p − 1 from a float32 p would keep an ulp of 1), and
+    # e / Σe, not exp(z − log Σ), keeps log Σ's rounding out of p
+    others = jnp.sum(e * (1.0 - onehot), axis=1, keepdims=True)
+    resid = jnp.where(onehot > 0, -others, e) / s * mask[:, None]
     with jax.named_scope("logreg.grad"):
-        ep = lay.crows.shape[0]
-        size = min(_BLOCK, ep)
-        chunks = lay.col_end.shape[0]
-        ends = jnp.maximum(lay.col_end, 0)
-
-        def col_sums(r):
-            def step(i):
-                at = i * size
-                rows = jax.lax.dynamic_slice(lay.crows, (at,), (size,))
-                vals = jax.lax.dynamic_slice(lay.cvals, (at,), (size,))
-                f = jax.lax.dynamic_slice(lay.col_start, (at,), (size,))
-                return _take_windowed(r, rows) * vals, f
-
-            at = _scan_blocks(step, ep // size)
-            hi, lo = at(ends + (jnp.arange(chunks) * (ep // chunks))[:, None])
-            found = lay.col_end >= 0
-            total = (jnp.zeros_like(hi[0]), jnp.zeros_like(hi[0]))
-            for c in range(chunks):  # the chunks' run totals, compensated
-                total = _add2(total, (jnp.where(found[c], hi[c], 0.0),
-                                      jnp.where(found[c], lo[c], 0.0)))
-            return total[0] + total[1]
-
-        g = jax.lax.map(col_sums, resid[:, 1:].T)  # (k − 1, d)
+        g = jnp.stack([sparse_buckets.bucket_product(layout.cols, r, d)
+                       for r in resid[:, 1:].T])  # (k − 1, d)
     grad = jnp.concatenate([-jnp.sum(g, 0, keepdims=True), g]).T
     return ll / n + 0.5 * reg * jnp.sum(W * W), grad / n + reg * W
 
@@ -486,11 +266,10 @@ class LogisticRegressionModel(Transformer):
         return jnp.argmax(mm(x, self.W), axis=-1)
 
     def scores_batch(self, ds: Dataset) -> Dataset:
-        """(n, k) logits X·W; BCOO rows take the grouped path's row
-        products."""
+        """(n, k) logits X·W; BCOO rows take the grouped path's bucket
+        kernel."""
         x = ds.padded()
         if _grouped(x):
-            x = _row_ordered(x)
             scores = _sparse_scores(x.data, x.indices, x.shape[0], self.W)
         else:
             scores = mm(x, self.W)
@@ -502,10 +281,11 @@ class LogisticRegressionModel(Transformer):
 
 
 def _grouped(x) -> bool:
-    """BCOO rows of one sparse (row, column) index a non-zero: the
-    grouped path's input."""
+    """BCOO rows of one sparse (row, column) index a non-zero, of a
+    shape whose tables the bucket kernel holds: the grouped path's
+    input."""
     return isinstance(x, jsparse.BCOO) and x.n_batch == 0 \
-        and x.n_dense == 0 and x.ndim == 2
+        and x.n_dense == 0 and x.ndim == 2 and sparse_buckets.fits(*x.shape)
 
 
 @dataclasses.dataclass(eq=False)
@@ -541,11 +321,10 @@ class LogisticRegressionEstimator(LabelEstimator):
         rows = x.shape[0]
         mask = data.mask()
         if _grouped(x):
-            x = _row_ordered(x)
             layout = SparseLogisticLayout.build(x)
             onehot = _device_onehot(labels.array(), k, rows)
             vg = _sparse_logistic_vg
-            args = (x.data, x.indices, layout, onehot)
+            args = (layout, onehot)
         else:
             y = np.asarray(labels.array()).reshape(-1).astype(np.int64)
             if y.size and (y.min() < 0 or y.max() >= k):
@@ -586,11 +365,11 @@ class LogisticRegressionEstimator(LabelEstimator):
                     vg,  # module-level: jit cache shared across fits
                     jnp.zeros((d, k), jnp.float32),
                     self.num_iters, convergence_tol=self.convergence_tol,
-                    data=args, counted=True,
+                    data=args,
                 )
                 count_lbfgs(iters, evals)
             if grouped:  # an out-of-range label poisoned the indicators
-                W = jnp.where(jnp.any(jnp.isnan(args[3])), jnp.nan, W)
+                W = jnp.where(jnp.any(jnp.isnan(args[1])), jnp.nan, W)
             return LogisticRegressionModel(W)
 
         jit_vg = _jit_sparse_logistic_vg if grouped else _jit_logistic_vg

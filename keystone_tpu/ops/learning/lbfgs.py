@@ -113,7 +113,6 @@ def run_lbfgs_device(
     num_corrections: int = 10,
     convergence_tol: float = 1e-4,
     data: tuple = (),
-    counted: bool = False,
 ):
     """The ENTIRE L-BFGS optimization as one device program: two-loop
     recursion over a ring-buffered (m, ...) history, Armijo backtracking
@@ -128,22 +127,20 @@ def run_lbfgs_device(
     arrays in ``data``, or every call re-traces and re-compiles the
     whole nested-loop program (~70 s of XLA compile measured).
 
-    ``counted``: return ``(W, iterations, evaluations)``, the last two
-    int32 device scalars the program counts (iterations taken; calls of
-    ``device_vg``, the first included) — ``count_lbfgs`` adds them to
-    the registry once the caller reads them.
+    Returns ``(W, iterations, evaluations)``, the last two int32 device
+    scalars the program counts (iterations taken; calls of
+    ``device_vg``, the first included): ``count_lbfgs`` adds them to the
+    registry, reading them once the fit is done.
     """
-    w, iterations, evaluations = _lbfgs_device_run(
+    return _lbfgs_device_run(
         device_vg, num_iterations, num_corrections,
         jnp.float32(convergence_tol), jnp.asarray(w0, jnp.float32), *data
     )
-    return (w, iterations, evaluations) if counted else w
 
 
 def count_lbfgs(iterations, evaluations) -> None:
     """Add one fused fit's iteration and evaluation counts (device
-    scalars from ``run_lbfgs_device(..., counted=True)``: reading them
-    waits for the fit) to ``keystone_solver_lbfgs_iterations_total`` and
+    scalars from ``run_lbfgs_device``: reading them waits for the fit) to ``keystone_solver_lbfgs_iterations_total`` and
     ``keystone_solver_lbfgs_evaluations_total``."""
     from keystone_tpu.observability.registry import get_global_registry
 
@@ -379,7 +376,7 @@ class LBFGSwithL2(LabelEstimator, CostModel):
             )
 
         if self.driver == "device":
-            W = run_lbfgs_device(
+            W, iterations, evaluations = run_lbfgs_device(
                 self.gradient.regularized_vg,  # bound method: stable key
                 jnp.zeros((d, k), jnp.float32),
                 self.num_iterations,
@@ -387,6 +384,7 @@ class LBFGSwithL2(LabelEstimator, CostModel):
                 self.convergence_tol,
                 data=(A, b, jnp.float32(self.reg_param), jnp.float32(n)),
             )
+            count_lbfgs(iterations, evaluations)
         else:
             def vg(w_flat: np.ndarray):
                 W = jnp.asarray(

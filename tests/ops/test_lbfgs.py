@@ -108,7 +108,7 @@ def test_device_lbfgs_line_search_failure_terminates():
         # claims descent direction -g, but f grows along it
         return jnp.sum(w * w) + 1.0, -jnp.ones_like(w)
 
-    w = run_lbfgs_device(bad_vg, jnp.zeros((4, 2)), 10)
+    w, _, _ = run_lbfgs_device(bad_vg, jnp.zeros((4, 2)), 10)
     assert np.isfinite(np.asarray(w)).all()
     # at f32 resolution the backtracked step may be accepted at rounding
     # noise; the property that matters is no runaway along the bogus
@@ -129,8 +129,10 @@ def test_device_lbfgs_convergence_tol_is_traced():
     A = jnp.eye(8) * jnp.arange(1.0, 9.0)
     w0 = jnp.ones((8,))
     before = _lbfgs_device_run._cache_size()
-    w1 = run_lbfgs_device(quad_vg, w0, 50, convergence_tol=1e-2, data=(A,))
-    w2 = run_lbfgs_device(quad_vg, w0, 50, convergence_tol=1e-8, data=(A,))
+    w1, _, _ = run_lbfgs_device(quad_vg, w0, 50, convergence_tol=1e-2,
+                                 data=(A,))
+    w2, _, _ = run_lbfgs_device(quad_vg, w0, 50, convergence_tol=1e-8,
+                                 data=(A,))
     after = _lbfgs_device_run._cache_size()
     assert after - before == 1  # one compile for both tolerances
     # tighter tolerance gets at least as close to the optimum (0)

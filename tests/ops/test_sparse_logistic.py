@@ -8,7 +8,7 @@ import jax.numpy as jnp
 from jax.experimental import sparse as jsparse
 
 from keystone_tpu.observability.registry import get_global_registry
-from keystone_tpu.ops.learning import classifiers
+from keystone_tpu.ops.learning import sparse_buckets
 from keystone_tpu.ops.learning.classifiers import (
     LogisticRegressionEstimator,
     LogisticRegressionModel,
@@ -16,6 +16,7 @@ from keystone_tpu.ops.learning.classifiers import (
 from keystone_tpu.parallel.dataset import Dataset
 
 N, D = 3000, 5000
+CHIP_WIDTHS = (sparse_buckets.BUCKET, sparse_buckets.UNROLL)
 
 
 def rows(seed, case="zipf"):
@@ -61,13 +62,24 @@ def reference(r, c, v, y, W):
     return loss, g / N
 
 
+@pytest.fixture(autouse=True)
+def quick_kernel(monkeypatch):
+    """A narrower bucket and window unroll than the chip's: the Pallas
+    interpreter runs a kernel's unrolled loops op by op, and the
+    products do not depend on either (``test_bucket_product_*`` holds
+    the chip's widths)."""
+    monkeypatch.setattr(sparse_buckets, "BUCKET", 128)
+    monkeypatch.setattr(sparse_buckets, "UNROLL", 2)
+
+
 @pytest.fixture(params=[False, True], ids=["one_chunk", "many_chunks"])
 def chunks(request, monkeypatch):
-    """Small blocks and sort chunks, so that a fit of this size crosses
-    their boundaries as the full size does."""
+    """Small grid steps and sort chunks, so that a fit of this size
+    crosses their boundaries as the full size does."""
     if request.param:
-        monkeypatch.setattr(classifiers, "_BLOCK", 1024)
-        monkeypatch.setattr(classifiers, "_SORT_CHUNK", 8192)
+        monkeypatch.setattr(sparse_buckets, "STEP_REGS", 2)
+        monkeypatch.setattr(sparse_buckets, "STEP", 2 * sparse_buckets.REG)
+        monkeypatch.setattr(sparse_buckets, "_SORT_CHUNK", 8192)
     return request.param
 
 
@@ -88,6 +100,36 @@ def test_gradient_matches_float64(chunks, case, order):
     assert abs(float(f) - want_f) <= 1e-6 * abs(want_f)
     assert np.linalg.norm(np.asarray(g) - want_g) \
         <= 1e-5 * np.linalg.norm(want_g)
+
+
+@pytest.mark.parametrize("direction", ["rows", "cols"])
+@pytest.mark.parametrize("order", ["sorted", "any"])
+def test_bucket_product_matches_float64(monkeypatch, direction, order):
+    """The bucket kernel at the chip's bucket width and unroll, both ways
+    (X·w: destination row; Xᵀ·r: destination column), each sum within a
+    few float32 ulps of the sum of its terms' magnitudes."""
+    monkeypatch.setattr(sparse_buckets, "BUCKET", CHIP_WIDTHS[0])
+    monkeypatch.setattr(sparse_buckets, "UNROLL", CHIP_WIDTHS[1])
+    r, c, v, _ = rows(10)
+    keep = np.random.default_rng(11).random(r.size) < 0.25
+    r, c, v = r[keep], c[keep], v[keep]
+    if order == "any":
+        perm = np.random.default_rng(12).permutation(r.size)
+        r, c, v = r[perm], c[perm], v[perm]
+    dst_col = 0 if direction == "rows" else 1
+    dst, src = (r, c) if dst_col == 0 else (c, r)
+    n_dst, n_src = (N, D) if dst_col == 0 else (D, N)
+    table = np.random.default_rng(13).standard_normal(n_src).astype(
+        np.float32)
+    idx = jnp.asarray(np.stack([r, c], 1).astype(np.int32))
+    layout = sparse_buckets.bucket_layout(jnp.asarray(v), idx, dst_col,
+                                          n_dst, n_src)
+    got = np.asarray(sparse_buckets.bucket_product(
+        layout, jnp.asarray(table), n_dst))
+    terms = v.astype(np.float64) * table[src]
+    want = np.bincount(dst, terms, n_dst)
+    scale = np.bincount(dst, np.abs(terms), n_dst)
+    assert (np.abs(got - want) <= 1e-6 * scale).all()
 
 
 @pytest.mark.parametrize("k", [2, 3])
